@@ -1,14 +1,12 @@
 """Secondary benchmark: BERT-base fine-tune throughput (sequences/sec) on
-one chip (BASELINE.md metric 2). Same hardened architecture as bench.py:
-the parent never imports jax; each attempt is a child process with a hard
-wall-clock timeout, demoting batch on OOM/timeout with a labeled CPU
-fallback. Prints ONE JSON line.
+one chip (BASELINE.md metric 2). Same architecture as bench.py: the parent
+never imports jax; each attempt is a child process on ``TPUPlace(0)`` with
+a hard wall-clock timeout, demoting batch on OOM/timeout. A run that finds
+no TPU exits non-zero and prints no result. Prints ONE JSON line.
 """
 
 import json
 import os
-import signal
-import subprocess
 import sys
 import time
 
@@ -24,46 +22,19 @@ def _hb(msg):
 
 
 def child_main(cfg):
-    if cfg["platform"]:
-        os.environ["JAX_PLATFORMS"] = cfg["platform"]
-    import jax
-
     import bench
 
-    bench.honor_jax_platforms(jax)
-    bench.enable_compilation_cache(jax)
+    place = bench.chip_start()
+    import jax
     import numpy as np
 
     import paddle_tpu.fluid as fluid
     from paddle_tpu.models import bert
 
-    if cfg["platform"] == "cpu":
-        place = fluid.CPUPlace()
-        device = "cpu"
-    elif fluid.core.get_tpu_device_count() == 0:
-        print("CHILDERR " + json.dumps({"kind": "no_tpu", "msg": "no tpu"}),
-              flush=True)
-        sys.exit(1)
-    else:
-        place = fluid.TPUPlace(0)
-        device = "tpu"
     dev = fluid.core.get_jax_device(place)
-    import jax.numpy as jnp
-
-    _hb("probe start")
-    jax.jit(lambda a: (a @ a).sum())(
-        jax.device_put(jnp.ones((256, 256), jnp.bfloat16), dev)
-    ).block_until_ready()
-    _hb("probe ok")
-
     batch = cfg["batch"]
     seq_len = int(cfg.get("seq_len", DEFAULT_SEQ_LEN))
-    bcfg = (
-        bert.BertConfig() if cfg["full"] else bert.BertConfig(
-            hidden_size=256, num_layers=4, num_heads=4,
-            intermediate_size=1024,
-        )
-    )
+    bcfg = bert.BertConfig()
     bcfg.hidden_dropout = 0.0
     bcfg.attention_dropout = 0.0
     # fused Pallas flash attention (opt-in probe: BENCH_FLASH=1 or cfg)
@@ -118,7 +89,7 @@ def child_main(cfg):
     assert np.isfinite(lval), lval
     sps = batch * steps / dt
     _hb("timed ok %.2fs loss=%.4f sps=%.1f" % (dt, lval, sps))
-    result = {"sps": sps, "device": device, "loss": lval}
+    result = {"sps": sps, "device": "tpu", "loss": lval}
     # dense path only: cost analysis cannot see inside the flash Pallas
     # custom call, so a flash census would undercount (PERF.md round-5)
     if not bcfg.use_flash_attention:
@@ -131,27 +102,6 @@ def child_main(cfg):
     print("RESULT " + json.dumps(result), flush=True)
 
 
-def _child_entry(cfg):
-    try:
-        child_main(cfg)
-    except SystemExit:
-        raise
-    except Exception as e:  # classify for the parent (bench.py contract)
-        msg = str(e)
-        if "RESOURCE_EXHAUSTED" in msg or "out of memory" in msg.lower():
-            kind = "oom"
-        elif "UNAVAILABLE" in msg or "DEADLINE_EXCEEDED" in msg:
-            kind = "transient"
-        else:
-            kind = "other"
-        import traceback
-
-        traceback.print_exc(file=sys.stderr)
-        print("CHILDERR " + json.dumps({"kind": kind, "msg": msg[:300]}),
-              flush=True)
-        sys.exit(1)
-
-
 def main():
     import bench
 
@@ -161,22 +111,15 @@ def main():
     # batch scales down with seq len so the attempt fits the same slot
     big, small = (64, 16) if seq <= 128 else (24, 8)
     attempts = [
-        (dict(platform="", batch=big, steps=10, warmup=2, full=True,
-              seq_len=seq, flash=flash), 420),
-        (dict(platform="", batch=small, steps=10, warmup=2, full=True,
-              seq_len=seq, flash=flash), 360),
-        # the CPU fallback pins seq 128 AND flash off: the Pallas kernel
-        # cannot run there (the op silently uses the dense reference), so
-        # a flash_attention:true CPU line would be false provenance
-        (dict(platform="cpu", batch=4, steps=3, warmup=1, full=False,
-              seq_len=128, flash=False), 280),
+        (dict(batch=big, steps=10, warmup=2, seq_len=seq, flash=flash), 420),
+        (dict(batch=small, steps=10, warmup=2, seq_len=seq, flash=flash),
+         360),
     ]
     for cfg, slot in attempts:
-        label = "bert-%s-b%d-s%d%s" % (
-            cfg["platform"] or "tpu", cfg["batch"], cfg["seq_len"],
-            "-flash" if cfg["flash"] else "",
+        label = "bert-tpu-b%d-s%d%s" % (
+            cfg["batch"], cfg["seq_len"], "-flash" if cfg["flash"] else "",
         )
-        res, _kind, err, _probe_ok = bench._run_attempt(
+        res, _kind, err = bench._run_attempt(
             label, cfg, slot, deadline,
             script=os.path.abspath(__file__),
         )
@@ -184,49 +127,25 @@ def main():
             print("bench_bert[%s]: %s" % (label, err), file=sys.stderr,
                   flush=True)
         if res:
-            degraded = cfg["platform"] == "cpu" or not cfg["full"]
-            # single source of truth for baselines: bench.py (BASELINE.md
-            # documents the per-seq-len provenance)
-            baseline = bench.V100_BERT_BASE_SEQ_PER_SEC.get(cfg["seq_len"])
-            out = {
-                "metric": METRIC,
-                "value": round(res["sps"], 2),
-                "unit": UNIT,
-                # null when degraded OR the seq len has no documented constant
-                "vs_baseline": (
-                    round(res["sps"] / baseline, 3)
-                    if baseline and not degraded else None
-                ),
-                "batch": cfg["batch"],
-                "seq_len": cfg["seq_len"],
-                "device": res["device"],
-            }
-            if cfg["flash"]:
-                out["flash_attention"] = True
-            # propagate the child's fresh census (dense rungs only — the
-            # child skips it for flash) so a standalone run re-banks
-            # flops/bytes like the bench.py driver path does
-            for k in ("flops", "bytes_accessed", "out_bytes"):
-                if res.get(k) is not None:
-                    out[k] = res[k]
-                    out["census_source"] = "live_census"
-            if res["device"] == "tpu" and not degraded:
-                bench.bank_write(
-                    "bert_seq%d%s" % (cfg["seq_len"], "_flash" if cfg["flash"] else ""),
-                    bench._bank_entry(out),
-                )
-            if degraded:
-                out["degraded"] = "cpu-fallback tiny-config"
+            # single source of truth for lines and baselines: bench.py
+            # (BASELINE.md documents the per-seq-len provenance); the
+            # child's fresh census rides along for dense rungs
+            out = bench._bert_line(res, cfg["batch"], cfg["seq_len"],
+                                   cfg["flash"])
+            bench.bank_write(
+                "bert_seq%d%s" % (cfg["seq_len"], "_flash" if cfg["flash"] else ""),
+                bench._bank_entry(out),
+            )
             print(json.dumps(out), flush=True)
-            return
-    print(json.dumps({
-        "metric": METRIC, "value": 0.0, "unit": UNIT, "vs_baseline": None,
-        "error": "all attempts failed",
-    }), flush=True)
+            return 0
+    print("bench_bert: all attempts failed", file=sys.stderr, flush=True)
+    return 1
 
 
 if __name__ == "__main__":
     if len(sys.argv) >= 3 and sys.argv[1] == "--child":
-        _child_entry(json.loads(sys.argv[2]))
+        import bench
+
+        bench._child_entry(json.loads(sys.argv[2]), child_main)
     else:
-        main()
+        sys.exit(main())

@@ -1,18 +1,16 @@
 """Tertiary benchmark: GPT-2-small causal-LM training throughput
 (tokens/sec) on one chip. Exercises the CAUSAL flash-attention path (the
 in-kernel `causal` flag, no dense [T, T] bias) that neither headline
-metric covers. Same hardened architecture as bench.py / bench_bert.py:
-the parent never imports jax; each attempt is a child process with a hard
-wall-clock timeout, demoting batch on OOM/timeout with a labeled CPU
-fallback. Prints ONE JSON line. ``vs_baseline`` compares the seq-1024
-full config against the DERIVED V100-era constant below (BASELINE.md
-provenance); other configs report null.
+metric covers. Same architecture as bench.py / bench_bert.py: the parent
+never imports jax; each attempt is a child process on ``TPUPlace(0)`` with
+a hard wall-clock timeout, demoting batch on OOM/timeout. A run that finds
+no TPU exits non-zero and prints no result. Prints ONE JSON line.
+``vs_baseline`` compares the seq-1024 config against the DERIVED V100-era
+constant below (BASELINE.md provenance); other configs report null.
 """
 
 import json
 import os
-import signal  # noqa: F401  (parity with sibling harnesses' imports)
-import subprocess  # noqa: F401
 import sys
 import time
 
@@ -38,49 +36,22 @@ def _hb(msg):
 
 
 def child_main(cfg):
-    if cfg["platform"]:
-        os.environ["JAX_PLATFORMS"] = cfg["platform"]
-    import jax
-
     import bench
 
-    bench.honor_jax_platforms(jax)
-    bench.enable_compilation_cache(jax)
+    place = bench.chip_start()
+    import jax
     import numpy as np
 
     import paddle_tpu.fluid as fluid
     from paddle_tpu.models import gpt
 
-    if cfg["platform"] == "cpu":
-        place = fluid.CPUPlace()
-        device = "cpu"
-    elif fluid.core.get_tpu_device_count() == 0:
-        print("CHILDERR " + json.dumps({"kind": "no_tpu", "msg": "no tpu"}),
-              flush=True)
-        sys.exit(1)
-    else:
-        place = fluid.TPUPlace(0)
-        device = "tpu"
     dev = fluid.core.get_jax_device(place)
-    import jax.numpy as jnp
-
-    _hb("probe start")
-    jax.jit(lambda a: (a @ a).sum())(
-        jax.device_put(jnp.ones((256, 256), jnp.bfloat16), dev)
-    ).block_until_ready()
-    _hb("probe ok")
-
     batch = cfg["batch"]
     seq_len = int(cfg.get("seq_len", DEFAULT_SEQ_LEN))
-    gcfg = (
-        gpt.GPTConfig(
-            # long-context rungs (seq 4096) need a position table larger
-            # than GPT-2's stock 1024; growing it is the only change
-            max_position_embeddings=max(1024, seq_len),
-        ) if cfg["full"] else gpt.GPTConfig(
-            vocab_size=2048, hidden_size=256, num_layers=4, num_heads=4,
-            intermediate_size=1024, max_position_embeddings=seq_len,
-        )
+    gcfg = gpt.GPTConfig(
+        # long-context rungs (seq 4096) need a position table larger
+        # than GPT-2's stock 1024; growing it is the only change
+        max_position_embeddings=max(1024, seq_len),
     )
     # throughput config: dropout off (same convention as bench_bert)
     gcfg.hidden_dropout = 0.0
@@ -130,29 +101,8 @@ def child_main(cfg):
     assert np.isfinite(lval), lval
     tps = batch * seq_len * steps / dt
     _hb("timed ok %.2fs loss=%.4f tps=%.1f" % (dt, lval, tps))
-    print("RESULT " + json.dumps({"tps": tps, "device": device, "loss": lval}),
+    print("RESULT " + json.dumps({"tps": tps, "device": "tpu", "loss": lval}),
           flush=True)
-
-
-def _child_entry(cfg):
-    try:
-        child_main(cfg)
-    except SystemExit:
-        raise
-    except Exception as e:  # classify for the parent (bench.py contract)
-        msg = str(e)
-        if "RESOURCE_EXHAUSTED" in msg or "out of memory" in msg.lower():
-            kind = "oom"
-        elif "UNAVAILABLE" in msg or "DEADLINE_EXCEEDED" in msg:
-            kind = "transient"
-        else:
-            kind = "other"
-        import traceback
-
-        traceback.print_exc(file=sys.stderr)
-        print("CHILDERR " + json.dumps({"kind": kind, "msg": msg[:300]}),
-              flush=True)
-        sys.exit(1)
 
 
 def main():
@@ -164,22 +114,15 @@ def main():
     # batch scales down with seq len so the attempt fits the same slot
     big, small = (16, 4) if seq <= 1024 else (4, 1)
     attempts = [
-        (dict(platform="", batch=big, steps=10, warmup=2, full=True,
-              seq_len=seq, flash=flash), 420),
-        (dict(platform="", batch=small, steps=10, warmup=2, full=True,
-              seq_len=seq, flash=flash), 360),
-        # CPU fallback: tiny config, short seq, flash off (the kernel
-        # cannot run there — a flash:true CPU line would be false
-        # provenance, same rule as bench_bert)
-        (dict(platform="cpu", batch=4, steps=3, warmup=1, full=False,
-              seq_len=128, flash=False), 280),
+        (dict(batch=big, steps=10, warmup=2, seq_len=seq, flash=flash), 420),
+        (dict(batch=small, steps=10, warmup=2, seq_len=seq, flash=flash),
+         360),
     ]
     for cfg, slot in attempts:
-        label = "gpt-%s-b%d-s%d%s" % (
-            cfg["platform"] or "tpu", cfg["batch"], cfg["seq_len"],
-            "-flash" if cfg["flash"] else "",
+        label = "gpt-tpu-b%d-s%d%s" % (
+            cfg["batch"], cfg["seq_len"], "-flash" if cfg["flash"] else "",
         )
-        res, _kind, err, _probe_ok = bench._run_attempt(
+        res, _kind, err = bench._run_attempt(
             label, cfg, slot, deadline,
             script=os.path.abspath(__file__),
         )
@@ -187,44 +130,38 @@ def main():
             print("bench_gpt[%s]: %s" % (label, err), file=sys.stderr,
                   flush=True)
         if res:
-            degraded = cfg["platform"] == "cpu" or not cfg["full"]
-            # the derived V100 constant (BASELINE.md) covers exactly the
-            # seq-1024 GPT-2-small config; anything else reports null
-            vs = (
-                round(res["tps"] / V100_GPT2_SMALL_TOK_PER_SEC, 3)
-                if not degraded and cfg["seq_len"] == 1024
-                else None
-            )
             out = {
                 "metric": METRIC,
                 "value": round(res["tps"], 1),
                 "unit": UNIT,
-                "vs_baseline": vs,
+                # the derived V100 constant (BASELINE.md) covers exactly
+                # the seq-1024 GPT-2-small config; anything else is null
+                "vs_baseline": (
+                    round(res["tps"] / V100_GPT2_SMALL_TOK_PER_SEC, 3)
+                    if cfg["seq_len"] == 1024 else None
+                ),
                 "batch": cfg["batch"],
                 "seq_len": cfg["seq_len"],
                 "device": res["device"],
             }
             if cfg["flash"]:
                 out["flash_attention"] = True
-            if res["device"] == "tpu" and not degraded:
-                bench.bank_write(
-                    "gpt_seq%d%s" % (
-                        cfg["seq_len"], "_flash" if cfg["flash"] else ""
-                    ),
-                    bench._bank_entry(out),
-                )
-            if degraded:
-                out["degraded"] = "cpu-fallback tiny-config"
+            bench.bank_write(
+                "gpt_seq%d%s" % (
+                    cfg["seq_len"], "_flash" if cfg["flash"] else ""
+                ),
+                bench._bank_entry(out),
+            )
             print(json.dumps(out), flush=True)
-            return
-    print(json.dumps({
-        "metric": METRIC, "value": 0.0, "unit": UNIT, "vs_baseline": None,
-        "error": "all attempts failed",
-    }), flush=True)
+            return 0
+    print("bench_gpt: all attempts failed", file=sys.stderr, flush=True)
+    return 1
 
 
 if __name__ == "__main__":
     if len(sys.argv) >= 3 and sys.argv[1] == "--child":
-        _child_entry(json.loads(sys.argv[2]))
+        import bench
+
+        bench._child_entry(json.loads(sys.argv[2]), child_main)
     else:
-        main()
+        sys.exit(main())

@@ -20,6 +20,15 @@ from ..ops import registry as _registry
 from ..ops.registry import LowerCtx, _FakeOp
 
 
+def _guard_backend():
+    """Backend of the ``dygraph.guard(place)`` in force: eager values are
+    put on that Place's device (``to_variable``), so eager lowerings pick
+    their device-native code for it."""
+    from .. import framework
+
+    return core._jax_backend_for(framework._current_expected_place())
+
+
 class VarBase(object):
     """Eager tensor: jax.Array + grad slot (reference: imperative/layer.h:55)."""
 
@@ -202,15 +211,12 @@ class Tracer(object):
             out_names[slot] = [v.name for v in vs]
 
         fake = _FakeOp(type, in_names, out_names, dict(attrs or {}))
-        import jax
-
-        # eager ops run on the default jax device; pick layouts for it
-        _registry.set_lowering_backend(jax.default_backend())
         # host ops (print, detection/NMS, tree walks, ...) read and write
         # through ctx.scope; in eager mode the env IS the scope
         ctx = LowerCtx(env=env, base_key=self._next_key(),
                        scope=_EnvScope(env))
-        opdef.lower(ctx, fake)
+        with _registry.lowering_on(_guard_backend()):
+            opdef.lower(ctx, fake)
 
         for slot, vs in out_vars.items():
             for v in vs:
@@ -231,11 +237,12 @@ class Tracer(object):
 
     # -- backward (reference: BasicEngine::Execute, engine.cc) --
     def run_backward(self, loss, backward_strategy=None):
-        import jax
+        with _registry.lowering_on(_guard_backend()):
+            self._run_backward(loss, backward_strategy)
+
+    def _run_backward(self, loss, backward_strategy):
         import jax.numpy as jnp
 
-        # eager grad ops run on the default jax device; set once per replay
-        _registry.set_lowering_backend(jax.default_backend())
         sorted_sum = bool(
             backward_strategy is not None
             and getattr(backward_strategy, "sorted_sum_gradient", False)

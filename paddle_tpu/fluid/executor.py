@@ -724,7 +724,14 @@ class _CompiledBlock(object):
 
         fetch_set = set(self.fetch_names)
         self._plans = []
-        device_backend = core._jax_backend_for(place)
+        # the backend this block lowers FOR: a mesh's own devices where
+        # there is one (state lives there whatever Place the executor
+        # holds), else the Place's
+        on_mesh = spmd.mesh if spmd is not None else mesh
+        device_backend = (
+            on_mesh.devices.flat[0].platform if on_mesh is not None
+            else core._jax_backend_for(place)
+        )
         self.device_backend = device_backend
         self._check_tp_segment_safety()
         # `{name}@SEQ_LEN` companion availability: from LoD feeds and from
@@ -839,7 +846,7 @@ class _CompiledBlock(object):
             # armed.
             donate = (
                 (1,)
-                if (device_backend not in (None, "cpu")
+                if (device_backend != "cpu"
                     or getattr(program, "_donate_mutable", False))
                 and not getattr(program, "_keep_mutable", False)
                 else ()
@@ -995,9 +1002,9 @@ class _CompiledBlock(object):
         }
 
         backend = self.device_backend
+        gspmd_mesh = self.spmd.mesh if self.spmd is not None else None
 
         def fn(feed_vals, mutable_vals, sharded_vals, const_map, rng_key):
-            _registry.set_lowering_backend(backend)
             env = {}
             for n, v in zip(feeds, feed_vals):
                 env[n] = v
@@ -1010,8 +1017,9 @@ class _CompiledBlock(object):
                 env=env, base_key=rng_key, mesh_axes=mesh_axes, block=block,
                 dist_specs=dist_specs,
             )
-            for op_ in seg.ops:
-                _registry.run_op(ctx, op_)
+            with _registry.lowering_on(backend, mesh=gspmd_mesh):
+                for op_ in seg.ops:
+                    _registry.run_op(ctx, op_)
             return tuple(env[n] for n in out_names)
 
         return fn
@@ -1200,6 +1208,13 @@ class _CompiledBlock(object):
                         "program first)" % n
                     )
                 const_map[n] = _to_device(v, state_dev_for(n))
+                if (self.spmd is not None and const_map[n] is not v
+                        and n in self._persistable and n not in local_env):
+                    # commit the placement: a read-only var (a served
+                    # model's weights) never comes back as an output, so
+                    # left as it was it is resharded from where startup
+                    # put it — one device — on every step
+                    scope.set(n, const_map[n])
             outs = self._dispatch(
                 plan, tuple(feed_vals), tuple(mutable_vals),
                 tuple(sharded_vals), const_map, rng_key,
@@ -1279,6 +1294,9 @@ class Executor(object):
 
     def __init__(self, place=None):
         self.place = place if place is not None else core.CPUPlace()
+        if isinstance(self.place, core.TPUPlace):
+            # a chip that is absent fails here, not at the first run
+            core.get_jax_device(self.place)
         from collections import OrderedDict
 
         self._cache = OrderedDict()  # bounded LRU, see _cache_put

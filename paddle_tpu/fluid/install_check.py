@@ -29,10 +29,7 @@ def run_check():
     from .framework import Program, program_guard
     from . import unique_name
 
-    place = (
-        core.TPUPlace(0) if core.get_tpu_device_count() > 0
-        else core.CPUPlace()
-    )
+    place = core.default_place()
     np_inp = np.array([[1.0, 2.0], [3.0, 4.0]], dtype=np.float32)
 
     def build():

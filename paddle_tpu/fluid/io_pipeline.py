@@ -69,18 +69,13 @@ _END = _Sentinel()
 
 
 def resolve_device(place):
-    """Place -> jax Device, or None when staging is impossible (no place,
-    no jax, backend init failure) — the caller degrades to host batches."""
-    if place is None:
-        return None
+    """Place -> jax Device; None only when no place was given (the caller
+    then hands out host batches). A place that names no device raises."""
     if isinstance(place, (list, tuple)):
         place = place[0] if place else None
-        if place is None:
-            return None
-    try:
-        return core.get_jax_device(place)
-    except Exception:
+    if place is None:
         return None
+    return core.get_jax_device(place)
 
 
 class DeviceFeeder(object):

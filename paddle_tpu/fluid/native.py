@@ -1,7 +1,10 @@
 """ctypes bindings for the native C++ runtime library (csrc/).
 
-The library is compiled on first use with g++ (cached next to the source,
-keyed by source mtime). Components and their reference counterparts:
+The library is compiled on first use with g++ and cached next to the
+source, keyed by a hash of the ``.cpp`` files stored beside the binary: a
+binary whose stamp is missing or names other sources is rebuilt, so what
+loads was always built from the sources in this tree. Components and their
+reference counterparts:
 
 - ``serialize_tensor``/``deserialize_tensor`` — the LoDTensor stream format
   (framework/tensor_util.cc TensorToStream), byte-identical to the Python
@@ -15,6 +18,7 @@ keyed by source mtime). Components and their reference counterparts:
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import random
 import subprocess
@@ -25,6 +29,7 @@ import numpy as np
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _CSRC = os.path.join(_HERE, "..", "csrc")
 _SO = os.path.join(_CSRC, "_build", "libpaddle_tpu_native.so")
+_STAMP = _SO + ".sha256"
 
 _lib = None
 _lib_lock = threading.Lock()
@@ -39,7 +44,24 @@ def _sources():
     )
 
 
-def _compile():
+def _source_digest():
+    h = hashlib.sha256()
+    for path in _sources():
+        h.update(os.path.basename(path).encode() + b"\0")
+        with open(path, "rb") as f:
+            h.update(f.read())
+    return h.hexdigest()
+
+
+def _stamped_digest():
+    try:
+        with open(_STAMP) as f:
+            return f.read().strip()
+    except OSError:
+        return None
+
+
+def _compile(digest):
     os.makedirs(os.path.dirname(_SO), exist_ok=True)
     # compile to a per-pid temp file and rename: concurrent worker processes
     # must never CDLL a half-written library
@@ -50,6 +72,12 @@ def _compile():
     ]
     subprocess.run(cmd, check=True, capture_output=True)
     os.replace(tmp, _SO)
+    # stamp AFTER the binary: a crash in between leaves a stale stamp,
+    # which only costs the next process a rebuild
+    tmp = "%s.%d.tmp" % (_STAMP, os.getpid())
+    with open(tmp, "w") as f:
+        f.write(digest + "\n")
+    os.replace(tmp, _STAMP)
 
 
 def _load():
@@ -58,12 +86,9 @@ def _load():
         if _lib is not None or _compile_error is not None:
             return _lib
         try:
-            src_mtime = max(os.path.getmtime(s) for s in _sources())
-            if (
-                not os.path.exists(_SO)
-                or os.path.getmtime(_SO) < src_mtime
-            ):
-                _compile()
+            digest = _source_digest()
+            if not os.path.exists(_SO) or _stamped_digest() != digest:
+                _compile(digest)
             lib = ctypes.CDLL(_SO)
         except Exception as e:  # no g++ / compile failure -> Python fallback
             _compile_error = e

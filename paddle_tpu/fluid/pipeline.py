@@ -243,11 +243,12 @@ class PipelineProgram(object):
         def fn(env_in, key):
             import jax
 
-            _registry.set_lowering_backend(jax.default_backend())
             env = dict(env_in)
             ctx = LowerCtx(env=env, base_key=key, block=block)
-            for o in ops:
-                _registry.run_op(ctx, o)
+            # stages sit on jax.devices(): the default backend IS the target
+            with _registry.lowering_on(jax.default_backend()):
+                for o in ops:
+                    _registry.run_op(ctx, o)
             return {n: env[n] for n in out_names if n in env}
 
         import jax

@@ -67,7 +67,10 @@ class AnalysisConfig(object):
             self._model_dir = model_dir
             self._model_filename = None
             self._params_filename = None
-        self._use_tpu = True
+        # None: the predictor follows jax's default backend;
+        # enable_use_gpu / disable_gpu pin the TPU / the CPU, and a pinned
+        # TPU that is absent raises at predictor construction
+        self._use_tpu = None
         self._device_id = 0
         self._memory_optim = True
         self._ir_optim = True
@@ -95,7 +98,13 @@ class AnalysisConfig(object):
         self._use_tpu = False
 
     def use_gpu(self):
-        return self._use_tpu
+        return isinstance(self._place(), core.TPUPlace)
+
+    def _place(self):
+        if self._use_tpu is None:
+            return core.default_place()
+        return (core.TPUPlace(self._device_id) if self._use_tpu
+                else core.CPUPlace())
 
     def switch_ir_optim(self, x=True):
         self._ir_optim = x
@@ -194,11 +203,7 @@ class AnalysisPredictor(object):
 
     def __init__(self, config):
         self._config = config
-        self._place = (
-            core.TPUPlace(config._device_id)
-            if config._use_tpu and core.get_tpu_device_count() > 0
-            else core.CPUPlace()
-        )
+        self._place = config._place()
         self._scope = core.Scope()
         from ..fluid.executor import Executor
 
@@ -830,11 +835,7 @@ class _ShardedPredictor(object):
                 self._scope.set(k, z[k])
         self._feed_names = list(meta["feed_order"])
         self._fetch_names = list(meta["fetch_names"])
-        self._place = (
-            core.TPUPlace(0)
-            if core.get_tpu_device_count() > 0
-            else core.CPUPlace()
-        )
+        self._place = core.default_place()
         self._exe = Executor(self._place)
         axes = dict(mesh_axes if mesh_axes is not None
                     else meta.get("mesh_axes") or {})

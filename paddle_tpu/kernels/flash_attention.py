@@ -41,9 +41,9 @@ Bias comes in two flavors, usable together:
   bias shared across the batch ([1, N, Sq, Sk]) is handled by running
   the whole attention head-major (role swap B↔N in ``flash_attention``).
 
-The kernels run on the TPU backend (or anywhere under ``interpret=True``
-for tests); ``flash_attention`` transparently falls back to the jnp
-reference on other backends so models stay portable.
+The kernels run when the trace lowers for the TPU (``lowers_for_tpu``) or
+anywhere under ``interpret=True`` (tests); a trace that lowers for another
+backend takes the jnp reference, so models stay portable.
 """
 
 from __future__ import annotations
@@ -84,6 +84,19 @@ def _hash_keep(rows, cols, head, seed_u32, rate):
     # keep iff hash < keep_prob * 2^32 (threshold is static)
     thresh = int((1.0 - float(rate)) * 4294967296.0)
     return n < u(min(thresh, 4294967295))
+
+
+def lowers_for_tpu():
+    """Kernel or reference, decided once per trace: by the backend the
+    executor (or dygraph tracer) is lowering FOR, which it resolved from
+    its Place — a CPUPlace program on a chip host takes the reference, a
+    TPUPlace program takes the kernels and a kernel the chip's compiler
+    refuses raises. Called outside any fluid trace (a bare
+    ``flash_attention`` under the caller's own jit), jax's default
+    backend is the only target there is."""
+    from ..fluid.ops.registry import lowering_backend
+
+    return (lowering_backend() or jax.default_backend()) == "tpu"
 
 
 def reference_attention(q, k, v, bias=None, causal=False, scale=None):
@@ -296,10 +309,10 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, key_bias_ref, bias_ref, do_ref,
     dk = jnp.zeros((block_k, k.shape[-1]), jnp.float32)
     dv = jnp.zeros((block_k, k.shape[-1]), jnp.float32)
     dkb = jnp.zeros((1, block_k), jnp.float32)
-    dbias = (
-        None if dbias_ref is None
-        else jnp.zeros((q_len, block_k), jnp.float32)
-    )
+    # per-q-block ds tiles of the general-bias gradient column block;
+    # joined on the (tile-aligned) row axis at the end — Mosaic has no
+    # dynamic_update_slice on values
+    ds_blocks = []
 
     for ib in range(n_qb):
         qs = slice(ib * block_q, (ib + 1) * block_q)
@@ -342,13 +355,15 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, key_bias_ref, bias_ref, do_ref,
             preferred_element_type=jnp.float32,
         )
         dkb = dkb + ds.sum(axis=0, keepdims=True)
-        if dbias is not None:
-            dbias = jax.lax.dynamic_update_slice(dbias, ds, (ib * block_q, 0))
+        if dbias_ref is not None:
+            ds_blocks.append(ds)
 
     dk_ref[0] = (dk * scale).astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
     dkb_ref[0] = dkb
     if dbias_ref is not None:
+        dbias = jnp.concatenate(ds_blocks, axis=0)     # [q_len, BK]
+
         # heads h with equal h // bias_group share one gradient row;
         # they are consecutive on the (innermost) head axis
         @pl.when(h % bias_group == 0)
@@ -420,7 +435,7 @@ def flash_decode_attention(q, k, v, key_bias=None, scale=None,
         )
     scale = scale if scale is not None else 1.0 / float(np.sqrt(D))
     kb = _normalize_key_bias(key_bias, B, N, Sk)
-    on_tpu = jax.default_backend() == "tpu"
+    on_tpu = lowers_for_tpu()
     if interpret is None and not on_tpu:
         # dense fallback: bit-compatible math with reference_attention
         s = jnp.einsum("bnqd,bnkd->bnqk", q, k).astype(jnp.float32) * scale
@@ -467,7 +482,10 @@ def _decode_paged_kernel(tables_ref, q_ref, k_ref, v_ref, kb_ref, o_ref,
     the output block is written once on the last logical block. Same
     masking contract as ``_decode_kernel``: the per-slot key bias
     carries ALL masking, including sink-block garbage past the slot's
-    live length."""
+    live length. The bias rides as the slot's whole [max_blocks, block]
+    table (a block whose trailing dims equal the array's, which the
+    Mosaic (8, 128) rule admits where a lone (1, block) strip is
+    refused); the program picks its logical block's row."""
     from jax.experimental import pallas as pl
 
     i = pl.program_id(2)
@@ -481,8 +499,8 @@ def _decode_paged_kernel(tables_ref, q_ref, k_ref, v_ref, kb_ref, o_ref,
     q = q_ref[0, 0]                               # [BQ, D], input dtype
     kblk = k_ref[0, 0]                            # [blk, D]
     block_k = kblk.shape[0]
-    s = _scores(q, kblk, scale, kb_ref[0], None, 0, 0, False,
-                block_q, block_k)
+    s = _scores(q, kblk, scale, kb_ref[0, pl.ds(i, 1), :], None, 0, 0,
+                False, block_q, block_k)
     m = m_ref[...]
     m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
     alpha = jnp.exp(m - m_new)
@@ -534,7 +552,7 @@ def flash_decode_paged_attention(q, k_pool, v_pool, tables, key_bias=None,
         )
     scale = scale if scale is not None else 1.0 / float(np.sqrt(D))
     kb = _normalize_key_bias(key_bias, B, N, S)
-    on_tpu = jax.default_backend() == "tpu"
+    on_tpu = lowers_for_tpu()
     tables = tables.astype(jnp.int32)
     if interpret is None and not on_tpu:
         # dense fallback: gather the logical rows, then the same math as
@@ -552,9 +570,16 @@ def flash_decode_paged_attention(q, k_pool, v_pool, tables, key_bias=None,
             s = s + kb.reshape(B, N, 1, S)
         p = jax.nn.softmax(s, axis=-1)
         return jnp.einsum("bnqk,bnkd->bnqd", p.astype(q.dtype), rows_v)
-    if kb is None:
-        kb = jnp.zeros((B * N, S), jnp.float32)
-    kb = kb.reshape(B, N, S)
+    # [G, max_blocks, block] with G = B (one mask per slot, what the
+    # engine feeds) or B*N (per head): a per-slot mask is NOT expanded
+    # over heads — its block index ignores n, so the DMA is skipped
+    # across a slot's whole head and block sweep
+    if key_bias is not None and key_bias.size == B * S:
+        kb = key_bias.astype(jnp.float32)
+    elif kb is None:
+        kb = jnp.zeros((B, S), jnp.float32)
+    per_head = kb.size != B * S
+    kb = kb.reshape(-1, MB, blk)
     BQ = _round_up(Sq, 8)                      # Mosaic sublane minimum
     qp = jnp.pad(q, ((0, 0), (0, 0), (0, BQ - Sq), (0, 0)))
     kernel = functools.partial(
@@ -575,7 +600,9 @@ def flash_decode_paged_attention(q, k_pool, v_pool, tables, key_bias=None,
             pl.BlockSpec((1, 1, blk, D),
                          lambda b, n, i, t: (t[b, i], n, 0, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 1, blk), lambda b, n, i, t: (b, n, i),
+            pl.BlockSpec((1, MB, blk),
+                         lambda b, n, i, t: (b * N + n if per_head else b,
+                                             0, 0),
                          memory_space=pltpu.VMEM),
         ],
         out_specs=pl.BlockSpec((1, 1, BQ, D),
@@ -1061,7 +1088,7 @@ def flash_attention_lse(q, k, v, key_bias=None, bias=None, causal=False,
     seed = _norm_seed(dropout_seed)
     scale = scale if scale is not None else 1.0 / float(np.sqrt(d))
     kb = _normalize_key_bias(key_bias, B, N, Sk)
-    on_tpu = jax.default_backend() == "tpu"
+    on_tpu = lowers_for_tpu()
     if interpret is None and not on_tpu:
         # dense fallback with an explicit lse (same math as the kernels)
         s = jnp.einsum("bnqd,bnkd->bnqk", q, k).astype(jnp.float32) * scale

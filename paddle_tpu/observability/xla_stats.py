@@ -96,11 +96,13 @@ INTERESTING_OPS = (
     "all-reduce", "custom-call",
 )
 
-# `%name = <type> opcode(...)`; the type may be a tuple `(f32[..], ..)`
-# for multi-output fusions, so the type part must admit parentheses
+# `%name = <type> opcode(...)`: the opcode is the first lower-case word
+# followed by "(" after the "=". The type before it may be a tuple
+# `(f32[..], ..)` for multi-output fusions and, on the TPU, carries tiled
+# layouts `{1,0:T(8,128)(2,1)S(1)}` — parentheses, colons and upper-case
+# tags, but never a lower-case word before a parenthesis
 _HLO_OP_RE = re.compile(
-    r"^\s*(?:ROOT\s+)?%?[\w.\-]+\s*=\s*[\w\[\],{}()\s/]*\s"
-    r"([a-z][a-z\-]*)\(",
+    r"^\s*(?:ROOT\s+)?%?[\w.\-]+\s*=\s*[^\n]*?\s([a-z][a-z\-]*)\(",
     re.M,
 )
 
@@ -120,12 +122,8 @@ def interesting_ops(hist):
 
 def cost_summary(raw_cost):
     """{"flops", "bytes_accessed", "out_bytes"} from a
-    ``Compiled.cost_analysis()`` result (list-of-dict or dict across jax
-    versions; missing keys surface as None)."""
-    if isinstance(raw_cost, (list, tuple)):
-        cost = raw_cost[0] if raw_cost else {}
-    else:
-        cost = raw_cost or {}
+    ``Compiled.cost_analysis()`` dict (missing keys surface as None)."""
+    cost = raw_cost or {}
     return {
         "flops": cost.get("flops"),
         "bytes_accessed": cost.get("bytes accessed"),
@@ -146,9 +144,14 @@ def executable_census(compiled):
             )
         except Exception:
             pass
-    hist = op_census(compiled.as_text())
+    text = compiled.as_text()
+    hist = op_census(text)
     out["hlo_ops"] = hist
     out["total_hlo_ops"] = sum(hist.values())
+    # Pallas (Mosaic) kernels in the program. The TPU compiler adds
+    # custom calls of its own, so the opcode count above cannot tell
+    # whether a kernel is there or gave way to its jnp reference
+    out["pallas_calls"] = text.count('custom_call_target="tpu_custom_call"')
     return out
 
 

@@ -19,23 +19,12 @@ def _jax():
 
 
 def shard_map(fn, mesh, in_specs, out_specs):
-    """Version-portable jax shard_map wrapper (param names moved across
-    jax releases)."""
+    """``jax.shard_map`` without the varying-manual-axes check (the
+    lowerings' collectives are written against explicit axis names)."""
     import jax
 
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm  # type: ignore
-    for kwargs in (
-        dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False),
-        dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False),
-        dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs),
-    ):
-        try:
-            return sm(fn, **kwargs)
-        except TypeError:
-            continue
-    raise RuntimeError("no compatible jax shard_map signature found")
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def build_mesh(axes, devices=None):

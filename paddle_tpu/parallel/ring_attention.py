@@ -70,12 +70,13 @@ def ring_attention(q, k, v, axis_name, causal=False, scale=None,
     Default (None): flash on the TPU backend, dense elsewhere;
     ``interpret`` forces the Pallas interpreter for tests.
     """
-    import jax
     import jax.lax as lax
     import jax.numpy as jnp
 
     if use_flash is None:
-        use_flash = jax.default_backend() == "tpu" or bool(interpret)
+        from ..kernels.flash_attention import lowers_for_tpu
+
+        use_flash = lowers_for_tpu() or bool(interpret)
 
     n = lax.psum(1, axis_name)
     my_idx = lax.axis_index(axis_name)

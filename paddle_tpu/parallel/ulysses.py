@@ -44,7 +44,6 @@ def ulysses_attention(mesh, axis_name="sp", causal=False, use_flash=None,
     backend, dense elsewhere; ``interpret`` forces the Pallas interpreter
     for tests. ``causal`` masks by global position (exact, since the
     sequence is whole on each device here)."""
-    import jax
     import jax.lax as lax
     from jax.sharding import PartitionSpec as P
 
@@ -53,7 +52,9 @@ def ulysses_attention(mesh, axis_name="sp", causal=False, use_flash=None,
     def local_fn(q, k, v):
         flash = use_flash
         if flash is None:
-            flash = jax.default_backend() == "tpu" or bool(interpret)
+            from ..kernels.flash_attention import lowers_for_tpu
+
+            flash = lowers_for_tpu() or bool(interpret)
         if q.shape[2] % sp != 0:
             raise ValueError(
                 "ulysses_attention: head count %d must divide by sp=%d"

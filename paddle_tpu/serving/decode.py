@@ -608,7 +608,8 @@ class DecodeSession(object):
                 max_len,
                 _flag("decode_prefill_buckets", prefill_buckets) or None,
             )
-        self.place = place if place is not None else fluid.CPUPlace()
+        self.place = (place if place is not None
+                      else fluid.core.default_place())
         self.scope = scope if scope is not None else fluid.core.Scope()
         # own executor: the session's program/plan caches never contend
         # with (or evict) a caller's LRU entries
@@ -1512,7 +1513,11 @@ class DecodeEngine(object):
                  block_size=None, spec_tokens=None, spec_draft=None,
                  pool_blocks=0, drafter=None, tp=None):
         self._cfg = cfg
-        self._place = place
+        self._place = (place if place is not None
+                       else fluid.core.default_place())
+        # a Place that names no device of this process fails here, not
+        # in start()
+        fluid.core.get_jax_device(self._place)
         self._scope = scope
         # tensor-parallel serving over the GSPMD mesh: the replica's
         # device count; the session shards weights/KV over it

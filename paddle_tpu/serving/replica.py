@@ -94,12 +94,13 @@ def build_gpt_decode_engine(spec):
             cfg, max_len
         )
     startup.random_seed = seed
-    exe = fluid.Executor(fluid.CPUPlace())
+    place = fluid.core.default_place()
+    exe = fluid.Executor(place)
     scope = fluid.core.Scope()
     with fluid.executor.scope_guard(scope):
         exe.run(startup)
-    return DecodeEngine(cfg, scope=scope, slots=slots, max_len=max_len,
-                        prefill_buckets=buckets,
+    return DecodeEngine(cfg, place=place, scope=scope, slots=slots,
+                        max_len=max_len, prefill_buckets=buckets,
                         param_program=infer_prog)
 
 
@@ -129,7 +130,10 @@ def main(argv=None):
     args = ap.parse_args(argv)
 
     # heavy imports AFTER argparse: --help must not pay for jax
-    from paddle_tpu import inference, serving
+    from paddle_tpu import compile_cache, inference, serving
+
+    # a respawned replica compiles its whole ladder again: keep it cached
+    compile_cache.enable()
     from paddle_tpu.distributed import supervisor as _supervisor
     from paddle_tpu.observability import exporter as _obs_exporter
 

@@ -88,16 +88,16 @@ def test_conv_nhwc_matches_nchw(monkeypatch):
 
 def test_use_nhwc_flag_gate():
     from paddle_tpu.fluid import flags
-    from paddle_tpu.fluid.ops.registry import set_lowering_backend
+    from paddle_tpu.fluid.ops.registry import lowering_on
 
     try:
-        set_lowering_backend("tpu")
-        assert nn_ops._use_nhwc()
-        flags.set_flags({"FLAGS_conv_nhwc": False})
-        assert not nn_ops._use_nhwc()
-        flags.set_flags({"FLAGS_conv_nhwc": True})
-        set_lowering_backend("cpu")
-        assert not nn_ops._use_nhwc()
+        with lowering_on("tpu"):
+            assert nn_ops._use_nhwc()
+            flags.set_flags({"FLAGS_conv_nhwc": False})
+            assert not nn_ops._use_nhwc()
+            flags.set_flags({"FLAGS_conv_nhwc": True})
+        with lowering_on("cpu"):
+            assert not nn_ops._use_nhwc()
+        assert not nn_ops._use_nhwc()  # outside any trace
     finally:
-        set_lowering_backend(None)
         flags.set_flags({"FLAGS_conv_nhwc": True})

@@ -26,6 +26,40 @@ SPEC_K = 4
 
 
 # -- op units ---------------------------------------------------------------
+@pytest.mark.parametrize("mask", ["per_slot", "per_head", "none"])
+@pytest.mark.parametrize("dtype,tol", [("float32", 1e-5), ("bfloat16", 3e-2)])
+def test_paged_flash_kernel_matches_gather_reference(mask, dtype, tol):
+    """flash_decode_paged_attention's Pallas kernel (interpret mode)
+    against its gather-then-softmax reference, through permuted tables,
+    with the key bias in each layout the kernel's [G, max_blocks, block]
+    table admits: one mask per slot (what the engine feeds, G = slots),
+    one per head (G = slots*heads), and none."""
+    import importlib
+
+    import jax.numpy as jnp
+
+    fa = importlib.import_module("paddle_tpu.kernels.flash_attention")
+    S, H, D, MB = SLOTS, 2, 16, 5
+    NB = S * MB + 1
+    rs = np.random.RandomState(0)
+    q = jnp.asarray(rs.randn(S, H, 1, D), dtype)
+    k_pool = jnp.asarray(rs.randn(NB, H, BLOCK, D), dtype)
+    v_pool = jnp.asarray(rs.randn(NB, H, BLOCK, D), dtype)
+    tables = jnp.asarray(rs.permutation(NB - 1)[:S * MB].reshape(S, MB) + 1)
+    live = np.array([3, 11, 20])
+    kb = np.where(np.arange(MB * BLOCK)[None] < live[:, None], 0.0, -1e4)
+    if mask == "per_head":
+        kb = np.repeat(kb, H, 0) + 0.1 * rs.randn(S * H, MB * BLOCK)
+    kb = None if mask == "none" else jnp.asarray(kb, "float32")
+    want = fa.flash_decode_paged_attention(q, k_pool, v_pool, tables,
+                                           key_bias=kb)
+    got = fa.flash_decode_paged_attention(q, k_pool, v_pool, tables,
+                                          key_bias=kb, interpret=True)
+    assert got.shape == (S, H, 1, D) and got.dtype == q.dtype
+    np.testing.assert_allclose(np.asarray(got, "float32"),
+                               np.asarray(want, "float32"), atol=tol)
+
+
 def test_kv_cache_paged_write_gather_ops():
     """The paged scatter/gather pair through arbitrary runtime tables:
     a permuted write lands each token at tables[s, pos//B] offset

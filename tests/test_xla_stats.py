@@ -67,21 +67,26 @@ def test_op_census_parses_hlo_shapes_and_tuples():
         "  %t = f32[4,8]{0,1} transpose(%p0), dimensions={1,0}",
         "  ROOT %fused = (f32[4,8]{1,0}, f32[]) fusion(%t), kind=kLoop",
         "  %d = f32[8,8]{1,0} dot(%p0, %t)",
+        # what the TPU compiler prints: tiled layouts inside the type
+        "  %c.1 = f32[8,3,8,64]{3,2,1,0:T(8,128)S(1)} custom-call(%d), "
+        'custom_call_target="tpu_custom_call"',
+        "  %f.2 = (bf16[48,1024,64]{2,1,0:T(8,128)(2,1)S(1)}, "
+        "f32[48,1024,1]{2,1,0:T(8,128)}) fusion(%c.1), kind=kOutput",
     ])
     hist = xla_stats.op_census(hlo)
-    assert hist == {"parameter": 1, "transpose": 1, "fusion": 1, "dot": 1}
+    assert hist == {"parameter": 1, "transpose": 1, "fusion": 2, "dot": 1,
+                    "custom-call": 1}
     interesting = xla_stats.interesting_ops(hist)
     assert interesting["transpose"] == 1 and interesting["dot"] == 1
     assert interesting["convolution"] == 0  # zero-filled
     assert set(interesting) == set(xla_stats.INTERESTING_OPS)
 
 
-def test_cost_summary_handles_list_and_dict_and_missing():
+def test_cost_summary_handles_dict_and_missing():
     cost = {"flops": 8.0, "bytes accessed": 32.0,
             "bytes accessedout{}": 16.0}
-    assert xla_stats.cost_summary([cost]) == {
+    assert xla_stats.cost_summary(cost) == {
         "flops": 8.0, "bytes_accessed": 32.0, "out_bytes": 16.0}
-    assert xla_stats.cost_summary(cost)["flops"] == 8.0
     empty = xla_stats.cost_summary(None)
     assert empty == {"flops": None, "bytes_accessed": None,
                      "out_bytes": None}
@@ -97,6 +102,7 @@ def test_executable_census_on_real_compiled_fn():
     assert census["flops"] and census["flops"] > 0
     assert census["bytes_accessed"] and census["bytes_accessed"] > 0
     assert census["total_hlo_ops"] == sum(census["hlo_ops"].values())
+    assert census["pallas_calls"] == 0  # plain XLA, no kernel
 
 
 # ---------------------------------------------------------------------------
@@ -199,7 +205,7 @@ def test_census_missing_cost_keys_stay_none_not_zero():
 
     class Stub(object):
         def cost_analysis(self):
-            return [{}]
+            return {}
 
         def memory_analysis(self):
             raise RuntimeError("n/a")
@@ -520,13 +526,13 @@ def test_bench_lines_skip_census_for_flash_and_stamp_provenance():
 
     result = {"sps": 10.0, "device": "tpu", "flops": 1e12,
               "bytes_accessed": 2e9, "out_bytes": 1e8}
-    dense = bench._bert_line(result, 24, 384, [], False)
+    dense = bench._bert_line(result, 24, 384)
     assert dense["flops"] == 1e12
     assert dense["census_source"] == "live_census"
-    flash = bench._bert_line(result, 24, 384, [], False, flash=True)
+    flash = bench._bert_line(result, 24, 384, flash=True)
     for k in ("flops", "bytes_accessed", "out_bytes", "census_source"):
         assert k not in flash
-    rn = bench._resnet_line(dict(result, ips=10.0), 256, [], False)
+    rn = bench._resnet_line(dict(result, ips=10.0), 256)
     assert rn["census_source"] == "live_census"
 
 
