@@ -1,0 +1,14 @@
+"""Median over the window's ticks of ``tick_sample_emit``'s duration
+less its ``cpu_ms`` (the loop thread's CPU time inside it): time the
+loop thread held no CPU in a phase that makes no device call, so it was
+waiting for the interpreter lock or for a core."""
+
+from benchmark.harness import program_spans as ps
+from benchmark.harness.stats import median
+
+
+def read(ev):
+    xs = [ps.ms(s) - s["args"]["cpu_ms"]
+          for s in ps.named(ps.in_window(ev), "tick_sample_emit")
+          if s["args"].get("cpu_ms") is not None]
+    return median(xs) if xs else None

@@ -18,6 +18,8 @@ XLA behavior and are recorded but inert (SURVEY.md §2 #15).
 
 from __future__ import annotations
 
+import time
+
 import numpy as np
 
 from . import core
@@ -264,7 +266,6 @@ class CompiledProgram(object):
 
     def _run(self, executor, feed=None, fetch_list=None, scope=None,
              return_numpy=True):
-        from . import executor as _executor_mod
         from ..observability import trace as _obs_trace
 
         # user-injected pass pipeline (BuildStrategy pass builder,
@@ -275,6 +276,33 @@ class CompiledProgram(object):
                                  stage="pass_builder"):
                 pb.apply(self._program)
             self._passes_applied = True
+        if not (self._is_spmd_mesh or (self._is_data_parallel
+                                       and self._device_count() > 1)):
+            return executor.run(
+                self._program,
+                feed=feed,
+                fetch_list=fetch_list,
+                scope=scope,
+                return_numpy=return_numpy,
+            )
+        # the tail is the executor's own, so the mesh path records what
+        # Executor.run records: executor_run (prepare_ms, the block's
+        # phases) and executor_fetch
+        t_in = time.perf_counter()
+        compiled, scope, feed, fetch_names, hit = self._prepare(
+            executor, feed, fetch_list, scope
+        )
+        rng_key = executor._rng_for(compiled, self._program, scope)
+        return executor._run_compiled(
+            compiled, scope, feed, rng_key, fetch_names, return_numpy,
+            t_in, hit,
+        )
+
+    def _prepare(self, executor, feed, fetch_list, scope):
+        """Feed normalisation and the compiled block for this mesh. ->
+        (compiled block, scope, feed, fetch names, whether it was cached)"""
+        from . import executor as _executor_mod
+
         scope = scope or core.global_scope()
         feed = dict(feed or {})
         fetch_list = fetch_list or []
@@ -315,7 +343,8 @@ class CompiledProgram(object):
                 ),
             )
             compiled = executor._cache_get(key)
-            if compiled is None:
+            hit = compiled is not None
+            if not hit:
                 compiled = _executor_mod._CompiledBlock(
                     self._program,
                     0,
@@ -325,18 +354,7 @@ class CompiledProgram(object):
                     spmd=plan,
                 )
                 executor._cache_put(key, compiled)
-            return self._finish_run(
-                executor, compiled, scope, feed, return_numpy
-            )
-
-        if not self._is_data_parallel or self._device_count() == 1:
-            return executor.run(
-                self._program,
-                feed=feed,
-                fetch_list=fetch_list,
-                scope=scope,
-                return_numpy=return_numpy,
-            )
+            return compiled, scope, feed, fetch_names, hit
 
         mesh = self._get_mesh()
         self._apply_grad_allreduce(mesh)
@@ -349,10 +367,11 @@ class CompiledProgram(object):
             extra=("spmd", tuple(zip(mesh.axis_names, mesh.devices.shape))),
         )
         compiled = executor._cache_get(key)
+        hit = compiled is not None
         # _version is part of the key: a hit can never be stale — and a
         # miss builds a _CompiledBlock whose own instrumentation records
         # the build/compiles under a key carrying the spmd mesh extra
-        if compiled is None:
+        if not hit:
             mesh_axes = dict(
                 zip(mesh.axis_names, mesh.devices.shape)
             )
@@ -366,25 +385,7 @@ class CompiledProgram(object):
                 mesh=mesh,
             )
             executor._cache_put(key, compiled)
-        return self._finish_run(executor, compiled, scope, feed, return_numpy)
-
-    def _finish_run(self, executor, compiled, scope, feed, return_numpy):
-        from . import executor as _executor_mod
-        from .executor import _fetch_to_host
-
-        # same rng-skip contract as Executor.run: programs with no random
-        # ops neither pay the fold_in nor bump the scope run index
-        if getattr(compiled, "needs_rng", True):
-            rng_key = executor._next_rng(self._program, scope)
-        else:
-            rng_key = _executor_mod._fixed_rng()
-        outs = compiled.run(scope, feed, rng_key, executor.place)
-        outs = [None if o is None else _fetch_to_host(o) for o in outs]
-        if return_numpy:
-            return [None if o is None else np.asarray(o) for o in outs]
-        return [
-            None if o is None else core.LoDTensor(np.asarray(o)) for o in outs
-        ]
+        return compiled, scope, feed, fetch_names, hit
 
     def _get_spmd_mesh(self):
         """The GSPMD mesh: a prebuilt Mesh wins; else exactly the axes

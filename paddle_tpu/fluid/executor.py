@@ -1100,9 +1100,15 @@ class _CompiledBlock(object):
             )
             return ex
 
-    def run(self, scope, feed, rng_key, place):
+    def run(self, scope, feed, rng_key, place, span=None):
+        """One run of the block. ``span`` is the caller's open
+        ``executor_run`` span: the phases of each segment are marked on
+        it (``executor_marshal``, ``executor_dispatch``,
+        ``executor_host_ops``, then ``executor_writeback``), which costs
+        a clock read each where a span of its own would cost a record."""
         import jax
 
+        phase = span.phase if span is not None else _no_phase
         if self.spmd is not None:
             # GSPMD placement: feeds batch-shard over the data axis when
             # their leading dim divides (replicate otherwise — decode's
@@ -1161,13 +1167,57 @@ class _CompiledBlock(object):
                 v = feed[name]
             return v
 
+        def need(name):
+            v = lookup(name)
+            if v is None:
+                raise ValueError(
+                    "variable %r is not initialized (run the startup "
+                    "program first)" % name
+                )
+            return v
+
+        placed = 0
+
+        def put(val, device):
+            nonlocal placed
+            if _is_resident(val, device):
+                return val
+            placed += 1
+            return _to_device(val, device)
+
+        def state(name, v=None):
+            if v is None:
+                v = need(name)
+            out = put(v, state_dev_for(name))
+            if (self.spmd is not None and out is not v
+                    and name in self._persistable
+                    and name not in local_env):
+                # commit a placement the first time it is made (after it
+                # the value is resident and ``put`` hands it back as it
+                # is). A read-only var (a served model's weights) never
+                # comes back as an output, so left as it was it is
+                # resharded from where startup put it, one device, on
+                # every step; and a trained var's unsharded original
+                # would stay on that device beside its shards until
+                # writeback, through the first step's own temporaries
+                # (gpt2-large under FSDP: 6.7 GB of Adam moments on
+                # device 0, and the step did not load)
+                scope.set(name, out)
+            return out
+
         for kind, seg, plan in self._plans:
             if kind == "host":
+                phase("executor_host_ops", ops=len(seg.ops))
                 for op_ in seg.ops:
                     _run_host_op(
                         op_, scope, place, local_env, self.block, feed
                     )
                 continue
+            # the phases of one XLA segment: gathering the arguments
+            # (executor_marshal), then the call and keeping what it
+            # returned (executor_dispatch)
+            note = phase("executor_marshal", segment=plan["seg_index"])
+            placed = 0
             feed_vals = []
             for n in plan["feeds"]:
                 val = feed.get(n)
@@ -1178,43 +1228,21 @@ class _CompiledBlock(object):
                     val = lookup(n)
                 if val is None:
                     raise ValueError("feed variable %r was not provided" % n)
-                feed_vals.append(_to_device(val, feed_dev_of(val)))
-            mutable_vals = []
-            for n in plan["mutable"]:
-                v = lookup(n)
-                if v is None:
-                    raise ValueError(
-                        "variable %r is not initialized (run the startup "
-                        "program first)" % n
-                    )
-                mutable_vals.append(_to_device(v, state_dev_for(n)))
-            sharded_vals = []
-            for n in plan.get("sharded_const", ()):
-                v = lookup(n)
-                if v is None:
-                    raise ValueError(
-                        "variable %r is not initialized (run the startup "
-                        "program first)" % n
-                    )
-                sharded_vals.append(_to_device(v, state_dev_for(n)))
+                feed_vals.append(put(val, feed_dev_of(val)))
+            mutable_vals = [state(n) for n in plan["mutable"]]
+            sharded_vals = [state(n) for n in plan.get("sharded_const", ())]
             const_map = {}
             for n in plan["const"]:
                 v = lookup(n)
-                if v is None:
-                    if _is_optional_missing(n):
-                        continue  # absent key: lowering treats it as zeros
-                    raise ValueError(
-                        "variable %r is not initialized (run the startup "
-                        "program first)" % n
-                    )
-                const_map[n] = _to_device(v, state_dev_for(n))
-                if (self.spmd is not None and const_map[n] is not v
-                        and n in self._persistable and n not in local_env):
-                    # commit the placement: a read-only var (a served
-                    # model's weights) never comes back as an output, so
-                    # left as it was it is resharded from where startup
-                    # put it — one device — on every step
-                    scope.set(n, const_map[n])
+                if v is None and _is_optional_missing(n):
+                    continue  # absent key: lowering treats it as zeros
+                const_map[n] = state(n, v)
+            note["values"] = (len(feed_vals) + len(mutable_vals)
+                              + len(sharded_vals) + len(const_map))
+            note["placed"] = placed
+            if placed:
+                _profiler.bump_counter("executor_values_placed", placed)
+            phase("executor_dispatch", segment=plan["seg_index"])
             outs = self._dispatch(
                 plan, tuple(feed_vals), tuple(mutable_vals),
                 tuple(sharded_vals), const_map, rng_key,
@@ -1223,36 +1251,59 @@ class _CompiledBlock(object):
                 local_env[n] = v
 
         # persist writes + collect fetches
+        note = phase("executor_writeback")
         persistable = self._persistable
+        written = 0
         for n, v in local_env.items():
             if n in persistable:
                 scope.set(n, v)
+                written += 1
         for n in self.fetch_names:
             v = local_env.get(n)
             if v is None:
                 v = scope.get(n)
             results[n] = v
+        note["values"] = written
         return [results[n] for n in self.fetch_names]
+
+
+def _no_phase(name, **args):
+    """``span.phase`` for a block run outside any span."""
+    return args
+
+
+def _is_resident(val, device):
+    """Whether ``val`` is a device array that already sits where
+    ``device`` says: on that one device, or laid out as that
+    ``Sharding``. State vars (params, KV caches, optimizer accumulators)
+    come back from every step as device arrays, so the steady-state walk
+    re-places values that never moved. jax.device_put would conclude the
+    same — at ~40-50 µs of dispatch per value, which for a ~40-param
+    program is a milliseconds-per-step tax (the decode probe measured it
+    at a third of the whole single-token step; gpt2-large under FSDP,
+    2,900 values: 138 ms of a 430 ms step). devices() is a stored set,
+    the compare ~0.1 µs; a step's outputs carry the plan's own sharding
+    objects (``out_shardings``), so under a mesh the compare is as
+    short."""
+    import jax
+    from jax.sharding import Sharding
+
+    if isinstance(val, jax.Array):
+        try:
+            if isinstance(device, Sharding):
+                return val.sharding == device
+            return val.devices() == {device}
+        except Exception:
+            pass  # fall through to the canonical path
+    return False
 
 
 def _to_device(val, device):
     import jax
     from jax.sharding import Sharding
 
-    if isinstance(val, jax.Array) and not isinstance(device, Sharding):
-        # already-resident fast path: state vars (params, KV caches,
-        # optimizer accumulators) come back from every step as device
-        # arrays, so the steady-state walk re-places values that never
-        # moved. jax.device_put would conclude the same — at ~40-50 µs of
-        # dispatch per value, which for a ~40-param program is a
-        # milliseconds-per-step tax (the decode probe measured it at a
-        # third of the whole single-token step). devices() is a stored
-        # set; the compare is ~0.1 µs.
-        try:
-            if val.devices() == {device}:
-                return val
-        except Exception:
-            pass  # fall through to the canonical path
+    if _is_resident(val, device):
+        return val
     if isinstance(val, core.LoDTensor):
         val = val.numpy()
     if isinstance(device, Sharding) and not device.is_fully_addressable:
@@ -1380,6 +1431,20 @@ class Executor(object):
                 self, feed=feed, fetch_list=fetch_list, scope=scope,
                 return_numpy=return_numpy,
             )
+        t_in = time.perf_counter()
+        compiled, scope, feed, fetch_names, plan_hit = self._prepare(
+            program, feed, fetch_list, scope, use_program_cache
+        )
+        rng_key = self._rng_for(compiled, program, scope)
+        return self._run_compiled(
+            compiled, scope, feed, rng_key, fetch_names, return_numpy,
+            t_in, plan_hit,
+        )
+
+    def _prepare(self, program, feed, fetch_list, scope, use_program_cache):
+        """Feed normalisation, LoD companions and the plan / cache lookup
+        of one ``run``. -> (compiled block, scope, feed, fetch names,
+        whether the dispatch-plan fast lane hit)"""
         scope = scope or core.global_scope()
         fetch_list = fetch_list or []
         if not isinstance(fetch_list, (list, tuple)):
@@ -1430,7 +1495,8 @@ class Executor(object):
             tuple(fetch_names),
         )
         compiled = self._plans.get(plan_key) if use_program_cache else None
-        if compiled is not None:
+        plan_hit = compiled is not None
+        if plan_hit:
             self._plans.move_to_end(plan_key)
             _profiler.bump_counter("executor_plan_cache_hits")
         else:
@@ -1467,22 +1533,41 @@ class Executor(object):
                 self._plans.move_to_end(plan_key)
                 while len(self._plans) > self._CACHE_CAPACITY:
                     self._plans.popitem(last=False)
+        return compiled, scope, feed, fetch_names, plan_hit
 
-        # programs with no random ops skip the per-run fold_in AND the
-        # scope run-index bump (a counter only random programs ever
-        # consume — skipping keeps "fresh scope -> same init" intact and
-        # shaves ~0.5 ms off every inference/decode step); the fixed key
-        # satisfies the compiled signature's rng argument, which the
-        # traced fn never reads
+    def _rng_for(self, compiled, program, scope):
+        """Programs with no random ops skip the per-run fold_in AND the
+        scope run-index bump (a counter only random programs ever
+        consume — skipping keeps "fresh scope -> same init" intact and
+        shaves ~0.5 ms off every inference/decode step); the fixed key
+        satisfies the compiled signature's rng argument, which the
+        traced fn never reads."""
         if getattr(compiled, "needs_rng", True):
-            rng_key = self._next_rng(program, scope)
-        else:
-            rng_key = _fixed_rng()
+            return self._next_rng(program, scope)
+        return _fixed_rng()
+
+    def _run_compiled(self, compiled, scope, feed, rng_key, fetch_names,
+                      return_numpy, t_in, plan_hit):
+        """The tail every entry point shares (``Executor.run`` and
+        ``CompiledProgram._run``): run the compiled block, bring the
+        fetches to the host. ``t_in`` is when the entry point began its
+        own normalisation and lookup: ``executor_run`` carries that as
+        ``prepare_ms`` (with ``plan_hit``) and the block's phases
+        (marshal, dispatch, writeback); ``executor_fetch`` is the wait
+        for the device and the copy back."""
         # the step-loop span: one per run(), nesting under the trainer's
         # train_step span and over any RecordEvents ops open inside
-        with _obs_trace.span("executor_run", cat="exec"):
-            outs = compiled.run(scope, feed, rng_key, self.place)
-        outs = [None if o is None else _fetch_to_host(o) for o in outs]
+        with _obs_trace.span(
+            "executor_run", cat="exec", plan_hit=plan_hit,
+            prepare_ms=(time.perf_counter() - t_in) * 1e3,
+        ) as sp:
+            outs = compiled.run(scope, feed, rng_key, self.place, sp)
+        with _obs_trace.span("executor_fetch", cat="exec") as sp:
+            outs = [
+                None if o is None else np.asarray(_fetch_to_host(o))
+                for o in outs
+            ]
+            sp.note(bytes=sum(o.nbytes for o in outs if o is not None))
         if _flags.get_flag("check_nan_inf", False):
             # the executor-level post-run fetch scan the reference ran
             # per op (operator.cc:945): raises a structured NanInfError
@@ -1493,10 +1578,8 @@ class Executor(object):
 
             _debugger.scan_fetches(fetch_names, outs)
         if return_numpy:
-            return [None if o is None else np.asarray(o) for o in outs]
-        return [
-            None if o is None else core.LoDTensor(np.asarray(o)) for o in outs
-        ]
+            return outs
+        return [None if o is None else core.LoDTensor(o) for o in outs]
 
     def _next_rng(self, program, scope):
         """Per-run PRNG base key: fold_in(key(seed or 12345), run_index),

@@ -278,7 +278,9 @@ class PipelineProgram(object):
             )
 
     # -- run ----------------------------------------------------------------
-    def run(self, scope, feed, rng_key, place):
+    def run(self, scope, feed, rng_key, place, span=None):
+        # span: the executor's open executor_run span, as every compiled
+        # program is handed it; a pipeline run marks no phases on it
         import jax
 
         M = self.num_microbatches

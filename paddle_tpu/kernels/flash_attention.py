@@ -452,6 +452,7 @@ def flash_decode_attention(q, k, v, key_bias=None, scale=None,
     )
     out = pl.pallas_call(
         kernel,
+        name="flash_decode",
         out_shape=jax.ShapeDtypeStruct((B * N, Sqp, D), q.dtype),
         grid=(B * N,),
         in_specs=[
@@ -616,6 +617,7 @@ def flash_decode_paged_attention(q, k_pool, v_pool, tables, key_bias=None,
     )
     out = pl.pallas_call(
         kernel,
+        name="flash_decode_paged",
         out_shape=jax.ShapeDtypeStruct((B, N, BQ, D), q.dtype),
         grid_spec=grid_spec,
         interpret=bool(interpret),
@@ -727,6 +729,7 @@ def _flash_fwd_impl(q, k, v, key_bias, bias, seed, causal, scale,
     )
     out, lse = pl.pallas_call(
         kernel,
+        name="flash_fwd",
         out_shape=[
             jax.ShapeDtypeStruct((B * N, Sqp, D), q.dtype),
             jax.ShapeDtypeStruct((B * N, Sqp, 1), jnp.float32),
@@ -797,6 +800,7 @@ def _flash_bwd_core(causal, scale, dropout_rate, interpret, head_swap, res,
     delta3 = delta[:, :, None]
     dq = pl.pallas_call(
         dq_kernel,
+        name="flash_bwd_dq",
         out_shape=jax.ShapeDtypeStruct((B * N, Sqp, D), q.dtype),
         grid=(B * N, Sqp // bq),
         in_specs=_common_in_specs(pl, pltpu, geom, G, D)
@@ -882,6 +886,7 @@ def _flash_bwd_core(causal, scale, dropout_rate, interpret, head_swap, res,
         )
     outs = pl.pallas_call(
         dkv_kernel,
+        name="flash_bwd_dkv",
         out_shape=out_shape,
         grid=(
             (B * N, Skp // bk) if head_major else (Skp // bk, B * N)

@@ -29,6 +29,30 @@ Design constraints, in order:
 - **Standard format**: ``chrome_trace()`` emits trace-event JSON
   (``ph: "X"`` complete events + thread-name metadata) that loads in
   Perfetto / chrome://tracing unchanged.
+
+- **Phases, where a record each would cost too much**: a span that is
+  a sequence of steps marks where each begins (``span.phase(name)``: one
+  clock read and a list append, a tenth of a span) and carries the marks
+  in its one record (``args["phases"]``). ``with_phases()`` turns them
+  into child spans when the buffer is READ (``chrome_trace()`` always
+  does). A millisecond
+  ``Executor.run`` so makes two records, not seven.
+
+Names on the two hot paths (PERF.md section 3 lists each with the
+benchmark metric that reads it). One ``Executor.run`` or
+``CompiledProgram`` run: ``executor_run`` (args ``prepare_ms``: the
+entry point's own work before it, and ``plan_hit``) with the phases
+``executor_marshal`` / ``executor_dispatch`` per XLA segment
+(``executor_host_ops`` per host segment) and ``executor_writeback``,
+then the span ``executor_fetch`` (the wait for the device). One engine
+tick:
+``engine_tick`` holding ``tick_reap``, ``tick_admit``, ``tick_prefill``,
+``tick_build``, ``decode_tick``, ``tick_sample_emit``; ``engine_wait``
+while the loop has nothing to do. ``decode_tick`` is NOT the tick: it is
+the fused device call of a tick (``step_feed``, ``decode_paged_step`` or
+``decode_step``, ``step_logits``), annotated with the trace ids of the
+streams it decoded. A request leaves one ``decode_request`` instant
+(its times on this clock) and one ``gateway_request`` span.
 """
 
 from __future__ import annotations
@@ -52,6 +76,7 @@ __all__ = [
     "force_enable",
     "gang_rank",
     "get_spans",
+    "with_phases",
     "reset",
     "chrome_trace",
     "save_chrome_trace",
@@ -244,13 +269,15 @@ class span(object):
     enter/exit a near-no-op."""
 
     __slots__ = ("name", "cat", "args", "_t0", "_armed", "_parent",
-                 "trace_id", "span_id", "_parent_hex", "_ctx_pushed")
+                 "trace_id", "span_id", "_parent_hex", "_ctx_pushed",
+                 "_stack", "_phases")
 
     def __init__(self, name, cat="host", **args):
         self.name = name
         self.cat = cat
         self.args = args or None
         self._armed = False
+        self._phases = None
         # distributed identity, populated at __enter__ when an ambient
         # trace_scope is active on this thread (None otherwise). span_id
         # is readable the moment the span opens — a hop forwards it in
@@ -258,17 +285,42 @@ class span(object):
         self.trace_id = None
         self.span_id = None
 
+    def note(self, **args):
+        """Add facts known only once the work is under way (how many
+        values were placed, the status a handler ended with): they land
+        in the record's ``args`` when the span closes."""
+        if self.args is None:
+            self.args = args
+        else:
+            self.args.update(args)
+
+    def phase(self, name, **args):
+        """Mark that the phase ``name`` of this span begins now; it ends
+        where the next one begins or the span closes. -> the phase's
+        ``args`` dict, for facts known only when it is over. The marks
+        travel in the span's own record; ``with_phases`` makes child
+        spans of them when the buffer is read."""
+        if self._armed:
+            if self._phases is None:
+                self._phases = []
+            self._phases.append((name, time.perf_counter(), args))
+        return args
+
     def __enter__(self):
         if not enabled():
             return self
-        stack = getattr(_tls, "stack", None)
-        if stack is None:
-            stack = _tls.stack = []
+        try:
+            stack = _tls.stack
+            ctx = _tls.ctx
+        except AttributeError:
+            # first span of this thread (a trace_scope may have made ctx)
+            stack = _tls.stack = getattr(_tls, "stack", [])
+            ctx = _tls.ctx = getattr(_tls, "ctx", [])
+        self._stack = stack
         self._parent = stack[-1] if stack else None
         stack.append(self.name)
         self._armed = True
         self._ctx_pushed = False
-        ctx = getattr(_tls, "ctx", None)
         if ctx:
             # inside a trace_scope: mint this span's W3C id, remember
             # the enclosing id as parent, and become the ambient parent
@@ -287,12 +339,14 @@ class span(object):
             return False
         t1 = time.perf_counter()
         self._armed = False
-        stack = _tls.stack
+        stack = self._stack
         if stack:
             stack.pop()
         if self._ctx_pushed:
             _tls.ctx.pop()
             self._ctx_pushed = False
+        if self._phases is not None:
+            self.note(phases=self._phases)
         tid = threading.get_ident()
         rec = (
             self.name, self.cat, self._t0, t1, tid, len(stack),
@@ -361,7 +415,8 @@ def get_spans(newest=None):
     identity (None outside a trace_scope); ``instant`` marks
     zero-duration events. ``newest=`` bounds the snapshot to the newest
     N records BEFORE dict conversion — the periodic black-box dump must
-    not pay a full-ring copy to keep 1/16th of it."""
+    not pay a full-ring copy to keep 1/16th of it. Phases a span marked
+    stay in its ``args``; ``with_phases`` makes child spans of them."""
     with _lock:
         recs = list(_buf)
     if newest is not None:
@@ -377,6 +432,29 @@ def get_spans(newest=None):
         }
         for r in recs
     ]
+
+
+def with_phases(spans):
+    """``spans`` (dicts as ``get_spans`` gives them) and, after each span
+    that marked phases, one child span a phase: from its mark to the
+    next one or to the parent's end, on the parent's thread, one level
+    deeper, with the phase's own ``args``. The children were never
+    records (``id`` None); inside a trace scope each gets a span id of its
+    own under its parent's, so a merged trace keeps its tree."""
+    out = []
+    for s in spans:
+        out.append(s)
+        marks = s["args"].get("phases")
+        if not marks:
+            continue
+        ends = [m[1] for m in marks[1:]] + [s["end"]]
+        for (name, start, args), end in zip(marks, ends):
+            out.append(dict(
+                s, name=name, start=start, end=end, depth=s["depth"] + 1,
+                parent=s["name"], id=None, args=dict(args or {}),
+                span_id=_span_hex(next(_ids)) if s["span_id"] else None,
+                parent_span_id=s["span_id"]))
+    return out
 
 
 def reset():
@@ -433,6 +511,13 @@ def chrome_trace(trace_id=None, newest=None):
         if newest is not None:
             n = int(newest)
             spans = spans[-n:] if n > 0 else []
+    # a phase shows as the child span it is; its parent keeps the marks
+    # out of its own event's args
+    spans = [
+        dict(s, args={k: v for k, v in s["args"].items() if k != "phases"})
+        if "phases" in s["args"] else s
+        for s in with_phases(spans)
+    ]
     rank = gang_rank()
     t0 = min((s["start"] for s in spans), default=0.0)
     events = [

@@ -931,19 +931,20 @@ class DecodeSession(object):
         masked write at the next window's start (overwritten before
         anything attends to it). The slot's attention output is fully
         masked and ignored either way. Returns logits [slots, vocab]."""
-        act = np.asarray(active, bool)
-        pos = np.asarray(positions, "int64")
-        tok = np.where(act, np.asarray(tokens, "int64"), 0)
-        key_bias = (
-            ((self._cols[None, :] > pos[:, None]) | ~act[:, None])
-            .astype("float32") * -1e4
-        )
-        main, fetch_name = self._decode
-        feed = {
-            "step_ids": tok.reshape(self.slots, 1, 1),
-            "step_pos": pos.reshape(self.slots, 1, 1),
-            "key_bias": key_bias,
-        }
+        with _trace.span("step_feed", cat="serving"):
+            act = np.asarray(active, bool)
+            pos = np.asarray(positions, "int64")
+            tok = np.where(act, np.asarray(tokens, "int64"), 0)
+            key_bias = (
+                ((self._cols[None, :] > pos[:, None]) | ~act[:, None])
+                .astype("float32") * -1e4
+            )
+            main, fetch_name = self._decode
+            feed = {
+                "step_ids": tok.reshape(self.slots, 1, 1),
+                "step_pos": pos.reshape(self.slots, 1, 1),
+                "key_bias": key_bias,
+            }
         t0 = time.perf_counter()
         with _trace.span(
             "decode_step", cat="serving", active=int(act.sum())
@@ -956,7 +957,8 @@ class DecodeSession(object):
         _profiler.bump_histogram(
             "decode_step_ms", (time.perf_counter() - t0) * 1e3
         )
-        return np.asarray(lv)
+        with _trace.span("step_logits", cat="serving"):
+            return np.asarray(lv)
 
     # -- paged device steps --------------------------------------------------
     def paged_window(self, table, window_ids, offset):
@@ -979,25 +981,26 @@ class DecodeSession(object):
                 % (offset, offset + T, span)
             )
         main, fetch_name = self._paged_window[T]
-        ids = np.zeros((1, T, 1), "int64")
-        ids[0, :P, 0] = window_ids
-        # offset-shifted causal mask over the gathered logical row; the
-        # -1e4 side also buries sink garbage past the live length
-        allow = self._cols[None, :] <= (offset + np.arange(T))[:, None]
-        bias = np.where(allow, 0.0, -1e4).astype("float32")[None]
-        last_onehot = np.zeros((1, T, 1), "float32")
-        last_onehot[0, P - 1, 0] = 1.0
-        tbl = np.zeros((1, self.max_blocks), "int64")
-        tbl[0, :len(table)] = table
-        feed = {
-            "ids": ids,
-            "pos_ids": (offset + np.arange(T)).reshape(1, T, 1)
-            .astype("int64"),
-            "table": tbl,
-            "window_pos": np.array([[offset]], "int64"),
-            "resume_bias": bias,
-            "last_onehot": last_onehot,
-        }
+        with _trace.span("step_feed", cat="serving"):
+            ids = np.zeros((1, T, 1), "int64")
+            ids[0, :P, 0] = window_ids
+            # offset-shifted causal mask over the gathered logical row;
+            # the -1e4 side also buries sink garbage past the live length
+            allow = self._cols[None, :] <= (offset + np.arange(T))[:, None]
+            bias = np.where(allow, 0.0, -1e4).astype("float32")[None]
+            last_onehot = np.zeros((1, T, 1), "float32")
+            last_onehot[0, P - 1, 0] = 1.0
+            tbl = np.zeros((1, self.max_blocks), "int64")
+            tbl[0, :len(table)] = table
+            feed = {
+                "ids": ids,
+                "pos_ids": (offset + np.arange(T)).reshape(1, T, 1)
+                .astype("int64"),
+                "table": tbl,
+                "window_pos": np.array([[offset]], "int64"),
+                "resume_bias": bias,
+                "last_onehot": last_onehot,
+            }
         t0 = time.perf_counter()
         with _trace.span("decode_paged_window", cat="serving",
                          bucket=T, rows=P, offset=offset):
@@ -1009,7 +1012,8 @@ class DecodeSession(object):
         _profiler.bump_histogram(
             "decode_prefill_ms", (time.perf_counter() - t0) * 1e3
         )
-        return np.asarray(lv)[0]
+        with _trace.span("step_logits", cat="serving"):
+            return np.asarray(lv)[0]
 
     def paged_step(self, tokens, positions, tables, active, width=1):
         """ONE fused paged step over all slots: slot s advances the
@@ -1029,32 +1033,33 @@ class DecodeSession(object):
                 "no paged step program of width %d (built: %s)"
                 % (width, sorted(self._paged_step))
             )
-        act = np.asarray(active, bool)
-        pos = np.asarray(positions, "int64")
-        tok = np.where(act[:, None],
-                       np.asarray(tokens, "int64").reshape(self.slots,
-                                                           width), 0)
-        qpos = pos[:, None] + np.arange(width)[None, :]
-        # query i of slot s sees logical cache positions <= qpos[s, i];
-        # inactive rows mask everything (finite softmax over garbage,
-        # output ignored)
-        bias = (
-            ((self._cols[None, None, :] > qpos[:, :, None])
-             | ~act[:, None, None]).astype("float32") * -1e4
-        )
-        tbl = np.zeros((self.slots, self.max_blocks), "int64")
-        for s in range(self.slots):
-            row = tables[s] if tables is not None else ()
-            if len(row):
-                tbl[s, :len(row)] = row
-        main, fetch_name = self._paged_step[width]
-        feed = {
-            "step_ids": tok.reshape(self.slots, width, 1),
-            "step_pos": qpos.reshape(self.slots, width, 1)
-            .astype("int64"),
-            "tables": tbl,
-            "step_bias": bias,
-        }
+        with _trace.span("step_feed", cat="serving"):
+            act = np.asarray(active, bool)
+            pos = np.asarray(positions, "int64")
+            tok = np.where(act[:, None],
+                           np.asarray(tokens, "int64").reshape(self.slots,
+                                                               width), 0)
+            qpos = pos[:, None] + np.arange(width)[None, :]
+            # query i of slot s sees logical cache positions
+            # <= qpos[s, i]; inactive rows mask everything (finite
+            # softmax over garbage, output ignored)
+            bias = (
+                ((self._cols[None, None, :] > qpos[:, :, None])
+                 | ~act[:, None, None]).astype("float32") * -1e4
+            )
+            tbl = np.zeros((self.slots, self.max_blocks), "int64")
+            for s in range(self.slots):
+                row = tables[s] if tables is not None else ()
+                if len(row):
+                    tbl[s, :len(row)] = row
+            main, fetch_name = self._paged_step[width]
+            feed = {
+                "step_ids": tok.reshape(self.slots, width, 1),
+                "step_pos": qpos.reshape(self.slots, width, 1)
+                .astype("int64"),
+                "tables": tbl,
+                "step_bias": bias,
+            }
         t0 = time.perf_counter()
         with _trace.span("decode_paged_step", cat="serving",
                          active=int(act.sum()), width=width):
@@ -1066,7 +1071,8 @@ class DecodeSession(object):
         _profiler.bump_histogram(
             "decode_step_ms", (time.perf_counter() - t0) * 1e3
         )
-        return np.asarray(lv).reshape(self.slots, width, -1)
+        with _trace.span("step_logits", cat="serving"):
+            return np.asarray(lv).reshape(self.slots, width, -1)
 
     def block_copy(self, src_blocks, dst_blocks):
         """Pool-internal block copy (all layers, K and V):
@@ -1301,6 +1307,14 @@ class GenerationStream(object):
         self.trace_ctx = _trace.current_context()
         self._t_submit = time.monotonic()
         self._t_last_emit = None
+        # the request's times on the spans' clock (perf_counter): submit,
+        # dequeue (the engine's _admit), every pushed token (a list
+        # beside _tokens; the first is the first token), finish. They go
+        # out in the one decode_request record the stream leaves
+        self.t_submit = time.perf_counter()
+        self.t_dequeue = None
+        self.t_finish = None
+        self._emit_times = []
         self._q = queue.Queue()
         self._tokens = []
         self._done = threading.Event()
@@ -1341,18 +1355,45 @@ class GenerationStream(object):
                             rng=self._rng)
 
     def _push(self, tok):
+        self._emit_times.append(time.perf_counter())
         self._tokens.append(int(tok))
         self._q.put(int(tok))
 
     def _finish(self, reason):
         self.finish_reason = reason
+        self._record(reason)
         self._done.set()
         self._q.put(_SENTINEL)
 
     def _fail(self, exc):
         self._error = exc
+        self._record("error")
         self._done.set()
         self._q.put(_SENTINEL)
+
+    def _record(self, reason):
+        """The one record a request leaves: a ``decode_request`` instant
+        with its times on the spans' clock, under the request's trace id.
+        An instant, not a span: a request-long interval on the loop
+        thread would swallow every idle gap that no phase span explains."""
+        self.t_finish = time.perf_counter()
+        if not _trace.enabled():
+            return
+        deq = self.t_dequeue
+        first = self._emit_times[0] if self._emit_times else None
+        with _stream_scope(self):
+            _trace.instant(
+                "decode_request", cat="serving",
+                submit=self.t_submit, dequeue=deq, first_token=first,
+                finish=self.t_finish,
+                queue_wait_ms=(None if deq is None
+                               else (deq - self.t_submit) * 1e3),
+                first_token_ms=(None if deq is None or first is None
+                                else (first - deq) * 1e3),
+                prefill_windows=self.admit_windows,
+                tokens=len(self._tokens), finish_reason=reason,
+                preempted=self.preemptions,
+            )
 
     # consumer side
     @property
@@ -2084,10 +2125,10 @@ class DecodeEngine(object):
     def _loop(self):
         while True:
             with self._cond:
-                while (not self._stop and not self._pending
-                       and not self._active and not self._prefilling
-                       and not self._export_jobs):
-                    self._cond.wait()
+                if self._idle():
+                    with _trace.span("engine_wait", cat="serving"):
+                        while self._idle():
+                            self._cond.wait()
                 if self._stop:
                     return
             try:
@@ -2113,6 +2154,12 @@ class DecodeEngine(object):
                 self._free.extend(self._prefilling.keys())
                 self._prefilling.clear()
 
+    def _idle(self):
+        """Nothing to do and not stopping (under ``_cond``)."""
+        return (not self._stop and not self._pending
+                and not self._active and not self._prefilling
+                and not self._export_jobs)
+
     def _tick(self):
         """One engine tick: reap cancellations, admit queued requests
         (prefix-cache copy + their first window; short prompts finish
@@ -2120,14 +2167,45 @@ class DecodeEngine(object):
         chunked-prefill window, then ONE fused decode step over every
         active slot. The chunk cap is the inter-token latency bound: a
         max-length prompt costs in-flight streams one bucket-shaped
-        window per tick instead of a monolithic prefill stall."""
-        self._drain_spill_done()
-        self._serve_export_jobs()
-        self._reap_cancelled()
-        self._admit()
-        self._advance_prefills()
-        if self._active:
-            self._step()
+        window per tick instead of a monolithic prefill stall.
+
+        ``engine_tick`` is the tick's one parent span and the phase
+        spans its children, which tile it: reap, admit, prefill, then in
+        ``_step`` build, the device call (``decode_tick``) and
+        sample + emit. No span per token and none per slot."""
+        cpu0 = time.thread_time()
+        with _trace.span("engine_tick", cat="serving",
+                         tick=self.tick) as sp:
+            with _trace.span("tick_reap", cat="serving"):
+                self._drain_spill_done()
+                self._serve_export_jobs()
+                self._reap_cancelled()
+            with _trace.span("tick_admit", cat="serving"):
+                self._admit()
+            with _trace.span("tick_prefill", cat="serving"):
+                self._advance_prefills()
+            if self._active:
+                self._step()
+            if _trace.enabled():
+                sp.note(cpu_ms=(time.thread_time() - cpu0) * 1e3,
+                        **self._occupancy())
+
+    def _occupancy(self):
+        """What a tick leaves behind, for its span: streams by state,
+        KV blocks handed out against the pool, and the tokens they hold
+        (``next_pos`` summed over the active slots)."""
+        out = {
+            "active": len(self._active),
+            "prefilling": len(self._prefilling),
+            "queued": len(self._pending),
+            "live_tokens": sum(s.next_pos for s in self._active.values()),
+        }
+        if self.allocator is not None:
+            # block 0 is the sink: never handed out
+            total = self.allocator.blocks - 1
+            out["blocks_total"] = total
+            out["blocks_in_use"] = total - self.allocator.free_blocks
+        return out
 
     def _reap_cancelled(self):
         """Retire slots whose consumer abandoned the stream (transport
@@ -2349,6 +2427,10 @@ class DecodeEngine(object):
                 stream = self._dequeue_locked()
             if stream is None:
                 return
+            if getattr(stream, "t_dequeue", 0) is None:
+                # the first dequeue only: a preempted stream's second
+                # wait is the scheduler's doing, not the queue's
+                stream.t_dequeue = time.perf_counter()
             if stream._cancelled:
                 # cancelled while queued: never admitted, so no slot,
                 # no retirement tally — just finish the dead handle
@@ -3012,33 +3094,24 @@ class DecodeEngine(object):
             self._step_paged()
             return
         sess = self.session
-        tokens = [0] * sess.slots
-        positions = [0] * sess.slots
-        active = [False] * sess.slots
-        for idx, slot in self._active.items():
-            tokens[idx] = slot.pending_token
-            positions[idx] = slot.next_pos
-            active[idx] = True
-        for idx, job in self._prefilling.items():
-            # the fused program scatter-writes EVERY slot, active or
-            # not: a mid-chunked-prefill row is live (copied prefix +
-            # finished windows), so its masked write must land on the
-            # next window's start — the window overwrites that position
-            # before any attention reads it. The free-slot convention
-            # (position 0) would corrupt the row head and poison blocks
-            # later published to the prefix store.
-            positions[idx] = job.windows[job.wi][0]
-        # a fused tick decodes EVERY traced stream at once: annotate it
-        # with the slots' trace ids (like the batcher's dispatch span)
-        # so each request's merged tree shows the ticks it rode —
-        # skipped entirely for untraced traffic (greedy_generate et al.)
-        # and when span recording is off (gateway streams always carry
-        # trace ids for the header/log round-trip, but a disarmed
-        # tracer must cost the tick loop nothing)
-        tids = sorted({
-            s.stream.trace_ctx[0] for s in self._active.values()
-            if getattr(s.stream, "trace_ctx", None)
-        }) if _trace.enabled() else None
+        with _trace.span("tick_build", cat="serving"):
+            tokens = [0] * sess.slots
+            positions = [0] * sess.slots
+            active = [False] * sess.slots
+            for idx, slot in self._active.items():
+                tokens[idx] = slot.pending_token
+                positions[idx] = slot.next_pos
+                active[idx] = True
+            for idx, job in self._prefilling.items():
+                # the fused program scatter-writes EVERY slot, active or
+                # not: a mid-chunked-prefill row is live (copied prefix
+                # + finished windows), so its masked write must land on
+                # the next window's start — the window overwrites that
+                # position before any attention reads it. The free-slot
+                # convention (position 0) would corrupt the row head and
+                # poison blocks later published to the prefix store.
+                positions[idx] = job.windows[job.wi][0]
+            tids = self._traced_ids()
         if tids:
             with _trace.span("decode_tick", cat="serving",
                              tick=self.tick, trace_ids=tids), \
@@ -3048,21 +3121,43 @@ class DecodeEngine(object):
             with _xla_stats.serving_request_window():
                 logits = sess.decode_step(tokens, positions, active)
         self.tick += 1
-        for idx in list(self._active.keys()):
-            slot = self._active[idx]
-            try:
-                tok = slot.stream.pick(logits[idx])
-            except Exception as e:  # noqa: BLE001 - fail THIS stream only
-                self._active.pop(idx, None)
-                self._free.append(idx)
-                _profiler.bump_counter("serving_slot_retirements")
-                self._counts["retirements"] += 1
-                slot.stream._fail(e)
-                continue
-            slot.next_pos += 1
-            slot.generated += 1
-            slot.pending_token = tok
-            self._emit(idx, slot, tok)
+        cpu0 = time.thread_time()
+        with _trace.span("tick_sample_emit", cat="serving") as sp:
+            emitted = 0
+            for idx in list(self._active.keys()):
+                slot = self._active[idx]
+                try:
+                    tok = slot.stream.pick(logits[idx])
+                except Exception as e:  # noqa: BLE001 - THIS stream only
+                    self._active.pop(idx, None)
+                    self._free.append(idx)
+                    _profiler.bump_counter("serving_slot_retirements")
+                    self._counts["retirements"] += 1
+                    slot.stream._fail(e)
+                    continue
+                slot.next_pos += 1
+                slot.generated += 1
+                slot.pending_token = tok
+                self._emit(idx, slot, tok)
+                emitted += 1
+            sp.note(tokens=emitted,
+                    cpu_ms=(time.thread_time() - cpu0) * 1e3)
+
+    def _traced_ids(self):
+        """A fused tick decodes EVERY traced stream at once: its
+        ``decode_tick`` span is annotated with the slots' trace ids
+        (like the batcher's dispatch span) so each request's merged
+        tree shows the ticks it rode — skipped entirely for untraced
+        traffic (greedy_generate et al.) and when span recording is off
+        (gateway streams always carry trace ids for the header/log
+        round-trip, but a disarmed tracer must cost the tick loop
+        nothing)."""
+        if not _trace.enabled():
+            return None
+        return sorted({
+            s.stream.trace_ctx[0] for s in self._active.values()
+            if getattr(s.stream, "trace_ctx", None)
+        })
 
     def _step_paged(self):
         """One fused paged tick over every active slot — the plain
@@ -3085,6 +3180,74 @@ class DecodeEngine(object):
         whole rejected blocks back by table edit."""
         sess = self.session
         width = self._spec_width
+        with _trace.span("tick_build", cat="serving"):
+            built = self._build_paged_step(width)
+            tids = self._traced_ids()
+        if built is None:
+            return
+        tokens, positions, tables, active, windows = built
+        if tids:
+            with _trace.span("decode_tick", cat="serving",
+                             tick=self.tick, trace_ids=tids), \
+                    _xla_stats.serving_request_window():
+                logits = sess.paged_step(tokens, positions, tables,
+                                         active, width=width)
+        else:
+            with _xla_stats.serving_request_window():
+                logits = sess.paged_step(tokens, positions, tables,
+                                         active, width=width)
+        self.tick += 1
+        cpu0 = time.thread_time()
+        with _trace.span("tick_sample_emit", cat="serving") as sp:
+            total = 0
+            for idx in list(self._active.keys()):
+                slot = self._active[idx]
+                win = windows[idx]
+                emitted = 0
+                failed = False
+                for j in range(width):
+                    try:
+                        tok = slot.stream.pick(logits[idx, j])
+                    except Exception as e:  # noqa: BLE001 - this stream
+                        self._active.pop(idx, None)
+                        self._free.append(idx)
+                        self._release_slot_blocks(idx)
+                        _profiler.bump_counter("serving_slot_retirements")
+                        self._counts["retirements"] += 1
+                        slot.stream._fail(e)
+                        failed = True
+                        break
+                    emitted += 1
+                    slot.next_pos += 1
+                    slot.generated += 1
+                    slot.pending_token = tok
+                    self._emit(idx, slot, tok)
+                    if idx not in self._active:
+                        break  # retired: eos / length budget hit mid-window
+                    if j < width - 1 and tok != win[j + 1]:
+                        break  # draft diverged — the tail is dead weight
+                if width > 1 and not failed:
+                    drafted = width - 1
+                    accepted = max(emitted - 1, 0)
+                    _profiler.bump_counter("decode_spec_drafted", drafted)
+                    _profiler.bump_counter("decode_spec_accepted",
+                                           accepted)
+                    self._counts["spec_drafted"] += drafted
+                    self._counts["spec_accepted"] += accepted
+                    slot.stream.spec_drafted += drafted
+                    slot.stream.spec_accepted += accepted
+                if idx in self._active:
+                    self._trim_blocks(idx, slot.next_pos)
+                total += emitted
+            sp.note(tokens=total,
+                    cpu_ms=(time.thread_time() - cpu0) * 1e3)
+
+    def _build_paged_step(self, width):
+        """Before the device call of a paged tick: grow and unshare the
+        slots' block tables, draft, and lay out the step's arguments.
+        -> (tokens, positions, tables, active, windows), or None when
+        every slot was shed."""
+        sess = self.session
         bs = self.block_size
         # grow each active slot's table through this window's last
         # write; a slot the pool cannot cover (even after store
@@ -3118,7 +3281,7 @@ class DecodeEngine(object):
                 self._counts["oom_sheds"] += 1
                 slot.stream._fail(shed)
         if not self._active:
-            return
+            return None
         tokens = np.zeros((sess.slots, width), "int64")
         positions = [0] * sess.slots
         active = [False] * sess.slots
@@ -3137,55 +3300,4 @@ class DecodeEngine(object):
         # idle AND mid-prefill slots keep the all-sink default table:
         # their scatter-writes land in reserved block 0, so unlike the
         # legacy step there is no write position to aim
-        tids = sorted({
-            s.stream.trace_ctx[0] for s in self._active.values()
-            if getattr(s.stream, "trace_ctx", None)
-        }) if _trace.enabled() else None
-        if tids:
-            with _trace.span("decode_tick", cat="serving",
-                             tick=self.tick, trace_ids=tids), \
-                    _xla_stats.serving_request_window():
-                logits = sess.paged_step(tokens, positions, tables,
-                                         active, width=width)
-        else:
-            with _xla_stats.serving_request_window():
-                logits = sess.paged_step(tokens, positions, tables,
-                                         active, width=width)
-        self.tick += 1
-        for idx in list(self._active.keys()):
-            slot = self._active[idx]
-            win = windows[idx]
-            emitted = 0
-            failed = False
-            for j in range(width):
-                try:
-                    tok = slot.stream.pick(logits[idx, j])
-                except Exception as e:  # noqa: BLE001 - this stream only
-                    self._active.pop(idx, None)
-                    self._free.append(idx)
-                    self._release_slot_blocks(idx)
-                    _profiler.bump_counter("serving_slot_retirements")
-                    self._counts["retirements"] += 1
-                    slot.stream._fail(e)
-                    failed = True
-                    break
-                emitted += 1
-                slot.next_pos += 1
-                slot.generated += 1
-                slot.pending_token = tok
-                self._emit(idx, slot, tok)
-                if idx not in self._active:
-                    break  # retired: eos / length budget hit mid-window
-                if j < width - 1 and tok != win[j + 1]:
-                    break  # draft diverged — the tail is dead weight
-            if width > 1 and not failed:
-                drafted = width - 1
-                accepted = max(emitted - 1, 0)
-                _profiler.bump_counter("decode_spec_drafted", drafted)
-                _profiler.bump_counter("decode_spec_accepted", accepted)
-                self._counts["spec_drafted"] += drafted
-                self._counts["spec_accepted"] += accepted
-                slot.stream.spec_drafted += drafted
-                slot.stream.spec_accepted += accepted
-            if idx in self._active:
-                self._trim_blocks(idx, slot.next_pos)
+        return tokens, positions, tables, active, windows

@@ -934,6 +934,7 @@ def _make_handler(gw):
             # must never leak into this request's access-log line
             self._log_extra = None
             self._flight_extra = None
+            self._span_extra = None
             _profiler.bump_counter("gateway_requests")
             _profiler.bump_counter("gateway_tenant_requests_"
                                    + _tenant_slug(tenant))
@@ -992,10 +993,12 @@ def _make_handler(gw):
                         status, reason, tokens = fn(tenant, rid, body)
                     finally:
                         gw.admission.release(tenant)
-                    if sp.args is not None:
-                        # the span records its kwargs dict by reference,
-                        # so the status lands in the exported trace args
-                        sp.args["status"] = status
+                        # what the SSE writer measured lands in the
+                        # exported trace args, also for a client that
+                        # hung up on the stream's last bytes
+                        if self._span_extra:
+                            sp.note(**self._span_extra)
+                    sp.note(status=status)
             except ConnectionError:
                 # BrokenPipe AND ConnectionReset/Aborted: the client
                 # went away — not a server error, don't write to the
@@ -1406,6 +1409,10 @@ def _make_handler(gw):
             sent = 0
             first_tok_ms = None
             t0 = time.monotonic()
+            # per token, flush time minus the engine's emit stamp (both
+            # perf_counter): how long a made token waits for this thread
+            emitted_at = getattr(stream, "_emit_times", None)
+            lags = []
             # ENGINE exceptions (deadline, stream failure) and CLIENT
             # write exceptions must be told apart by SOURCE, not type:
             # on py3.10+ socket.timeout IS TimeoutError, so a write to
@@ -1472,6 +1479,8 @@ def _make_handler(gw):
                     if isinstance(e, ConnectionError):
                         raise
                     return 499, "client_stalled", sent
+                if emitted_at is not None and sent < len(emitted_at):
+                    lags.append(time.perf_counter() - emitted_at[sent])
                 sent += 1
                 _profiler.bump_counter("gateway_stream_tokens")
                 # chaos seam (no-op unless FLAGS_chaos_die_after_tokens
@@ -1485,6 +1494,13 @@ def _make_handler(gw):
             # amortization — same dict the access log records
             facts = self._stash_gen_facts(stream,
                                           fallback_ttft_ms=first_tok_ms)
+            if lags:
+                lags.sort()
+                self._span_extra = {
+                    "sse_lag_ms_p50": 1e3 * lags[len(lags) // 2],
+                    "sse_lag_ms_max": 1e3 * lags[-1],
+                    "tokens": sent,
+                }
             try:
                 self._chunk('data: %s\n\n' % json.dumps(
                     dict({"done": True,

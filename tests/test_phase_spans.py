@@ -1,0 +1,486 @@
+"""What the host does while the chip waits, as the program's own tracer
+records it: the executor's phases on both entry points (``Executor.run``
+and ``CompiledProgram.with_mesh``; marks on ``executor_run``'s one
+record, child spans when the buffer is read through ``with_phases``), the
+engine tick's children, one record per request, the Pallas kernels'
+names, and nothing at all with ``FLAGS_obs_trace`` off.
+"""
+
+import ast
+import http.client
+import json
+import os
+import statistics
+import time
+
+import jax
+import numpy as np
+import pytest
+
+import paddle_tpu.fluid as fluid
+from paddle_tpu import serving
+from paddle_tpu.fluid import compiler, profiler
+from paddle_tpu.models import gpt
+from paddle_tpu.observability import trace
+from paddle_tpu.serving.decode import DecodeEngine
+
+PHASES = ("executor_prepare", "executor_run", "executor_marshal",
+          "executor_dispatch", "executor_writeback", "executor_fetch")
+
+
+# -- executor ----------------------------------------------------------------
+def _train_program():
+    with fluid.unique_name.guard():
+        main, startup = fluid.Program(), fluid.Program()
+        main.random_seed = startup.random_seed = 11
+        with fluid.program_guard(main, startup):
+            x = fluid.layers.data(name="x", shape=[8], dtype="float32")
+            y = fluid.layers.data(name="y", shape=[1], dtype="int64")
+            h = fluid.layers.fc(input=x, size=16, act="relu")
+            loss = fluid.layers.mean(fluid.layers.softmax_with_cross_entropy(
+                fluid.layers.fc(input=h, size=4), y))
+            fluid.optimizer.Adam(learning_rate=0.01).minimize(loss)
+    return main, startup, loss
+
+
+def _feed(n=8):
+    r = np.random.RandomState(5)
+    return {"x": r.rand(n, 8).astype("float32"),
+            "y": r.randint(0, 4, (n, 1)).astype("int64")}
+
+
+def _program_on(entry):
+    """-> (executor, scope after startup, what to run, loss)"""
+    main, startup, loss = _train_program()
+    exe = fluid.Executor(fluid.CPUPlace())
+    scope = fluid.core.Scope()
+    exe.run(startup, scope=scope)
+    target = main
+    if entry == "with_mesh":
+        target = compiler.CompiledProgram(main).with_mesh(
+            loss_name=loss.name, mesh_axes={"data": 4}, fsdp=True)
+    return exe, scope, target, loss
+
+
+def _steps(entry):
+    """Two warm steps, then one recorded. -> (its spans with a child
+    span a phase, the number of ``jax.device_put`` calls it made, the
+    wall time of the run call)"""
+    exe, scope, target, loss = _program_on(entry)
+    for _ in range(2):
+        exe.run(target, feed=_feed(), fetch_list=[loss], scope=scope)
+    puts = []
+    real = jax.device_put
+
+    def counting(*a, **kw):
+        puts.append(1)
+        return real(*a, **kw)
+
+    feed = _feed()
+    trace.reset()
+    jax.device_put = counting
+    try:
+        t0 = time.perf_counter()
+        exe.run(target, feed=feed, fetch_list=[loss], scope=scope)
+        wall = time.perf_counter() - t0
+    finally:
+        jax.device_put = real
+    return trace.with_phases(trace.get_spans()), len(puts), wall
+
+
+@pytest.fixture(scope="module", params=["run", "with_mesh"])
+def step(request):
+    if request.param == "with_mesh" and len(jax.devices()) < 4:
+        pytest.skip("needs 4 devices")
+    return (request.param,) + _steps(request.param)
+
+
+@pytest.mark.parametrize("phase", PHASES)
+def test_executor_phase_once_a_segment(step, phase):
+    """One XLA segment: each phase occurs exactly once a run, on
+    ``Executor.run`` and on ``CompiledProgram.with_mesh`` alike;
+    prepare is two clock reads carried on ``executor_run``."""
+    _entry, spans, _puts, _wall = step
+    if phase == "executor_prepare":
+        (run,) = [s for s in spans if s["name"] == "executor_run"]
+        assert run["args"]["prepare_ms"] > 0 and run["args"]["plan_hit"]
+        return
+    assert [s["name"] for s in spans].count(phase) == 1
+
+
+def test_a_run_makes_two_records(step):
+    """What a run appends to the ring buffer: ``executor_run`` (with its
+    phases as marks) and ``executor_fetch``. The tracer's gate (under
+    2 % of a sub-millisecond step, ``tools/obs_probe.py``) counts
+    records."""
+    _entry, spans, _puts, _wall = step
+    records = [s["name"] for s in spans if s["id"] is not None]
+    assert records == ["executor_run", "executor_fetch"]
+    (run,) = [s for s in spans if s["name"] == "executor_run"]
+    assert [m[0] for m in run["args"]["phases"]] == [
+        "executor_marshal", "executor_dispatch", "executor_writeback"]
+
+
+def test_executor_phases_nest_and_add_up(step):
+    """marshal, dispatch and writeback tile ``executor_run``; prepare
+    comes before it and fetch after; together they are the run call."""
+    _entry, spans, _puts, wall = step
+    by = {s["name"]: s for s in spans}
+    run = by["executor_run"]
+    edge = run["start"]
+    for child in ("executor_marshal", "executor_dispatch",
+                  "executor_writeback"):
+        assert by[child]["parent"] == "executor_run"
+        assert by[child]["tid"] == run["tid"]
+        assert by[child]["depth"] == run["depth"] + 1
+        assert edge <= by[child]["start"] <= by[child]["end"]
+        edge = by[child]["end"]
+    assert edge == run["end"]
+    assert by["executor_fetch"]["start"] >= run["end"]
+    assert by["executor_fetch"]["parent"] != "executor_run"
+    total = run["args"]["prepare_ms"] / 1e3 + sum(
+        by[n]["end"] - by[n]["start"]
+        for n in ("executor_run", "executor_fetch"))
+    # a sub-millisecond toy step: what is left over is the calls and
+    # returns between the phases (the chip's 100 ms steps: PERF.md)
+    assert 0.6 * wall <= total <= wall
+
+
+def test_marshal_counts_the_values_it_places(step):
+    """``placed`` is the number of ``jax.device_put`` calls marshal made:
+    the two feeds, on one device and under a mesh alike, because the
+    state a step hands back is resident where the next step wants it."""
+    _entry, spans, puts, _wall = step
+    args = [s for s in spans if s["name"] == "executor_marshal"][0]["args"]
+    assert args["placed"] == puts == 2
+    assert args["segment"] == 0 and args["values"] > 10
+
+
+@pytest.mark.skipif(len(jax.devices()) < 4, reason="needs 4 devices")
+def test_mesh_state_is_placed_once_and_kept_in_the_scope():
+    """Under ``with_mesh(fsdp=True)`` the first step lays every
+    persistable out over the mesh and commits it to the scope as it does
+    (the unsharded original of startup is released before the step
+    runs); later steps find it resident: nothing but the feeds is placed
+    and nothing is written to the scope before writeback."""
+    exe, scope, target, loss = _program_on("with_mesh")
+    names = [v.name for v in target._program.list_vars() if v.persistable
+             and scope.get(v.name) is not None]
+    before = {n: scope.get(n) for n in names}
+    assert all(len(v.devices()) == 1 for v in before.values()
+               if isinstance(v, jax.Array))
+
+    def marshal_of_one_step():
+        trace.reset()
+        exe.run(target, feed=_feed(), fetch_list=[loss], scope=scope)
+        (m,) = [s for s in trace.with_phases(trace.get_spans())
+                if s["name"] == "executor_marshal"]
+        return m["args"]
+
+    first = marshal_of_one_step()
+    assert first["placed"] == first["values"] > 10
+    held = {n: scope.get(n) for n in names}
+    assert all(isinstance(v, jax.Array) and len(v.devices()) == 4
+               for v in held.values())
+    assert any(not v.sharding.is_fully_replicated for v in held.values())
+    sets = []
+    real = scope.set
+    scope.set = lambda n, v: (sets.append(n), real(n, v))[1]
+    try:
+        second = marshal_of_one_step()
+    finally:
+        scope.set = real
+    assert second["placed"] == 2 and second["values"] == first["values"]
+    # the writeback of the step's outputs, once each, and no placement
+    assert len(sets) == len(set(sets))
+
+
+def test_placed_counter_follows_the_spans():
+    before = profiler.get_counter("executor_values_placed")
+    _spans, puts, _wall = _steps("run")
+    # two warm steps and the recorded one, two feeds each; startup's run
+    # places nothing
+    assert profiler.get_counter("executor_values_placed") - before == 3 * puts
+
+
+def test_host_segment_gets_its_own_span():
+    with fluid.unique_name.guard():
+        main, startup = fluid.Program(), fluid.Program()
+        with fluid.program_guard(main, startup):
+            x = fluid.layers.data(name="x", shape=[4], dtype="float32")
+            out = fluid.layers.Print(fluid.layers.scale(x, scale=2.0),
+                                     message="phase-span-test")
+            out = fluid.layers.scale(out, scale=3.0)
+    exe = fluid.Executor(fluid.CPUPlace())
+    scope = fluid.core.Scope()
+    exe.run(startup, scope=scope)
+    trace.reset()
+    exe.run(main, feed={"x": np.ones((2, 4), "float32")},
+            fetch_list=[out], scope=scope)
+    names = [s["name"] for s in trace.with_phases(trace.get_spans())]
+    assert names.count("executor_host_ops") == 1
+    assert names.count("executor_marshal") == names.count(
+        "executor_dispatch") == 2
+
+
+# -- engine ------------------------------------------------------------------
+MAX_LEN = 48
+
+
+@pytest.fixture(scope="module")
+def gen_server():
+    # wide enough that a tick is milliseconds of work on the CPU: the
+    # tick's self time (a few tens of microseconds of span bookkeeping)
+    # is held to a share of it
+    cfg = gpt.GPTConfig.tiny(hidden_dropout=0.0, attention_dropout=0.0,
+                             hidden_size=256, num_layers=6,
+                             intermediate_size=1024, vocab_size=2048)
+    cfg.max_position_embeddings = MAX_LEN
+    with fluid.unique_name.guard():
+        infer, startup, _n, _l = gpt.build_gpt_infer(cfg, MAX_LEN)
+    exe = fluid.Executor(fluid.CPUPlace())
+    scope = fluid.core.Scope()
+    with fluid.executor.scope_guard(scope):
+        exe.run(startup)
+    engine = DecodeEngine(cfg, scope=scope, slots=4, max_len=MAX_LEN,
+                          block_size=4, prefill_buckets=[8, 16],
+                          param_program=infer)
+
+    class Echo(object):
+        def run(self, feeds):
+            return [np.asarray(feeds[0])]
+
+        def clone(self, share_plans=True):
+            return self
+
+    server = serving.InferenceServer(
+        Echo(), max_batch_size=1, num_workers=1, decode_engine=engine,
+    ).start(warmup_inputs=[np.ones((1, 4), np.float32)])
+    yield server
+    server.stop()
+
+
+TICK_CHILDREN = ("tick_reap", "tick_admit", "tick_prefill", "tick_build",
+                 "decode_tick", "tick_sample_emit")
+
+
+@pytest.fixture(scope="module")
+def ticks(gen_server):
+    """Spans of a few dozen ticks with three streams in flight."""
+    trace.reset()
+    with trace.trace_scope(trace.new_trace_id()):
+        streams = [gen_server.generate([3 + i, 7, 11], max_new_tokens=30)
+                   for i in range(3)]
+    for s in streams:
+        s.tokens(timeout=120)
+    return trace.get_spans()
+
+
+def _children(parent, spans):
+    return [s for s in spans if s is not parent and not s["instant"]
+            and s["tid"] == parent["tid"] and s["depth"] == parent["depth"] + 1
+            and s["start"] >= parent["start"] and s["end"] <= parent["end"]]
+
+
+def test_tick_children_tile_the_tick(ticks):
+    """Every ``engine_tick`` holds its phases in ``_tick`` order, and what
+    the phases leave uncovered (its self time) is under 2 % of it at the
+    median."""
+    parents = [s for s in ticks if s["name"] == "engine_tick"]
+    assert len(parents) >= 20
+    shares = []
+    for p in parents:
+        kids = sorted(_children(p, ticks), key=lambda s: s["start"])
+        names = [k["name"] for k in kids]
+        assert names[:3] == ["tick_reap", "tick_admit", "tick_prefill"]
+        assert set(names) <= set(TICK_CHILDREN)
+        if "decode_tick" in names:
+            assert names[3:] == ["tick_build", "decode_tick",
+                                 "tick_sample_emit"]
+        covered = sum(k["end"] - k["start"] for k in kids)
+        shares.append(1.0 - covered / (p["end"] - p["start"]))
+    assert statistics.median(shares) < 0.02
+
+
+def test_tick_says_what_it_held(ticks):
+    stepped = [s for s in ticks if s["name"] == "engine_tick"
+               and s["args"]["active"]]
+    a = stepped[len(stepped) // 2]["args"]
+    assert set(a) >= {"tick", "active", "prefilling", "queued", "cpu_ms",
+                      "blocks_in_use", "blocks_total", "live_tokens"}
+    assert 0 < a["blocks_in_use"] <= a["blocks_total"]
+    # a block holds 4 tokens: the live tokens fit the blocks handed out
+    assert a["live_tokens"] <= 4 * a["blocks_in_use"]
+    emit = [s for s in ticks if s["name"] == "tick_sample_emit"]
+    assert all(s["args"]["tokens"] >= 1 and s["args"]["cpu_ms"] >= 0
+               for s in emit)
+
+
+def test_decode_tick_keeps_its_extent(ticks):
+    """``decode_tick`` stays the fused device call: it holds the step's
+    feed building, ``decode_paged_step`` and the logits' reshape, and no
+    sampling."""
+    tick = [s for s in ticks if s["name"] == "decode_tick"][-1]
+    names = {k["name"] for k in _children(tick, ticks)}
+    assert names == {"step_feed", "decode_paged_step", "step_logits"}
+
+
+def test_no_span_per_token_or_slot(ticks):
+    n_ticks = len([s for s in ticks if s["name"] == "engine_tick"])
+    on_loop = [s for s in ticks if not s["instant"] and s["tid"] == [
+        t for t in ticks if t["name"] == "engine_tick"][0]["tid"]]
+    # the phases, the step's own spans and the executor's: a fixed number
+    # a tick, however many streams or tokens
+    assert len(on_loop) <= 16 * n_ticks + 40
+
+
+# -- one record per request --------------------------------------------------
+def _sse_request(port, prompt, n):
+    """-> (tokens, the done event, POST sent -> first token seconds)."""
+    conn = http.client.HTTPConnection("127.0.0.1", port, timeout=120)
+    body = json.dumps({"prompt_ids": prompt, "max_new_tokens": n}).encode()
+    toks, done, first = [], None, None
+    try:
+        sent = time.perf_counter()
+        conn.request("POST", "/v1/generate", body=body,
+                     headers={"Content-Type": "application/json"})
+        resp = conn.getresponse()
+        assert resp.status == 200
+        for line in resp:
+            if not line.startswith(b"data: "):
+                continue
+            event = json.loads(line[6:])
+            if "token" in event:
+                if first is None:
+                    first = time.perf_counter() - sent
+                toks.append(event["token"])
+            elif event.get("done"):
+                done = event  # and read on to the end of the response
+    finally:
+        conn.close()
+    return toks, done, first
+
+
+def test_request_records_share_a_trace_id_and_agree_with_the_client(
+        gen_server):
+    gw = serving.Gateway(gen_server, port=0).start()
+    try:
+        _sse_request(gw.port, [2, 9, 4], 4)  # warm the handler path
+        trace.reset()
+        toks, done, ttft = _sse_request(gw.port, [5, 6, 7, 8], 12)
+    finally:
+        gw.stop()
+    spans = trace.get_spans()
+    tid = done["trace_id"]
+    # by the id the done event gave the client (the warm request's
+    # handler may close its span after the client has gone on)
+    (req,) = [s for s in spans if s["name"] == "decode_request"
+              and s["trace_id"] == tid]
+    (gws,) = [s for s in spans if s["name"] == "gateway_request"
+              and s["trace_id"] == tid]
+    assert req["instant"]
+    a = req["args"]
+    assert a["tokens"] == len(toks) == 12
+    assert a["finish_reason"] == "length" and a["preempted"] == 0
+    assert a["prefill_windows"] == 1
+    assert a["submit"] <= a["dequeue"] <= a["first_token"] <= a["finish"]
+    assert a["queue_wait_ms"] == pytest.approx(
+        1e3 * (a["dequeue"] - a["submit"]))
+    # the client's time to first token is the engine's queue wait and
+    # prefill plus the request path on either side of them
+    # (same process, same clock; the slack is for a loaded machine)
+    inside = (a["queue_wait_ms"] + a["first_token_ms"]) / 1e3
+    assert inside <= ttft <= inside + 2.0
+    g = gws["args"]
+    assert g["status"] == 200 and g["tokens"] == 12
+    assert 0 <= g["sse_lag_ms_p50"] <= g["sse_lag_ms_max"] < 2000.0
+
+
+def test_failed_stream_leaves_a_record_too(gen_server):
+    trace.reset()
+    stream = gen_server.generate([1, 2, 3], max_new_tokens=40)
+    next(iter(stream))
+    stream.cancel()
+    with pytest.raises(Exception):
+        stream.tokens(timeout=60)
+        raise RuntimeError("cancelled streams end without an error")
+    recs = [s for s in trace.get_spans() if s["name"] == "decode_request"]
+    assert len(recs) == 1 and recs[0]["args"]["finish_reason"] == "cancelled"
+
+
+# -- kernels -----------------------------------------------------------------
+KERNELS = {
+    "flash_decode_attention": "flash_decode",
+    "flash_decode_paged_attention": "flash_decode_paged",
+    "_flash_fwd_impl": "flash_fwd",
+    "_flash_bwd_core": ("flash_bwd_dq", "flash_bwd_dkv"),
+}
+
+
+def _pallas_call_names():
+    """{enclosing function: [the ``name=`` of each pallas_call in it]}"""
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "paddle_tpu", "kernels",
+        "flash_attention.py")
+    with open(path) as f:
+        tree = ast.parse(f.read())
+    out = {}
+    for fn in ast.walk(tree):
+        if not isinstance(fn, ast.FunctionDef):
+            continue
+        for node in ast.walk(fn):
+            if (isinstance(node, ast.Call)
+                    and isinstance(node.func, ast.Attribute)
+                    and node.func.attr == "pallas_call"):
+                names = [k.value.value for k in node.keywords
+                         if k.arg == "name"]
+                out.setdefault(fn.name, []).append(
+                    names[0] if names else None)
+    return out
+
+
+@pytest.mark.parametrize("site", sorted(KERNELS))
+def test_each_pallas_call_passes_its_own_name(site):
+    want = KERNELS[site]
+    want = list(want) if isinstance(want, tuple) else [want]
+    found = _pallas_call_names()
+    assert found[site] == want
+    every = [n for names in found.values() for n in names]
+    assert None not in every and len(set(every)) == len(every) == 5
+
+
+def test_kernel_name_reaches_the_lowered_program():
+    """The name is what a device trace shows: it is in the text of the
+    program jax lowers (interpret mode off, nothing compiled or run)."""
+    import importlib
+
+    fa = importlib.import_module("paddle_tpu.kernels.flash_attention")
+    q = jax.ShapeDtypeStruct((1, 2, 128, 64), jax.numpy.bfloat16)
+
+    def loss(q, k, v):
+        return fa.flash_attention(q, k, v, causal=True,
+                                  interpret=False).astype("float32").sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).trace(
+        q, q, q).jaxpr.pretty_print(use_color=False)
+    for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+        assert name in text
+
+
+# -- off means off -----------------------------------------------------------
+def test_no_record_with_tracing_off(gen_server):
+    fluid.set_flags({"FLAGS_obs_trace": False})
+    try:
+        # the idle loop's engine_wait was opened with tracing on: let a
+        # request close it before the buffer is emptied
+        gen_server.generate([1, 2], max_new_tokens=2).tokens(timeout=60)
+        trace.reset()
+        _steps_main, startup, loss = _train_program()
+        exe = fluid.Executor(fluid.CPUPlace())
+        scope = fluid.core.Scope()
+        exe.run(startup, scope=scope)
+        exe.run(_steps_main, feed=_feed(), fetch_list=[loss], scope=scope)
+        gen_server.generate([4, 5, 6], max_new_tokens=6).tokens(timeout=60)
+        assert trace.get_spans() == []
+    finally:
+        fluid.set_flags({"FLAGS_obs_trace": True})
