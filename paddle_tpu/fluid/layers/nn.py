@@ -1474,20 +1474,26 @@ def kv_cache_gather(cache, slot_idx, name=None):
 
 
 def flash_decode_paged_attention(q, k, v, tables, key_bias=None,
-                                 scale=0.0, interpret=False, name=None):
+                                 scale=0.0, interpret=False, lengths=None,
+                                 name=None):
     """Decode-mode single-query fused attention THROUGH a block table:
     ``q`` [N, heads, 1, d_head] against the shared paged pool ``k``/``v``
     [blocks, heads, block, d_head], with ``tables`` [N, max_blocks]
     int32 mapping each slot's logical blocks to physical pool blocks.
     ``key_bias`` [N, max_blocks*block] masks positions at/beyond each
-    slot's live length (and any sink-block garbage). Tables are runtime
-    data (scalar-prefetched on TPU) — one compiled program serves every
-    table layout. Forward-only; ``scale`` 0 means 1/sqrt(d_head)."""
+    slot's live length (and any sink-block garbage). ``lengths`` [N]
+    int, live keys a slot: table entries past ceil(length / block) are
+    neither fetched nor computed (without it every entry is live).
+    Tables and lengths are runtime data (scalar-prefetched on TPU) — one
+    compiled program serves every table layout and length mix.
+    Forward-only; ``scale`` 0 means 1/sqrt(d_head)."""
     helper = LayerHelper("flash_decode_paged_attention", **locals())
     out = helper.create_variable_for_type_inference(dtype=q.dtype)
     inputs = {"Q": [q], "K": [k], "V": [v], "Tables": [tables]}
     if key_bias is not None:
         inputs["KeyBias"] = [key_bias]
+    if lengths is not None:
+        inputs["Lengths"] = [lengths]
     helper.append_op(
         type="flash_decode_paged_attention",
         inputs=inputs,

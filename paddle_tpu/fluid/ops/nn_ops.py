@@ -1031,7 +1031,10 @@ def _flash_decode_paged_attention(ctx, op_):
     THROUGH a fed [slots, max_blocks] block table over the shared
     [blocks, heads, block, d_head] pool — on TPU the table rides scalar
     prefetch so the kernel's DMA chases the indirection without ever
-    materializing the logical rows. Inference-only; no grad."""
+    materializing the logical rows. The optional ``Lengths`` [slots]
+    (live keys a slot) rides beside it: table entries past a slot's
+    live blocks are neither fetched nor computed; without it every
+    entry is live. Inference-only; no grad."""
     from ...kernels.flash_attention import flash_decode_paged_attention
 
     q = ctx.in1(op_, "Q")
@@ -1040,18 +1043,20 @@ def _flash_decode_paged_attention(ctx, op_):
     tables = ctx.in1(op_, "Tables")
     kb_names = op_.inputs.get("KeyBias") or []
     key_bias = ctx.in1(op_, "KeyBias") if kb_names else None
+    len_names = op_.inputs.get("Lengths") or []
+    lengths = ctx.in1(op_, "Lengths") if len_names else None
     scale = op_.attr("scale", 0.0)
     interpret = bool(op_.attr("interpret", False)) or None
     B, N = q.shape[:2]
     key_bias, kb_dims = _key_bias_dims(key_bias, B, N)
     # heads only: the pool's block dim belongs to no slot, so slots (and
-    # with them tables and a per-slot mask) stay whole
+    # with them tables, lengths and a per-slot mask) stay whole
     ctx.out(op_, "Out", _per_shard(
-        lambda q, k, v, tables, kb: flash_decode_paged_attention(
-            q, k, v, tables, key_bias=kb,
+        lambda q, k, v, tables, kb, lengths: flash_decode_paged_attention(
+            q, k, v, tables, key_bias=kb, lengths=lengths,
             scale=float(scale) if scale else None, interpret=interpret),
-        (q, k, v, tables, key_bias), (2, 2, 2, 0, kb_dims), ((2, 4),),
-        *_shard_axes(B, N, interpret, batch_axis=False),
+        (q, k, v, tables, key_bias, lengths), (2, 2, 2, 0, kb_dims, 0),
+        ((2, 4),), *_shard_axes(B, N, interpret, batch_axis=False),
     ))
 
 

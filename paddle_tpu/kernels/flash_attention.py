@@ -472,24 +472,66 @@ def flash_decode_attention(q, k, v, key_bias=None, scale=None,
     return out[:, :1, :].reshape(B, N, 1, D)
 
 
-def _decode_paged_kernel(tables_ref, q_ref, k_ref, v_ref, kb_ref, o_ref,
-                         m_ref, l_ref, acc_ref, *, scale, block_q):
-    """Paged decode step, one (slot, head, logical-block) program: the
-    grid's innermost dimension sweeps a slot's LOGICAL blocks while the
-    K/V BlockSpec index maps read the slot's block TABLE (a
-    scalar-prefetch operand) to pick the physical pool block — the DMA
-    engine chases the indirection, the kernel body never sees it. Online
-    softmax state (m, l, acc) lives in VMEM scratch across the sweep;
-    the output block is written once on the last logical block. Same
-    masking contract as ``_decode_kernel``: the per-slot key bias
-    carries ALL masking, including sink-block garbage past the slot's
-    live length. The bias rides as the slot's whole [max_blocks, block]
-    table (a block whose trailing dims equal the array's, which the
-    Mosaic (8, 128) rule admits where a lone (1, block) strip is
-    refused); the program picks its logical block's row."""
+# keys a paged-decode program takes at least: one lane tile of scores, so
+# the online softmax touches its scratch once per PAGED_KEYS keys and a
+# program's DMAs are large enough to be worth their descriptors
+PAGED_KEYS = 128
+
+
+def _live_blocks(length, block):
+    """Table entries of a slot that hold a live key: ceil(length / block)
+    (``flash_decode_paged_attention`` holds a length to 1 .. the table's
+    keys, so at least one — an inactive slot still reads one sink block —
+    and at most the table)."""
+    return (length + block - 1) // block
+
+
+def _held_blocks(tables, lengths, block, pages):
+    """[B, programs*pages] int32: the PHYSICAL block that K/V operand
+    j = e % pages of a slot's program i = e // pages holds. Table entry e
+    itself while it is live; past the live entries the operand stays on
+    the last live block it held (or, never having held one, on the slot's
+    last live block) — an index that does not change from one program to
+    the next is not fetched again, and a dead entry's own block is never
+    read. Computed once a call, outside the kernel: the index maps only
+    look it up."""
+    MB = tables.shape[1]
+    last = _live_blocks(lengths, block)[:, None] - 1
+    e = jnp.arange(-(-MB // pages) * pages, dtype=jnp.int32)[None, :]
+    j = e % pages
+    held = jnp.where(j <= last, last - (last - j) % pages, last)
+    return jnp.take_along_axis(tables, jnp.minimum(e, held), axis=1)
+
+
+def _decode_paged_kernel(held_ref, lengths_ref, q_ref, *refs, scale,
+                         pages):
+    """Paged decode step, one (slot, group of ``pages`` logical blocks)
+    program over ALL heads of the slot. The pool rides in ``pages`` times
+    for K and ``pages`` times for V; operand j's index map looks up the
+    slot's block table (scalar prefetch, as ``_held_blocks`` laid it out)
+    for logical block i*pages + j, so the pipeline's own DMAs chase the
+    indirection and each pulls the [heads, block, d_head] of one physical
+    block in one piece. The slot's live length rides beside the table: a
+    logical block past the last live one is not fetched (its entry names
+    a block the operand already holds) and not computed — a program with
+    no live block does nothing, and inside the last live program the dead
+    columns are masked to ``_NEG`` before the key bias could matter.
+    Inside the live blocks the masking contract is ``_decode_kernel``'s:
+    the per-slot key bias carries ALL masking, the last block's unfilled
+    tail included. Online softmax state (m, l, acc per head) lives in
+    VMEM scratch across a slot's programs and is touched once per
+    program; the output block is written on the slot's last program. The
+    bias rides as the slot's whole [programs, pages*block] table (a block
+    whose trailing dims equal the array's, which the Mosaic (8, 128) rule
+    admits where a lone (1, block) strip is refused); the program picks
+    its own row."""
     from jax.experimental import pallas as pl
 
-    i = pl.program_id(2)
+    k_refs, v_refs = refs[:pages], refs[pages:2 * pages]
+    kb_ref, o_ref, m_ref, l_ref, acc_ref = refs[2 * pages:]
+    b, i = pl.program_id(0), pl.program_id(1)
+    block = k_refs[0].shape[2]
+    keys = pages * block
 
     @pl.when(i == 0)
     def _init():
@@ -497,30 +539,42 @@ def _decode_paged_kernel(tables_ref, q_ref, k_ref, v_ref, kb_ref, o_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0, 0]                               # [BQ, D], input dtype
-    kblk = k_ref[0, 0]                            # [blk, D]
-    block_k = kblk.shape[0]
-    s = _scores(q, kblk, scale, kb_ref[0, pl.ds(i, 1), :], None, 0, 0,
-                False, block_q, block_k)
-    m = m_ref[...]
-    m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
-    alpha = jnp.exp(m - m_new)
-    p = jnp.exp(s - m_new)
-    l_ref[...] = l_ref[...] * alpha + p.sum(axis=-1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-        p.astype(v_ref.dtype), v_ref[0, 0], (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-    m_ref[...] = m_new
+    # live blocks from this program's first one on
+    live = _live_blocks(lengths_ref[b], block) - i * pages
 
-    @pl.when(i == pl.num_programs(2) - 1)
+    @pl.when(live > 0)
+    def _attend():
+        # heads are the batch dimension of both products: [N, BQ, D] x
+        # [N, keys, D] -> [N, BQ, keys], operands in their INPUT dtype and
+        # the accumulator fp32, as in ``_scores``
+        kblk = jnp.concatenate([r[0] for r in k_refs], axis=1)
+        vblk = jnp.concatenate([r[0] for r in v_refs], axis=1)
+        dead = jax.lax.broadcasted_iota(
+            jnp.int32, (1, 1, keys), 2) >= live * block
+        s = jax.lax.dot_general(
+            q_ref[0], kblk, (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32,
+        )
+        s = s * scale + jnp.where(dead, _NEG, kb_ref[:, pl.ds(i, 1), :])
+        m = m_ref[...]
+        m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
+        alpha = jnp.exp(m - m_new)
+        p = jnp.exp(s - m_new)
+        l_ref[...] = l_ref[...] * alpha + p.sum(axis=-1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+            p.astype(vblk.dtype), vblk, (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32,
+        )
+        m_ref[...] = m_new
+
+    @pl.when(i == pl.num_programs(1) - 1)
     def _emit():
-        o_ref[0, 0] = (acc_ref[...]
-                       / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...]
+                    / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
 
 
 def flash_decode_paged_attention(q, k_pool, v_pool, tables, key_bias=None,
-                                 scale=None, interpret=None):
+                                 scale=None, interpret=None, lengths=None):
     """Decode-mode attention reading K/V THROUGH a block table: ``q``
     [B, N, 1, D] (one live token per slot) against a shared paged pool
     ``k_pool``/``v_pool`` [blocks, N, block, D], with ``tables``
@@ -528,12 +582,19 @@ def flash_decode_paged_attention(q, k_pool, v_pool, tables, key_bias=None,
     physical pool block. ``key_bias`` [B, S] (S = max_blocks*block)
     additively masks positions at/beyond the slot's live length — which
     also covers any garbage the mapped blocks hold (the serving layer
-    parks idle table entries on a sink block). Tables are runtime data:
-    on TPU they ride scalar prefetch, so the index maps resolve the
-    indirection before each DMA and ONE compiled kernel serves every
-    table layout. Forward-only; dense gather-then-softmax fallback off
-    TPU — bit-compatible with gathering the logical rows and calling
-    ``flash_decode_attention``."""
+    parks idle table entries on a sink block). ``lengths`` [B] int32,
+    live keys a slot, says how far a table row is worth reading: entries
+    at or past ceil(length / block) are neither fetched nor computed
+    (so they may point anywhere in the pool), and the kernel's work
+    follows the live keys instead of the table's width. Without it every
+    table entry is live. Tables and lengths are runtime data: on TPU
+    they ride scalar prefetch, so the index maps resolve the indirection
+    before each DMA and ONE compiled kernel serves every table layout
+    and every mix of lengths. The tiling comes from the shapes: all N
+    heads of a slot and ``PAGED_KEYS`` keys (or the whole table, where it
+    is shorter) a program. Forward-only; dense gather-then-softmax
+    fallback off TPU — bit-compatible with gathering the logical rows
+    and calling ``flash_decode_attention``."""
     from jax.experimental import pallas as pl  # noqa: F401 (dispatch)
     from jax.experimental.pallas import tpu as pltpu
 
@@ -555,9 +616,18 @@ def flash_decode_paged_attention(q, k_pool, v_pool, tables, key_bias=None,
     kb = _normalize_key_bias(key_bias, B, N, S)
     on_tpu = lowers_for_tpu()
     tables = tables.astype(jnp.int32)
+    if lengths is not None:
+        lengths = jnp.clip(lengths.astype(jnp.int32).reshape(B), 1, S)
     if interpret is None and not on_tpu:
         # dense fallback: gather the logical rows, then the same math as
-        # flash_decode_attention's reference path
+        # flash_decode_attention's reference path. With lengths, a dead
+        # entry reads the slot's last live block instead and is masked.
+        dead = None
+        if lengths is not None:
+            tables = _held_blocks(tables, lengths, blk, MB)
+            dead = jnp.repeat(
+                jnp.arange(MB)[None, :]
+                >= _live_blocks(lengths, blk)[:, None], blk, axis=1)
         rows_k = k_pool[tables].transpose(0, 2, 1, 3, 4).reshape(
             B, N, S, D
         )
@@ -569,50 +639,58 @@ def flash_decode_paged_attention(q, k_pool, v_pool, tables, key_bias=None,
         ) * scale
         if kb is not None:
             s = s + kb.reshape(B, N, 1, S)
+        if dead is not None:
+            s = jnp.where(dead[:, None, None, :], _NEG, s)
         p = jax.nn.softmax(s, axis=-1)
         return jnp.einsum("bnqk,bnkd->bnqd", p.astype(q.dtype), rows_v)
-    # [G, max_blocks, block] with G = B (one mask per slot, what the
+    P = min(MB, -(-PAGED_KEYS // blk))         # logical blocks a program
+    programs = -(-MB // P)
+    if lengths is None:
+        lengths = jnp.full((B,), S, jnp.int32)
+    # [G, programs, P*block] with G = B (one mask per slot, what the
     # engine feeds) or B*N (per head): a per-slot mask is NOT expanded
-    # over heads — its block index ignores n, so the DMA is skipped
-    # across a slot's whole head and block sweep
+    # over heads — its block index ignores the program, so it is fetched
+    # once a slot
     if key_bias is not None and key_bias.size == B * S:
         kb = key_bias.astype(jnp.float32)
     elif kb is None:
         kb = jnp.zeros((B, S), jnp.float32)
     per_head = kb.size != B * S
-    kb = kb.reshape(-1, MB, blk)
+    kb = kb.reshape(-1, S)
+    kb = jnp.pad(kb, ((0, 0), (0, programs * P * blk - S)))
+    kb = kb.reshape(-1, programs, P * blk)
     BQ = _round_up(Sq, 8)                      # Mosaic sublane minimum
     qp = jnp.pad(q, ((0, 0), (0, 0), (0, BQ - Sq), (0, 0)))
-    kernel = functools.partial(
-        _decode_paged_kernel, scale=scale, block_q=BQ,
-    )
-    # index maps receive the grid indices first, then the prefetched
-    # scalar ref (the table) — the K/V maps dereference it so each DMA
-    # pulls the slot's PHYSICAL block for logical block i
+    kernel = functools.partial(_decode_paged_kernel, scale=scale, pages=P)
+
+    def pool_spec(j):
+        # index maps receive the grid indices first, then the prefetched
+        # scalar refs: operand j of program i pulls the physical block the
+        # held table names for it
+        return pl.BlockSpec(
+            (1, N, blk, D),
+            lambda b, i, held, lens: (held[b, i * P + j], 0, 0, 0),
+            memory_space=pltpu.VMEM,
+        )
+
+    pool_specs = [pool_spec(j) for j in range(P)]
+    slot = lambda b, i, held, lens: (b, 0, 0, 0)  # noqa: E731
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(B, N, MB),
+        num_scalar_prefetch=2,
+        grid=(B, programs),
         in_specs=[
-            pl.BlockSpec((1, 1, BQ, D), lambda b, n, i, t: (b, n, 0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 1, blk, D),
-                         lambda b, n, i, t: (t[b, i], n, 0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 1, blk, D),
-                         lambda b, n, i, t: (t[b, i], n, 0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, MB, blk),
-                         lambda b, n, i, t: (b * N + n if per_head else b,
-                                             0, 0),
+            pl.BlockSpec((1, N, BQ, D), slot, memory_space=pltpu.VMEM),
+            *pool_specs, *pool_specs,
+            pl.BlockSpec((N if per_head else 1, programs, P * blk),
+                         lambda b, i, held, lens: (b, 0, 0),
                          memory_space=pltpu.VMEM),
         ],
-        out_specs=pl.BlockSpec((1, 1, BQ, D),
-                               lambda b, n, i, t: (b, n, 0, 0),
+        out_specs=pl.BlockSpec((1, N, BQ, D), slot,
                                memory_space=pltpu.VMEM),
         scratch_shapes=[
-            pltpu.VMEM((BQ, 1), jnp.float32),
-            pltpu.VMEM((BQ, 1), jnp.float32),
-            pltpu.VMEM((BQ, D), jnp.float32),
+            pltpu.VMEM((N, BQ, 1), jnp.float32),
+            pltpu.VMEM((N, BQ, 1), jnp.float32),
+            pltpu.VMEM((N, BQ, D), jnp.float32),
         ],
     )
     out = pl.pallas_call(
@@ -621,7 +699,8 @@ def flash_decode_paged_attention(q, k_pool, v_pool, tables, key_bias=None,
         out_shape=jax.ShapeDtypeStruct((B, N, BQ, D), q.dtype),
         grid_spec=grid_spec,
         interpret=bool(interpret),
-    )(tables, qp, k_pool, v_pool, kb)
+    )(_held_blocks(tables, lengths, blk, P), lengths, qp,
+      *([k_pool] * P), *([v_pool] * P), kb)
     return out[:, :, :1, :]
 
 

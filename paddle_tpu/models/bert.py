@@ -247,12 +247,17 @@ def multi_head_attention(q_in, kv_in, attn_bias, cfg, name, key_bias=None,
         T_static = q.shape[2]
         if use_flash and T_static == 1:
             # single-query path: the Pallas kernel chases the table via
-            # scalar prefetch — the logical rows never materialize.
+            # scalar prefetch — the logical rows never materialize. The
+            # slot's live keys (its write position + the token just
+            # written) tell the kernel where the table row stops being
+            # worth reading; the bias still masks inside the live blocks.
             kb = fluid.layers.reshape(cache["step_bias"], shape=[0, -1])
             kb.stop_gradient = True
+            lengths = fluid.layers.scale(cache["pos"], bias=1.0)
+            lengths.stop_gradient = True
             ctxt = fluid.layers.flash_decode_paged_attention(
                 q, cache["k"], cache["v"], cache["tables"], key_bias=kb,
-                scale=scale_,
+                scale=scale_, lengths=lengths,
                 interpret=getattr(cfg, "flash_interpret", False),
             )
         else:

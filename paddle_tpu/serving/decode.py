@@ -1060,9 +1060,17 @@ class DecodeSession(object):
                 "tables": tbl,
                 "step_bias": bias,
             }
+            # of the slots x max_blocks table entries, the ones that hold
+            # a live key after this window's writes: the share of the
+            # table the T = 1 kernel fetches and computes
+            blocks_live = int(
+                (-(-(pos[act] + width) // self.block_size)).sum()
+            )
         t0 = time.perf_counter()
         with _trace.span("decode_paged_step", cat="serving",
-                         active=int(act.sum()), width=width):
+                         active=int(act.sum()), width=width,
+                         blocks_live=blocks_live,
+                         blocks_table=self.slots * self.max_blocks):
             (lv,) = self.exe.run(
                 main, feed=feed, fetch_list=[fetch_name], scope=self.scope
             )

@@ -26,37 +26,86 @@ SPEC_K = 4
 
 
 # -- op units ---------------------------------------------------------------
+# name -> (heads, block, table entries, live keys a slot, Lengths given,
+# dead entries poisoned). The kernel takes ceil(128 / block) table entries
+# a program, or the whole table where it is shorter.
+PAGED_KERNEL_CASES = {
+    # every table entry live: the semantics without Lengths
+    "whole_table": (2, BLOCK, 5, [3, 11, 20], False, False),
+    "lengths": (2, BLOCK, 5, [3, 11, 20], True, False),
+    # one key, exactly one block, one block + 1, the whole table
+    "length_edges": (2, BLOCK, 5, [1, BLOCK, BLOCK + 1, 5 * BLOCK], True,
+                     False),
+    # 11 entries in programs of 8: the last program is short, and the
+    # lengths end in the first program, at its edge, and in the second
+    "ragged_programs": (2, 16, 11, [7, 128, 129, 176], True, False),
+    "ragged_whole_table": (2, 16, 11, [7, 130, 176], False, False),
+    # a tp = 4 shard of 12 heads
+    "three_heads": (3, BLOCK, 5, [3, 11, 20], True, False),
+    # dead entries aimed at a block of NaN keys and inf values
+    "poisoned_dead": (2, BLOCK, 5, [3, 11, 17], True, True),
+    "poisoned_dead_ragged": (2, 16, 11, [7, 128, 129], True, True),
+}
+
+
+@pytest.mark.parametrize("case", sorted(PAGED_KERNEL_CASES))
 @pytest.mark.parametrize("mask", ["per_slot", "per_head", "none"])
 @pytest.mark.parametrize("dtype,tol", [("float32", 1e-5), ("bfloat16", 3e-2)])
-def test_paged_flash_kernel_matches_gather_reference(mask, dtype, tol):
+def test_paged_flash_kernel_matches_gather_reference(case, mask, dtype, tol):
     """flash_decode_paged_attention's Pallas kernel (interpret mode)
     against its gather-then-softmax reference, through permuted tables,
-    with the key bias in each layout the kernel's [G, max_blocks, block]
-    table admits: one mask per slot (what the engine feeds, G = slots),
-    one per head (G = slots*heads), and none."""
+    with the key bias in each layout the kernel's [G, programs,
+    pages*block] table admits: one mask per slot (what the engine feeds,
+    G = slots), one per head (G = slots*heads), and none. With Lengths
+    the reference masks the dead table entries itself and reads clean
+    tables, so a kernel (or fallback) that let a dead block into the
+    result — the poisoned cases aim them at NaN keys and inf values —
+    would differ or not be finite."""
     import importlib
 
     import jax.numpy as jnp
 
     fa = importlib.import_module("paddle_tpu.kernels.flash_attention")
-    S, H, D, MB = SLOTS, 2, 16, 5
+    H, block, MB, live, given, poisoned = PAGED_KERNEL_CASES[case]
+    live = np.array(live)
+    S, D = len(live), 16
     NB = S * MB + 1
     rs = np.random.RandomState(0)
     q = jnp.asarray(rs.randn(S, H, 1, D), dtype)
-    k_pool = jnp.asarray(rs.randn(NB, H, BLOCK, D), dtype)
-    v_pool = jnp.asarray(rs.randn(NB, H, BLOCK, D), dtype)
-    tables = jnp.asarray(rs.permutation(NB - 1)[:S * MB].reshape(S, MB) + 1)
-    live = np.array([3, 11, 20])
-    kb = np.where(np.arange(MB * BLOCK)[None] < live[:, None], 0.0, -1e4)
+    k_pool = rs.randn(NB + 1, H, block, D)
+    v_pool = rs.randn(NB + 1, H, block, D)
+    k_pool[NB], v_pool[NB] = np.nan, np.inf      # no clean table names it
+    k_pool, v_pool = jnp.asarray(k_pool, dtype), jnp.asarray(v_pool, dtype)
+    clean = rs.permutation(NB - 1)[:S * MB].reshape(S, MB) + 1
+    cols = np.arange(MB * block)[None]
+    kb = np.where(cols < live[:, None], 0.0, -1e4)
     if mask == "per_head":
-        kb = np.repeat(kb, H, 0) + 0.1 * rs.randn(S * H, MB * BLOCK)
+        kb = np.repeat(kb, H, 0) + 0.1 * rs.randn(S * H, MB * block)
     kb = None if mask == "none" else jnp.asarray(kb, "float32")
-    want = fa.flash_decode_paged_attention(q, k_pool, v_pool, tables,
-                                           key_bias=kb)
+    ref_kb, kwargs, tables = kb, {}, clean
+    if given:
+        dead = cols >= (-(-live // block) * block)[:, None]
+        ref_kb = np.where(dead, -1e30, 0.0)
+        if mask == "per_head":
+            ref_kb = np.repeat(ref_kb, H, 0)
+        ref_kb = jnp.asarray(ref_kb, "float32") + (0.0 if kb is None else kb)
+        kwargs["lengths"] = jnp.asarray(live, "int32")
+        if poisoned:
+            tables = np.where(dead[:, ::block], NB, clean)
+    want = fa.flash_decode_paged_attention(q, k_pool, v_pool,
+                                           jnp.asarray(clean), key_bias=ref_kb)
+    tables = jnp.asarray(tables)
     got = fa.flash_decode_paged_attention(q, k_pool, v_pool, tables,
-                                          key_bias=kb, interpret=True)
+                                          key_bias=kb, interpret=True,
+                                          **kwargs)
     assert got.shape == (S, H, 1, D) and got.dtype == q.dtype
+    assert np.isfinite(np.asarray(got, "float32")).all()
     np.testing.assert_allclose(np.asarray(got, "float32"),
+                               np.asarray(want, "float32"), atol=tol)
+    # the fallback reads a table row no further than the kernel does
+    dense = fa.flash_decode_paged_attention(q, k_pool, v_pool, tables,
+                                            key_bias=kb, **kwargs)
+    np.testing.assert_allclose(np.asarray(dense, "float32"),
                                np.asarray(want, "float32"), atol=tol)
 
 

@@ -84,15 +84,17 @@ def _decode(seq, dtype):
                 ((SLOTS, seq), F32)], 1
 
 
-def _paged(max_blocks, dtype):
-    def fn(q, kp, vp, tables, kb):
+def _paged(max_blocks, dtype, slots=SLOTS):
+    def fn(q, kp, vp, tables, kb, lengths):
         return fa.flash_decode_paged_attention(q, kp, vp, tables,
-                                               key_bias=kb, interpret=False)
+                                               key_bias=kb, lengths=lengths,
+                                               interpret=False)
 
-    pool = ((SLOTS * max_blocks + 1, HEADS, BLOCK, D_HEAD), dtype)
-    return fn, [((SLOTS, HEADS, 1, D_HEAD), dtype), pool, pool,
-                ((SLOTS, max_blocks), jnp.int32),
-                ((SLOTS, max_blocks * BLOCK), F32)], 1
+    pool = ((slots * max_blocks + 1, HEADS, BLOCK, D_HEAD), dtype)
+    return fn, [((slots, HEADS, 1, D_HEAD), dtype), pool, pool,
+                ((slots, max_blocks), jnp.int32),
+                ((slots, max_blocks * BLOCK), F32),
+                ((slots,), jnp.int32)], 1
 
 
 CASES = {
@@ -115,6 +117,8 @@ CASES = {
     "paged_64blocks_bf16": lambda: _paged(64, BF16),
     "paged_256blocks_f32": lambda: _paged(256, F32),
     "paged_256blocks_bf16": lambda: _paged(256, BF16),
+    # the serve cell's own geometry: 64 slots of max_len 1024
+    "paged_64blocks_64slots_f32": lambda: _paged(64, F32, slots=64),
 }
 
 

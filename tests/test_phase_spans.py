@@ -325,6 +325,38 @@ def test_decode_tick_keeps_its_extent(ticks):
     assert names == {"step_feed", "decode_paged_step", "step_logits"}
 
 
+def test_paged_step_says_how_much_of_the_table_is_live(gen_server):
+    """``decode_paged_step`` carries ``blocks_live`` and ``blocks_table``:
+    of the slots x max_blocks table entries the T = 1 kernel is handed,
+    the ones that hold a live key — which is the blocks the allocator has
+    handed to the active slots at that call."""
+    engine = gen_server._decode_engine
+    sess, held = engine.session, []
+    step = sess.paged_step
+
+    def counting(tokens, positions, tables, active, width=1):
+        held.append(sum(len(tables[s]) for s in range(sess.slots)
+                        if active[s]))
+        return step(tokens, positions, tables, active, width=width)
+
+    trace.reset()
+    sess.paged_step = counting
+    try:
+        streams = [gen_server.generate([5 + i] * (3 + 4 * i),
+                                       max_new_tokens=9) for i in range(3)]
+        for s in streams:
+            s.tokens(timeout=120)
+    finally:
+        del sess.paged_step
+    args = [s["args"] for s in trace.get_spans()
+            if s["name"] == "decode_paged_step"]
+    assert len(args) == len(held) >= 8
+    assert [a["blocks_live"] for a in args] == held
+    assert {a["blocks_table"] for a in args} == {sess.slots * sess.max_blocks}
+    # streams of different lengths, each well short of its table row
+    assert 0 < max(held) < sess.slots * sess.max_blocks // 2
+
+
 def test_no_span_per_token_or_slot(ticks):
     n_ticks = len([s for s in ticks if s["name"] == "engine_tick"])
     on_loop = [s for s in ticks if not s["instant"] and s["tid"] == [
