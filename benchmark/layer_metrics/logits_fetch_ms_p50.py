@@ -1,0 +1,15 @@
+"""Median over the window's T = 1 steps of the ``executor_fetch`` span
+inside ``decode_paged_step``: the wait for the step and the copy of its
+[slots, vocab] float32 logits to the host."""
+
+from benchmark.harness import program_spans as ps
+from benchmark.harness.stats import median
+
+
+def read(ev):
+    spans = ps.in_window(ev)
+    fetches = ps.named(spans, "executor_fetch")
+    xs = [sum(ps.ms(f) for f in ps.inside(step, fetches))
+          for step in ps.named(spans, "decode_paged_step")]
+    xs = [x for x in xs if x > 0]
+    return median(xs) if xs else None

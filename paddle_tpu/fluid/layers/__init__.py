@@ -5,6 +5,7 @@ from . import math_op_patch  # patches Variable operators; must import first
 from .tensor import *  # noqa: F401,F403
 from .ops import *  # noqa: F401,F403
 from .nn import *  # noqa: F401,F403
+from .decoder import *  # noqa: F401,F403
 from .io import *  # noqa: F401,F403
 from .control_flow import *  # noqa: F401,F403
 from .extended import *  # noqa: F401,F403

@@ -1,0 +1,96 @@
+"""Layer functions of the latent-attention / routed-expert decoder ops
+(``fluid/ops/decoder_ops.py``). All inference only."""
+
+from ..layer_helper import LayerHelper
+
+__all__ = [
+    "rms_norm",
+    "rotary_embedding",
+    "swiglu",
+    "moe_ffn",
+    "mla_window_attention",
+    "mla_decode_paged_attention",
+]
+
+
+def _one_out(op_type, inputs, attrs, dtype, name=None):
+    helper = LayerHelper(op_type, name=name)
+    out = helper.create_variable_for_type_inference(dtype=dtype)
+    helper.append_op(type=op_type, inputs=inputs, outputs={"Out": [out]},
+                     attrs=attrs)
+    return out
+
+
+def rms_norm(x, scale, epsilon=1e-6, name=None):
+    """``scale * x / sqrt(mean(x^2) + epsilon)`` over the last axis
+    (float32 statistics); ``scale`` is a [C] parameter the caller made."""
+    return _one_out("rms_norm", {"X": [x], "Scale": [scale]},
+                    {"epsilon": float(epsilon)}, x.dtype, name)
+
+
+def rotary_embedding(x, pos, head_dim, rope_dim, theta=10000.0,
+                     interleaved=False, name=None):
+    """Rotary positions at the fed ``pos`` [N, T(, 1)] on the last
+    ``rope_dim`` values of every ``head_dim`` chunk of ``x`` [N, T, C]."""
+    return _one_out("rotary_embedding", {"X": [x], "Pos": [pos]},
+                    {"head_dim": int(head_dim), "rope_dim": int(rope_dim),
+                     "theta": float(theta),
+                     "interleaved": bool(interleaved)}, x.dtype, name)
+
+
+def swiglu(gate, up, name=None):
+    """silu(gate) * up."""
+    return _one_out("swiglu", {"Gate": [gate], "Up": [up]}, {}, gate.dtype,
+                    name)
+
+
+def moe_ffn(x, router_w, router_bias, w1, w3, w2, num_experts,
+            experts_per_token, expert_offset=0, scaling=1.0, name=None):
+    """The routed part of a sparse expert layer over the experts held
+    (``w1``/``w3`` [E_held, H, I], ``w2`` [E_held, I, H], global numbers
+    from ``expert_offset``). -> (out like ``x``, counts int32 [E_held])."""
+    helper = LayerHelper("moe_ffn", name=name)
+    out = helper.create_variable_for_type_inference(dtype=x.dtype)
+    counts = helper.create_variable_for_type_inference(dtype="int32")
+    helper.append_op(
+        type="moe_ffn",
+        inputs={"X": [x], "RouterW": [router_w],
+                "RouterBias": [router_bias], "W1": [w1], "W3": [w3],
+                "W2": [w2]},
+        outputs={"Out": [out], "Counts": [counts]},
+        attrs={"num_experts": int(num_experts),
+               "experts_per_token": int(experts_per_token),
+               "expert_offset": int(expert_offset),
+               "scaling": float(scaling)},
+    )
+    return out, counts
+
+
+def _mla_attrs(num_heads, nope_dim, rope_dim, v_dim):
+    return {"num_heads": int(num_heads), "nope_dim": int(nope_dim),
+            "rope_dim": int(rope_dim), "v_dim": int(v_dim)}
+
+
+def mla_window_attention(q, rows, wkvb, qpos, num_heads, nope_dim,
+                         rope_dim, v_dim, name=None):
+    """Up-projected latent attention of ``q`` [N, T, heads*(nope+rope)]
+    over the latent ``rows`` [N, S, W]; key j visible to query i iff
+    j <= qpos[i]. -> [N, T, heads*v_dim]."""
+    return _one_out(
+        "mla_window_attention",
+        {"Q": [q], "Rows": [rows], "Wkvb": [wkvb], "QPos": [qpos]},
+        _mla_attrs(num_heads, nope_dim, rope_dim, v_dim), q.dtype, name)
+
+
+def mla_decode_paged_attention(q, pool, tables, lengths, wkvb, num_heads,
+                               nope_dim, rope_dim, v_dim, interpret=False,
+                               name=None):
+    """Absorbed latent attention of one query a slot (``q`` [slots, 1,
+    heads*(nope+rope)]) against the paged latent ``pool`` through
+    ``tables`` up to ``lengths`` live keys. -> [slots, 1, heads*v_dim]."""
+    return _one_out(
+        "mla_decode_paged_attention",
+        {"Q": [q], "Pool": [pool], "Tables": [tables],
+         "Lengths": [lengths], "Wkvb": [wkvb]},
+        dict(_mla_attrs(num_heads, nope_dim, rope_dim, v_dim),
+             interpret=bool(interpret)), q.dtype, name)

@@ -24,6 +24,7 @@ import random
 import subprocess
 import threading
 
+import ml_dtypes
 import numpy as np
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
@@ -206,6 +207,9 @@ _NP_TO_ENUM = {
     np.dtype(np.bool_): 0, np.dtype(np.int16): 1, np.dtype(np.int32): 2,
     np.dtype(np.int64): 3, np.dtype(np.float16): 4, np.dtype(np.float32): 5,
     np.dtype(np.float64): 6, np.dtype(np.uint8): 20, np.dtype(np.int8): 21,
+    # bfloat16 (a served model's parameters): numpy knows it through
+    # ml_dtypes, which jax brings
+    np.dtype(ml_dtypes.bfloat16): 22,
 }
 _ENUM_TO_NP = {v: k for k, v in _NP_TO_ENUM.items()}
 

@@ -17,6 +17,7 @@ from .registry import get_op_def, register_op, LowerCtx  # noqa: F401
 from . import tensor_ops  # noqa: F401
 from . import math_ops  # noqa: F401
 from . import nn_ops  # noqa: F401
+from . import decoder_ops  # noqa: F401
 from . import optimizer_ops  # noqa: F401
 from . import collective_ops  # noqa: F401
 from . import controlflow_ops  # noqa: F401

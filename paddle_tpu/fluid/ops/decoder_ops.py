@@ -1,0 +1,313 @@
+"""Ops of a pre-norm decoder with latent (MLA) attention and routed
+experts (the ``deepseek_v3`` block): RMSNorm, rotary positions at fed
+positions, the gated SiLU product, one routed-expert op, and latent
+attention in its two forms (up-projected for a window of queries,
+absorbed for one query a slot read through a block table).
+
+All are inference ops: none registers a gradient. Matmuls take their
+operands in the dtype they come in (bfloat16 in a served program) and
+accumulate in float32; norms, softmax and the router compute in float32.
+"""
+
+from __future__ import annotations
+
+import math
+
+from .registry import in_var, op, same_shape_infer, set_out
+
+_NEG = -1e30
+_WINDOW_BLOCK = 512   # queries a block, keys a chunk of ``mla_window``
+
+
+def rms_norm(x, w, eps):
+    """w * x / sqrt(mean(x^2) + eps) over the last axis, statistics in
+    float32, result in x's dtype."""
+    import jax
+    import jax.numpy as jnp
+
+    xf = x.astype(jnp.float32)
+    y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, -1, keepdims=True) + eps)
+    return (y * w.astype(jnp.float32)).astype(x.dtype)
+
+
+@op("rms_norm", infer_shape=same_shape_infer("X"))
+def _rms_norm(ctx, op_):
+    """RMSNorm over the last axis: ``Scale * X / sqrt(mean(X^2) +
+    epsilon)``. Inference only (no grad op)."""
+    ctx.out(op_, "Out", rms_norm(
+        ctx.in1(op_, "X"), ctx.in1(op_, "Scale"),
+        float(op_.attr("epsilon", 1e-6))))
+
+
+def rotary(x, pos, head_dim, rope_dim, theta, interleaved):
+    """Rotate the LAST ``rope_dim`` values of every ``head_dim``-wide head
+    of ``x`` [N, T, heads*head_dim] by the fed positions ``pos`` [N, T].
+    ``interleaved``: the values come as pairs (x0, y0, x1, y1, ...) and
+    are regrouped to (x0, x1, ..., y0, y1, ...) before the rotation by
+    halves, as ``apply_rotary_pos_emb_interleave`` of the ``deepseek_v3``
+    modelling code does; the result keeps the regrouped order."""
+    import jax.numpy as jnp
+
+    n, t, c = x.shape
+    xh = x.reshape(n, t, c // head_dim, head_dim)
+    keep, r = xh[..., :head_dim - rope_dim], xh[..., head_dim - rope_dim:]
+    r = r.astype(jnp.float32)
+    half = rope_dim // 2
+    if interleaved:
+        r = r.reshape(r.shape[:-1] + (half, 2))
+        r = jnp.swapaxes(r, -1, -2).reshape(r.shape[:-2] + (rope_dim,))
+    inv = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    ang = pos.reshape(n, t, 1, 1).astype(jnp.float32) * inv
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    r1, r2 = r[..., :half], r[..., half:]
+    rot = jnp.concatenate([r1 * cos - r2 * sin, r2 * cos + r1 * sin], -1)
+    return jnp.concatenate([keep, rot.astype(x.dtype)], -1).reshape(n, t, c)
+
+
+@op("rotary_embedding", infer_shape=same_shape_infer("X"))
+def _rotary_embedding(ctx, op_):
+    """Rotary positions at FED positions (``Pos`` [N, T] or [N, T, 1]) on
+    the last ``rope_dim`` values of each ``head_dim`` chunk of ``X``
+    [N, T, C]; see ``rotary``. Inference only (no grad op)."""
+    ctx.out(op_, "Out", rotary(
+        ctx.in1(op_, "X"), ctx.in1(op_, "Pos"),
+        int(op_.attr("head_dim")), int(op_.attr("rope_dim")),
+        float(op_.attr("theta", 10000.0)),
+        bool(op_.attr("interleaved", False))))
+
+
+@op("swiglu", infer_shape=same_shape_infer("Gate"))
+def _swiglu(ctx, op_):
+    """silu(Gate) * Up, computed in float32, in Gate's dtype. Inference
+    only (no grad op)."""
+    import jax
+    import jax.numpy as jnp
+
+    g = ctx.in1(op_, "Gate")
+    u = ctx.in1(op_, "Up")
+    ctx.out(op_, "Out", (jax.nn.silu(g.astype(jnp.float32))
+                         * u.astype(jnp.float32)).astype(g.dtype))
+
+
+def route(x, wg, bias, k, scaling):
+    """-> (experts [T, k] int32, gates [T, k] float32). Scores are
+    sigmoid(x Wg) in float32 from the float32-cast input; the k experts
+    are the top k of score + bias; gates are the chosen SCORES (the bias
+    chooses and does not weigh), renormalised to sum 1 and scaled."""
+    import jax
+    import jax.numpy as jnp
+
+    s = jax.nn.sigmoid(jnp.dot(
+        x.astype(jnp.float32), wg.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST))
+    _, experts = jax.lax.top_k(s + bias.astype(jnp.float32)[None, :], k)
+    chosen = jnp.take_along_axis(s, experts, axis=1)
+    gates = scaling * chosen / (chosen.sum(-1, keepdims=True) + 1e-20)
+    return experts.astype(jnp.int32), gates
+
+
+def grouped_experts(x, experts, gates, w1, w3, w2, expert_offset):
+    """The part of sum_e gate_e * E_e(x) that the HELD experts give
+    (global numbers ``expert_offset`` .. ``expert_offset + E_held - 1``),
+    and the assignments each held expert received. ``x`` [T, H],
+    ``experts``/``gates`` [T, k], ``w1``/``w3`` [E_held, H, I], ``w2``
+    [E_held, I, H]. Assignments are sorted by expert and each group runs
+    as one product (``jax.lax.ragged_dot``): work follows the assignments,
+    nothing is dropped, and nothing of shape [T, E, I] exists."""
+    import jax
+    import jax.numpy as jnp
+
+    t, k = experts.shape
+    held_n = w1.shape[0]
+    local = experts - expert_offset
+    held = (local >= 0) & (local < held_n)
+    # an assignment to an expert held elsewhere sorts past every group
+    flat = jnp.where(held, local, held_n).reshape(-1)
+    order = jnp.argsort(flat, stable=True)
+    sizes = jnp.bincount(flat, length=held_n + 1)[:held_n].astype(jnp.int32)
+    xs = x[order // k]
+    f32 = jnp.float32
+    a = jax.lax.ragged_dot(xs, w1, sizes, preferred_element_type=f32)
+    b = jax.lax.ragged_dot(xs, w3, sizes, preferred_element_type=f32)
+    h = (jax.nn.silu(a) * b).astype(x.dtype)
+    y = jax.lax.ragged_dot(h, w2, sizes, preferred_element_type=f32)
+    weight = jnp.where(held, gates, 0.0).reshape(-1)[order]
+    y = jnp.where(weight[:, None] != 0.0, y * weight[:, None], 0.0)
+    # back to assignment order, then the k parts of a token add up
+    y = y[jnp.argsort(order)].reshape(t, k, -1).sum(1)
+    return y.astype(x.dtype), sizes
+
+
+def _moe_ffn_infer(op_, block):
+    x = in_var(op_, block, "X")
+    w1 = in_var(op_, block, "W1")
+    set_out(op_, block, "Out", list(x.shape), x.dtype)
+    set_out(op_, block, "Counts", [int(w1.shape[0])], "int32")
+
+
+@op("moe_ffn", infer_shape=_moe_ffn_infer)
+def _moe_ffn(ctx, op_):
+    """The routed part of a sparse expert layer. ``X`` [..., H] tokens,
+    ``RouterW`` [H, num_experts], ``RouterBias`` [num_experts] (added to
+    the scores to CHOOSE, not to weigh), stacked held experts ``W1``,
+    ``W3`` [E_held, H, I] and ``W2`` [E_held, I, H] (``E_e(x) =
+    (silu(x W1_e) * (x W3_e)) W2_e``). Every token routes over all
+    ``num_experts`` (float32 sigmoid scores, top ``experts_per_token`` of
+    score + bias, gates renormalised and times ``scaling``); the op adds
+    up what the experts it HOLDS give, global numbers ``expert_offset`` ..
+    ``expert_offset + E_held - 1`` — which is what expert parallelism
+    asks of one shard; shards' results add up to the whole layer. No
+    capacity, no token dropped. ``Counts`` int32 [E_held]: assignments
+    each held expert received. Inference only: no grad op is registered
+    (training needs one for the grouped product and the router)."""
+    import jax
+
+    x = ctx.in1(op_, "X")
+    with jax.named_scope("moe_ffn"):
+        x2 = x.reshape(-1, x.shape[-1])
+        experts, gates = route(
+            x2, ctx.in1(op_, "RouterW"), ctx.in1(op_, "RouterBias"),
+            int(op_.attr("experts_per_token")),
+            float(op_.attr("scaling", 1.0)))
+        y, counts = grouped_experts(
+            x2, experts, gates, ctx.in1(op_, "W1"), ctx.in1(op_, "W3"),
+            ctx.in1(op_, "W2"), int(op_.attr("expert_offset", 0)))
+    ctx.out(op_, "Out", y.reshape(x.shape))
+    ctx.out(op_, "Counts", counts)
+
+
+def mla_window(q, rows, wkvb, qpos, heads, nope, rope, vdim):
+    """Up-projected latent attention of a window of queries. ``q``
+    [N, T, heads*(nope+rope)] (rope part rotated), ``rows`` [N, S, W]
+    latent rows (normed latent, rotated shared rope key, padding),
+    ``wkvb`` [latent, heads*(nope+vdim)], ``qpos`` [N, T]: query i sees
+    the keys at positions <= qpos[i]. -> [N, T, heads*vdim]. Blocks of
+    queries go one after another over chunks of keys with a running
+    softmax, and a block stops at the last chunk one of its queries can
+    see: up-projection, scores and softmax follow the keys a window can
+    see, not the row's capacity. Blocks and chunks are the largest
+    divisors of T and S up to ``_WINDOW_BLOCK``."""
+    import jax
+    import jax.numpy as jnp
+
+    n, t, _ = q.shape
+    s, latent = rows.shape[1], wkvb.shape[0]
+    tq, tk = math.gcd(t, _WINDOW_BLOCK), math.gcd(s, _WINDOW_BLOCK)
+    f32 = jnp.float32
+    scale = 1.0 / float(nope + rope) ** 0.5
+    # [blocks of queries, N, tq, ...]
+    q = q.reshape(n, t // tq, tq, heads, nope + rope).swapaxes(0, 1)
+    qpos = qpos.reshape(n, t // tq, 1, tq, 1).astype(jnp.int32).swapaxes(0, 1)
+
+    def attend(queries):
+        q, qpos = queries              # [N, tq, heads, d], [N, 1, tq, 1]
+
+        def chunk(i, carry):
+            top, total, acc = carry
+            r = jax.lax.dynamic_slice_in_dim(rows, i * tk, tk, axis=1)
+            c, kr = r[..., :latent], r[..., latent:latent + rope]
+            kv = jnp.einsum("nsl,lf->nsf", c, wkvb,
+                            preferred_element_type=f32)
+            kv = kv.astype(q.dtype).reshape(n, tk, heads, nope + vdim)
+            sc = jnp.einsum("nthd,nshd->nhts", q[..., :nope], kv[..., :nope],
+                            preferred_element_type=f32)
+            sc = sc + jnp.einsum("nthr,nsr->nhts", q[..., nope:], kr,
+                                 preferred_element_type=f32)
+            seen = i * tk + jnp.arange(tk)[None, None, None, :] <= qpos
+            sc = jnp.where(seen, sc * scale, _NEG)
+            # every query sees key 0, so ``top`` is a real score from the
+            # first chunk on and a masked score's weight is exp(-huge) = 0
+            new_top = jnp.maximum(top, sc.max(-1, keepdims=True))
+            p = jnp.exp(sc - new_top)
+            keep = jnp.exp(top - new_top)
+            pv = jnp.einsum("nhts,nshv->nhtv", p.astype(q.dtype),
+                            kv[..., nope:], preferred_element_type=f32)
+            return (new_top, keep * total + p.sum(-1, keepdims=True),
+                    keep * acc + pv)
+
+        init = (jnp.full((n, heads, tq, 1), _NEG, f32),
+                jnp.zeros((n, heads, tq, 1), f32),
+                jnp.zeros((n, heads, tq, vdim), f32))
+        _top, total, acc = jax.lax.fori_loop(
+            0, qpos.max() // tk + 1, chunk, init)
+        return (acc / total).astype(q.dtype)
+
+    o = jax.lax.map(attend, (q, qpos))           # [blocks, N, heads, tq, v]
+    return o.transpose(1, 0, 3, 2, 4).reshape(n, t, heads * vdim)
+
+
+def _mla_out_infer(op_, block):
+    q = in_var(op_, block, "Q")
+    heads, vdim = int(op_.attr("num_heads")), int(op_.attr("v_dim"))
+    set_out(op_, block, "Out", list(q.shape[:2]) + [heads * vdim], q.dtype)
+
+
+@op("mla_window_attention", infer_shape=_mla_out_infer)
+def _mla_window_attention(ctx, op_):
+    """Latent attention, the UP-PROJECTED form (a prefill window, or a
+    whole prompt without a cache): keys and values of every row come from
+    ``Rows`` [N, S, W] through ``Wkvb``; ``QPos`` [N, T] (or [N, T, 1])
+    gives the causal mask, key j visible to query i iff j <= QPos[i]. See
+    ``mla_window``. Inference only (no grad op)."""
+    import jax
+
+    with jax.named_scope("mla_window"):
+        out = mla_window(
+            ctx.in1(op_, "Q"), ctx.in1(op_, "Rows"), ctx.in1(op_, "Wkvb"),
+            ctx.in1(op_, "QPos"), int(op_.attr("num_heads")),
+            int(op_.attr("nope_dim")), int(op_.attr("rope_dim")),
+            int(op_.attr("v_dim")))
+    ctx.out(op_, "Out", out)
+
+
+def mla_absorbed(q, pool, tables, lengths, wkvb, heads, nope, rope, vdim,
+                 interpret=None):
+    """Absorbed latent attention of ONE query a slot against the paged
+    latent pool: the key up-projection is folded into the query
+    (``q_nope Wkvb_K^T``, latent wide), scores and the weighted sum run
+    over the latent rows as they lie in the pool (kernel
+    ``mla_decode_paged``), and the value up-projection is applied to the
+    sum. ``q`` [B, 1, heads*(nope+rope)], ``pool`` [blocks, 1, block, W],
+    ``tables`` [B, max_blocks], ``lengths`` [B] live keys a slot.
+    -> [B, 1, heads*vdim]."""
+    import jax.numpy as jnp
+
+    from ...kernels.flash_attention import mla_decode_paged_attention
+
+    b = q.shape[0]
+    latent = wkvb.shape[0]
+    width = pool.shape[-1]
+    f32 = jnp.float32
+    q = q.reshape(b, heads, nope + rope)
+    w = wkvb.reshape(latent, heads, nope + vdim)
+    qa = jnp.einsum("bhd,lhd->bhl", q[..., :nope], w[..., :nope],
+                    preferred_element_type=f32)
+    qfull = jnp.concatenate([
+        qa.astype(pool.dtype), q[..., nope:].astype(pool.dtype),
+        jnp.zeros((b, heads, width - latent - rope), pool.dtype)], -1)
+    u = mla_decode_paged_attention(
+        qfull, pool.reshape(pool.shape[0], pool.shape[2], width), tables,
+        lengths, latent, 1.0 / float(nope + rope) ** 0.5,
+        interpret=interpret)
+    o = jnp.einsum("bhl,lhv->bhv", u.astype(q.dtype), w[..., nope:],
+                   preferred_element_type=f32)
+    return o.astype(q.dtype).reshape(b, 1, heads * vdim)
+
+
+@op("mla_decode_paged_attention", infer_shape=_mla_out_infer)
+def _mla_decode_paged_attention(ctx, op_):
+    """Latent attention, the ABSORBED form (the T = 1 step): one query a
+    slot against the latent ``Pool`` [blocks, 1, block, W] read through
+    ``Tables`` [slots, max_blocks] up to ``Lengths`` [slots] live keys;
+    see ``mla_absorbed``. Inference only (no grad op)."""
+    import jax
+
+    with jax.named_scope("mla_absorb"):
+        out = mla_absorbed(
+            ctx.in1(op_, "Q"), ctx.in1(op_, "Pool"),
+            ctx.in1(op_, "Tables"), ctx.in1(op_, "Lengths"),
+            ctx.in1(op_, "Wkvb"), int(op_.attr("num_heads")),
+            int(op_.attr("nope_dim")), int(op_.attr("rope_dim")),
+            int(op_.attr("v_dim")),
+            interpret=bool(op_.attr("interpret", False)) or None)
+    ctx.out(op_, "Out", out)

@@ -35,6 +35,7 @@ _NP_TO_PROTO = {
     np.dtype(np.float64): core.VarDesc.VarType.FP64,
     np.dtype(np.uint8): core.VarDesc.VarType.UINT8,
     np.dtype(np.int8): core.VarDesc.VarType.INT8,
+    np.dtype(core.dtype_to_np("bfloat16")): core.VarDesc.VarType.BF16,
 }
 _PROTO_TO_NP = {v: k for k, v in _NP_TO_PROTO.items()}
 

@@ -213,7 +213,8 @@ def _mul_infer(op_, block):
         raise SkipInferShape()
     xnc = int(op_.attr("x_num_col_dims", 1))
     ync = int(op_.attr("y_num_col_dims", 1))
-    set_out(op_, block, "Out", tuple(x.shape[:xnc]) + tuple(y.shape[ync:]), x.dtype)
+    set_out(op_, block, "Out", tuple(x.shape[:xnc]) + tuple(y.shape[ync:]),
+            op_.attr("out_dtype", None) or x.dtype)  # a VarType enum
 
 
 def _copy_to_tp(axis_name):
@@ -271,7 +272,14 @@ def _mul(ctx, op_):
         x = _copy_to_tp(col_axis)(x)
     xm = x.reshape((int(np.prod(x.shape[:xnc])), -1))
     ym = y.reshape((int(np.prod(y.shape[:ync])), -1))
-    out = jnp.dot(xm, ym)
+    # ``out_dtype``: the accumulator's dtype kept for the result (float32
+    # logits from bfloat16 operands); absent, the operands' own
+    out_dtype = op_.attr("out_dtype", None)
+    if out_dtype:
+        from .. import core as _core
+
+        out_dtype = _core.dtype_to_np(out_dtype)
+    out = jnp.dot(xm, ym, preferred_element_type=out_dtype)
     if row_axis is not None:
         # row-parallel: each shard holds a slice of the contraction dim —
         # partial products sum over the TP axis (Megatron's `g` operator);

@@ -704,6 +704,134 @@ def flash_decode_paged_attention(q, k_pool, v_pool, tables, key_bias=None,
     return out[:, :, :1, :]
 
 
+def _mla_decode_paged_kernel(held_ref, lengths_ref, q_ref, *refs, scale,
+                             pages, value_width):
+    """Latent (MLA) paged decode step, one (slot, group of ``pages``
+    logical blocks) program. All H query heads of the slot are the ROWS
+    of one product: q [H, W] against the block's latent rows [keys, W]
+    (one shared key row a token, W = latent ‖ rope key ‖ zero pad), and
+    the value is the first ``value_width`` lanes of the same block, so a
+    block is one DMA, not two. Dead table entries are neither fetched nor
+    computed (``_held_blocks``); inside the live blocks the slot's live
+    length masks, so no key bias rides in. Online softmax state lives in
+    VMEM scratch across a slot's programs, as in
+    ``_decode_paged_kernel``."""
+    from jax.experimental import pallas as pl
+
+    pool_refs = refs[:pages]
+    o_ref, m_ref, l_ref, acc_ref = refs[pages:]
+    b, i = pl.program_id(0), pl.program_id(1)
+    block = pool_refs[0].shape[1]
+    keys = pages * block
+
+    @pl.when(i == 0)
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, _NEG)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    length = lengths_ref[b]
+    live = _live_blocks(length, block) - i * pages
+
+    @pl.when(live > 0)
+    def _attend():
+        rows = (pool_refs[0][0] if pages == 1 else
+                jnp.concatenate([r[0] for r in pool_refs], axis=0))
+        s = jax.lax.dot_general(
+            q_ref[0], rows, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * scale
+        col = i * keys + jax.lax.broadcasted_iota(jnp.int32, (1, keys), 1)
+        s = jnp.where(col < length, s, _NEG)
+        m = m_ref[...]
+        m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
+        alpha = jnp.exp(m - m_new)
+        p = jnp.exp(s - m_new)
+        l_ref[...] = l_ref[...] * alpha + p.sum(axis=-1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+            p.astype(rows.dtype), rows[:, :value_width],
+            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
+        )
+        m_ref[...] = m_new
+
+    @pl.when(i == pl.num_programs(1) - 1)
+    def _emit():
+        o_ref[0] = (acc_ref[...]
+                    / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
+
+
+def mla_decode_paged_attention(q, pool, tables, lengths, value_width,
+                               scale, interpret=None):
+    """Absorbed latent attention of one query token a slot THROUGH a
+    block table: ``q`` [B, H, W] (per head the absorbed query over the
+    latent, then the rotated rope query, then zeros up to the pool's
+    row), ``pool`` [blocks, block, W] (per token the normed latent ``c``,
+    the rotated shared rope key, zeros), ``tables`` [B, max_blocks],
+    ``lengths`` [B] live keys a slot (held to 1 .. the table's keys: an
+    inactive slot reads one sink block). Score of key j for head h is
+    ``q[h] . pool_row[j] * scale``; the result [B, H, value_width] is the
+    softmax-weighted sum of the rows' first ``value_width`` lanes (the
+    latent), float32. Dense gather-then-softmax off TPU, the same
+    arithmetic."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, H, W = q.shape
+    blocks, blk, Wp = pool.shape
+    MB = tables.shape[1]
+    if Wp != W:
+        raise ValueError("pool row %d does not match q width %d" % (Wp, W))
+    tables = tables.astype(jnp.int32)
+    lengths = jnp.clip(lengths.astype(jnp.int32).reshape(B), 1, MB * blk)
+    if interpret is None and not lowers_for_tpu():
+        rows = pool[_held_blocks(tables, lengths, blk, MB)].reshape(
+            B, MB * blk, W)
+        s = jnp.einsum("bhw,bkw->bhk", q, rows,
+                       preferred_element_type=jnp.float32) * scale
+        live = jnp.arange(MB * blk)[None, None, :] < lengths[:, None, None]
+        p = jax.nn.softmax(jnp.where(live, s, _NEG), axis=-1)
+        return jnp.einsum("bhk,bkv->bhv", p.astype(rows.dtype),
+                          rows[..., :value_width],
+                          preferred_element_type=jnp.float32)
+    P = min(MB, -(-PAGED_KEYS // blk))
+    programs = -(-MB // P)
+    Hp = _round_up(H, 8)
+    qp = jnp.pad(q, ((0, 0), (0, Hp - H), (0, 0)))
+    kernel = functools.partial(_mla_decode_paged_kernel, scale=scale,
+                               pages=P, value_width=value_width)
+
+    def pool_spec(j):
+        return pl.BlockSpec(
+            (1, blk, W), lambda b, i, held, lens: (held[b, i * P + j], 0, 0),
+            memory_space=pltpu.VMEM,
+        )
+
+    slot = lambda b, i, held, lens: (b, 0, 0)  # noqa: E731
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(B, programs),
+        in_specs=[
+            pl.BlockSpec((1, Hp, W), slot, memory_space=pltpu.VMEM),
+            *[pool_spec(j) for j in range(P)],
+        ],
+        out_specs=pl.BlockSpec((1, Hp, value_width), slot,
+                               memory_space=pltpu.VMEM),
+        scratch_shapes=[
+            pltpu.VMEM((Hp, 1), jnp.float32),
+            pltpu.VMEM((Hp, 1), jnp.float32),
+            pltpu.VMEM((Hp, value_width), jnp.float32),
+        ],
+    )
+    out = pl.pallas_call(
+        kernel,
+        name="mla_decode_paged",
+        out_shape=jax.ShapeDtypeStruct((B, Hp, value_width), jnp.float32),
+        grid_spec=grid_spec,
+        interpret=bool(interpret),
+    )(_held_blocks(tables, lengths, blk, P), lengths, qp, *([pool] * P))
+    return out[:, :H, :]
+
+
 # --------------------------------------------------------------------------
 # padding / plumbing
 # --------------------------------------------------------------------------

@@ -20,6 +20,7 @@ import numpy as np
 import paddle_tpu.fluid as fluid
 
 from . import bert as _bert
+from .cache_kinds import CachePool
 
 
 class GPTConfig(object):
@@ -682,6 +683,31 @@ def build_gpt_paged_block_copy(cfg, blocks, block, npairs):
             fluid.layers.kv_cache_block_copy(pv, src, dst)
         ok = fluid.layers.fill_constant(shape=[1], dtype="int32", value=1)
     return main, startup, ["src", "dst"], ok
+
+
+def cache_kinds(cfg):
+    """Per layer, the pools the paged runtime keeps (``cache_kinds.py``):
+    K and V, ``[heads, d_head]`` float32 a token — the names, shapes and
+    bytes of ``paged_pool_names`` / ``paged_pool_shape`` /
+    ``paged_block_bytes``."""
+    row = [cfg.num_heads, cfg.hidden_size // cfg.num_heads]
+    return [
+        (CachePool("gpt_paged_k_%d" % i, row, "float32"),
+         CachePool("gpt_paged_v_%d" % i, row, "float32"))
+        for i in range(cfg.num_layers)
+    ]
+
+
+# what ``serving/decode.py`` asks a served model's module for, under the
+# names every such module gives them; every mode is built for this cache
+UNSUPPORTED = {}
+build_prefill = build_gpt_prefill
+build_resume_prefill = build_gpt_resume_prefill
+build_prefix_copy = build_gpt_prefix_copy
+build_decode_step = build_gpt_decode_step
+build_paged_window = build_gpt_paged_window
+build_paged_step = build_gpt_paged_step
+build_paged_block_copy = build_gpt_paged_block_copy
 
 
 def _reference_generate(exe, infer_prog, logits_var, cfg, prompt_ids,

@@ -66,6 +66,7 @@ import paddle_tpu.fluid as fluid
 
 from ..fluid import flags as _flags
 from ..fluid import profiler as _profiler
+from ..models import cache_kinds as _cache_kinds
 from ..models import gpt as _gpt
 from ..observability import exporter as _obs_exporter
 from ..observability import registry as _obs_registry
@@ -88,6 +89,15 @@ __all__ = [
 
 def _flag(name, override):
     return override if override is not None else _flags.get_flag(name)
+
+
+def _require(model, mode):
+    """A served model's module lists the modes that are not built for its
+    cache (``UNSUPPORTED``: mode -> what it is)."""
+    what = getattr(model, "UNSUPPORTED", {}).get(mode)
+    if what is not None:
+        raise NotImplementedError(
+            "%s does not build %s" % (model.__name__, what))
 
 
 def prefill_ladder(max_len, buckets=None):
@@ -533,9 +543,13 @@ class DecodeSession(object):
     def __init__(self, cfg, place=None, scope=None, slots=None,
                  max_len=None, prefill_buckets=None, prefix_blocks=0,
                  prefix_block=None, build_resume=False, block_size=None,
-                 pool_blocks=0, spec_tokens=None, window_cap=0, tp=None):
+                 pool_blocks=0, spec_tokens=None, window_cap=0, tp=None,
+                 model=None):
         self.cfg = copy.copy(cfg)
         self.cfg.is_test = True
+        # the served model's module (models/gpt.py unless told): it builds
+        # the programs and says what its cache holds (``cache_kinds``)
+        self.model = model if model is not None else _gpt
         self.slots = int(_flag("decode_slots", slots))
         # tensor-parallel serving (parallel/spmd.py): tp > 1 runs every
         # session program through the GSPMD mesh path over a
@@ -548,6 +562,7 @@ class DecodeSession(object):
         self.tp = max(int(_flag("spmd_decode_tp", tp)), 1)
         self._tp_mesh = None
         if self.tp > 1:
+            _require(self.model, "tp")
             from ..parallel import spmd as _spmd
 
             self._tp_mesh = _spmd.tp_mesh(self.tp)
@@ -574,6 +589,12 @@ class DecodeSession(object):
         self.spec_tokens = max(int(_flag("decode_spec_tokens",
                                          spec_tokens)), 0)
         self.paged = self.block_size > 0
+        if not self.paged:
+            _require(self.model, "contiguous")
+        if self.spec_tokens > 1:
+            _require(self.model, "spec_tokens")
+        if int(prefix_blocks):
+            _require(self.model, "prefix_store")
         if self.paged:
             width = max(self.spec_tokens, 1)
             # speculative verify writes/embeds positions up to
@@ -634,7 +655,7 @@ class DecodeSession(object):
             for seq_len in self.buckets:
                 with fluid.unique_name.guard():
                     main, _startup, _feeds, next_logits = (
-                        _gpt.build_gpt_prefill(
+                        self.model.build_prefill(
                             self.cfg, self.slots, seq_len, max_len
                         )
                     )
@@ -642,7 +663,8 @@ class DecodeSession(object):
                                           next_logits.name)
             with fluid.unique_name.guard():
                 main, _startup, _feeds, step_logits = (
-                    _gpt.build_gpt_decode_step(self.cfg, self.slots, max_len)
+                    self.model.build_decode_step(self.cfg, self.slots,
+                                                 max_len)
                 )
             self._decode = (self._maybe_tp(main), step_logits.name)
         else:
@@ -652,23 +674,30 @@ class DecodeSession(object):
             # the batched verify), and one block-copy for COW
             for seq_len in self.buckets:
                 with fluid.unique_name.guard():
-                    main, _s, _f, nl = _gpt.build_gpt_paged_window(
+                    main, _s, feeds, nl = self.model.build_paged_window(
                         self.cfg, self.pool_blocks, self.block_size,
                         self.max_blocks, seq_len,
                     )
                 self._paged_window[seq_len] = (self._maybe_tp(main), nl.name)
+                self._window_feeds = frozenset(feeds)
             widths = [1]
             if self.spec_tokens > 1:
                 widths.append(self.spec_tokens)
             for w in widths:
                 with fluid.unique_name.guard():
-                    main, _s, _f, sl = _gpt.build_gpt_paged_step(
+                    main, _s, feeds, sl = self.model.build_paged_step(
                         self.cfg, self.slots, self.pool_blocks,
                         self.block_size, self.max_blocks, step_w=w,
                     )
-                self._paged_step[w] = (self._maybe_tp(main), sl.name)
+                # a program may name values to fetch beside the logits
+                # (``_step_stats``), which the model's ``step_stats`` reads
+                self._paged_step[w] = (
+                    self._maybe_tp(main),
+                    [sl.name] + list(getattr(main, "_step_stats", ())),
+                )
+                self._step_feeds = frozenset(feeds)
             with fluid.unique_name.guard():
-                main, _s, _f, ok = _gpt.build_gpt_paged_block_copy(
+                main, _s, _f, ok = self.model.build_paged_block_copy(
                     self.cfg, self.pool_blocks, self.block_size, npairs=1
                 )
             self._block_copy = (self._maybe_tp(main), ok.name)
@@ -688,7 +717,7 @@ class DecodeSession(object):
         if (build_resume or self.prefix_blocks) and not self.paged:
             for seq_len in self.buckets:
                 with fluid.unique_name.guard():
-                    main, _s, _f, nl = _gpt.build_gpt_resume_prefill(
+                    main, _s, _f, nl = self.model.build_resume_prefill(
                         self.cfg, self.slots, seq_len, max_len
                     )
                 self._resume[seq_len] = (self._maybe_tp(main), nl.name)
@@ -698,13 +727,13 @@ class DecodeSession(object):
         self._publish = None
         if self.prefix_blocks and not self.paged:
             with fluid.unique_name.guard():
-                m_in, _s, _f, ok_in = _gpt.build_gpt_prefix_copy(
+                m_in, _s, _f, ok_in = self.model.build_prefix_copy(
                     self.cfg, self.slots, max_len, self.prefix_blocks,
                     self.prefix_block, publish=False,
                 )
             self._copy_in = (self._maybe_tp(m_in), ok_in.name)
             with fluid.unique_name.guard():
-                m_pub, _s, _f, ok_pub = _gpt.build_gpt_prefix_copy(
+                m_pub, _s, _f, ok_pub = self.model.build_prefix_copy(
                     self.cfg, self.slots, max_len, self.prefix_blocks,
                     self.prefix_block, publish=True,
                 )
@@ -735,32 +764,39 @@ class DecodeSession(object):
         )
 
     # -- state ---------------------------------------------------------------
+    def pool_names(self):
+        """Per layer, the scope names of its pools."""
+        return [tuple(p.name(self.pool_blocks, self.block_size)
+                      for p in layer)
+                for layer in self.model.cache_kinds(self.cfg)]
+
     def reset_caches(self):
         """Zero every cache var in the scope (host-side: no program, no
         param re-init). Correctness never depends on this — prefill
         replaces a slot's whole row — but fresh buffers make warmup and
         tests deterministic."""
         if self.paged:
-            pshape = _gpt.paged_pool_shape(
-                self.cfg, self.pool_blocks, self.block_size
-            )
-            for k_name, v_name in _gpt.paged_pool_names(
-                self.cfg, self.pool_blocks, self.block_size
-            ):
-                self.scope.set(k_name, np.zeros(pshape, "float32"))
-                self.scope.set(v_name, np.zeros(pshape, "float32"))
+            geometry = (self.pool_blocks, self.block_size)
+            for layer in self.model.cache_kinds(self.cfg):
+                for pool in layer:
+                    self.scope.set(
+                        pool.name(*geometry),
+                        np.zeros(pool.shape(*geometry),
+                                 fluid.core.dtype_to_np(pool.dtype)),
+                    )
             return
-        shape = _gpt.decode_cache_shape(self.cfg, self.slots, self.max_len)
-        for k_name, v_name in _gpt.decode_cache_names(
+        shape = self.model.decode_cache_shape(self.cfg, self.slots,
+                                              self.max_len)
+        for k_name, v_name in self.model.decode_cache_names(
             self.cfg, self.slots, self.max_len
         ):
             self.scope.set(k_name, np.zeros(shape, "float32"))
             self.scope.set(v_name, np.zeros(shape, "float32"))
         if self.prefix_blocks:
-            pshape = _gpt.prefix_store_shape(
+            pshape = self.model.prefix_store_shape(
                 self.cfg, self.prefix_blocks, self.prefix_block
             )
-            for k_name, v_name in _gpt.prefix_store_names(
+            for k_name, v_name in self.model.prefix_store_names(
                 self.cfg, self.prefix_blocks, self.prefix_block
             ):
                 self.scope.set(k_name, np.zeros(pshape, "float32"))
@@ -984,10 +1020,6 @@ class DecodeSession(object):
         with _trace.span("step_feed", cat="serving"):
             ids = np.zeros((1, T, 1), "int64")
             ids[0, :P, 0] = window_ids
-            # offset-shifted causal mask over the gathered logical row;
-            # the -1e4 side also buries sink garbage past the live length
-            allow = self._cols[None, :] <= (offset + np.arange(T))[:, None]
-            bias = np.where(allow, 0.0, -1e4).astype("float32")[None]
             last_onehot = np.zeros((1, T, 1), "float32")
             last_onehot[0, P - 1, 0] = 1.0
             tbl = np.zeros((1, self.max_blocks), "int64")
@@ -998,9 +1030,17 @@ class DecodeSession(object):
                 .astype("int64"),
                 "table": tbl,
                 "window_pos": np.array([[offset]], "int64"),
-                "resume_bias": bias,
                 "last_onehot": last_onehot,
             }
+            if "resume_bias" in self._window_feeds:
+                # offset-shifted causal mask over the gathered logical
+                # row; the -1e4 side also buries sink garbage past the
+                # live length (a program that does not declare the feed
+                # masks from ``pos_ids`` itself)
+                allow = (self._cols[None, :]
+                         <= (offset + np.arange(T))[:, None])
+                feed["resume_bias"] = np.where(
+                    allow, 0.0, -1e4).astype("float32")[None]
         t0 = time.perf_counter()
         with _trace.span("decode_paged_window", cat="serving",
                          bucket=T, rows=P, offset=offset):
@@ -1040,26 +1080,27 @@ class DecodeSession(object):
                            np.asarray(tokens, "int64").reshape(self.slots,
                                                                width), 0)
             qpos = pos[:, None] + np.arange(width)[None, :]
-            # query i of slot s sees logical cache positions
-            # <= qpos[s, i]; inactive rows mask everything (finite
-            # softmax over garbage, output ignored)
-            bias = (
-                ((self._cols[None, None, :] > qpos[:, :, None])
-                 | ~act[:, None, None]).astype("float32") * -1e4
-            )
             tbl = np.zeros((self.slots, self.max_blocks), "int64")
             for s in range(self.slots):
                 row = tables[s] if tables is not None else ()
                 if len(row):
                     tbl[s, :len(row)] = row
-            main, fetch_name = self._paged_step[width]
+            main, fetches = self._paged_step[width]
             feed = {
                 "step_ids": tok.reshape(self.slots, width, 1),
                 "step_pos": qpos.reshape(self.slots, width, 1)
                 .astype("int64"),
                 "tables": tbl,
-                "step_bias": bias,
             }
+            if "step_bias" in self._step_feeds:
+                # query i of slot s sees logical cache positions
+                # <= qpos[s, i]; inactive rows mask everything (finite
+                # softmax over garbage, output ignored). A program that
+                # does not declare the feed masks from ``step_pos`` itself
+                feed["step_bias"] = (
+                    ((self._cols[None, None, :] > qpos[:, :, None])
+                     | ~act[:, None, None]).astype("float32") * -1e4
+                )
             # of the slots x max_blocks table entries, the ones that hold
             # a live key after this window's writes: the share of the
             # table the T = 1 kernel fetches and computes
@@ -1070,10 +1111,14 @@ class DecodeSession(object):
         with _trace.span("decode_paged_step", cat="serving",
                          active=int(act.sum()), width=width,
                          blocks_live=blocks_live,
-                         blocks_table=self.slots * self.max_blocks):
-            (lv,) = self.exe.run(
-                main, feed=feed, fetch_list=[fetch_name], scope=self.scope
+                         blocks_table=self.slots * self.max_blocks) as sp:
+            lv, *stats = self.exe.run(
+                main, feed=feed, fetch_list=fetches, scope=self.scope
             )
+            if len(fetches) > 1:
+                sp.note(**self.model.step_stats(
+                    [np.asarray(v) for v in stats],
+                    live_rows=int((pos[act] + width).sum())))
         _profiler.bump_counter("decode_steps")
         self.steps += 1
         _profiler.bump_histogram(
@@ -1560,8 +1605,14 @@ class DecodeEngine(object):
                  param_program=None, prefix_block=None,
                  prefix_cache_mb=None, prefill_chunk=None,
                  block_size=None, spec_tokens=None, spec_draft=None,
-                 pool_blocks=0, drafter=None, tp=None):
+                 pool_blocks=0, drafter=None, tp=None, model=None):
         self._cfg = cfg
+        # the served model's module: ``models/gpt.py`` unless told. Its
+        # ``cache_kinds`` give the cache bytes a token costs over all
+        # layers, which is what sizes the pool's accounting
+        self._model = model if model is not None else _gpt
+        self.kv_bytes_per_token = _cache_kinds.bytes_per_token(
+            self._model.cache_kinds(cfg))
         self._place = (place if place is not None
                        else fluid.core.default_place())
         # a Place that names no device of this process fails here, not
@@ -1703,7 +1754,7 @@ class DecodeEngine(object):
                 pool_blocks=self._pool_blocks_arg,
                 spec_tokens=self.spec_tokens,
                 window_cap=self.prefill_chunk,
-                tp=self.tp,
+                tp=self.tp, model=self._model,
             )
             self.allocator = BlockAllocator(self.session.pool_blocks)
             self.prefix = None
@@ -1714,12 +1765,13 @@ class DecodeEngine(object):
                 # blocks the store may pin, not a separate allocation
                 cap = max(1, int(
                     self.prefix_cache_mb * 2 ** 20
-                    // _gpt.paged_block_bytes(self._cfg, self.block_size)
+                    // (self.kv_bytes_per_token * self.block_size)
                 ))
                 self.pindex = PagedPrefixIndex(
                     self.block_size, cap, self.allocator
                 )
                 if self.kv_host_mb > 0:
+                    _require(self._model, "kv_host_tier")
                     # host tier behind the device index: eviction spills
                     # instead of vanishing, admission walks here when
                     # the device chain runs out
@@ -1736,8 +1788,8 @@ class DecodeEngine(object):
             if self.prefix_cache_mb > 0:
                 blocks = max(1, int(
                     self.prefix_cache_mb * 2 ** 20
-                    // _gpt.prefix_block_bytes(self._cfg,
-                                               self.prefix_block)
+                    // self._model.prefix_block_bytes(self._cfg,
+                                                      self.prefix_block)
                 ))
             self.session = DecodeSession(
                 self._cfg, place=self._place, scope=self._scope,
@@ -1745,7 +1797,7 @@ class DecodeEngine(object):
                 prefill_buckets=self._buckets_arg, prefix_blocks=blocks,
                 prefix_block=self.prefix_block,
                 build_resume=bool(blocks or self.prefill_chunk),
-                tp=self.tp,
+                tp=self.tp, model=self._model,
             )
             self.prefix = PrefixCache(blocks, self.prefix_block) \
                 if blocks else None
@@ -2213,6 +2265,7 @@ class DecodeEngine(object):
             total = self.allocator.blocks - 1
             out["blocks_total"] = total
             out["blocks_in_use"] = total - self.allocator.free_blocks
+            out["kv_bytes_per_token"] = self.kv_bytes_per_token
         return out
 
     def _reap_cancelled(self):
@@ -2622,9 +2675,7 @@ class DecodeEngine(object):
         scope value (post reset/readmit) it is a zero-copy view."""
         sess = self.session
         out = []
-        for k_name, v_name in _gpt.paged_pool_names(
-            sess.cfg, sess.pool_blocks, sess.block_size
-        ):
+        for k_name, v_name in sess.pool_names():
             out.append((np.asarray(sess.scope.get(k_name)),
                         np.asarray(sess.scope.get(v_name))))
         return out
@@ -2717,8 +2768,7 @@ class DecodeEngine(object):
         if not hits:
             return entries
         sess = self.session
-        names = _gpt.paged_pool_names(sess.cfg, sess.pool_blocks,
-                                      sess.block_size)
+        names = sess.pool_names()
         idx = np.array([blk for _he, blk in hits], np.int32)
         for li, (k_name, v_name) in enumerate(names):
             k_rows = np.stack([he.payload[li][0] for he, _b in hits])

@@ -97,6 +97,21 @@ def _paged(max_blocks, dtype, slots=SLOTS):
                 ((slots,), jnp.int32)], 1
 
 
+def _latent(block, dtype, slots=64, max_len=4352, heads=32, row=640,
+            latent=512):
+    """The latent (MLA) T = 1 kernel at the widths of the cell
+    ``kanana2-serve-chat4k``: 32 heads over a 640-lane pool row."""
+    def fn(q, pool, tables, lengths):
+        return fa.mla_decode_paged_attention(
+            q, pool, tables, lengths, latent, 192 ** -0.5, interpret=False)
+
+    max_blocks = max_len // block
+    return fn, [((slots, heads, row), dtype),
+                ((slots * max_blocks + 1, block, row), dtype),
+                ((slots, max_blocks), jnp.int32),
+                ((slots,), jnp.int32)], 1
+
+
 CASES = {
     "flash_causal_b8_s1024": lambda: _train(8, 1024, True),
     "flash_causal_b4_s4096": lambda: _train(4, 4096, True),
@@ -119,6 +134,11 @@ CASES = {
     "paged_256blocks_bf16": lambda: _paged(256, BF16),
     # the serve cell's own geometry: 64 slots of max_len 1024
     "paged_64blocks_64slots_f32": lambda: _paged(64, F32, slots=64),
+    # the latent pool follows the model's dtype (bf16); 128 rows a block
+    # is one operand a program, 16 rows eight
+    "latent_block128_bf16": lambda: _latent(128, BF16),
+    "latent_block128_f32": lambda: _latent(128, F32),
+    "latent_block16_bf16": lambda: _latent(16, BF16),
 }
 
 
@@ -129,3 +149,120 @@ def test_kernel_compiles_for_v5e(chip, case):
     compiled = jax.jit(fn).lower(*args).compile()
     # the kernel is in the program: it did not give way to the reference
     assert compiled.as_text().count("tpu_custom_call") >= n_kernels
+
+
+def _compile_latent_program(chip, monkeypatch, which, feed_shapes):
+    """One paged program of the cell ``kanana2-serve-chat4k`` (its
+    configuration file, 64 slots of 4352 positions, blocks of 128), built
+    by ``models/deepseek.py`` (``which(cfg, blocks, block, max_blocks,
+    slots)`` -> main, fetch names) and lowered as the executor lowers it,
+    for the described v5e. -> (cfg, blocks, block, compiled)."""
+    import json
+    import os
+
+    import paddle_tpu.fluid as fluid
+    from paddle_tpu.fluid import executor
+    from paddle_tpu.fluid.ops import registry
+    from paddle_tpu.models import deepseek
+
+    monkeypatch.setattr(registry, "lowering_backend", lambda: "tpu")
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark", "configs",
+        "kanana-2-30b-a3b.json")
+    with open(path) as f:
+        config = json.load(f)
+    cfg = deepseek.DeepseekConfig.from_config(config, dtype="bfloat16")
+    serve = config["serve"]
+    slots, block = serve["slots"], serve["block_size"]
+    max_blocks = serve["max_len"] // block
+    blocks = slots * max_blocks + 1
+    with fluid.unique_name.guard():
+        main, feeds, fetches = which(cfg, blocks, block, max_blocks, slots)
+    compiled = executor._CompiledBlock(
+        main, 0, feeds, fetches, fluid.CPUPlace())
+    (plan,) = [p for kind, _seg, p in compiled._plans if kind == "xla"]
+    declared = main.global_block()
+
+    def state(name):
+        var = declared._find_var_recursive(name)
+        return jax.ShapeDtypeStruct(
+            tuple(int(d) for d in var.shape),
+            fluid.core.dtype_to_np(var.dtype), sharding=chip)
+
+    shapes = feed_shapes(slots, max_blocks)
+    args = ([jax.ShapeDtypeStruct(
+                shapes[n], jnp.float32 if n == "last_onehot" else jnp.int32,
+                sharding=chip) for n in plan["feeds"]],
+            [state(n) for n in plan["mutable"]],
+            [state(n) for n in plan["sharded_const"]],
+            {n: state(n) for n in plan["const"]}, None)
+    built = jax.jit(plan["raw_fn"], donate_argnums=(1,)).lower(
+        *args).compile()
+    return cfg, blocks, block, built
+
+
+def _latent_pool_checks(cfg, blocks, block, built):
+    """The program fits the chip, updates the pools in place and copies
+    no pool whole into another layout (PR 26 found 24 such copies, ~34 ms
+    a step, under the GPT pools' 64-lane rows). -> its text."""
+    import re
+
+    memory = built.memory_analysis()
+    pool_bytes = cfg.num_hidden_layers * blocks * block * cfg.latent_row * 2
+    assert memory.alias_size_in_bytes >= pool_bytes      # updated in place
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            < 15.5e9)
+    text = built.as_text()
+    pool = r"bf16\[%d,1,%d,%d\]" % (blocks, block, cfg.latent_row)
+    assert not re.findall(r"%%copy[.\d]* = %s" % pool, text)
+    return text
+
+
+def test_latent_step_program_takes_the_pool_as_it_lies(chip, monkeypatch):
+    """The whole T = 1 step: the latent kernel and the three grouped
+    products a layer are in it."""
+    import re
+
+    from paddle_tpu.models import deepseek
+
+    def step(cfg, blocks, block, max_blocks, slots):
+        main, _s, feeds, logits = deepseek.build_paged_step(
+            cfg, slots, blocks, block, max_blocks)
+        return main, feeds, [logits.name] + main._step_stats
+
+    cfg, blocks, block, built = _compile_latent_program(
+        chip, monkeypatch, step, lambda slots, max_blocks: {
+            "step_ids": (slots, 1, 1), "step_pos": (slots, 1, 1),
+            "tables": (slots, max_blocks)})
+    text = _latent_pool_checks(cfg, blocks, block, built)
+    layers = cfg.num_hidden_layers
+    assert len(re.findall(r"%mla_decode_paged[.\d]* = ", text)) == layers
+    assert len(re.findall(r"%ragged-dot-none[.\d]* = ", text)) == 3 * (
+        layers - cfg.first_k_dense_replace)
+
+
+def test_latent_window_program_loops_over_the_keys_it_can_see(
+        chip, monkeypatch):
+    """The largest prefill window (2048 tokens): one loop over blocks of
+    queries and one over chunks of keys a layer, no branch by the fed
+    position, and no [heads, 2048, 4352] float32 scores among its
+    temporaries (1.1 GB a layer)."""
+    import re
+
+    from paddle_tpu.models import deepseek
+
+    t = 2048
+
+    def window(cfg, blocks, block, max_blocks, slots):
+        main, _s, feeds, logits = deepseek.build_paged_window(
+            cfg, blocks, block, max_blocks, t)
+        return main, feeds, [logits.name]
+
+    cfg, blocks, block, built = _compile_latent_program(
+        chip, monkeypatch, window, lambda slots, max_blocks: {
+            "ids": (1, t, 1), "pos_ids": (1, t, 1), "table": (1, max_blocks),
+            "window_pos": (1, 1), "last_onehot": (1, t, 1)})
+    text = _latent_pool_checks(cfg, blocks, block, built)
+    assert len(re.findall(r" while\(", text)) == 2 * cfg.num_hidden_layers
+    assert not re.findall(r" conditional\(", text)
+    assert built.memory_analysis().temp_size_in_bytes < 0.5e9
