@@ -265,7 +265,7 @@ class CompiledProgram(object):
             self._program._grad_allreduce_applied = nranks
 
     def _run(self, executor, feed=None, fetch_list=None, scope=None,
-             return_numpy=True):
+             return_numpy=True, while_device_runs=None):
         from ..observability import trace as _obs_trace
 
         # user-injected pass pipeline (BuildStrategy pass builder,
@@ -278,12 +278,9 @@ class CompiledProgram(object):
             self._passes_applied = True
         if not (self._is_spmd_mesh or (self._is_data_parallel
                                        and self._device_count() > 1)):
-            return executor.run(
-                self._program,
-                feed=feed,
-                fetch_list=fetch_list,
-                scope=scope,
-                return_numpy=return_numpy,
+            return executor._run(
+                self._program, feed, fetch_list, scope, return_numpy,
+                while_device_runs=while_device_runs,
             )
         # the tail is the executor's own, so the mesh path records what
         # Executor.run records: executor_run (prepare_ms, the block's
@@ -295,7 +292,7 @@ class CompiledProgram(object):
         rng_key = executor._rng_for(compiled, self._program, scope)
         return executor._run_compiled(
             compiled, scope, feed, rng_key, fetch_names, return_numpy,
-            t_in, hit,
+            t_in, hit, while_device_runs,
         )
 
     def _prepare(self, executor, feed, fetch_list, scope):

@@ -1420,6 +1420,17 @@ class Executor(object):
         use_program_cache=True,
         return_merged=True,
     ):
+        return self._run(program, feed, fetch_list, scope, return_numpy,
+                         use_program_cache)
+
+    def _run(self, program, feed, fetch_list, scope, return_numpy=True,
+             use_program_cache=True, while_device_runs=None):
+        """``run``, for it and for a caller inside the package that has
+        host work to put under the device's: ``while_device_runs()`` is
+        called once, after the run is dispatched (``executor_run`` has
+        closed) and before its fetch is waited for (``executor_fetch``
+        opens). The decode engine hands a step's tokens to their readers
+        there. What it raises fails the run."""
         from . import compiler as _compiler
 
         if self._closed:
@@ -1430,6 +1441,7 @@ class Executor(object):
             return program._run(
                 self, feed=feed, fetch_list=fetch_list, scope=scope,
                 return_numpy=return_numpy,
+                while_device_runs=while_device_runs,
             )
         t_in = time.perf_counter()
         compiled, scope, feed, fetch_names, plan_hit = self._prepare(
@@ -1438,7 +1450,7 @@ class Executor(object):
         rng_key = self._rng_for(compiled, program, scope)
         return self._run_compiled(
             compiled, scope, feed, rng_key, fetch_names, return_numpy,
-            t_in, plan_hit,
+            t_in, plan_hit, while_device_runs,
         )
 
     def _prepare(self, program, feed, fetch_list, scope, use_program_cache):
@@ -1547,14 +1559,16 @@ class Executor(object):
         return _fixed_rng()
 
     def _run_compiled(self, compiled, scope, feed, rng_key, fetch_names,
-                      return_numpy, t_in, plan_hit):
+                      return_numpy, t_in, plan_hit, while_device_runs=None):
         """The tail every entry point shares (``Executor.run`` and
         ``CompiledProgram._run``): run the compiled block, bring the
         fetches to the host. ``t_in`` is when the entry point began its
         own normalisation and lookup: ``executor_run`` carries that as
         ``prepare_ms`` (with ``plan_hit``) and the block's phases
         (marshal, dispatch, writeback); ``executor_fetch`` is the wait
-        for the device and the copy back."""
+        for the device and the copy back. Between the two the device has
+        its work and nobody waits for it yet: ``while_device_runs``
+        (``_run``) is called there."""
         # the step-loop span: one per run(), nesting under the trainer's
         # train_step span and over any RecordEvents ops open inside
         with _obs_trace.span(
@@ -1562,6 +1576,8 @@ class Executor(object):
             prepare_ms=(time.perf_counter() - t_in) * 1e3,
         ) as sp:
             outs = compiled.run(scope, feed, rng_key, self.place, sp)
+        if while_device_runs is not None:
+            while_device_runs()
         with _obs_trace.span("executor_fetch", cat="exec") as sp:
             outs = [
                 None if o is None else np.asarray(_fetch_to_host(o))

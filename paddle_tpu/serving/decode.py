@@ -635,6 +635,11 @@ class DecodeSession(object):
         # own executor: the session's program/plan caches never contend
         # with (or evict) a caller's LRU entries
         self.exe = fluid.Executor(self.place)
+        # host work of the session's driver to put under each device
+        # call: called once between the call's dispatch and the wait for
+        # its fetch (``Executor._run``). The engine publishes the last
+        # step's tokens there; None for a session driven directly
+        self.while_device_runs = None
         # session-local activity tallies (the process-global profiler
         # counters aggregate every session in the process; per-engine
         # stats need the unshared view)
@@ -837,6 +842,13 @@ class DecodeSession(object):
         )
 
     # -- device steps --------------------------------------------------------
+    def _run(self, main, feed, fetches):
+        """One device call: every program of the session runs through
+        here, so whichever the driver makes first carries its
+        ``while_device_runs``."""
+        return self.exe._run(main, feed, fetches, self.scope,
+                             while_device_runs=self.while_device_runs)
+
     def prefill(self, slot, prompt_ids):
         """Run the prompt through the bucketed prefill program, writing
         slot ``slot``'s cache row; returns the next-token logits
@@ -862,9 +874,7 @@ class DecodeSession(object):
         }
         t0 = time.perf_counter()
         with _trace.span("decode_prefill", cat="serving", bucket=T, rows=P):
-            (lv,) = self.exe.run(
-                main, feed=feed, fetch_list=[fetch_name], scope=self.scope
-            )
+            (lv,) = self._run(main, feed, [fetch_name])
         _profiler.bump_counter("decode_prefills")
         self.prefills += 1
         _profiler.bump_histogram(
@@ -917,9 +927,7 @@ class DecodeSession(object):
         t0 = time.perf_counter()
         with _trace.span("decode_resume_prefill", cat="serving",
                          bucket=T, rows=P, offset=offset):
-            (lv,) = self.exe.run(
-                main, feed=feed, fetch_list=[fetch_name], scope=self.scope
-            )
+            (lv,) = self._run(main, feed, [fetch_name])
         _profiler.bump_counter("decode_prefills")
         self.prefills += 1
         _profiler.bump_histogram(
@@ -935,11 +943,11 @@ class DecodeSession(object):
         main, fetch_name = self._copy_in
         with _trace.span("decode_prefix_copy", cat="serving",
                          block=src_block, pos=dst_pos):
-            self.exe.run(
+            self._run(
                 main,
-                feed={"dst_loc": np.array([[slot, dst_pos]], "int64"),
-                      "src_loc": np.array([[src_block, 0]], "int64")},
-                fetch_list=[fetch_name], scope=self.scope,
+                {"dst_loc": np.array([[slot, dst_pos]], "int64"),
+                 "src_loc": np.array([[src_block, 0]], "int64")},
+                [fetch_name],
             )
 
     def prefix_publish(self, slot, src_pos, dst_block):
@@ -949,11 +957,11 @@ class DecodeSession(object):
         main, fetch_name = self._publish
         with _trace.span("decode_prefix_publish", cat="serving",
                          block=dst_block, pos=src_pos):
-            self.exe.run(
+            self._run(
                 main,
-                feed={"dst_loc": np.array([[dst_block, 0]], "int64"),
-                      "src_loc": np.array([[slot, src_pos]], "int64")},
-                fetch_list=[fetch_name], scope=self.scope,
+                {"dst_loc": np.array([[dst_block, 0]], "int64"),
+                 "src_loc": np.array([[slot, src_pos]], "int64")},
+                [fetch_name],
             )
 
     def decode_step(self, tokens, positions, active):
@@ -985,9 +993,7 @@ class DecodeSession(object):
         with _trace.span(
             "decode_step", cat="serving", active=int(act.sum())
         ):
-            (lv,) = self.exe.run(
-                main, feed=feed, fetch_list=[fetch_name], scope=self.scope
-            )
+            (lv,) = self._run(main, feed, [fetch_name])
         _profiler.bump_counter("decode_steps")
         self.steps += 1
         _profiler.bump_histogram(
@@ -1044,9 +1050,7 @@ class DecodeSession(object):
         t0 = time.perf_counter()
         with _trace.span("decode_paged_window", cat="serving",
                          bucket=T, rows=P, offset=offset):
-            (lv,) = self.exe.run(
-                main, feed=feed, fetch_list=[fetch_name], scope=self.scope
-            )
+            (lv,) = self._run(main, feed, [fetch_name])
         _profiler.bump_counter("decode_prefills")
         self.prefills += 1
         _profiler.bump_histogram(
@@ -1112,9 +1116,7 @@ class DecodeSession(object):
                          active=int(act.sum()), width=width,
                          blocks_live=blocks_live,
                          blocks_table=self.slots * self.max_blocks) as sp:
-            lv, *stats = self.exe.run(
-                main, feed=feed, fetch_list=fetches, scope=self.scope
-            )
+            lv, *stats = self._run(main, feed, fetches)
             if len(fetches) > 1:
                 sp.note(**self.model.step_stats(
                     [np.asarray(v) for v in stats],
@@ -1138,11 +1140,11 @@ class DecodeSession(object):
         for src, dst in zip(src_blocks, dst_blocks):
             with _trace.span("decode_block_copy", cat="serving",
                              src=int(src), dst=int(dst)):
-                self.exe.run(
+                self._run(
                     main,
-                    feed={"src": np.array([[src]], "int64"),
-                          "dst": np.array([[dst]], "int64")},
-                    fetch_list=[fetch_name], scope=self.scope,
+                    {"src": np.array([[src]], "int64"),
+                     "dst": np.array([[dst]], "int64")},
+                    [fetch_name],
                 )
 
 
@@ -1407,22 +1409,37 @@ class GenerationStream(object):
                             top_k=self.top_k, top_p=self.top_p,
                             rng=self._rng)
 
-    def _push(self, tok):
+    # Each of the three decides (what the engine's next tick reads:
+    # ``_tokens``, the stamps, ``finish_reason``, ``_error``, the
+    # request's record) and then publishes (what wakes the consumer's
+    # thread). With an ``outbox`` the publication waits there, in order,
+    # until its owner hands it to ``_publish``: the engine's loop does
+    # that once the next device call is dispatched, so the readers run
+    # while the chip works.
+    def _push(self, tok, outbox=None):
         self._emit_times.append(time.perf_counter())
         self._tokens.append(int(tok))
-        self._q.put(int(tok))
+        self._publish(int(tok), outbox)
 
-    def _finish(self, reason):
+    def _finish(self, reason, outbox=None):
         self.finish_reason = reason
         self._record(reason)
-        self._done.set()
-        self._q.put(_SENTINEL)
+        self._publish(_SENTINEL, outbox)
 
-    def _fail(self, exc):
+    def _fail(self, exc, outbox=None):
         self._error = exc
         self._record("error")
-        self._done.set()
-        self._q.put(_SENTINEL)
+        self._publish(_SENTINEL, outbox)
+
+    def _publish(self, item, outbox=None):
+        """Hand ``item`` (a token, or the sentinel that ends the stream)
+        to the consumer, or to ``outbox`` as ``(stream, item)``."""
+        if outbox is not None:
+            outbox.append((self, item))
+            return
+        if item is _SENTINEL:
+            self._done.set()
+        self._q.put(item)
 
     def _record(self, reason):
         """The one record a request leaves: a ``decode_request`` instant
@@ -1695,7 +1712,15 @@ class DecodeEngine(object):
                         "spec_drafted": 0, "spec_accepted": 0,
                         "oom_sheds": 0,
                         "kv_readmits": 0, "kv_readmit_tokens": 0,
-                        "preemptions": 0, "preempt_replayed_tokens": 0}
+                        "preemptions": 0, "preempt_replayed_tokens": 0,
+                        "published_overlapped": 0, "published_exposed": 0}
+        # what the loop thread has decided and not yet handed to the
+        # streams' consumers: (stream, token or sentinel) in order.
+        # ``_publish`` empties it once the next device call is
+        # dispatched, or at once where none follows; the lock keeps a
+        # stop() whose join timed out from interleaving with the loop
+        self._outbox = deque()
+        self._publish_lock = threading.Lock()
         # weighted-fair scheduler state (stride scheduling): per-tenant
         # virtual time + the global virtual clock a joining tenant
         # starts at (so a newcomer can't claim "unused" history)
@@ -1803,6 +1828,7 @@ class DecodeEngine(object):
                 if blocks else None
         if self._param_program is not None:
             self.session.bind_params(self._param_program)
+        self.session.while_device_runs = self._publish_overlapped
         self._warmup()
         self._free = list(range(self.session.slots))
         self._stop = False
@@ -1980,6 +2006,8 @@ class DecodeEngine(object):
             # next start() rebuilds — just drop the host-side tables
             self._slot_blocks.clear()
             self.started = False
+        # the tokens already decided, before the streams are failed
+        self._publish()
         err = ServingError("decode engine stopped")
         for stream in failed:
             stream._fail(err)
@@ -2160,6 +2188,8 @@ class DecodeEngine(object):
             "preemptions": self._counts["preemptions"],
             "preempt_replayed_tokens":
                 self._counts["preempt_replayed_tokens"],
+            "published_overlapped": self._counts["published_overlapped"],
+            "published_exposed": self._counts["published_exposed"],
         }
         if self._counts["spec_drafted"]:
             out["spec_acceptance"] = (
@@ -2201,18 +2231,21 @@ class DecodeEngine(object):
                 # admissions == retirements + occupancy invariant holds
                 # across recovered failures (prefilling slots were never
                 # counted as admissions, so they free without a tally)
+                # Each stream gets the tokens decided before the failure,
+                # then the error: the outbox keeps that order
                 for idx, slot in list(self._active.items()):
-                    slot.stream._fail(e)
+                    slot.stream._fail(e, self._outbox)
                     self._release_slot_blocks(idx)
                     _profiler.bump_counter("serving_slot_retirements")
                     self._counts["retirements"] += 1
                 self._free.extend(self._active.keys())
                 self._active.clear()
                 for idx, job in list(self._prefilling.items()):
-                    job.stream._fail(e)
+                    job.stream._fail(e, self._outbox)
                     self._release_slot_blocks(idx)
                 self._free.extend(self._prefilling.keys())
                 self._prefilling.clear()
+                self._publish()
 
     def _idle(self):
         """Nothing to do and not stopping (under ``_cond``)."""
@@ -2232,8 +2265,16 @@ class DecodeEngine(object):
         ``engine_tick`` is the tick's one parent span and the phase
         spans its children, which tile it: reap, admit, prefill, then in
         ``_step`` build, the device call (``decode_tick``) and
-        sample + emit. No span per token and none per slot."""
+        sample + emit. No span per token and none per slot.
+
+        What a tick decides for a stream (tokens, endings) it publishes
+        under a device call: ``tick_publish`` runs inside the first one
+        dispatched after the decision, between its ``executor_run`` and
+        its ``executor_fetch``, which for the step's own tokens is the
+        next tick's. Where no device call follows, at the tick's end."""
         cpu0 = time.thread_time()
+        counts = self._counts
+        pub0 = (counts["published_overlapped"], counts["published_exposed"])
         with _trace.span("engine_tick", cat="serving",
                          tick=self.tick) as sp:
             with _trace.span("tick_reap", cat="serving"):
@@ -2246,9 +2287,64 @@ class DecodeEngine(object):
                 self._advance_prefills()
             if self._active:
                 self._step()
+            if not self._next_tick_carries():
+                self._publish()
             if _trace.enabled():
                 sp.note(cpu_ms=(time.thread_time() - cpu0) * 1e3,
+                        published_overlapped=(
+                            counts["published_overlapped"] - pub0[0]),
+                        published_exposed=(
+                            counts["published_exposed"] - pub0[1]),
                         **self._occupancy())
+
+    def _next_tick_carries(self):
+        """Whether what this tick decided after its last device call may
+        wait for the next tick's first one. Only on the loop thread,
+        whose next tick follows at once, and only while a stream is
+        active or prefilling, so that the next tick makes a device call
+        (the step, or a window). A caller that drives ``_tick()`` itself
+        reads the streams when it returns; an engine going idle or
+        stopping has no next call to ride."""
+        return (threading.current_thread() is self._thread
+                and not self._stop
+                and bool(self._active or self._prefilling))
+
+    def _publish_overlapped(self):
+        """The session's ``while_device_runs``: a device call of this
+        thread has been dispatched and its fetch is not yet waited for."""
+        self._publish(overlapped=True)
+
+    def _publish(self, overlapped=False):
+        """Hand everything decided and not yet published to the streams'
+        consumers, in the order it was decided (a stream's tokens, then
+        its end): this is what wakes the gateway's handler threads and,
+        through their writes, the clients. ``overlapped`` says whether a
+        device call of this thread is in flight, for the two counters
+        ``decode_tokens_published_overlapped`` / ``_exposed``."""
+        box = self._outbox
+        if not box:
+            return
+        with self._publish_lock, \
+                _trace.span("tick_publish", cat="serving",
+                            overlapped=overlapped) as sp:
+            tokens, streams = 0, set()
+            while box:
+                stream, item = box.popleft()
+                stream._publish(item)
+                streams.add(stream)
+                if item is not _SENTINEL:
+                    tokens += 1
+            sp.note(tokens=tokens, streams=len(streams))
+        if not tokens:
+            return
+        if overlapped:
+            _profiler.bump_counter("decode_tokens_published_overlapped",
+                                   tokens)
+            self._counts["published_overlapped"] += tokens
+        else:
+            _profiler.bump_counter("decode_tokens_published_exposed",
+                                   tokens)
+            self._counts["published_exposed"] += tokens
 
     def _occupancy(self):
         """What a tick leaves behind, for its span: streams by state,
@@ -2283,7 +2379,7 @@ class DecodeEngine(object):
                 self._release_slot_blocks(idx)
                 _profiler.bump_counter("serving_slot_retirements")
                 self._counts["retirements"] += 1
-                slot.stream._finish("cancelled")
+                slot.stream._finish("cancelled", self._outbox)
         for idx, job in list(self._prefilling.items()):
             if job.stream._cancelled:
                 # cancelled mid-chunked-prefill: the slot frees without a
@@ -2292,13 +2388,13 @@ class DecodeEngine(object):
                 self._prefilling.pop(idx, None)
                 self._free.append(idx)
                 self._release_slot_blocks(idx)
-                job.stream._finish("cancelled")
+                job.stream._finish("cancelled", self._outbox)
         with self._cond:
             if any(s._cancelled for s in self._pending):
                 live = deque()
                 for s in self._pending:
                     if s._cancelled:
-                        s._finish("cancelled")
+                        s._finish("cancelled", self._outbox)
                     else:
                         live.append(s)
                 self._pending = live
@@ -2495,7 +2591,7 @@ class DecodeEngine(object):
             if stream._cancelled:
                 # cancelled while queued: never admitted, so no slot,
                 # no retirement tally — just finish the dead handle
-                stream._finish("cancelled")
+                stream._finish("cancelled", self._outbox)
                 continue
             slot_idx = self._free.pop()
             if self._paged:
@@ -2528,7 +2624,7 @@ class DecodeEngine(object):
                             )
             except Exception as exc:  # noqa: BLE001 - per-request failure
                 self._free.append(slot_idx)
-                stream._fail(exc)
+                stream._fail(exc, self._outbox)
                 continue
             finally:
                 # copy done (or failed): the store may evict these
@@ -2565,7 +2661,8 @@ class DecodeEngine(object):
                 with self._cond:
                     if self._stop or not self.started:
                         self._free.append(slot_idx)
-                        stream._fail(ServingError("decode engine stopped"))
+                        stream._fail(ServingError("decode engine stopped"),
+                                     self._outbox)
                         continue
                     self._prefilling[slot_idx] = job
 
@@ -2609,7 +2706,7 @@ class DecodeEngine(object):
             stream._fail(ServerOverloadedError(
                 "paged KV pool exhausted (%d blocks short after "
                 "eviction)" % need, retry_after_ms=50,
-            ))
+            ), self._outbox)
             return
         self._slot_blocks[slot_idx] = blocks + owned
         stream.cached_prefix_tokens = prefix_tokens
@@ -2637,7 +2734,8 @@ class DecodeEngine(object):
                 if self._stop or not self.started:
                     self._free.append(slot_idx)
                     self._release_slot_blocks(slot_idx)
-                    stream._fail(ServingError("decode engine stopped"))
+                    stream._fail(ServingError("decode engine stopped"),
+                                 self._outbox)
                     return
                 self._prefilling[slot_idx] = job
 
@@ -3029,7 +3127,8 @@ class DecodeEngine(object):
                         self._prefilling.pop(slot_idx, None)
                         self._free.append(slot_idx)
                         self._release_slot_blocks(slot_idx)
-                        stream._fail(ServingError("decode engine stopped"))
+                        stream._fail(ServingError("decode engine stopped"),
+                                     self._outbox)
                         return
                     self._prefilling[slot_idx] = job
                 return
@@ -3042,7 +3141,7 @@ class DecodeEngine(object):
             self._prefilling.pop(slot_idx, None)
             self._free.append(slot_idx)
             self._release_slot_blocks(slot_idx)
-            stream._fail(exc)
+            stream._fail(exc, self._outbox)
             return
         self._prefilling.pop(slot_idx, None)
         if self._paged:
@@ -3069,7 +3168,8 @@ class DecodeEngine(object):
             if self._stop or not self.started:
                 self._free.append(slot_idx)
                 self._release_slot_blocks(slot_idx)
-                stream._fail(ServingError("decode engine stopped"))
+                stream._fail(ServingError("decode engine stopped"),
+                             self._outbox)
                 return
             self._active[slot_idx] = slot
         _profiler.bump_counter("serving_slot_admissions")
@@ -3112,9 +3212,12 @@ class DecodeEngine(object):
                 self.prefix.forget(entry)
 
     def _emit(self, slot_idx, slot, tok):
-        """Stream one generated token and retire the slot if finished."""
+        """Stream one generated token and retire the slot if finished:
+        the one place a served token enters its stream. Everything the
+        next tick reads is decided here; the token and the ending reach
+        the consumer when the outbox is published (``_publish``)."""
         stream = slot.stream
-        stream._push(tok)
+        stream._push(tok, self._outbox)
         stream.last_tick = self.tick
         now = time.monotonic()
         if stream._t_last_emit is not None:
@@ -3144,7 +3247,7 @@ class DecodeEngine(object):
             self._release_slot_blocks(slot_idx)
             _profiler.bump_counter("serving_slot_retirements")
             self._counts["retirements"] += 1
-            stream._finish(reason)
+            stream._finish(reason, self._outbox)
 
     def _step(self):
         """One fused decode step over every active slot."""
@@ -3191,7 +3294,7 @@ class DecodeEngine(object):
                     self._free.append(idx)
                     _profiler.bump_counter("serving_slot_retirements")
                     self._counts["retirements"] += 1
-                    slot.stream._fail(e)
+                    slot.stream._fail(e, self._outbox)
                     continue
                 slot.next_pos += 1
                 slot.generated += 1
@@ -3272,7 +3375,7 @@ class DecodeEngine(object):
                         self._release_slot_blocks(idx)
                         _profiler.bump_counter("serving_slot_retirements")
                         self._counts["retirements"] += 1
-                        slot.stream._fail(e)
+                        slot.stream._fail(e, self._outbox)
                         failed = True
                         break
                     emitted += 1
@@ -3337,7 +3440,7 @@ class DecodeEngine(object):
                 self._counts["retirements"] += 1
                 _profiler.bump_counter("decode_paged_oom_sheds")
                 self._counts["oom_sheds"] += 1
-                slot.stream._fail(shed)
+                slot.stream._fail(shed, self._outbox)
         if not self._active:
             return None
         tokens = np.zeros((sess.slots, width), "int64")

@@ -146,6 +146,59 @@ def test_executor_phases_nest_and_add_up(step):
     assert 0.6 * wall <= total <= wall
 
 
+@pytest.mark.parametrize("entry", ["run", "with_mesh"])
+def test_host_work_between_dispatch_and_fetch(entry):
+    """The seam the decode engine publishes through (``Executor._run``'s
+    ``while_device_runs``): called exactly once a run, after
+    ``executor_run`` has closed and before ``executor_fetch`` opens, on
+    both entry points; the run returns what ``run`` returns and leaves
+    the records ``run`` leaves. What the callable raises fails the run
+    before anything is fetched. ``Executor.run`` itself takes nothing
+    new."""
+    import inspect
+
+    if entry == "with_mesh" and len(jax.devices()) < 4:
+        pytest.skip("needs 4 devices")
+    assert list(inspect.signature(fluid.Executor.run).parameters) == [
+        "self", "program", "feed", "fetch_list", "feed_var_name",
+        "fetch_var_name", "scope", "return_numpy", "use_program_cache",
+        "return_merged"]
+    exe, scope, target, loss = _program_on(entry)
+    feed = _feed()
+    for _ in range(2):
+        exe.run(target, feed=feed, fetch_list=[loss], scope=scope)
+    trace.reset()
+    calls = []
+
+    def host_work():
+        with trace.span("host_work_under_the_device"):
+            calls.append(time.perf_counter())
+
+    (got,) = exe._run(target, feed, [loss], scope,
+                      while_device_runs=host_work)
+    spans = trace.get_spans()
+    assert len(calls) == 1 and isinstance(got, np.ndarray)
+    assert [s["name"] for s in spans if s["id"] is not None] == [
+        "executor_run", "host_work_under_the_device", "executor_fetch"]
+    run, work, fetch = spans
+    assert run["end"] <= work["start"] <= work["end"] <= fetch["start"]
+    assert run["depth"] == work["depth"] == fetch["depth"]
+    # without the callable: the two records of before
+    trace.reset()
+    (plain,) = exe.run(target, feed=feed, fetch_list=[loss], scope=scope)
+    assert [s["name"] for s in trace.get_spans()] == [
+        "executor_run", "executor_fetch"]
+    assert plain.shape == got.shape and plain.dtype == got.dtype
+
+    def broken():
+        raise RuntimeError("host work failed")
+
+    trace.reset()
+    with pytest.raises(RuntimeError, match="host work failed"):
+        exe._run(target, feed, [loss], scope, while_device_runs=broken)
+    assert [s["name"] for s in trace.get_spans()] == ["executor_run"]
+
+
 def test_marshal_counts_the_values_it_places(step):
     """``placed`` is the number of ``jax.device_put`` calls marshal made:
     the two feeds, on one device and under a mesh alike, because the
@@ -261,7 +314,7 @@ def gen_server():
 
 
 TICK_CHILDREN = ("tick_reap", "tick_admit", "tick_prefill", "tick_build",
-                 "decode_tick", "tick_sample_emit")
+                 "decode_tick", "tick_sample_emit", "tick_publish")
 
 
 @pytest.fixture(scope="module")
@@ -285,21 +338,65 @@ def _children(parent, spans):
 def test_tick_children_tile_the_tick(ticks):
     """Every ``engine_tick`` holds its phases in ``_tick`` order, and what
     the phases leave uncovered (its self time) is under 2 % of it at the
-    median."""
+    median. ``tick_publish`` is a child of the tick only where no device
+    call follows (the last stream's last token); everywhere else it lies
+    inside one, under ``decode_paged_step`` or a window."""
     parents = [s for s in ticks if s["name"] == "engine_tick"]
     assert len(parents) >= 20
-    shares = []
+    shares, at_the_end = [], 0
     for p in parents:
         kids = sorted(_children(p, ticks), key=lambda s: s["start"])
         names = [k["name"] for k in kids]
         assert names[:3] == ["tick_reap", "tick_admit", "tick_prefill"]
         assert set(names) <= set(TICK_CHILDREN)
+        if names[-1] == "tick_publish":
+            at_the_end += 1
+            names.pop()
         if "decode_tick" in names:
             assert names[3:] == ["tick_build", "decode_tick",
                                  "tick_sample_emit"]
         covered = sum(k["end"] - k["start"] for k in kids)
         shares.append(1.0 - covered / (p["end"] - p["start"]))
     assert statistics.median(shares) < 0.02
+    assert 1 <= at_the_end <= 3   # three streams, each ends once
+
+
+@pytest.mark.parametrize("call,at_least", [("decode_paged_step", 20),
+                                           ("decode_paged_window", 2)])
+def test_publish_lies_between_dispatch_and_fetch(ticks, call, at_least):
+    """With the loop thread, what a tick decided is handed to the streams
+    inside the next device call, whichever that is: the next tick's fused
+    step, or an admission's window (the three streams are admitted one
+    after another: the second's window carries the first's first token).
+    ``tick_publish`` starts after that call's ``executor_run`` ends and
+    ends before its ``executor_fetch`` begins."""
+    loop = [s for s in ticks if s["name"] == "engine_tick"][0]["tid"]
+    on_loop = sorted((s for s in ticks if s["tid"] == loop
+                      and not s["instant"]), key=lambda s: s["start"])
+    under = [s for s in on_loop if s["name"] == "tick_publish"
+             and s["args"]["overlapped"] and s["parent"] == call]
+    assert len(under) >= at_least
+    emitted = {}
+    for p in under:
+        run = [s for s in on_loop if s["name"] == "executor_run"
+               and s["end"] <= p["start"]][-1]
+        fetch = [s for s in on_loop if s["name"] == "executor_fetch"
+                 and s["start"] >= p["end"]][0]
+        # the same device call: nothing of the executor in between
+        between = [s for s in on_loop if s["name"] in PHASES
+                   and run["end"] < s["start"] < fetch["start"]]
+        assert between == []
+        assert run["parent"] == fetch["parent"] == call
+        assert p["args"]["tokens"] >= 1 and p["args"]["streams"] >= 1
+        emit = [s for s in on_loop if s["name"] == "tick_sample_emit"
+                and s["end"] <= p["start"]]
+        if emit and call == "decode_paged_step":
+            emitted[p["start"]] = emit[-1]["args"]["tokens"]
+    # a step's publish hands out what the sample + emit before it decided
+    # (plus, on some ticks, an admission's first token)
+    assert all(p["args"]["tokens"] >= emitted[p["start"]]
+               for p in under if p["start"] in emitted)
+    assert emitted or call != "decode_paged_step"
 
 
 def test_tick_says_what_it_held(ticks):
