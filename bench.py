@@ -64,8 +64,8 @@ def best_window_rate(samples, min_window_s):
     """Best (events/sec) over any sample window spanning at least
     ``min_window_s``, from a monotone list of (t, cumulative_count)
     pairs; falls back to the full span when no window is long enough.
-    The load-robust throughput estimator shared by the decode probe and
-    the BENCH_DECODE rung: external load only ever subtracts throughput,
+    The load-robust throughput estimator of the decode, SPMD and
+    serving-load probes: external load only ever subtracts throughput,
     so the max window is the undisturbed steady-state figure without the
     admission ramp / drain tail. The O(n^2) pairwise scan is fine for
     the sample counts involved (sub-second polling over seconds-long
@@ -377,287 +377,9 @@ def _serving_measure(cfg, inference, serving, np, export_dir, gcfg,
     }), flush=True)
 
 
-def decode_child_main(cfg):
-    """BENCH_DECODE=1 rung: autoregressive tokens/sec through the
-    KV-cache continuous-batching engine (paddle_tpu/serving/decode.py)
-    at N concurrent streams. Headline is tokens/sec/USER (= total
-    decode throughput / streams) — the metric the ROADMAP's
-    "millions of users" serving item is denominated in. Banked under
-    'gpt_decode', never promoted to a training headline. The decode-step
-    program's flops/bytes census rides along where cost analysis
-    permits (flash-decode engages the Pallas kernel, which cost
-    analysis cannot see inside — those rungs bank without a census,
-    like every other flash rung)."""
-    tp = int(cfg.get("tp", 0) or 0)
-    place = chip_start()
-
-    import jax
-    import numpy as np
-
-    import paddle_tpu.fluid as fluid
-    from paddle_tpu.fluid import profiler
-    from paddle_tpu.models.gpt import GPTConfig, build_gpt_infer
-    from paddle_tpu.observability import xla_stats as _xla_stats
-    from paddle_tpu.serving.decode import DecodeEngine
-
-    streams = cfg.get("streams", 8)
-    max_len = cfg.get("max_len", 256)
-    # decode engine v2 knobs: paged_block > 0 routes through the
-    # block-table runtime; spec_tokens > 1 additionally arms the k-token
-    # speculative verify (spec rung runs a width-1 baseline first)
-    paged_block = int(cfg.get("paged_block", 0) or 0)
-    spec_k = int(cfg.get("spec_tokens", 0) or 0)
-    gcfg = GPTConfig(
-        vocab_size=cfg.get("vocab", 50257),
-        hidden_size=cfg.get("hidden", 768),
-        num_layers=cfg.get("layers", 12),
-        num_heads=cfg.get("heads", 12),
-        intermediate_size=cfg.get("hidden", 768) * 4,
-        # spec verify embeds positions up to max_len + k - 2
-        max_position_embeddings=max(max_len + max(spec_k - 1, 0), 256),
-        is_test=True,
-        use_flash_attention=bool(cfg.get("flash")),
-    )
-    t0 = time.time()
-    _hb("build start (GPT infer graph for params)")
-    with fluid.unique_name.guard():
-        main_prog, startup, _feeds, _logits = build_gpt_infer(gcfg, max_len)
-    scope = fluid.core.Scope()
-    exe = fluid.Executor(place)
-    with fluid.scope_guard(scope):
-        exe.run(startup, scope=scope)
-    _hb("params ok %.1fs" % (time.time() - t0))
-
-    t0 = time.time()
-    _hb("engine warmup start (prefill ladder + decode step compiles)")
-    prompt_len = cfg.get("prompt_len", 32)
-    rs = np.random.RandomState(0)
-    prefix_cache = bool(cfg.get("prefix_cache"))
-    eng_kw = {}
-    shared = None
-    if prefix_cache:
-        # BENCH_DECODE prefix rung: every request shares a system-prompt
-        # prefix of ~prefix_share of the prompt (block-aligned); the
-        # store is sized generously so the trial measures reuse, not
-        # eviction churn
-        from paddle_tpu.models.gpt import prefix_block_bytes
-
-        block = int(cfg.get("prefix_block", 16))
-        share = float(cfg.get("prefix_share", 0.9))
-        # block-aligned, and capped at prompt_len - 1 so the suffix is
-        # never empty (mirrors the engine's len-1 lookup cap); a prompt
-        # too short to hold even one shared block is a config error,
-        # reported instead of crashing mk_prompt with a negative size
-        shared_len = min(int(prompt_len * share) // block * block,
-                         (prompt_len - 1) // block * block)
-        if shared_len < block:
-            _child_fail(
-                "config",
-                "prefix rung needs prompt_len > prefix_block "
-                "(prompt_len %d, block %d, share %.2f)"
-                % (prompt_len, block, share),
-            )
-        shared = list(rs.randint(0, gcfg.vocab_size, shared_len))
-        blocks = 8 * (shared_len // block)
-        eng_kw = dict(
-            prefix_block=block,
-            prefix_cache_mb=blocks * prefix_block_bytes(gcfg, block)
-            / 2.0 ** 20,
-        )
-    pool_blocks = 0
-    pool_bytes = None
-    if paged_block:
-        from paddle_tpu.models.gpt import paged_block_bytes
-
-        # pool sized to the HBM an anchor-geometry LEGACY engine spends
-        # on contiguous [slots, anchor_len] rows (+ the sink block) —
-        # the seq-4k rung's claim is "longer streams at UNCHANGED pool
-        # bytes", so the anchor is the budget, not max_len
-        anchor = int(cfg.get("pool_anchor_len", 0) or 0)
-        if anchor:
-            pool_blocks = streams * anchor // paged_block + 1
-        eng_kw.update(block_size=paged_block, pool_blocks=pool_blocks)
-    if tp > 1:
-        # tensor-parallel rung: every decode/prefill/paged program runs
-        # GSPMD-sharded over a {"model": tp} mesh (KV pools partitioned
-        # on the heads axis, block tables replicated)
-        if jax.device_count() < tp:
-            _child_fail(
-                "config",
-                "tp rung needs >= %d devices, backend has %d"
-                % (tp, jax.device_count()),
-            )
-        eng_kw["tp"] = tp
-
-    n_requests = cfg.get("requests", 4 * streams)
-    max_new = cfg.get("max_new", 64)
-
-    def mk_prompt():
-        if shared is None:
-            return list(rs.randint(0, gcfg.vocab_size, prompt_len))
-        return shared + list(rs.randint(
-            0, gcfg.vocab_size, prompt_len - len(shared)))
-
-    # fixed prompt pool (cycled) so the spec rung's replay-drafter phase
-    # sees the exact workload its width-1 baseline recorded
-    prompt_pool = [mk_prompt() for _ in range(2 * streams)]
-
-    def run_workload(engine):
-        handles = [
-            engine.generate(prompt_pool[i % len(prompt_pool)],
-                            max_new_tokens=max_new)
-            for i in range(n_requests)
-        ]
-        samples = [(time.perf_counter(),
-                    profiler.get_counters().get("decode_tokens", 0))]
-        while not all(h.done for h in handles):
-            time.sleep(0.1)
-            samples.append((time.perf_counter(),
-                            profiler.get_counters().get("decode_tokens", 0)))
-        samples.append((time.perf_counter(),
-                        profiler.get_counters().get("decode_tokens", 0)))
-        for h in handles:
-            h.tokens(timeout=600)
-        # best >=2 s window = steady-state rate without ramp/drain tails
-        return best_window_rate(samples, 2.0), handles
-
-    base_kw = dict(gcfg=gcfg, place=place, scope=scope, slots=streams,
-                   max_len=max_len,
-                   prefill_buckets=[prompt_len, max_len],
-                   param_program=main_prog)
-    spec_facts = {}
-    drafter = None
-    if spec_k > 1:
-        # phase 1 of the spec rung: the SAME paged geometry at width 1.
-        # Greedy decode is deterministic, so its streams double as the
-        # recorded continuations the replay drafter proposes in phase 2
-        # at a controlled accuracy — the banked speedup measures the
-        # k-token verify/rollback machinery at that acceptance, not
-        # drafter luck on random weights
-        _hb("spec baseline start (width-1 paged engine)")
-        kw = dict(base_kw)
-        g = kw.pop("gcfg")
-        base_eng = DecodeEngine(g, **kw, **dict(eng_kw, spec_tokens=0))\
-            .start()
-        try:
-            base_tps, base_handles = run_workload(base_eng)
-        finally:
-            base_eng.stop()
-        recorded = {}
-        for h in base_handles:
-            p = list(h.prompt_ids)
-            recorded[tuple(p)] = p + h.tokens(timeout=10)
-        accuracy = float(cfg.get("draft_accuracy", 0.9))
-        drs = np.random.RandomState(11)
-
-        def drafter(hist, k):
-            full = recorded.get(tuple(hist[:prompt_len]))
-            if full is None:
-                return [0] * k
-            d = list(full[len(hist):len(hist) + k])
-            d += [0] * (k - len(d))
-            return [t if drs.random_sample() < accuracy
-                    else (int(t) + 1) % gcfg.vocab_size for t in d]
-
-        eng_kw["spec_tokens"] = spec_k
-        spec_facts = {
-            "baseline_tok_per_sec_user": round(base_tps / streams, 2),
-            "draft_accuracy": accuracy,
-        }
-        _hb("spec baseline ok %.1f tok/s" % base_tps)
-
-    engine = DecodeEngine(
-        gcfg, place=place, scope=scope, slots=streams, max_len=max_len,
-        prefill_buckets=[prompt_len, max_len], param_program=main_prog,
-        drafter=drafter, **eng_kw
-    ).start()
-    _hb("engine warmup ok %.1fs" % (time.time() - t0))
-    try:
-        tok_s, handles = run_workload(engine)
-        stats = engine.stats()
-        if spec_k > 1:
-            base_u = spec_facts["baseline_tok_per_sec_user"]
-            spec_facts.update({
-                "spec_speedup": round(
-                    tok_s / streams / max(base_u, 1e-9), 2),
-                "spec_acceptance": round(
-                    stats.get("spec_acceptance", 0.0), 3),
-                # greedy determinism: the spec streams must be byte-
-                # identical to the width-1 recordings
-                "spec_parity": all(
-                    list(h.prompt_ids) + h.tokens(timeout=10)
-                    == recorded.get(tuple(h.prompt_ids))
-                    for h in handles
-                ),
-            })
-        census = None
-        if not cfg.get("flash") and not paged_block:
-            # census of the DECODE-STEP program specifically — the
-            # generic heaviest-program headline would pick a prefill
-            # bucket, whose bytes budget is not the serving steady state
-            # (the paged step is fed block tables; its census rides the
-            # same xla_stats path but is not this rung's banked fact)
-            dmain, dfetch = engine.session._decode
-            fp = _xla_stats.fingerprint(_xla_stats.make_key(
-                dmain, ["step_ids", "step_pos", "key_bias"], [dfetch]
-            ))
-            census = _xla_stats.census_by_key().get(fp)
-        if paged_block:
-            pool_blocks = engine.session.pool_blocks
-            pool_bytes = pool_blocks * paged_block_bytes(gcfg, paged_block)
-    finally:
-        engine.stop()
-    _hb("decode ok %.1f tok/s at %d streams" % (tok_s, streams))
-    result = {
-        "tok_per_sec": tok_s,
-        "tok_per_sec_user": tok_s / streams,
-        "streams": streams,
-        "max_len": max_len,
-        "max_new": max_new,
-        "requests": stats["requests"],
-        "steps": stats["steps"],
-        "device": "tpu",
-    }
-    if paged_block:
-        result.update({
-            "paged": True,
-            "paged_block": paged_block,
-            "pool_blocks": pool_blocks,
-            "pool_bytes": int(pool_bytes),
-            "pool_anchor_len": int(cfg.get("pool_anchor_len", 0) or 0),
-            "oom_sheds": stats.get("oom_sheds", 0),
-        })
-    if spec_k > 1:
-        result.update(spec_facts)
-        result.update({"spec": True, "spec_tokens": spec_k})
-    if tp > 1:
-        result.update({"tp": True, "tp_degree": tp})
-    if prefix_cache:
-        hit_ttfts = [h.ttft_ms for h in handles
-                     if getattr(h, "cached_prefix_tokens", 0) > 0
-                     and h.ttft_ms is not None]
-        result.update({
-            "prefix_share": round(len(shared) / prompt_len, 3),
-            "prefix_hits": stats.get("prefix_hits", 0),
-            "prefix_hit_rate": round(
-                stats.get("prefix_hits", 0) / max(1, n_requests), 3),
-            "cached_prefix_tokens": stats.get("prefix_cached_tokens", 0),
-            "ttft_ms": round(float(np.mean(hit_ttfts)), 2)
-            if hit_ttfts else None,
-        })
-    if census is not None:
-        for k in ("flops", "bytes_accessed", "out_bytes"):
-            if census.get(k) is not None:
-                result[k] = census[k]
-        result["census_source"] = "live_census"
-    print("RESULT " + json.dumps(result), flush=True)
-
-
 def child_main(cfg):
     if cfg.get("serving"):
         return serving_child_main(cfg)
-    if cfg.get("decode"):
-        return decode_child_main(cfg)
     place = chip_start()
 
     import jax
@@ -1111,218 +833,6 @@ def parent_main():
         }))
         return True
 
-    def try_decode_tpu(slot):
-        """BENCH_DECODE=1 rung: bank autoregressive decode tokens/sec/user
-        through the KV-cache continuous-batching engine under
-        'gpt_decode'. Bank-only (never an emit line): a serving-side
-        per-user rate, not a training-headline convention — bank_best
-        guards it behind a 'decode'-containing prefix like the serving
-        and hostfeed rungs."""
-        cfg = {
-            "decode": True,
-            "streams": int(os.environ.get("BENCH_DECODE_STREAMS", "8")),
-            "max_len": int(os.environ.get("BENCH_DECODE_MAXLEN", "256")),
-            "max_new": int(os.environ.get("BENCH_DECODE_MAXNEW", "64")),
-            "prompt_len": int(os.environ.get("BENCH_DECODE_PROMPT", "32")),
-            "layers": int(os.environ.get("BENCH_DECODE_LAYERS", "12")),
-            "hidden": int(os.environ.get("BENCH_DECODE_HIDDEN", "768")),
-            "heads": int(os.environ.get("BENCH_DECODE_HEADS", "12")),
-            "vocab": int(os.environ.get("BENCH_DECODE_VOCAB", "50257")),
-            "flash": os.environ.get("BENCH_DECODE_FLASH", "0") == "1",
-        }
-        label = "decode-gpt-%ds-m%d" % (cfg["streams"], cfg["max_len"])
-        result, kind, err = _run_attempt(label, cfg, slot, hard_deadline)
-        if result is None:
-            note_fail(label, kind, err)
-            return False
-        bank_write("gpt_decode", _bank_entry(dict(result, **{
-            "metric": "gpt2_decode_throughput",
-            "value": round(result["tok_per_sec_user"], 2),
-            "unit": "tokens/sec/user",
-            "device": "tpu",
-            "decode": True,
-            "tok_per_sec": round(result["tok_per_sec"], 1),
-            "flash_attention": cfg["flash"],
-        })))
-        return True
-
-    def try_decode_prefix_tpu(slot):
-        """BENCH_DECODE=1 prefix rung: tokens/sec/user AND mean hit TTFT
-        through the prefix-cache + resume-prefill path at ~90% prefix
-        share, banked under 'gpt_decode_prefix'. Bank-only, and doubly
-        guarded: bank_best hides it from any prefix not containing
-        'prefix' (an amortized shared-prefix rate must never replace the
-        cold-prompt 'gpt_decode' headline)."""
-        cfg = {
-            "decode": True,
-            "prefix_cache": True,
-            "streams": int(os.environ.get("BENCH_DECODE_STREAMS", "8")),
-            "max_len": int(os.environ.get("BENCH_DECODE_MAXLEN", "256")),
-            "max_new": int(os.environ.get("BENCH_DECODE_MAXNEW", "64")),
-            "prompt_len": int(os.environ.get("BENCH_DECODE_PREFIX_PROMPT",
-                                             "128")),
-            "prefix_block": int(os.environ.get("BENCH_DECODE_PREFIX_BLOCK",
-                                               "16")),
-            "prefix_share": float(os.environ.get("BENCH_DECODE_PREFIX_SHARE",
-                                                 "0.9")),
-            "layers": int(os.environ.get("BENCH_DECODE_LAYERS", "12")),
-            "hidden": int(os.environ.get("BENCH_DECODE_HIDDEN", "768")),
-            "heads": int(os.environ.get("BENCH_DECODE_HEADS", "12")),
-            "vocab": int(os.environ.get("BENCH_DECODE_VOCAB", "50257")),
-            "flash": os.environ.get("BENCH_DECODE_FLASH", "0") == "1",
-        }
-        label = "decode-prefix-gpt-%ds-p%d" % (cfg["streams"],
-                                               cfg["prompt_len"])
-        result, kind, err = _run_attempt(label, cfg, slot, hard_deadline)
-        if result is None:
-            note_fail(label, kind, err)
-            return False
-        bank_write("gpt_decode_prefix", _bank_entry(dict(result, **{
-            "metric": "gpt2_decode_prefix_throughput",
-            "value": round(result["tok_per_sec_user"], 2),
-            "unit": "tokens/sec/user",
-            "device": "tpu",
-            "decode": True,
-            "prefix_cache": True,
-            "tok_per_sec": round(result["tok_per_sec"], 1),
-            "flash_attention": cfg["flash"],
-        })))
-        return True
-
-    def try_decode_paged_tpu(slot):
-        """BENCH_DECODE=1 paged rung: tokens/sec/user through the
-        block-table (paged KV) runtime at seq-4k max_len, with the pool
-        byte-budget ANCHORED to the cold-prompt rung's geometry
-        (streams x 256 contiguous rows) — the banked fact is that 16x
-        longer streams fit at unchanged pool bytes because a slot holds
-        ceil(len/block) blocks, not max_len rows. Banked under
-        'gpt_decode_paged'; bank_best hides it from any prefix not
-        containing 'paged'."""
-        cfg = {
-            "decode": True,
-            "streams": int(os.environ.get("BENCH_DECODE_STREAMS", "8")),
-            "max_len": int(os.environ.get("BENCH_DECODE_PAGED_MAXLEN",
-                                          "4096")),
-            "max_new": int(os.environ.get("BENCH_DECODE_MAXNEW", "64")),
-            "prompt_len": int(os.environ.get("BENCH_DECODE_PROMPT", "32")),
-            "paged_block": int(os.environ.get("BENCH_DECODE_PAGED_BLOCK",
-                                              "16")),
-            "pool_anchor_len": int(os.environ.get("BENCH_DECODE_MAXLEN",
-                                                  "256")),
-            "layers": int(os.environ.get("BENCH_DECODE_LAYERS", "12")),
-            "hidden": int(os.environ.get("BENCH_DECODE_HIDDEN", "768")),
-            "heads": int(os.environ.get("BENCH_DECODE_HEADS", "12")),
-            "vocab": int(os.environ.get("BENCH_DECODE_VOCAB", "50257")),
-            "flash": os.environ.get("BENCH_DECODE_FLASH", "0") == "1",
-        }
-        label = "decode-paged-gpt-%ds-m%d" % (cfg["streams"],
-                                              cfg["max_len"])
-        result, kind, err = _run_attempt(label, cfg, slot, hard_deadline)
-        if result is None:
-            note_fail(label, kind, err)
-            return False
-        bank_write("gpt_decode_paged", _bank_entry(dict(result, **{
-            "metric": "gpt2_decode_paged_throughput",
-            "value": round(result["tok_per_sec_user"], 2),
-            "unit": "tokens/sec/user",
-            "device": "tpu",
-            "decode": True,
-            "tok_per_sec": round(result["tok_per_sec"], 1),
-            "flash_attention": cfg["flash"],
-        })))
-        return True
-
-    def try_decode_spec_tpu(slot):
-        """BENCH_DECODE=1 speculative rung: tokens/sec/user with the
-        k-token draft/verify armed, vs the width-1 baseline the SAME
-        child measures first on identical paged geometry + workload.
-        The drafter replays the baseline's recorded continuations at a
-        controlled accuracy (default 0.9), so the banked speedup prices
-        the fused verify + rollback machinery at that acceptance rather
-        than n-gram drafter luck. Banked under 'gpt_decode_spec' with
-        the 'spec' guard flag ('paged' is dropped from the entry — the
-        spec guard alone isolates it; the rung is paged by
-        construction)."""
-        cfg = {
-            "decode": True,
-            "streams": int(os.environ.get("BENCH_DECODE_STREAMS", "8")),
-            "max_len": int(os.environ.get("BENCH_DECODE_MAXLEN", "256")),
-            "max_new": int(os.environ.get("BENCH_DECODE_MAXNEW", "64")),
-            "prompt_len": int(os.environ.get("BENCH_DECODE_PROMPT", "32")),
-            "paged_block": int(os.environ.get("BENCH_DECODE_PAGED_BLOCK",
-                                              "16")),
-            "spec_tokens": int(os.environ.get("BENCH_DECODE_SPEC_TOKENS",
-                                              "4")),
-            "draft_accuracy": float(os.environ.get(
-                "BENCH_DECODE_SPEC_ACCURACY", "0.9")),
-            "layers": int(os.environ.get("BENCH_DECODE_LAYERS", "12")),
-            "hidden": int(os.environ.get("BENCH_DECODE_HIDDEN", "768")),
-            "heads": int(os.environ.get("BENCH_DECODE_HEADS", "12")),
-            "vocab": int(os.environ.get("BENCH_DECODE_VOCAB", "50257")),
-            "flash": os.environ.get("BENCH_DECODE_FLASH", "0") == "1",
-        }
-        label = "decode-spec-gpt-%ds-k%d" % (cfg["streams"],
-                                             cfg["spec_tokens"])
-        result, kind, err = _run_attempt(label, cfg, slot, hard_deadline)
-        if result is None:
-            note_fail(label, kind, err)
-            return False
-        entry = _bank_entry(dict(result, **{
-            "metric": "gpt2_decode_spec_throughput",
-            "value": round(result["tok_per_sec_user"], 2),
-            "unit": "tokens/sec/user",
-            "device": "tpu",
-            "decode": True,
-            "tok_per_sec": round(result["tok_per_sec"], 1),
-            "flash_attention": cfg["flash"],
-        }))
-        entry.pop("paged", None)
-        bank_write("gpt_decode_spec", entry)
-        return True
-
-    def try_decode_tp_tpu(slot):
-        """BENCH_DECODE=1 tensor-parallel rung: tokens/sec/user with the
-        paged engine's programs GSPMD-sharded over a {"model": TP} mesh
-        (attention heads and KV pools partitioned, block tables
-        replicated) — the serving shape the SPMD mainline exists for.
-        Banked under 'gpt_decode_tp' with the 'tp' guard flag: a TP=2
-        rate spends 2 devices per user, so bank_best hides it from every
-        prefix not containing 'tp' (mirror of the paged/spec guards;
-        'paged' is dropped from the entry — the rung is paged by
-        construction and the tp guard alone isolates it)."""
-        cfg = {
-            "decode": True,
-            "tp": int(os.environ.get("BENCH_DECODE_TP", "2")),
-            "streams": int(os.environ.get("BENCH_DECODE_STREAMS", "8")),
-            "max_len": int(os.environ.get("BENCH_DECODE_MAXLEN", "256")),
-            "max_new": int(os.environ.get("BENCH_DECODE_MAXNEW", "64")),
-            "prompt_len": int(os.environ.get("BENCH_DECODE_PROMPT", "32")),
-            "paged_block": int(os.environ.get("BENCH_DECODE_PAGED_BLOCK",
-                                              "16")),
-            "layers": int(os.environ.get("BENCH_DECODE_LAYERS", "12")),
-            "hidden": int(os.environ.get("BENCH_DECODE_HIDDEN", "768")),
-            "heads": int(os.environ.get("BENCH_DECODE_HEADS", "12")),
-            "vocab": int(os.environ.get("BENCH_DECODE_VOCAB", "50257")),
-            "flash": os.environ.get("BENCH_DECODE_FLASH", "0") == "1",
-        }
-        label = "decode-tp-gpt-%ds-tp%d" % (cfg["streams"], cfg["tp"])
-        result, kind, err = _run_attempt(label, cfg, slot, hard_deadline)
-        if result is None:
-            note_fail(label, kind, err)
-            return False
-        entry = _bank_entry(dict(result, **{
-            "metric": "gpt2_decode_tp_throughput",
-            "value": round(result["tok_per_sec_user"], 2),
-            "unit": "tokens/sec/user",
-            "device": "tpu",
-            "decode": True,
-            "tok_per_sec": round(result["tok_per_sec"], 1),
-            "flash_attention": cfg["flash"],
-        }))
-        entry.pop("paged", None)
-        bank_write("gpt_decode_tp", entry)
-        return True
-
     # compile-slot budget per batch
     slot_for = {64: 260.0, 256: 240.0, 1024: 280.0}
 
@@ -1339,19 +849,6 @@ def parent_main():
     # ---- phase B2: opt-in serving rung (BENCH_SERVING=1; bank-only) ----
     if os.environ.get("BENCH_SERVING", "0") == "1":
         try_serving_tpu(300.0)
-
-    # ---- phase B3: opt-in decode rungs (BENCH_DECODE=1; bank-only):
-    # the cold-prompt headline, then the ~90%-prefix-share rung ----
-    if os.environ.get("BENCH_DECODE", "0") == "1":
-        try_decode_tpu(300.0)
-        try_decode_prefix_tpu(300.0)
-        # decode engine v2 rungs: the seq-4k block-table rate at the
-        # cold rung's pool byte budget, then speculative vs width-1
-        try_decode_paged_tpu(300.0)
-        try_decode_spec_tpu(340.0)
-        # SPMD mainline rung: the paged rate again, sharded over a
-        # {"model": TP} mesh
-        try_decode_tp_tpu(300.0)
 
     # ---- phase C: variants of what succeeded, while the window lasts:
     # remat at the best ResNet batch (a DIFFERENT HLO, so a full compile

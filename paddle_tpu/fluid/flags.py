@@ -82,33 +82,29 @@ _DEFAULTS = {
     "decode_max_len": 0,
     "decode_prefill_buckets": "",
     "decode_queue_depth": 64,
-    # prefix KV-cache reuse + chunked prefill: decode_prefix_cache_mb
-    # bounds the device-resident block store shared-prefix K/V is
-    # published to (0 = prefix caching off); decode_prefix_block is the
-    # reuse granularity in tokens (a prompt reuses its longest cached
-    # whole-block prefix, hash-chain keyed and token-verified);
-    # decode_prefill_chunk caps how many prompt tokens one engine tick
-    # may prefill (0 = monolithic prefill at admission) so a long
-    # prompt admits as bucket-shaped resume-prefill chunks interleaved
-    # with the fused decode steps instead of stalling live streams.
-    "decode_prefix_block": 64,
+    # the KV cache is ONE shared pool of decode_block_size-token blocks
+    # addressed through per-slot block tables: a slot's footprint is
+    # ceil(len/block) blocks, and the block is also the prefix reuse
+    # granularity (must be >= 1; 16 is what the gpt2s-serve-chat cell and
+    # chip_smoke.py run). decode_prefix_cache_mb bounds how many pool
+    # blocks the zero-copy prefix index may pin (0 = prefix caching off;
+    # a prompt reuses its longest cached whole-block prefix, hash-chain
+    # keyed and token-verified, by a table edit); decode_prefill_chunk
+    # caps how many prompt tokens one engine tick may prefill (0 = one
+    # window at admission) so a long prompt admits as bucket-shaped
+    # windows interleaved with the fused decode steps instead of
+    # stalling live streams.
+    "decode_block_size": 16,
     "decode_prefix_cache_mb": 0.0,
     "decode_prefill_chunk": 0,
-    # decode engine v2 — paged KV + speculative decoding:
-    # decode_block_size > 0 switches the engine to block-table
-    # addressing over ONE shared pool (slot footprint becomes
-    # ceil(len/block) blocks instead of a max_len row; prefix hits are
-    # zero-copy table edits; in paged mode it is ALSO the prefix reuse
-    # granularity, superseding decode_prefix_block). 0 keeps the legacy
-    # contiguous runtime. decode_spec_tokens = k > 1 arms speculative
-    # decoding on top of the paged runtime: a k-1-token draft per slot
-    # per tick, ONE batched verify program scoring all k positions, and
-    # host-side longest-matching-prefix acceptance that stays token-
-    # exact with sequential decoding (greedy and seeded-sampled).
-    # decode_spec_draft picks the drafter: "ngram" (self-draft from the
-    # stream's own history) or "repeat" (last-token run-length); a
-    # small-model drafter plugs in via DecodeEngine(drafter=...).
-    "decode_block_size": 0,
+    # decode_spec_tokens = k > 1 arms speculative decoding: a k-1-token
+    # draft per slot per tick, ONE batched verify program scoring all k
+    # positions, and host-side longest-matching-prefix acceptance that
+    # stays token-exact with sequential decoding (greedy and
+    # seeded-sampled). decode_spec_draft picks the drafter: "ngram"
+    # (self-draft from the stream's own history) or "repeat" (last-token
+    # run-length); a small-model drafter plugs in via
+    # DecodeEngine(drafter=...).
     "decode_spec_tokens": 0,
     "decode_spec_draft": "ngram",
     # SPMD mesh (paddle_tpu/parallel/spmd.py): spmd_decode_tp > 1 serves

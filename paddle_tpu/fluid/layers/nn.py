@@ -17,11 +17,7 @@ __all__ = [
     "nce",
     "cos_sim",
     "flash_attention",
-    "flash_decode_attention",
     "flash_decode_paged_attention",
-    "kv_cache_write",
-    "kv_cache_copy",
-    "kv_cache_gather",
     "kv_cache_write_paged",
     "kv_cache_gather_paged",
     "kv_cache_block_copy",
@@ -1379,96 +1375,6 @@ def flash_attention(q, k, v, key_bias=None, bias=None, causal=False,
         attrs={"causal": causal, "scale": float(scale),
                "dropout_rate": float(dropout_rate), "is_test": bool(is_test),
                "interpret": bool(interpret)},
-    )
-    return out
-
-
-def flash_decode_attention(q, k, v, key_bias=None, scale=0.0,
-                           interpret=False, name=None):
-    """Decode-mode single-query fused attention: ``q`` [N, heads, 1,
-    d_head] (one live token per KV-cache slot) against the fixed-shape
-    cache ``k``/``v`` [N, heads, max_len, d_head]. ``key_bias``
-    [N, max_len] additively masks cache positions at/beyond each slot's
-    live length (-1e4) — the only mask decode needs, since a slot's cache
-    never holds a future token. Forward-only (inference); Pallas kernel
-    on TPU, dense reference elsewhere; ``scale`` 0 means 1/sqrt(d_head)."""
-    helper = LayerHelper("flash_decode_attention", **locals())
-    out = helper.create_variable_for_type_inference(dtype=q.dtype)
-    inputs = {"Q": [q], "K": [k], "V": [v]}
-    if key_bias is not None:
-        inputs["KeyBias"] = [key_bias]
-    helper.append_op(
-        type="flash_decode_attention",
-        inputs=inputs,
-        outputs={"Out": [out]},
-        attrs={"scale": float(scale), "interpret": bool(interpret)},
-    )
-    return out
-
-
-def kv_cache_write(cache, new, pos, slot_mode=False, name=None):
-    """In-place-shaped KV-cache write: lands ``new`` into ``cache``
-    [slots, heads, max_len, d_head] by dynamic-update-slice — O(written
-    bytes), not O(cache) like a one-hot blend — and returns the SAME
-    cache variable carrying the updated value (the op's output aliases
-    its input var, so the executor persists the new buffer and, with
-    donation armed, XLA updates it in place).
-
-    ``slot_mode=False`` (decode): ``new`` [slots, heads, 1, d_head] is
-    one token per slot, ``pos`` [slots, ...] its per-slot cache
-    position. ``slot_mode=True`` (prefill): ``new`` [1, heads, T,
-    d_head] is one prompt's K/V, ``pos`` a scalar slot index — the row's
-    first T positions are replaced (stale tail stays masked until decode
-    overwrites it position by position). A 2-element ``pos``
-    (slot, offset) lands the block at ``offset`` within the row instead
-    of position 0 — resume-prefill's suffix-window write after a cached
-    prefix. Inference-only (no gradient)."""
-    helper = LayerHelper("kv_cache_write", **locals())
-    helper.append_op(
-        type="kv_cache_write",
-        inputs={"Cache": [cache], "New": [new], "Pos": [pos]},
-        outputs={"Out": [cache]},
-        attrs={"slot_mode": bool(slot_mode)},
-    )
-    return cache
-
-
-def kv_cache_copy(dst, src, dst_loc, src_loc, length, name=None):
-    """Block-granular transfer between two K/V pools: copies
-    ``src[src_loc[0], :, src_loc[1]:src_loc[1]+length, :]`` into
-    ``dst[dst_loc[0], :, dst_loc[1]:dst_loc[1]+length, :]`` by a
-    dynamic-slice → dynamic-update-slice pair — O(copied bytes), the
-    same cost discipline as ``kv_cache_write``. Both 2-element
-    (row, position) locations are runtime data, so ONE compiled program
-    moves any cached prefix block between the prefix store and a slot
-    row (either direction: pass the store as ``src`` to admit a hit,
-    as ``dst`` to publish a finished prefill). Returns ``dst`` — the
-    op's output aliases its input var, so the executor persists the
-    updated pool and, with donation armed, XLA copies in place.
-    Inference-only (no gradient)."""
-    helper = LayerHelper("kv_cache_copy", **locals())
-    helper.append_op(
-        type="kv_cache_copy",
-        inputs={"Dst": [dst], "Src": [src], "DstLoc": [dst_loc],
-                "SrcLoc": [src_loc]},
-        outputs={"Out": [dst]},
-        attrs={"length": int(length)},
-    )
-    return dst
-
-
-def kv_cache_gather(cache, slot_idx, name=None):
-    """One slot's [1, heads, max_len, d_head] row of a
-    [slots, heads, max_len, d_head] cache pool, selected by a fed index
-    (runtime data — every slot shares one compiled program). The read
-    half of resume-prefill: the suffix window's queries attend over the
-    full updated row. Inference-only (no gradient)."""
-    helper = LayerHelper("kv_cache_gather", **locals())
-    out = helper.create_variable_for_type_inference(dtype=cache.dtype)
-    helper.append_op(
-        type="kv_cache_gather",
-        inputs={"Cache": [cache], "Pos": [slot_idx]},
-        outputs={"Out": [out]},
     )
     return out
 

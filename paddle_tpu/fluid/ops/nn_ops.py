@@ -988,37 +988,6 @@ def _flash_attention(ctx, op_):
         ctx.set(oname + "@FLASH_SEED", seed)
 
 
-def _flash_decode_infer(op_, block):
-    q = in_var(op_, block, "Q")
-    set_out(op_, block, "Out", list(q.shape), q.dtype)
-
-
-@op("flash_decode_attention", infer_shape=_flash_decode_infer)
-def _flash_decode_attention(ctx, op_):
-    """Decode-mode single-query attention (kernels/flash_attention.py
-    flash_decode_attention): one live token per KV-cache slot against the
-    fixed-shape cache, per-slot length masking via KeyBias. Inference
-    only — no grad registered; the decode graph never differentiates."""
-    from ...kernels.flash_attention import flash_decode_attention
-
-    q = ctx.in1(op_, "Q")
-    k = ctx.in1(op_, "K")
-    v = ctx.in1(op_, "V")
-    kb_names = op_.inputs.get("KeyBias") or []
-    key_bias = ctx.in1(op_, "KeyBias") if kb_names else None
-    scale = op_.attr("scale", 0.0)
-    interpret = bool(op_.attr("interpret", False)) or None
-    B, N = q.shape[:2]
-    key_bias, kb_dims = _key_bias_dims(key_bias, B, N)
-    ctx.out(op_, "Out", _per_shard(
-        lambda q, k, v, kb: flash_decode_attention(
-            q, k, v, key_bias=kb,
-            scale=float(scale) if scale else None, interpret=interpret),
-        (q, k, v, key_bias), (2, 2, 2, kb_dims), ((2, 4),),
-        *_shard_axes(B, N, interpret),
-    ))
-
-
 def _flash_decode_paged_infer(op_, block):
     q = in_var(op_, block, "Q")
     set_out(op_, block, "Out", list(q.shape), q.dtype)
@@ -1060,99 +1029,6 @@ def _flash_decode_paged_attention(ctx, op_):
     ))
 
 
-def _kv_cache_write_infer(op_, block):
-    c = in_var(op_, block, "Cache")
-    set_out(op_, block, "Out", list(c.shape), c.dtype)
-
-
-@op("kv_cache_write", infer_shape=_kv_cache_write_infer)
-def _kv_cache_write(ctx, op_):
-    """KV-cache scatter via dynamic_update_slice: O(written bytes)
-    instead of the one-hot blend's O(cache) multiply-add passes — the
-    decode step is bandwidth-bound on exactly this traffic. Indices are
-    runtime DATA (never part of the compiled shape), so admission /
-    per-step writes reuse one executable. With the owning program's
-    mutable-donation opt-in the update happens in the cache's own
-    buffer. Inference-only — no gradient registered."""
-    import jax
-    import jax.numpy as jnp
-
-    cache = ctx.in1(op_, "Cache")
-    new = ctx.in1(op_, "New").astype(cache.dtype)
-    pos = ctx.in1(op_, "Pos")
-    z = jnp.int32(0)
-    if bool(op_.attr("slot_mode", False)):
-        # Pos is (slot,) or (slot, offset) — the 2-element form lands the
-        # block at a fed position WITHIN the slot's row (resume-prefill:
-        # a suffix window written after a cached prefix). The element
-        # count is part of the fed shape, so the branch is static.
-        p = pos.reshape(-1).astype(jnp.int32)
-        off = p[1] if p.shape[0] > 1 else z
-        out = jax.lax.dynamic_update_slice(cache, new, (p[0], z, off, z))
-    else:
-        p = pos.reshape(-1).astype(jnp.int32)  # [slots]
-
-        def one(c, n, p_):
-            return jax.lax.dynamic_update_slice(c, n, (z, p_, z))
-
-        out = jax.vmap(one)(cache, new, p)
-    ctx.out(op_, "Out", out)
-
-
-def _kv_cache_copy_infer(op_, block):
-    d = in_var(op_, block, "Dst")
-    set_out(op_, block, "Out", list(d.shape), d.dtype)
-
-
-@op("kv_cache_copy", infer_shape=_kv_cache_copy_infer)
-def _kv_cache_copy(ctx, op_):
-    """Block-granular K/V transfer between two cache pools (the prefix
-    store and a request's slot row): a ``length``-token block is sliced
-    out of ``Src`` at (src row, src position) and update-sliced into
-    ``Dst`` at (dst row, dst position) — slice-to-slice, O(copied
-    bytes), like ``kv_cache_write``. Every index is runtime DATA, so
-    one compiled program moves any block between any rows; only the
-    (static) block length is part of the shape. Inference-only — no
-    gradient registered."""
-    import jax
-    import jax.numpy as jnp
-
-    dst = ctx.in1(op_, "Dst")
-    src = ctx.in1(op_, "Src")
-    dl = ctx.in1(op_, "DstLoc").reshape(-1).astype(jnp.int32)
-    sl = ctx.in1(op_, "SrcLoc").reshape(-1).astype(jnp.int32)
-    length = int(op_.attr("length", 0))
-    z = jnp.int32(0)
-    heads, d_head = int(src.shape[1]), int(src.shape[3])
-    blk = jax.lax.dynamic_slice(
-        src, (sl[0], z, sl[1], z), (1, heads, length, d_head)
-    ).astype(dst.dtype)
-    ctx.out(op_, "Out",
-            jax.lax.dynamic_update_slice(dst, blk, (dl[0], z, dl[1], z)))
-
-
-def _kv_cache_gather_infer(op_, block):
-    c = in_var(op_, block, "Cache")
-    set_out(op_, block, "Out", [1] + list(c.shape)[1:], c.dtype)
-
-
-@op("kv_cache_gather", infer_shape=_kv_cache_gather_infer)
-def _kv_cache_gather(ctx, op_):
-    """Select ONE slot's [1, heads, max_len, d_head] cache row at a fed
-    index — the read half of resume-prefill: the window's queries attend
-    over the full updated row (cached prefix + just-written window).
-    The index is runtime data; O(row bytes). Inference-only."""
-    import jax
-    import jax.numpy as jnp
-
-    cache = ctx.in1(op_, "Cache")
-    p = ctx.in1(op_, "Pos").reshape(-1).astype(jnp.int32)
-    z = jnp.int32(0)
-    ctx.out(op_, "Out", jax.lax.dynamic_slice(
-        cache, (p[0], z, z, z), (1,) + tuple(cache.shape[1:])
-    ))
-
-
 def _kv_cache_write_paged_infer(op_, block):
     c = in_var(op_, block, "Cache")
     set_out(op_, block, "Out", list(c.shape), c.dtype)
@@ -1160,9 +1036,8 @@ def _kv_cache_write_paged_infer(op_, block):
 
 @op("kv_cache_write_paged", infer_shape=_kv_cache_write_paged_infer)
 def _kv_cache_write_paged(ctx, op_):
-    """Block-table KV scatter: the paged generalization of
-    ``kv_cache_write``. ``Cache`` is ONE shared [blocks, heads, block,
-    d_head] pool for every slot AND the prefix index; ``New`` carries
+    """Block-table KV scatter. ``Cache`` is ONE shared [blocks, heads,
+    block, d_head] pool for every slot AND the prefix index; ``New`` carries
     each slot's token window [slots, heads, T, d_head]; ``Tables``
     [slots, max_blocks] int32 maps a slot's logical block number to a
     physical pool block; ``Pos`` [slots] is each slot's logical start

@@ -375,103 +375,6 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, key_bias_ref, bias_ref, do_ref,
             dbias_ref[0] += dbias
 
 
-def _decode_kernel(q_ref, k_ref, v_ref, key_bias_ref, o_ref, *, scale,
-                   kv_len, block_q, block_k):
-    """One head per program: the decode-mode single-query path. The whole
-    (padded) query block is one [BQ, D] tile — autoregressive decode has
-    exactly one live query row per slot, padded up to the Mosaic minimum —
-    swept over the K/V cache blocks with the same online softmax as the
-    training kernel. No lse output (nothing differentiates through
-    decode), no dropout (is_test), no causal flag: the per-slot key bias
-    carries ALL masking (cache positions at or beyond the slot's length
-    ride in at -1e4), which is what makes one compiled program serve every
-    mix of slot lengths."""
-    q = q_ref[0]                              # [BQ, D], input dtype
-    m = jnp.full((block_q, 1), _NEG, jnp.float32)
-    l = jnp.zeros((block_q, 1), jnp.float32)
-    acc = jnp.zeros((block_q, q.shape[-1]), jnp.float32)
-    for kb in range(kv_len // block_k):
-        ks = slice(kb * block_k, (kb + 1) * block_k)
-        s = _scores(
-            q, k_ref[0, ks, :], scale, key_bias_ref[0, :, ks],
-            None, 0, kb * block_k, False, block_q, block_k,
-        )
-        m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
-        alpha = jnp.exp(m - m_new)
-        p = jnp.exp(s - m_new)
-        l = l * alpha + p.sum(axis=-1, keepdims=True)
-        acc = acc * alpha + jax.lax.dot_general(
-            p.astype(v_ref.dtype), v_ref[0, ks, :], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        m = m_new
-    o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
-
-
-def flash_decode_attention(q, k, v, key_bias=None, scale=None,
-                           interpret=None):
-    """Decode-mode attention: ONE query token per (batch-slot, head)
-    against a fixed-shape K/V cache.
-
-    q [B, N, 1, D]; k/v [B, N, S, D] (the cache, S = max cache length);
-    ``key_bias`` additive mask over cache positions, [B, S] / [B*N, S] or
-    broadcastable — the caller masks positions >= the slot's live length
-    with -1e4 (and that mask alone carries causality: a slot's cache
-    never holds a future token). Forward-only (no custom VJP — decode is
-    inference), fp32 accumulation.
-
-    Runs the Pallas kernel on TPU (or under ``interpret=True``), and a
-    dense jnp reference on other backends — same dispatch contract as
-    ``flash_attention``."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    B, N, Sq, D = q.shape
-    Sk = k.shape[2]
-    if Sq != 1:
-        raise ValueError(
-            "flash_decode_attention is the single-query path, got Sq=%d"
-            % Sq
-        )
-    scale = scale if scale is not None else 1.0 / float(np.sqrt(D))
-    kb = _normalize_key_bias(key_bias, B, N, Sk)
-    on_tpu = lowers_for_tpu()
-    if interpret is None and not on_tpu:
-        # dense fallback: bit-compatible math with reference_attention
-        s = jnp.einsum("bnqd,bnkd->bnqk", q, k).astype(jnp.float32) * scale
-        if kb is not None:
-            s = s + kb.reshape(B, N, 1, Sk)
-        p = jax.nn.softmax(s, axis=-1)
-        return jnp.einsum("bnqk,bnkd->bnqd", p.astype(q.dtype), v)
-    if kb is None:
-        kb = jnp.zeros((B * N, Sk), jnp.float32)
-    qf, kf, vf, kbp, _bf, _g, geom = _prep(q, k, v, kb, None)
-    _B, _N, _Sq, _Sk, Sqp, Skp, _bq, bk = geom
-    kernel = functools.partial(
-        _decode_kernel, scale=scale, kv_len=Skp, block_q=Sqp, block_k=bk,
-    )
-    out = pl.pallas_call(
-        kernel,
-        name="flash_decode",
-        out_shape=jax.ShapeDtypeStruct((B * N, Sqp, D), q.dtype),
-        grid=(B * N,),
-        in_specs=[
-            pl.BlockSpec((1, Sqp, D), lambda h: (h, 0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, Skp, D), lambda h: (h, 0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, Skp, D), lambda h: (h, 0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 1, Skp), lambda h: (h, 0, 0),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_specs=pl.BlockSpec((1, Sqp, D), lambda h: (h, 0, 0),
-                               memory_space=pltpu.VMEM),
-        interpret=bool(interpret),
-    )(qf, kf, vf, kbp[:, None, :])
-    return out[:, :1, :].reshape(B, N, 1, D)
-
-
 # keys a paged-decode program takes at least: one lane tile of scores, so
 # the online softmax touches its scratch once per PAGED_KEYS keys and a
 # program's DMAs are large enough to be worth their descriptors
@@ -516,9 +419,10 @@ def _decode_paged_kernel(held_ref, lengths_ref, q_ref, *refs, scale,
     a block the operand already holds) and not computed — a program with
     no live block does nothing, and inside the last live program the dead
     columns are masked to ``_NEG`` before the key bias could matter.
-    Inside the live blocks the masking contract is ``_decode_kernel``'s:
-    the per-slot key bias carries ALL masking, the last block's unfilled
-    tail included. Online softmax state (m, l, acc per head) lives in
+    Inside the live blocks the per-slot key bias carries ALL masking, the
+    last block's unfilled tail included (positions at or beyond the
+    slot's length ride in at -1e4; no causal flag, no dropout, no lse:
+    nothing differentiates through decode). Online softmax state (m, l, acc per head) lives in
     VMEM scratch across a slot's programs and is touched once per
     program; the output block is written on the slot's last program. The
     bias rides as the slot's whole [programs, pages*block] table (a block
@@ -593,8 +497,8 @@ def flash_decode_paged_attention(q, k_pool, v_pool, tables, key_bias=None,
     and every mix of lengths. The tiling comes from the shapes: all N
     heads of a slot and ``PAGED_KEYS`` keys (or the whole table, where it
     is shorter) a program. Forward-only; dense gather-then-softmax
-    fallback off TPU — bit-compatible with gathering the logical rows
-    and calling ``flash_decode_attention``."""
+    fallback off TPU — bit-compatible math with ``reference_attention``
+    over the gathered logical rows."""
     from jax.experimental import pallas as pl  # noqa: F401 (dispatch)
     from jax.experimental.pallas import tpu as pltpu
 
@@ -619,9 +523,9 @@ def flash_decode_paged_attention(q, k_pool, v_pool, tables, key_bias=None,
     if lengths is not None:
         lengths = jnp.clip(lengths.astype(jnp.int32).reshape(B), 1, S)
     if interpret is None and not on_tpu:
-        # dense fallback: gather the logical rows, then the same math as
-        # flash_decode_attention's reference path. With lengths, a dead
-        # entry reads the slot's last live block instead and is masked.
+        # dense fallback: gather the logical rows, then dense softmax
+        # attention. With lengths, a dead entry reads the slot's last
+        # live block instead and is masked.
         dead = None
         if lengths is not None:
             tables = _held_blocks(tables, lengths, blk, MB)

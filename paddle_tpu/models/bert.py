@@ -120,37 +120,15 @@ def flash_wanted(cfg, seq_len=None):
 
 
 def _apply_kv_cache(cache, k, v, cfg):
-    """Write this call's split-head K/V into the cache described by
-    ``cache`` (see ``multi_head_attention``) via the ``kv_cache_write``
-    dynamic-update-slice op — O(written bytes), with the write position /
-    slot index as runtime DATA, so one compiled program covers every
-    admission pattern. Prefill lands the prompt's [1, heads, T, d] K/V
-    at the head of slot ``slot_idx``'s row (the stale tail beyond T
-    stays key-bias-masked until decode overwrites it position by
-    position); decode lands one token per slot at its ``pos``. Returns
-    (k, v) for the attention that follows: the LOCAL prompt K/V for
-    prefill (attention runs within the prompt), the full UPDATED cache
-    for decode (the query attends to everything written so far)."""
-    if cache["mode"] == "prefill":
-        fluid.layers.kv_cache_write(cache["k"], k, cache["slot_idx"],
-                                    slot_mode=True)
-        fluid.layers.kv_cache_write(cache["v"], v, cache["slot_idx"],
-                                    slot_mode=True)
-        return k, v
-    if cache["mode"] == "resume":
-        # resume-prefill: the window's K/V lands at the fed
-        # (slot, offset) — AFTER a cached prefix already copied into the
-        # row head — and attention needs the full updated row (prefix +
-        # window), so gather the slot back out. Both indices are runtime
-        # data: one compiled program per bucket covers every offset.
-        k_upd = fluid.layers.kv_cache_write(cache["k"], k,
-                                            cache["slot_off"],
-                                            slot_mode=True)
-        v_upd = fluid.layers.kv_cache_write(cache["v"], v,
-                                            cache["slot_off"],
-                                            slot_mode=True)
-        return (fluid.layers.kv_cache_gather(k_upd, cache["slot_off"]),
-                fluid.layers.kv_cache_gather(v_upd, cache["slot_off"]))
+    """Write this call's split-head K/V into the paged pool described by
+    ``cache`` (see ``multi_head_attention``) via ``kv_cache_write_paged``
+    — O(written bytes), with the block table and the write position as
+    runtime DATA, so one compiled program covers every admission
+    pattern. Returns (k, v) for the attention that follows."""
+    k_upd = fluid.layers.kv_cache_write_paged(
+        cache["k"], k, cache["tables"], cache["pos"])
+    v_upd = fluid.layers.kv_cache_write_paged(
+        cache["v"], v, cache["tables"], cache["pos"])
     if cache["mode"] == "paged_window":
         # batch-1 window through the slot's block TABLE: the window's
         # K/V lands at logical positions pos..pos+T-1, scattered into
@@ -160,25 +138,13 @@ def _apply_kv_cache(cache, k, v, cfg):
         # the window's queries. Covers monolithic prefill (pos 0) and
         # chunked resume alike: offset, table, and positions are all
         # runtime data, so ONE program per bucket serves both.
-        k_upd = fluid.layers.kv_cache_write_paged(
-            cache["k"], k, cache["tables"], cache["pos"])
-        v_upd = fluid.layers.kv_cache_write_paged(
-            cache["v"], v, cache["tables"], cache["pos"])
         return (fluid.layers.kv_cache_gather_paged(k_upd, cache["tables"]),
                 fluid.layers.kv_cache_gather_paged(v_upd, cache["tables"]))
-    if cache["mode"] == "paged_step":
-        # fused multi-slot step (T=1 decode / T=k speculative verify):
-        # each slot's T-token window scatters through its table row;
-        # the attention branch reads the pool back through the tables
-        # (paged flash kernel or gather+dense), so just return the
-        # updated pool vars.
-        k_upd = fluid.layers.kv_cache_write_paged(
-            cache["k"], k, cache["tables"], cache["pos"])
-        v_upd = fluid.layers.kv_cache_write_paged(
-            cache["v"], v, cache["tables"], cache["pos"])
-        return k_upd, v_upd
-    k_upd = fluid.layers.kv_cache_write(cache["k"], k, cache["pos"])
-    v_upd = fluid.layers.kv_cache_write(cache["v"], v, cache["pos"])
+    # paged_step, the fused multi-slot step (T=1 decode / T=k speculative
+    # verify): each slot's T-token window scatters through its table
+    # row; the attention branch reads the pool back through the tables
+    # (paged flash kernel or gather+dense), so just return the updated
+    # pool vars.
     return k_upd, v_upd
 
 
@@ -200,23 +166,19 @@ def multi_head_attention(q_in, kv_in, attn_bias, cfg, name, key_bias=None,
 
     ``cache``: KV-cache plumbing for autoregressive serving (None for
     training/encoder use). A dict with ``k``/``v`` — persistable
-    [slots, heads, max_len, d_head] cache vars — plus ``mode``:
+    [blocks, heads, block, d_head] pool vars — the fed block ``tables``,
+    the write position ``pos``, plus ``mode``:
 
-    - ``"prefill"``: attention runs the NORMAL path over the prompt
-      (causal + padding masks as usual) and, as a side effect, writes the
-      prompt's K/V into the cache slot indexed by the fed scalar
-      ``slot_idx``;
-    - ``"decode"``: the single-query step. Each slot's new-token K/V
-      lands at its fed ``pos`` [slots] cache position (inactive slots
-      write wherever the engine aims them — a dead row tolerates any
-      spot; a mid-chunked-prefill row gets its next window start, which
-      the window rewrites), then the length-1 query attends over the updated
-      cache under ``key_bias`` [slots, max_len] (additive, -1e4 beyond
-      each slot's live length) — via the decode-mode flash kernel when
-      ``use_flash``, dense single-query attention otherwise.
-      ``attn_bias``/``causal`` are ignored: the per-slot key mask IS the
-      causal mask, since a slot's cache never holds an unmasked future
-      token."""
+    - ``"paged_window"``: one prompt window (batch 1) lands through its
+      table at ``pos`` and attends dense over the gathered logical row
+      under the fed ``resume_bias`` [T, max_blocks*block];
+    - ``"paged_step"``: the fused step. Each slot's T-token window lands
+      through its table row at its ``pos`` [slots] (inactive slots feed
+      an all-sink table), then attends over the slot's logical row under
+      ``step_bias`` [slots, T, max_blocks*block] — via the table-chasing
+      flash kernel when ``use_flash`` and T = 1, gather + dense otherwise.
+      ``attn_bias``/``causal`` are ignored: the fed bias IS the causal
+      mask, since a slot's row never holds an unmasked future token."""
     d_head = cfg.hidden_size // cfg.num_heads
 
     def _proj(x, suffix):
@@ -280,9 +242,9 @@ def multi_head_attention(q_in, kv_in, attn_bias, cfg, name, key_bias=None,
             input=ctxt, size=cfg.hidden_size, num_flatten_dims=2,
             name="%s_out" % name,
         )
-    if cache is not None and cache["mode"] in ("resume", "paged_window"):
-        # resume-prefill: window queries [1, heads, T, d] against the
-        # slot's full updated row [1, heads, max_len, d] under the FED
+    if cache is not None:
+        # prefill window: queries [1, heads, T, d] against the slot's
+        # full updated row [1, heads, max_len, d] under the FED
         # [T, max_len] additive bias (0 on cache position j <= offset+i
         # for window query i, -1e4 beyond) — the causal mask shifted by
         # the runtime offset, which must stay out of the compiled shape.
@@ -297,31 +259,6 @@ def multi_head_attention(q_in, kv_in, attn_bias, cfg, name, key_bias=None,
             fluid.layers.elementwise_add(scores, bias4), axis=-1
         )
         ctxt = fluid.layers.matmul(weights, v)
-        ctxt = fluid.layers.transpose(ctxt, perm=[0, 2, 1, 3])
-        ctxt = fluid.layers.reshape(ctxt, shape=[0, 0, cfg.hidden_size])
-        return fluid.layers.fc(
-            input=ctxt, size=cfg.hidden_size, num_flatten_dims=2,
-            name="%s_out" % name,
-        )
-    if cache is not None and cache["mode"] == "decode":
-        scale_ = 1.0 / math.sqrt(d_head)
-        if use_flash:
-            ctxt = fluid.layers.flash_decode_attention(
-                q, k, v, key_bias=cache["key_bias"], scale=scale_,
-                interpret=getattr(cfg, "flash_interpret", False),
-            )
-        else:
-            scores = fluid.layers.matmul(
-                q, k, transpose_y=True, alpha=scale_
-            )
-            bias4 = fluid.layers.reshape(
-                cache["key_bias"], shape=[0, 1, 1, -1]
-            )
-            bias4.stop_gradient = True
-            weights = fluid.layers.softmax(
-                fluid.layers.elementwise_add(scores, bias4), axis=-1
-            )
-            ctxt = fluid.layers.matmul(weights, v)
         ctxt = fluid.layers.transpose(ctxt, perm=[0, 2, 1, 3])
         ctxt = fluid.layers.reshape(ctxt, shape=[0, 0, cfg.hidden_size])
         return fluid.layers.fc(

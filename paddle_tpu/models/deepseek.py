@@ -104,9 +104,6 @@ def cache_kinds(cfg):
 # modes of ``serving/decode.py`` that are not built for a latent cache;
 # the engine raises NotImplementedError naming the mode
 UNSUPPORTED = {
-    "contiguous": "contiguous (non-paged) decode: set block_size > 0",
-    "prefix_store": "the contiguous prefix store (prefix_blocks); the paged "
-                    "prefix index works",
     "spec_tokens": "speculative step widths > 1",
     "tp": "tensor-parallel serving (tp > 1)",
     "kv_host_tier": "the host KV tier (kv_tier_host_mb)",

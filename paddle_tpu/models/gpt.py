@@ -66,9 +66,8 @@ def gpt_decoder(ids, pos_ids, input_mask, cfg, kv_cache=None):
 
     ``kv_cache`` (None for training/full-forward inference) threads the
     decode runtime's cache plumbing through every layer's attention —
-    see ``build_gpt_prefill`` / ``build_gpt_decode_step``. In ``decode``
-    mode ``input_mask`` is unused (the per-slot cache key bias carries
-    all masking) and T is 1."""
+    see ``build_gpt_paged_window`` / ``build_gpt_paged_step``. With a
+    cache ``input_mask`` is unused: the fed bias carries all masking."""
     emb = fluid.layers.embedding(
         input=ids, size=[cfg.vocab_size, cfg.hidden_size],
         param_attr=fluid.ParamAttr(name="tok_embedding"),
@@ -83,25 +82,19 @@ def gpt_decoder(ids, pos_ids, input_mask, cfg, kv_cache=None):
     key_bias = None
     attn_bias = None
     mode = kv_cache["mode"] if kv_cache is not None else None
-    if mode in ("resume", "paged_window"):
-        # resume-prefill window: masking lives entirely in the fed
-        # [T, max_len] resume bias (offset-shifted causal + prefix),
-        # and attention is dense window×row by design — see
-        # multi_head_attention's resume branch. The paged variant is
-        # the same regime with the row read through the block table.
+    if mode == "paged_window":
+        # prefill window: masking lives entirely in the fed
+        # [T, max_blocks*block] resume bias (offset-shifted causal +
+        # prefix), and attention is dense window×row by design — see
+        # multi_head_attention's window branch
         use_flash = False
     elif mode == "paged_step":
         # fused paged step/verify: masking lives in the fed per-slot
         # step bias; flash (the table-chasing decode kernel) engages
         # only on the T=1 single-query form — the T=k verify is the
-        # window×row dense regime like resume
-        use_flash = _bert.flash_wanted(
-            cfg, seq_len=int(kv_cache["max_len"])
-        )
-    elif mode == "decode":
-        # single-query step: masking lives entirely in the fed per-slot
-        # cache key bias; the flash policy keys on the CACHE length (the
-        # kv extent the kernel actually sweeps), not the length-1 query
+        # window×row dense regime like a prefill window. The flash
+        # policy keys on the CACHE length (the kv extent the kernel
+        # actually sweeps), not the length-1 query
         use_flash = _bert.flash_wanted(
             cfg, seq_len=int(kv_cache["max_len"])
         )
@@ -136,23 +129,13 @@ def gpt_decoder(ids, pos_ids, input_mask, cfg, kv_cache=None):
         cache_i = None
         if kv_cache is not None:
             k_var, v_var = kv_cache["caches"][i]
-            cache_i = {"k": k_var, "v": v_var, "mode": mode}
-            if mode == "prefill":
-                cache_i["slot_idx"] = kv_cache["slot_idx"]
-            elif mode == "resume":
-                cache_i["slot_off"] = kv_cache["slot_off"]
+            cache_i = {"k": k_var, "v": v_var, "mode": mode,
+                       "tables": kv_cache["tables"],
+                       "pos": kv_cache["pos"]}
+            if mode == "paged_window":
                 cache_i["resume_bias"] = kv_cache["resume_bias"]
-            elif mode == "paged_window":
-                cache_i["tables"] = kv_cache["tables"]
-                cache_i["pos"] = kv_cache["pos"]
-                cache_i["resume_bias"] = kv_cache["resume_bias"]
-            elif mode == "paged_step":
-                cache_i["tables"] = kv_cache["tables"]
-                cache_i["pos"] = kv_cache["pos"]
-                cache_i["step_bias"] = kv_cache["step_bias"]
             else:
-                cache_i["pos"] = kv_cache["pos"]
-                cache_i["key_bias"] = kv_cache["key_bias"]
+                cache_i["step_bias"] = kv_cache["step_bias"]
         attn = _bert.multi_head_attention(
             h, h, attn_bias, cfg, name + "_att", key_bias=key_bias,
             causal=True, use_flash=use_flash, cache=cache_i,
@@ -240,283 +223,17 @@ def build_gpt_infer(cfg, seq_len):
 
 
 # ---------------------------------------------------------------------------
-# autoregressive decode runtime graphs (KV-cache prefill / single-step decode)
+# autoregressive decode runtime graphs: a paged KV pool (block-table
+# addressing: ONE shared pool for live slots AND the prefix cache; a slot's
+# row is whatever its fed table maps to), prefill windows and the fused step
 # ---------------------------------------------------------------------------
-
-
-def decode_cache_names(cfg, slots, max_len):
-    """Per-layer (K, V) cache var names — one fixed contract shared by
-    the prefill and decode programs (and the host-side cache init). The
-    pool geometry is part of the name: two sessions sharing one scope
-    (e.g. a 1-slot greedy_generate session next to an 8-slot serving
-    engine) must never read each other's differently-shaped buffers."""
-    return [
-        ("gpt_cache_k_%d_p%dx%d" % (i, slots, max_len),
-         "gpt_cache_v_%d_p%dx%d" % (i, slots, max_len))
-        for i in range(cfg.num_layers)
-    ]
-
-
-def decode_cache_shape(cfg, slots, max_len):
-    return [
-        int(slots), cfg.num_heads, int(max_len),
-        cfg.hidden_size // cfg.num_heads,
-    ]
-
-
-def _declare_cache_vars(cfg, slots, max_len):
-    """Declare the per-layer persistable cache vars in the CURRENT main
-    program. No initializer: the host seeds them with zeros directly in
-    the scope (running a startup here would also re-init the shared
-    model params)."""
-    block = fluid.default_main_program().global_block()
-    shape = decode_cache_shape(cfg, slots, max_len)
-    return [
-        tuple(
-            block.create_var(
-                name=n, shape=shape, dtype="float32", persistable=True
-            )
-            for n in names
-        )
-        for names in decode_cache_names(cfg, slots, max_len)
-    ]
-
-
-def build_gpt_prefill(cfg, slots, seq_len, max_len):
-    """Prefill graph: ONE prompt (batch 1, padded to the ``seq_len``
-    bucket) runs the normal causal forward and, per layer, writes its
-    K/V into the cache slot indexed by the fed scalar ``slot_idx``
-    (dynamic-update-slice — the index is runtime data, so every slot
-    shares this one compiled program). ``last_onehot`` [1, seq_len, 1]
-    selects the last real prompt position's logits in-graph, so the
-    fetch is [1, vocab] — not [seq_len, vocab].
-
-    Returns (main, startup, feed names, next_logits). The startup is a
-    byproduct (param initializers) and is NOT meant to be run by the
-    decode runtime — params come from the scope it attaches to."""
-    import copy
-
-    cfg = copy.copy(cfg)
-    cfg.is_test = True
-    main, startup = fluid.Program(), fluid.Program()
-    # the cache vars are this program's only mutable state and the
-    # session owns them outright: donate, so XLA writes the slot row in
-    # the cache's own buffer instead of copying the pool per prefill
-    main._donate_mutable = True
-    with fluid.program_guard(main, startup):
-        ids = fluid.layers.data(name="ids", shape=[seq_len, 1],
-                                dtype="int64")
-        pos_ids = fluid.layers.data(name="pos_ids", shape=[seq_len, 1],
-                                    dtype="int64")
-        input_mask = fluid.layers.data(
-            name="input_mask", shape=[seq_len, 1], dtype="float32"
-        )
-        slot_idx = fluid.layers.data(name="slot_idx", shape=[1],
-                                     dtype="int64")
-        last_onehot = fluid.layers.data(
-            name="last_onehot", shape=[seq_len, 1], dtype="float32"
-        )
-        kv_cache = {
-            "mode": "prefill",
-            "caches": _declare_cache_vars(cfg, slots, max_len),
-            "slot_idx": slot_idx,
-            "max_len": max_len,
-        }
-        logits = gpt_lm_logits(ids, pos_ids, input_mask, cfg,
-                               kv_cache=kv_cache)
-        next_logits = fluid.layers.reduce_sum(
-            fluid.layers.elementwise_mul(logits, last_onehot), dim=1
-        )
-    feeds = ["ids", "pos_ids", "input_mask", "slot_idx", "last_onehot"]
-    return main, startup, feeds, next_logits
-
-
-def build_gpt_resume_prefill(cfg, slots, seq_len, max_len):
-    """Resume-prefill graph: ONE prompt *window* (batch 1, padded to the
-    ``seq_len`` bucket) prefills starting at a FED cache position — the
-    program-shape family behind prefix-cache hits and chunked prefill.
-    Per layer the window's K/V is written at (slot, offset) — both
-    runtime data via ``slot_off`` [2], so the whole bucket ladder keeps
-    compiling exactly once regardless of where windows land — and the
-    window's queries attend DENSE over the slot's full updated row
-    (cached prefix + window) under the fed ``resume_bias``
-    [seq_len, max_len]: 0 where cache position j <= offset + i for
-    window query i, -1e4 beyond. That bias IS the causal mask shifted
-    by the runtime offset; feeding it keeps the offset out of the
-    compiled shape. ``last_onehot`` selects the last real window
-    token's logits (meaningful on a prompt's FINAL window; earlier
-    chunks ignore the fetch).
-
-    Returns (main, startup, feed names, next_logits [1, vocab])."""
-    import copy
-
-    cfg = copy.copy(cfg)
-    cfg.is_test = True
-    main, startup = fluid.Program(), fluid.Program()
-    # donate: the window write updates the slot row in the cache's own
-    # buffer, like the prefill/decode programs
-    main._donate_mutable = True
-    with fluid.program_guard(main, startup):
-        ids = fluid.layers.data(name="ids", shape=[seq_len, 1],
-                                dtype="int64")
-        pos_ids = fluid.layers.data(name="pos_ids", shape=[seq_len, 1],
-                                    dtype="int64")
-        slot_off = fluid.layers.data(name="slot_off", shape=[2],
-                                     dtype="int64")
-        resume_bias = fluid.layers.data(
-            name="resume_bias", shape=[seq_len, max_len], dtype="float32"
-        )
-        last_onehot = fluid.layers.data(
-            name="last_onehot", shape=[seq_len, 1], dtype="float32"
-        )
-        kv_cache = {
-            "mode": "resume",
-            "caches": _declare_cache_vars(cfg, slots, max_len),
-            "slot_off": slot_off,
-            "resume_bias": resume_bias,
-            "max_len": max_len,
-        }
-        logits = gpt_lm_logits(ids, pos_ids, None, cfg, kv_cache=kv_cache)
-        next_logits = fluid.layers.reduce_sum(
-            fluid.layers.elementwise_mul(logits, last_onehot), dim=1
-        )
-    feeds = ["ids", "pos_ids", "slot_off", "resume_bias", "last_onehot"]
-    return main, startup, feeds, next_logits
-
-
-# -- prefix K/V store (device-resident block pool for prefix-cache reuse) ----
-
-
-def prefix_store_names(cfg, blocks, block):
-    """Per-layer (K, V) prefix-store var names. Pool geometry is part of
-    the name for the same reason as ``decode_cache_names``: two stores
-    of different shapes sharing one scope must never alias."""
-    return [
-        ("gpt_prefix_k_%d_n%dx%d" % (i, blocks, block),
-         "gpt_prefix_v_%d_n%dx%d" % (i, blocks, block))
-        for i in range(cfg.num_layers)
-    ]
-
-
-def prefix_store_shape(cfg, blocks, block):
-    return [
-        int(blocks), cfg.num_heads, int(block),
-        cfg.hidden_size // cfg.num_heads,
-    ]
-
-
-def prefix_block_bytes(cfg, block):
-    """Device bytes one cached prefix block costs across all layers
-    (K + V, fp32) — what ``FLAGS_decode_prefix_cache_mb`` divides by."""
-    d_head = cfg.hidden_size // cfg.num_heads
-    return cfg.num_layers * 2 * cfg.num_heads * int(block) * d_head * 4
-
-
-def _declare_prefix_store_vars(cfg, blocks, block):
-    main_block = fluid.default_main_program().global_block()
-    shape = prefix_store_shape(cfg, blocks, block)
-    return [
-        tuple(
-            main_block.create_var(
-                name=n, shape=shape, dtype="float32", persistable=True
-            )
-            for n in names
-        )
-        for names in prefix_store_names(cfg, blocks, block)
-    ]
-
-
-def build_gpt_prefix_copy(cfg, slots, max_len, blocks, block,
-                          publish=False):
-    """ONE compiled block move between the prefix store and the slot
-    cache, across every layer's K and V: ``publish=False`` copies store
-    block ``src_loc`` into the slot row at ``dst_loc`` (admitting a
-    hit), ``publish=True`` copies a slot-row block into the store
-    (publishing a finished prefill). Both 2-element (row, position)
-    locations are fed int64 — runtime data, so a prompt's whole cached
-    prefix is n runs of this one program, O(copied bytes) each, and the
-    strict-compile gate never sees block placement.
-
-    Returns (main, startup, feed names, ok) — ``ok`` is a dummy scalar
-    fetch; the real outputs are the persistable pools themselves."""
-    main, startup = fluid.Program(), fluid.Program()
-    main._donate_mutable = True
-    with fluid.program_guard(main, startup):
-        dst_loc = fluid.layers.data(name="dst_loc", shape=[2],
-                                    dtype="int64")
-        src_loc = fluid.layers.data(name="src_loc", shape=[2],
-                                    dtype="int64")
-        caches = _declare_cache_vars(cfg, slots, max_len)
-        stores = _declare_prefix_store_vars(cfg, blocks, block)
-        for (ck, cv), (sk, sv) in zip(caches, stores):
-            if publish:
-                fluid.layers.kv_cache_copy(sk, ck, dst_loc, src_loc, block)
-                fluid.layers.kv_cache_copy(sv, cv, dst_loc, src_loc, block)
-            else:
-                fluid.layers.kv_cache_copy(ck, sk, dst_loc, src_loc, block)
-                fluid.layers.kv_cache_copy(cv, sv, dst_loc, src_loc, block)
-        ok = fluid.layers.fill_constant(shape=[1], dtype="int32", value=1)
-    return main, startup, ["dst_loc", "src_loc"], ok
-
-
-def build_gpt_decode_step(cfg, slots, max_len):
-    """Single-step decode graph: one new token per slot (query length 1)
-    against the per-layer KV caches. Feeds — all fixed-shape, so ONE
-    compiled program serves every mix of slot lengths / admissions /
-    retirements:
-
-    - ``step_ids`` / ``step_pos`` [slots, 1, 1] int64: each slot's newest
-      token and its cache position, which is also where its K/V is
-      scatter-written (inactive slots feed a zero token at a CALLER-
-      CHOSEN position — a free slot's dead row tolerates any landing
-      spot, but a mid-chunked-prefill row is live and the engine aims
-      the masked write at its next window start);
-    - ``key_bias`` [slots, max_len]: additive mask, 0 on live cache
-      positions (<= the slot's current position), -1e4 beyond — the only
-      mask decode needs, and the causal mask by construction.
-
-    Returns (main, startup, feed names, step_logits [slots, vocab])."""
-    import copy
-
-    cfg = copy.copy(cfg)
-    cfg.is_test = True
-    main, startup = fluid.Program(), fluid.Program()
-    # donate the caches: the per-token step updates them in place
-    # instead of copying the whole pool every token (decode is
-    # bandwidth-bound on exactly this traffic)
-    main._donate_mutable = True
-    with fluid.program_guard(main, startup):
-        step_ids = fluid.layers.data(name="step_ids", shape=[1, 1],
-                                     dtype="int64")
-        step_pos = fluid.layers.data(name="step_pos", shape=[1, 1],
-                                     dtype="int64")
-        key_bias = fluid.layers.data(
-            name="key_bias", shape=[max_len], dtype="float32"
-        )
-        kv_cache = {
-            "mode": "decode",
-            "caches": _declare_cache_vars(cfg, slots, max_len),
-            "pos": step_pos,
-            "key_bias": key_bias,
-            "max_len": max_len,
-        }
-        logits = gpt_lm_logits(step_ids, step_pos, None, cfg,
-                               kv_cache=kv_cache)
-        step_logits = fluid.layers.reshape(
-            logits, shape=[-1, cfg.vocab_size]
-        )
-    feeds = ["step_ids", "step_pos", "key_bias"]
-    return main, startup, feeds, step_logits
-
-
-# -- paged KV pool (block-table addressing: ONE shared pool for live slots
-# -- AND the prefix cache; a slot's row is whatever its fed table maps to) ---
 
 
 def paged_pool_names(cfg, blocks, block):
     """Per-layer (K, V) paged-pool var names. Pool geometry is part of
-    the name for the same reason as ``decode_cache_names``: two pools of
-    different shapes sharing one scope must never alias."""
+    the name: two sessions sharing one scope (a 1-slot greedy_generate
+    session next to a serving engine) must never read each other's
+    differently-shaped pools."""
     return [
         ("gpt_paged_k_%d_n%dx%d" % (i, blocks, block),
          "gpt_paged_v_%d_n%dx%d" % (i, blocks, block))
@@ -556,8 +273,8 @@ def _declare_paged_pool_vars(cfg, blocks, block):
 def build_gpt_paged_window(cfg, blocks, block, max_blocks, seq_len):
     """Paged prefill-window graph: ONE prompt window (batch 1, padded to
     the ``seq_len`` bucket) lands THROUGH the slot's fed block table —
-    the paged runtime's only prefill form (a monolithic prefill is a
-    window at position 0). Per layer the window's K/V scatters into the
+    the runtime's only prefill form (a whole prompt is a window at
+    position 0). Per layer the window's K/V scatters into the
     pool blocks its ``table`` [max_blocks] maps logical positions
     ``window_pos .. window_pos+T-1`` to, then the window's queries
     attend dense over the gathered logical row under the fed
@@ -686,7 +403,7 @@ def build_gpt_paged_block_copy(cfg, blocks, block, npairs):
 
 
 def cache_kinds(cfg):
-    """Per layer, the pools the paged runtime keeps (``cache_kinds.py``):
+    """Per layer, the pools the decode runtime keeps (``cache_kinds.py``):
     K and V, ``[heads, d_head]`` float32 a token — the names, shapes and
     bytes of ``paged_pool_names`` / ``paged_pool_shape`` /
     ``paged_block_bytes``."""
@@ -701,10 +418,6 @@ def cache_kinds(cfg):
 # what ``serving/decode.py`` asks a served model's module for, under the
 # names every such module gives them; every mode is built for this cache
 UNSUPPORTED = {}
-build_prefill = build_gpt_prefill
-build_resume_prefill = build_gpt_resume_prefill
-build_prefix_copy = build_gpt_prefix_copy
-build_decode_step = build_gpt_decode_step
 build_paged_window = build_gpt_paged_window
 build_paged_step = build_gpt_paged_step
 build_paged_block_copy = build_gpt_paged_block_copy
@@ -740,8 +453,8 @@ def _reference_generate(exe, infer_prog, logits_var, cfg, prompt_ids,
 
 def greedy_generate(exe, infer_prog, logits_var, cfg, prompt_ids, max_len,
                     scope=None):
-    """Greedy decode through the KV-cache runtime: one prefill over the
-    prompt, then O(1)-length incremental steps against the cache — O(T)
+    """Greedy decode through the KV-cache runtime: one prefill window over
+    the prompt, then O(1)-length incremental steps against the cache — O(T)
     total model work instead of the O(T^2) full-forward-per-token loop
     (kept as ``_reference_generate``, the parity oracle). Output is
     token-exact vs the oracle: the cached K/V are the same projections
@@ -749,7 +462,7 @@ def greedy_generate(exe, infer_prog, logits_var, cfg, prompt_ids, max_len,
     softmax weight in fp32, and the argmax sees bitwise-equal logits.
 
     The single-slot decode session is cached per (scope, model geometry),
-    so repeated calls reuse the compiled prefill/decode programs."""
+    so repeated calls reuse the compiled window/step programs."""
     ids = list(prompt_ids)
     if len(ids) >= max_len:
         return ids
@@ -757,14 +470,17 @@ def greedy_generate(exe, infer_prog, logits_var, cfg, prompt_ids, max_len,
 
     sess = _decode.session_for_generate(exe, cfg, scope, max_len,
                                         infer_prog)
+    # the one slot owns the whole pool past the sink: an identity table
+    table = list(range(1, sess.max_blocks + 1))
     # the session is cached per (scope, geometry): concurrent callers
     # (the old per-call loop was trivially reentrant) serialize on its
     # lock for the WHOLE generation so interleaved steps can never read
-    # each other's slot-0 cache
+    # each other's blocks
     with sess.lock:
-        logits = sess.prefill(0, ids)
+        logits = sess.paged_window(table, ids, 0)
         ids.append(int(np.asarray(logits).ravel().argmax()))
         while len(ids) < max_len:
-            step = sess.decode_step([ids[-1]], [len(ids) - 1], [True])
-            ids.append(int(np.asarray(step)[0].argmax()))
+            step = sess.paged_step([[ids[-1]]], [len(ids) - 1], [table],
+                                   [True])
+            ids.append(int(step[0, 0].argmax()))
     return ids

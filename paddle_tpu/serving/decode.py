@@ -1,53 +1,47 @@
-"""Autoregressive decode runtime: KV-cache slot pool + continuous batching.
+"""Autoregressive decode runtime: a paged KV cache + continuous batching.
 
 The serving stack's generation path. `InferenceServer` batches whole
 forwards; a GPT completion served that way recomputes the full
 [1, max_len] forward for every emitted token — O(T^2) model forwards at
-batch 1. This module replaces that with the production decode shape:
+batch 1. This module replaces that with the production decode shape.
 
-  prefill  (one compiled program per PROMPT bucket): the prompt runs one
-           causal forward and writes its per-layer K/V into a cache slot;
-  decode   (ONE compiled program, ever): every engine tick runs a single
-           fused step over ALL slots — each active slot contributes one
-           query token against its cache row, masked by its own length.
+  cache    ONE pool per layer of ``block_size``-token blocks
+           ([blocks, heads, block, d_head] persistable scope vars,
+           device-resident between steps), addressed through per-slot
+           BLOCK TABLES that ride every program as fed data. A slot holds
+           ceil(len/block) blocks, not a max_len row; ``BlockAllocator``
+           hands them out by refcount.
+  sink     block 0 is never handed out: idle and prefilling slots feed an
+           all-sink table, so the fused step's unconditional
+           scatter-writes can never touch a live block.
+  window   (one compiled program per PROMPT bucket) a prompt window runs
+           one causal forward at a FED offset and writes its K/V through
+           the slot's table. A whole prompt is a window at offset 0;
+           ``FLAGS_decode_prefill_chunk`` caps the tokens a tick may
+           prefill, so a long prompt admits as windows BETWEEN steps.
+  step     (ONE compiled program a width) every tick runs a single fused
+           step over ALL slots: each active slot contributes its pending
+           token (plus a k-1 draft under ``decode_spec_tokens`` = k, ONE
+           batched verify) against its table, masked by its own length.
+  prefix   ``PagedPrefixIndex`` maps hash-chained prompt-token blocks to
+           the pool blocks a finished prefill already wrote: ZERO-copy.
+           A hit puts the block into the admitted slot's table and
+           increfs it; eviction is a decref, LRU under
+           ``FLAGS_decode_prefix_cache_mb``. Cached K/V are the same
+           projections the full forward computes, so hit and miss stay
+           token-exact vs the oracle.
 
-The cache is a fixed pool of ``slots`` rows per layer
-([slots, heads, max_len, d_head] persistable scope vars, device-resident
-between steps). Admission writes a slot row, retirement just frees the
-index — neither changes any compiled shape, so a churned request mix
-holds the PR 7 strict-compile gate at zero steady-state recompiles by
-construction. Decode is the bandwidth-bound regime (every token re-reads
-the weights plus the cache; PAPERS "Operator Fusion in XLA"), which is
-exactly why batching all slots into one step is the throughput lever:
-the weight traffic amortizes over every live stream.
+Tables, offsets and lengths are runtime data: admission, retirement,
+sharing and chunking change no compiled shape, so a churned request mix
+holds the strict-compile gate at zero steady-state recompiles. Decode is
+bandwidth-bound (every token re-reads the weights plus the cache), which
+is why batching all slots into one step is the throughput lever.
 
-Two prefill amortizations ride the same zero-recompile discipline:
-
-  prefix cache   a device-resident, block-granular K/V store
-                 (``PrefixCache`` host index + per-layer persistable
-                 pools) keyed by the hash-chain of prompt token blocks:
-                 admission copies the longest cached prefix into the
-                 slot row (``kv_cache_copy``, O(copied bytes)) and only
-                 the suffix runs a **resume-prefill** program — the
-                 bucket ladder with the start position FED as runtime
-                 data. Finished prefills publish their blocks back
-                 under LRU eviction bounded by
-                 ``FLAGS_decode_prefix_cache_mb``, ref-counted so an
-                 in-use block is never evicted mid-copy. Cached K/V are
-                 the same projections the full forward computes, so hit
-                 and miss paths stay token-exact vs the oracle.
-  chunked prefill  ``FLAGS_decode_prefill_chunk`` caps how many prompt
-                 tokens one tick may prefill: a long prompt admits as
-                 bucket-shaped resume windows interleaved with the
-                 fused decode steps, bounding live streams' inter-token
-                 latency instead of stalling them for a monolithic
-                 prefill.
-
-Layering: ``DecodeSession`` is the synchronous core (programs, cache
-init, prefill / resume windows / block copies / fused step) —
-``gpt.greedy_generate`` drives a 1-slot session inline;
-``DecodeEngine`` owns the continuous-batching loop (admission queue,
-prefix store, chunked-prefill scheduler, streaming) and is what
+Layering: ``DecodeSession`` is the synchronous core (programs, pool init,
+``paged_window`` / ``paged_step`` / ``block_copy``) — ``gpt.greedy_generate``
+drives a 1-slot session inline; ``DecodeEngine`` owns the
+continuous-batching loop (admission queue, allocator, prefix index,
+chunked-prefill scheduler, streaming) and is what
 ``InferenceServer.generate()`` fronts.
 """
 
@@ -79,7 +73,6 @@ __all__ = [
     "DecodeSession",
     "DecodeEngine",
     "GenerationStream",
-    "PrefixCache",
     "fast_forward_rng",
     "prefill_ladder",
     "sample_token",
@@ -89,6 +82,17 @@ __all__ = [
 
 def _flag(name, override):
     return override if override is not None else _flags.get_flag(name)
+
+
+def _block_size(override):
+    """``block_size`` (else ``FLAGS_decode_block_size``): tokens a KV
+    block."""
+    n = int(_flag("decode_block_size", override))
+    if n < 1:
+        raise ValueError(
+            "block_size (FLAGS_decode_block_size) is the tokens a KV block "
+            "holds and must be >= 1, got %d" % n)
+    return n
 
 
 def _require(model, mode):
@@ -141,7 +145,7 @@ def prefill_ladder(max_len, buckets=None):
 
 
 # ---------------------------------------------------------------------------
-# prefix K/V cache — host index over the device-resident block store
+# the pool's host side: block allocator + zero-copy prefix index
 # ---------------------------------------------------------------------------
 
 
@@ -149,7 +153,7 @@ def prefill_ladder(max_len, buckets=None):
 # scorer and the host-spill store must compute the exact keys this
 # module publishes, so the one definition lives in kv_tier. Still a
 # module-level hook here so tests can inject colliding functions; the
-# cache never trusts the key alone — every match re-compares the stored
+# index never trusts the key alone — every match re-compares the stored
 # (prev, tokens) link and falls through to the full-prefill path on
 # mismatch.
 _block_hash = _kv_tier.block_hash
@@ -166,130 +170,6 @@ class _PrefixEntry(object):
         self.refs = 0
 
 
-class PrefixCache(object):
-    """Host-side index of the device prefix store: maps hash-chained
-    prompt-token blocks to store block indices, with LRU eviction and
-    ref-count pinning. The device pool itself (per-layer persistable
-    [blocks, heads, block, d_head] vars) is owned by ``DecodeSession``;
-    this class only decides WHICH block lives WHERE — the engine moves
-    the bytes via the compiled copy programs.
-
-    Single-mutator discipline: the engine's loop thread is the only
-    caller of ``lookup``/``publish``/``release``; pinning exists so an
-    eviction forced by one admission's publish can never reclaim a
-    block another in-flight admission is still copying from
-    (``refs > 0`` blocks are skipped by the LRU sweep)."""
-
-    def __init__(self, blocks, block):
-        if blocks < 1 or block < 1:
-            raise ValueError(
-                "need blocks >= 1 and block >= 1, got %d / %d"
-                % (blocks, block)
-            )
-        self.blocks = int(blocks)
-        self.block = int(block)
-        from collections import OrderedDict
-
-        self._entries = OrderedDict()  # key -> _PrefixEntry, LRU order
-        self._free = list(range(self.blocks))
-        self.evictions = 0
-
-    def __len__(self):
-        return len(self._entries)
-
-    def lookup(self, prompt):
-        """Longest cached block-chain prefix of ``prompt``, capped at
-        ``len(prompt) - 1`` tokens so admission ALWAYS recomputes at
-        least the last prompt token (its logits are the first emitted
-        token — a full-prompt hit would leave nothing to emit from).
-        Returns (entries, tokens); every returned entry is PINNED —
-        the caller must ``release`` them once its device copy is done.
-        A hash collision (equal key, different stored tokens) stops the
-        chain: the suffix from there runs the normal prefill path."""
-        usable = (len(prompt) - 1) // self.block
-        out = []
-        prev = 0
-        for b in range(usable):
-            toks = tuple(prompt[b * self.block:(b + 1) * self.block])
-            key = _block_hash(prev, toks)
-            e = self._entries.get(key)
-            # verify the WHOLE chain link, not just this block's tokens:
-            # a key collision with equal tokens but a different parent
-            # (A||X vs B||X) would otherwise splice another prompt's
-            # prefix K/V into this request
-            if e is None or e.tokens != toks or e.prev != prev:
-                break
-            out.append(e)
-            prev = key
-        for e in out:
-            e.refs += 1
-            self._entries.move_to_end(e.key)
-        return out, len(out) * self.block
-
-    def release(self, entries):
-        for e in entries:
-            e.refs -= 1
-
-    def publish(self, prompt):
-        """Register every full block of ``prompt`` not cached yet.
-        Returns [(entry, prompt_block_index)] for the NEW entries — the
-        caller must copy those blocks from the slot row into
-        ``entry.block_idx`` (or ``forget`` them on failure). Allocation
-        evicts the least-recently-used UNPINNED entry when the free
-        list is empty; an all-pinned store stops publishing instead of
-        corrupting a block mid-copy."""
-        new = []
-        prev = 0
-        for b in range(len(prompt) // self.block):
-            toks = tuple(prompt[b * self.block:(b + 1) * self.block])
-            key = _block_hash(prev, toks)
-            e = self._entries.get(key)
-            if e is not None:
-                if e.tokens != toks or e.prev != prev:
-                    break  # collision squatting on the key: stop chaining
-                self._entries.move_to_end(key)
-                prev = key
-                continue
-            idx = self._alloc()
-            if idx is None:
-                break  # every block pinned by in-flight copies
-            e = _PrefixEntry(key, prev, toks, idx)
-            self._entries[key] = e
-            new.append((e, b))
-            prev = key
-        return new
-
-    def forget(self, entry):
-        """Drop a registration whose device copy failed — the block
-        returns to the free list and the key stops matching."""
-        if self._entries.get(entry.key) is entry:
-            del self._entries[entry.key]
-            self._free.append(entry.block_idx)
-
-    def _alloc(self):
-        if self._free:
-            return self._free.pop()
-        victim = None
-        for e in self._entries.values():  # oldest first
-            if e.refs <= 0:
-                victim = e
-                break
-        if victim is None:
-            return None
-        del self._entries[victim.key]
-        self.evictions += 1
-        _profiler.bump_counter("decode_prefix_evictions")
-        return victim.block_idx
-
-    def stats(self):
-        return {
-            "blocks": self.blocks,
-            "block": self.block,
-            "cached_blocks": len(self._entries),
-            "evictions": self.evictions,
-        }
-
-
 class BlockAllocator(object):
     """Host free-list + refcount ledger over the paged pool's physical
     blocks. Block 0 is the reserved SINK (idle / prefilling slots park
@@ -299,8 +179,8 @@ class BlockAllocator(object):
     reference one block; whoever drops the last reference returns it to
     the free list — eviction and retirement are both just ``decref``.
 
-    Single-mutator discipline like ``PrefixCache``: only the engine's
-    loop thread allocates/increfs/decrefs."""
+    Single-mutator discipline: only the engine's loop thread
+    allocates/increfs/decrefs."""
 
     SINK = 0
 
@@ -368,17 +248,19 @@ class BlockAllocator(object):
 
 
 class PagedPrefixIndex(object):
-    """Hash-chain prefix index for the PAGED runtime: same chained-
-    digest lookup discipline as ``PrefixCache`` but ZERO-copy — entries
-    point straight at pool blocks (the slot's own finished-prefill
-    blocks at publish time), held alive by one allocator reference each.
+    """Hash-chain prefix index over the pool, ZERO-copy: entries point
+    straight at pool blocks (the slot's own finished-prefill blocks at
+    publish time), held alive by one allocator reference each.
     A hit extends the admitted slot's table with the entry's block and
     increfs it; no device copy moves in either direction. Eviction is a
     refcount decrement — a block still referenced by live slots survives
     until the last slot retires.
 
+    Single-mutator discipline: the engine's loop thread is the only
+    caller of ``lookup``/``publish``/``evict_one``.
+
     ``max_blocks`` caps how many pool blocks the store itself may pin
-    (the paged reading of ``FLAGS_decode_prefix_cache_mb``).
+    (``FLAGS_decode_prefix_cache_mb``).
 
     ``on_evict`` is the host-spill seam (kv_tier): called with the
     victim entry BEFORE the index drops its reference, while the block's
@@ -404,11 +286,15 @@ class PagedPrefixIndex(object):
         return len(self._entries)
 
     def lookup(self, prompt):
-        """Longest cached block-chain prefix of ``prompt`` (capped at
-        ``len(prompt) - 1`` tokens like the legacy cache). Every matched
-        entry's block is INCREF'D for the caller — the references become
-        the admitted slot's table entries; on a failed admission the
-        caller must decref them back."""
+        """Longest cached block-chain prefix of ``prompt``, capped at
+        ``len(prompt) - 1`` tokens so admission ALWAYS recomputes at
+        least the last prompt token (its logits are the first emitted
+        token — a full-prompt hit would leave nothing to emit from).
+        Every matched entry's block is INCREF'D for the caller — the
+        references become the admitted slot's table entries; on a failed
+        admission the caller must decref them back. A hash collision
+        (equal key, different stored tokens) stops the chain: the suffix
+        from there runs the normal prefill path."""
         usable = (len(prompt) - 1) // self.block
         out = []
         prev = 0
@@ -416,6 +302,10 @@ class PagedPrefixIndex(object):
             toks = tuple(prompt[b * self.block:(b + 1) * self.block])
             key = _block_hash(prev, toks)
             e = self._entries.get(key)
+            # verify the WHOLE chain link, not just this block's tokens:
+            # a key collision with equal tokens but a different parent
+            # (A||X vs B||X) would otherwise splice another prompt's
+            # prefix K/V into this request
             if e is None or e.tokens != toks or e.prev != prev:
                 break
             out.append(e)
@@ -531,18 +421,18 @@ class PagedPrefixIndex(object):
 class DecodeSession(object):
     """Synchronous KV-cache decode core over one Executor + scope.
 
-    Builds the bucketed prefill programs and the single fused decode-step
-    program (all under fresh ``unique_name`` guards, so their parameter
-    names are the canonical ``<layer>.w_0`` spellings), seeds the cache
-    vars with zeros directly in the scope (no startup run — the scope's
-    model params are someone else's and must not be re-initialized), and
-    exposes ``prefill`` / ``decode_step``. Thread-compatible, not
-    thread-safe: one driver at a time (the engine's loop thread, or the
-    caller of ``greedy_generate``)."""
+    Builds the bucketed window programs, the fused step program of each
+    width and the block copy (all under fresh ``unique_name`` guards, so
+    their parameter names are the canonical ``<layer>.w_0`` spellings),
+    seeds the pools with zeros directly in the scope (no startup run —
+    the scope's model params are someone else's and must not be
+    re-initialized), and exposes ``paged_window`` / ``paged_step`` /
+    ``block_copy``. Thread-compatible, not thread-safe: one driver at a
+    time (the engine's loop thread, or the caller of
+    ``greedy_generate``)."""
 
     def __init__(self, cfg, place=None, scope=None, slots=None,
-                 max_len=None, prefill_buckets=None, prefix_blocks=0,
-                 prefix_block=None, build_resume=False, block_size=None,
+                 max_len=None, prefill_buckets=None, block_size=None,
                  pool_blocks=0, spec_tokens=None, window_cap=0, tp=None,
                  model=None):
         self.cfg = copy.copy(cfg)
@@ -580,55 +470,41 @@ class DecodeSession(object):
                 % (self.slots, max_len)
             )
         self.max_len = max_len
-        # paged mode (decode engine v2): block-table addressing over ONE
-        # shared pool for live slots AND the prefix store. 0 = the
-        # legacy contiguous [slots, max_len] rows (greedy_generate's
-        # sessions stay legacy by construction — session_for_generate
-        # pins block_size=0)
-        self.block_size = int(_flag("decode_block_size", block_size))
+        # block-table addressing over ONE shared pool for live slots AND
+        # the prefix index
+        self.block_size = _block_size(block_size)
         self.spec_tokens = max(int(_flag("decode_spec_tokens",
                                          spec_tokens)), 0)
-        self.paged = self.block_size > 0
-        if not self.paged:
-            _require(self.model, "contiguous")
         if self.spec_tokens > 1:
             _require(self.model, "spec_tokens")
-        if int(prefix_blocks):
-            _require(self.model, "prefix_store")
-        if self.paged:
-            width = max(self.spec_tokens, 1)
-            # speculative verify writes/embeds positions up to
-            # max_len + k - 2 (a slot one token from the wall still
-            # feeds a full k-window; emission stops at the budget)
-            if max_len + width - 1 > cfg.max_position_embeddings:
-                raise ValueError(
-                    "paged decode needs max_len + spec_tokens - 1 <= "
-                    "max_position_embeddings (%d + %d - 1 > %d): lower "
-                    "decode_max_len or decode_spec_tokens"
-                    % (max_len, width, cfg.max_position_embeddings)
-                )
-            self.max_blocks = -(-(max_len + width - 1) // self.block_size)
-            self.pool_blocks = int(pool_blocks) or (
-                self.slots * self.max_blocks + 1
+        width = max(self.spec_tokens, 1)
+        # speculative verify writes/embeds positions up to
+        # max_len + k - 2 (a slot one token from the wall still
+        # feeds a full k-window; emission stops at the budget)
+        if max_len + width - 1 > cfg.max_position_embeddings:
+            raise ValueError(
+                "paged decode needs max_len + spec_tokens - 1 <= "
+                "max_position_embeddings (%d + %d - 1 > %d): lower "
+                "decode_max_len or decode_spec_tokens"
+                % (max_len, width, cfg.max_position_embeddings)
             )
-            # block 0 is the SINK: reserved garbage target every idle /
-            # prefilling slot's table points at, so the fused step's
-            # unconditional scatter-writes can never touch a live block
-            if self.pool_blocks < 2:
-                raise ValueError(
-                    "paged pool needs >= 2 blocks (sink + 1), got %d"
-                    % self.pool_blocks
-                )
-            wcap = int(window_cap) or max_len
-            self.buckets = prefill_ladder(
-                min(max_len, max(wcap, 1)),
-                _flag("decode_prefill_buckets", prefill_buckets) or None,
+        self.max_blocks = -(-(max_len + width - 1) // self.block_size)
+        self.pool_blocks = int(pool_blocks) or (
+            self.slots * self.max_blocks + 1
+        )
+        # block 0 is the SINK: reserved garbage target every idle /
+        # prefilling slot's table points at, so the fused step's
+        # unconditional scatter-writes can never touch a live block
+        if self.pool_blocks < 2:
+            raise ValueError(
+                "paged pool needs >= 2 blocks (sink + 1), got %d"
+                % self.pool_blocks
             )
-        else:
-            self.buckets = prefill_ladder(
-                max_len,
-                _flag("decode_prefill_buckets", prefill_buckets) or None,
-            )
+        wcap = int(window_cap) or max_len
+        self.buckets = prefill_ladder(
+            min(max_len, max(wcap, 1)),
+            _flag("decode_prefill_buckets", prefill_buckets) or None,
+        )
         self.place = (place if place is not None
                       else fluid.core.default_place())
         self.scope = scope if scope is not None else fluid.core.Scope()
@@ -648,109 +524,45 @@ class DecodeSession(object):
         # one driver at a time: the engine's loop thread is naturally
         # exclusive, but greedy_generate funnels arbitrary caller
         # threads into one CACHED session per (scope, geometry) — they
-        # serialize on this lock so interleaved prefill/decode_step
-        # calls can never cross-contaminate the slot-0 cache
+        # serialize on this lock so interleaved window/step calls can
+        # never cross-contaminate the one slot's blocks
         self.lock = threading.RLock()
-        self._prefill = {}
-        self._decode = None
         self._paged_window = {}
         self._paged_step = {}
-        self._block_copy = None
-        if not self.paged:
-            for seq_len in self.buckets:
-                with fluid.unique_name.guard():
-                    main, _startup, _feeds, next_logits = (
-                        self.model.build_prefill(
-                            self.cfg, self.slots, seq_len, max_len
-                        )
-                    )
-                self._prefill[seq_len] = (self._maybe_tp(main),
-                                          next_logits.name)
+        # one window program per bucket handles ALL prefill (a whole
+        # prompt is just a window at offset 0), one fused step per
+        # width (1 = plain decode, spec_tokens = the batched verify),
+        # and one block-copy for COW
+        for seq_len in self.buckets:
             with fluid.unique_name.guard():
-                main, _startup, _feeds, step_logits = (
-                    self.model.build_decode_step(self.cfg, self.slots,
-                                                 max_len)
+                main, _s, feeds, nl = self.model.build_paged_window(
+                    self.cfg, self.pool_blocks, self.block_size,
+                    self.max_blocks, seq_len,
                 )
-            self._decode = (self._maybe_tp(main), step_logits.name)
-        else:
-            # one window program per bucket handles ALL prefill in paged
-            # mode (a monolithic prefill is just a window at offset 0),
-            # one fused step per width (1 = plain decode, spec_tokens =
-            # the batched verify), and one block-copy for COW
-            for seq_len in self.buckets:
-                with fluid.unique_name.guard():
-                    main, _s, feeds, nl = self.model.build_paged_window(
-                        self.cfg, self.pool_blocks, self.block_size,
-                        self.max_blocks, seq_len,
-                    )
-                self._paged_window[seq_len] = (self._maybe_tp(main), nl.name)
-                self._window_feeds = frozenset(feeds)
-            widths = [1]
-            if self.spec_tokens > 1:
-                widths.append(self.spec_tokens)
-            for w in widths:
-                with fluid.unique_name.guard():
-                    main, _s, feeds, sl = self.model.build_paged_step(
-                        self.cfg, self.slots, self.pool_blocks,
-                        self.block_size, self.max_blocks, step_w=w,
-                    )
-                # a program may name values to fetch beside the logits
-                # (``_step_stats``), which the model's ``step_stats`` reads
-                self._paged_step[w] = (
-                    self._maybe_tp(main),
-                    [sl.name] + list(getattr(main, "_step_stats", ())),
-                )
-                self._step_feeds = frozenset(feeds)
+            self._paged_window[seq_len] = (self._maybe_tp(main), nl.name)
+            self._window_feeds = frozenset(feeds)
+        widths = [1]
+        if self.spec_tokens > 1:
+            widths.append(self.spec_tokens)
+        for w in widths:
             with fluid.unique_name.guard():
-                main, _s, _f, ok = self.model.build_paged_block_copy(
-                    self.cfg, self.pool_blocks, self.block_size, npairs=1
+                main, _s, feeds, sl = self.model.build_paged_step(
+                    self.cfg, self.slots, self.pool_blocks,
+                    self.block_size, self.max_blocks, step_w=w,
                 )
-            self._block_copy = (self._maybe_tp(main), ok.name)
-        # resume-prefill family (prefix-cache hits + chunked prefill):
-        # one program per bucket, prefilling a window at a FED offset.
-        # Graph-built only on request — a greedy_generate 1-slot session
-        # never pays the construction, and nothing compiles until the
-        # engine's warmup actually runs a window
-        self.prefix_block = int(_flag("decode_prefix_block", prefix_block))
-        self.prefix_blocks = int(prefix_blocks)
-        if self.prefix_blocks < 0 or self.prefix_block < 1:
-            raise ValueError(
-                "need prefix_blocks >= 0 and prefix_block >= 1, got %d / %d"
-                % (self.prefix_blocks, self.prefix_block)
+            # a program may name values to fetch beside the logits
+            # (``_step_stats``), which the model's ``step_stats`` reads
+            self._paged_step[w] = (
+                self._maybe_tp(main),
+                [sl.name] + list(getattr(main, "_step_stats", ())),
             )
-        self._resume = {}
-        if (build_resume or self.prefix_blocks) and not self.paged:
-            for seq_len in self.buckets:
-                with fluid.unique_name.guard():
-                    main, _s, _f, nl = self.model.build_resume_prefill(
-                        self.cfg, self.slots, seq_len, max_len
-                    )
-                self._resume[seq_len] = (self._maybe_tp(main), nl.name)
-        # block-copy programs between the prefix store and slot rows —
-        # both directions, each ONE compiled program with fed locations
-        self._copy_in = None
-        self._publish = None
-        if self.prefix_blocks and not self.paged:
-            with fluid.unique_name.guard():
-                m_in, _s, _f, ok_in = self.model.build_prefix_copy(
-                    self.cfg, self.slots, max_len, self.prefix_blocks,
-                    self.prefix_block, publish=False,
-                )
-            self._copy_in = (self._maybe_tp(m_in), ok_in.name)
-            with fluid.unique_name.guard():
-                m_pub, _s, _f, ok_pub = self.model.build_prefix_copy(
-                    self.cfg, self.slots, max_len, self.prefix_blocks,
-                    self.prefix_block, publish=True,
-                )
-            self._publish = (self._maybe_tp(m_pub), ok_pub.name)
-        if self.paged:
-            self._cols = np.arange(self.max_blocks * self.block_size)
-        else:
-            self._cols = np.arange(max_len)
-        self._pos_cache = {
-            T: np.arange(T).reshape(1, T, 1).astype("int64")
-            for T in self.buckets
-        }
+            self._step_feeds = frozenset(feeds)
+        with fluid.unique_name.guard():
+            main, _s, _f, ok = self.model.build_paged_block_copy(
+                self.cfg, self.pool_blocks, self.block_size, npairs=1
+            )
+        self._block_copy = (self._maybe_tp(main), ok.name)
+        self._cols = np.arange(self.max_blocks * self.block_size)
         self.reset_caches()
 
     def _maybe_tp(self, main):
@@ -759,7 +571,7 @@ class DecodeSession(object):
         ``exe.run(main, feed=..., ...)`` call sites (Executor delegates),
         so every device step below is parallelism-agnostic. Each program
         gets its own sharding plan (its persistable set differs —
-        prefill sees caches, block-copy sees only pools)."""
+        a window sees weights and pools, block-copy only pools)."""
         if self._tp_mesh is None:
             return main
         from ..fluid import compiler as _compiler
@@ -776,36 +588,18 @@ class DecodeSession(object):
                 for layer in self.model.cache_kinds(self.cfg)]
 
     def reset_caches(self):
-        """Zero every cache var in the scope (host-side: no program, no
-        param re-init). Correctness never depends on this — prefill
-        replaces a slot's whole row — but fresh buffers make warmup and
-        tests deterministic."""
-        if self.paged:
-            geometry = (self.pool_blocks, self.block_size)
-            for layer in self.model.cache_kinds(self.cfg):
-                for pool in layer:
-                    self.scope.set(
-                        pool.name(*geometry),
-                        np.zeros(pool.shape(*geometry),
-                                 fluid.core.dtype_to_np(pool.dtype)),
-                    )
-            return
-        shape = self.model.decode_cache_shape(self.cfg, self.slots,
-                                              self.max_len)
-        for k_name, v_name in self.model.decode_cache_names(
-            self.cfg, self.slots, self.max_len
-        ):
-            self.scope.set(k_name, np.zeros(shape, "float32"))
-            self.scope.set(v_name, np.zeros(shape, "float32"))
-        if self.prefix_blocks:
-            pshape = self.model.prefix_store_shape(
-                self.cfg, self.prefix_blocks, self.prefix_block
-            )
-            for k_name, v_name in self.model.prefix_store_names(
-                self.cfg, self.prefix_blocks, self.prefix_block
-            ):
-                self.scope.set(k_name, np.zeros(pshape, "float32"))
-                self.scope.set(v_name, np.zeros(pshape, "float32"))
+        """Zero every pool in the scope (host-side: no program, no
+        param re-init). Correctness never depends on this — nothing
+        attends to a position its slot has not written — but fresh
+        buffers make warmup and tests deterministic."""
+        geometry = (self.pool_blocks, self.block_size)
+        for layer in self.model.cache_kinds(self.cfg):
+            for pool in layer:
+                self.scope.set(
+                    pool.name(*geometry),
+                    np.zeros(pool.shape(*geometry),
+                             fluid.core.dtype_to_np(pool.dtype)),
+                )
 
     def bind_params(self, program):
         """Alias ``program``'s parameters onto this session's canonical
@@ -849,169 +643,18 @@ class DecodeSession(object):
         return self.exe._run(main, feed, fetches, self.scope,
                              while_device_runs=self.while_device_runs)
 
-    def prefill(self, slot, prompt_ids):
-        """Run the prompt through the bucketed prefill program, writing
-        slot ``slot``'s cache row; returns the next-token logits
-        [vocab] at the last real prompt position."""
-        P = len(prompt_ids)
-        if not 0 <= slot < self.slots:
-            raise ValueError("slot %d out of range" % slot)
-        if P < 1:
-            raise ValueError("empty prompt")
-        T = self.bucket_for(P)
-        main, fetch_name = self._prefill[T]
-        ids = np.zeros((1, T, 1), "int64")
-        ids[0, :P, 0] = prompt_ids
-        mask = (np.arange(T) < P).astype("float32").reshape(1, T, 1)
-        last_onehot = np.zeros((1, T, 1), "float32")
-        last_onehot[0, P - 1, 0] = 1.0
-        feed = {
-            "ids": ids,
-            "pos_ids": self._pos_cache[T],
-            "input_mask": mask,
-            "slot_idx": np.array([[slot]], "int64"),
-            "last_onehot": last_onehot,
-        }
-        t0 = time.perf_counter()
-        with _trace.span("decode_prefill", cat="serving", bucket=T, rows=P):
-            (lv,) = self._run(main, feed, [fetch_name])
-        _profiler.bump_counter("decode_prefills")
-        self.prefills += 1
-        _profiler.bump_histogram(
-            "decode_prefill_ms", (time.perf_counter() - t0) * 1e3
-        )
-        return np.asarray(lv)[0]
-
-    def resume_prefill(self, slot, window_ids, offset):
-        """Prefill a prompt *window* starting at cache position
-        ``offset`` of slot ``slot`` — the suffix after a copied prefix,
-        or one chunk of a chunked prefill. The window pads to its
-        bucket; the offset rides the feed, so the bucket ladder's
-        compiled programs cover every placement. Returns the logits
-        [vocab] at the window's last real token (the next-token logits
-        when this is the prompt's final window)."""
-        P = len(window_ids)
-        if not 0 <= slot < self.slots:
-            raise ValueError("slot %d out of range" % slot)
-        if P < 1:
-            raise ValueError("empty resume window")
-        if not self._resume:
-            raise RuntimeError("session built without resume programs")
-        T = self.bucket_for(P)
-        offset = int(offset)
-        if offset < 0 or offset + T > self.max_len:
-            raise ValueError(
-                "resume window bucket [%d, %d) exceeds max_len %d — the "
-                "engine's window planner must pick a fitting bucket"
-                % (offset, offset + T, self.max_len)
-            )
-        main, fetch_name = self._resume[T]
-        ids = np.zeros((1, T, 1), "int64")
-        ids[0, :P, 0] = window_ids
-        # offset-shifted causal mask over the full row: window query i
-        # (cache position offset+i) sees cache positions <= offset+i —
-        # the copied prefix plus its own causal window. Pad queries
-        # (i >= P) keep a finite row; their output is never selected
-        allow = self._cols[None, :] <= (offset + np.arange(T))[:, None]
-        bias = np.where(allow, 0.0, -1e4).astype("float32")[None]
-        last_onehot = np.zeros((1, T, 1), "float32")
-        last_onehot[0, P - 1, 0] = 1.0
-        feed = {
-            "ids": ids,
-            "pos_ids": (offset + np.arange(T)).reshape(1, T, 1)
-            .astype("int64"),
-            "slot_off": np.array([[slot, offset]], "int64"),
-            "resume_bias": bias,
-            "last_onehot": last_onehot,
-        }
-        t0 = time.perf_counter()
-        with _trace.span("decode_resume_prefill", cat="serving",
-                         bucket=T, rows=P, offset=offset):
-            (lv,) = self._run(main, feed, [fetch_name])
-        _profiler.bump_counter("decode_prefills")
-        self.prefills += 1
-        _profiler.bump_histogram(
-            "decode_prefill_ms", (time.perf_counter() - t0) * 1e3
-        )
-        return np.asarray(lv)[0]
-
-    def prefix_copy_in(self, slot, dst_pos, src_block):
-        """Copy prefix-store block ``src_block`` into slot ``slot``'s
-        cache row at position ``dst_pos`` (all layers, K and V) — the
-        hit path's O(copied bytes) replacement for recomputing a
-        block's prefill."""
-        main, fetch_name = self._copy_in
-        with _trace.span("decode_prefix_copy", cat="serving",
-                         block=src_block, pos=dst_pos):
-            self._run(
-                main,
-                {"dst_loc": np.array([[slot, dst_pos]], "int64"),
-                 "src_loc": np.array([[src_block, 0]], "int64")},
-                [fetch_name],
-            )
-
-    def prefix_publish(self, slot, src_pos, dst_block):
-        """Copy one block of slot ``slot``'s finished prefill (row
-        position ``src_pos``) into prefix-store block ``dst_block`` so
-        future admissions can reuse it."""
-        main, fetch_name = self._publish
-        with _trace.span("decode_prefix_publish", cat="serving",
-                         block=dst_block, pos=src_pos):
-            self._run(
-                main,
-                {"dst_loc": np.array([[dst_block, 0]], "int64"),
-                 "src_loc": np.array([[slot, src_pos]], "int64")},
-                [fetch_name],
-            )
-
-    def decode_step(self, tokens, positions, active):
-        """ONE fused step over all slots: slot i's ``tokens[i]`` lands at
-        cache position ``positions[i]`` and its next-token logits come
-        back; slots with ``active[i]`` False feed an inert zero TOKEN
-        but keep their CALLER-CHOSEN position — the fused program
-        scatter-writes every slot unconditionally, and while a free
-        slot's dead row tolerates any landing spot, a slot mid-chunked-
-        prefill holds live prefix/window K/V, so the engine aims its
-        masked write at the next window's start (overwritten before
-        anything attends to it). The slot's attention output is fully
-        masked and ignored either way. Returns logits [slots, vocab]."""
-        with _trace.span("step_feed", cat="serving"):
-            act = np.asarray(active, bool)
-            pos = np.asarray(positions, "int64")
-            tok = np.where(act, np.asarray(tokens, "int64"), 0)
-            key_bias = (
-                ((self._cols[None, :] > pos[:, None]) | ~act[:, None])
-                .astype("float32") * -1e4
-            )
-            main, fetch_name = self._decode
-            feed = {
-                "step_ids": tok.reshape(self.slots, 1, 1),
-                "step_pos": pos.reshape(self.slots, 1, 1),
-                "key_bias": key_bias,
-            }
-        t0 = time.perf_counter()
-        with _trace.span(
-            "decode_step", cat="serving", active=int(act.sum())
-        ):
-            (lv,) = self._run(main, feed, [fetch_name])
-        _profiler.bump_counter("decode_steps")
-        self.steps += 1
-        _profiler.bump_histogram(
-            "decode_step_ms", (time.perf_counter() - t0) * 1e3
-        )
-        with _trace.span("step_logits", cat="serving"):
-            return np.asarray(lv)
-
     # -- paged device steps --------------------------------------------------
     def paged_window(self, table, window_ids, offset):
         """Prefill one prompt window (batch 1) THROUGH a fed block
         table: window token i lands at logical position ``offset + i``,
-        which ``table`` maps to a physical pool block — the paged
-        runtime's only prefill form (offset 0 = monolithic). Returns
-        the logits [vocab] at the window's last real token."""
+        which ``table`` maps to a physical pool block — the only
+        prefill form (offset 0 = the whole prompt, later offsets the
+        suffix after a shared prefix or one chunk of a chunked prefill).
+        The window pads to its bucket; the offset rides the feed, so the
+        bucket ladder's compiled programs cover every placement. Returns
+        the logits [vocab] at the window's last real token (the
+        next-token logits when this is the prompt's final window)."""
         P = len(window_ids)
-        if not self.paged:
-            raise RuntimeError("paged_window on a non-paged session")
         if P < 1:
             raise ValueError("empty prefill window")
         T = self.bucket_for(P)
@@ -1065,13 +708,11 @@ class DecodeSession(object):
         positions ``positions[s] .. positions[s]+width-1`` through its
         block table ``tables[s]``. width=1 is the plain decode tick;
         width=k is the speculative VERIFY (all k draft positions scored
-        in one call). Inactive slots feed an all-sink table, so their
-        unconditional scatter-writes land in reserved block 0 and can
-        never corrupt a live block — unlike the legacy contiguous step
-        there is no caller-aimed masked write to reason about. Returns
-        logits [slots, width, vocab]."""
-        if not self.paged:
-            raise RuntimeError("paged_step on a non-paged session")
+        in one call). Inactive slots feed an inert zero token and an
+        all-sink table, so their unconditional scatter-writes land in
+        reserved block 0 and can never corrupt a live block; their
+        attention output is fully masked and ignored. Returns logits
+        [slots, width, vocab]."""
         if width not in self._paged_step:
             raise ValueError(
                 "no paged step program of width %d (built: %s)"
@@ -1134,8 +775,6 @@ class DecodeSession(object):
         ``pool[dst[i]] = pool[src[i]]`` — the copy-on-write device op.
         The compiled program carries one pair; callers pass equal-length
         lists and pairs run back to back."""
-        if self._block_copy is None:
-            raise RuntimeError("session built without block-copy program")
         main, fetch_name = self._block_copy
         for src, dst in zip(src_blocks, dst_blocks):
             with _trace.span("decode_block_copy", cat="serving",
@@ -1166,7 +805,7 @@ def session_for_generate(exe, cfg, scope, max_len, param_program):
         cfg.intermediate_size, cfg.max_position_embeddings,
         repr(getattr(cfg, "use_flash_attention", False)),
         bool(getattr(cfg, "flash_interpret", False)),
-        int(max_len), type(exe.place).__name__,
+        int(max_len), type(exe.place).__name__, _block_size(None),
     )
     with _GEN_LOCK:
         cache = getattr(scope_obj, "_decode_gen_sessions", None)
@@ -1179,14 +818,13 @@ def session_for_generate(exe, cfg, scope, max_len, param_program):
     with cache["lock"]:
         sess = cache["sessions"].get(key)
         if sess is None:
-            # block_size pinned 0: greedy_generate's 1-slot sessions
-            # stay on the legacy contiguous path regardless of the
-            # serving-engine paged flags
-            # tp likewise pinned 1: the oracle path stays single-device
-            # even when FLAGS_spmd_decode_tp arms a TP serving engine
+            # spec_tokens pinned 0 and tp pinned 1: greedy_generate's
+            # 1-slot sessions build one step width and stay
+            # single-device whatever FLAGS_decode_spec_tokens /
+            # FLAGS_spmd_decode_tp arm for a serving engine
             sess = DecodeSession(
                 cfg, place=exe.place, scope=scope_obj, slots=1,
-                max_len=max_len, block_size=0, spec_tokens=0, tp=1,
+                max_len=max_len, spec_tokens=0, tp=1,
             )
             cache["sessions"][key] = sess
     sess.bind_params(param_program)
@@ -1604,22 +1242,22 @@ class DecodeEngine(object):
     """Continuous batching over a ``DecodeSession`` slot pool.
 
     One loop thread ticks: admit queued requests into free slots via
-    prefill (mid-flight — active streams keep decoding across
+    prefill windows (mid-flight — active streams keep decoding across
     admissions), then run ONE fused decode step for every active slot,
     stream each new token out, and retire slots on EOS / max-tokens /
     max-length. Greedy (argmax) decoding — token-exact with
     ``gpt._reference_generate``.
 
-    ``start()`` eagerly compiles every prefill bucket and the decode
-    step inside a warmup window, then arms the PR 7 counted strict
-    serving gate: with ``FLAGS_serving_strict_compiles`` any later
+    ``start()`` eagerly compiles every window bucket, every step width
+    and the block copy inside a warmup window, then arms the PR 7 counted
+    strict serving gate: with ``FLAGS_serving_strict_compiles`` any later
     request-path XLA compile raises ``SteadyStateRecompileError`` with
     the sentinel's attribution. Admission/retirement churn cannot trip
     it — no compiled shape depends on which slots are live."""
 
     def __init__(self, cfg, place=None, scope=None, slots=None,
                  max_len=None, prefill_buckets=None, queue_depth=None,
-                 param_program=None, prefix_block=None,
+                 param_program=None,
                  prefix_cache_mb=None, prefill_chunk=None,
                  block_size=None, spec_tokens=None, spec_draft=None,
                  pool_blocks=0, drafter=None, tp=None, model=None):
@@ -1645,10 +1283,9 @@ class DecodeEngine(object):
         self.queue_depth = int(_flag("decode_queue_depth", queue_depth))
         self._param_program = param_program
         # prefix caching + chunked prefill knobs: prefix_cache_mb bounds
-        # the device block store (0 = prefix caching off), prefix_block
-        # is the reuse granularity in tokens, prefill_chunk caps how
-        # many prompt tokens one tick may prefill (0 = monolithic)
-        self.prefix_block = int(_flag("decode_prefix_block", prefix_block))
+        # the pool blocks the prefix index may pin (0 = prefix caching
+        # off), prefill_chunk caps how many prompt tokens one tick may
+        # prefill (0 = the whole prompt in one window)
         self.prefix_cache_mb = float(
             _flag("decode_prefix_cache_mb", prefix_cache_mb)
         )
@@ -1658,20 +1295,11 @@ class DecodeEngine(object):
             raise ValueError(
                 "prefill_chunk and prefix_cache_mb must be >= 0"
             )
-        # decode engine v2: block_size > 0 arms the PAGED runtime (one
-        # shared pool, per-slot block tables, zero-copy prefix sharing);
-        # spec_tokens > 1 arms speculative decoding on top of it
-        self.block_size = int(_flag("decode_block_size", block_size))
+        # tokens a KV block, which is also the prefix reuse granularity;
+        # spec_tokens > 1 arms speculative decoding
+        self.block_size = _block_size(block_size)
         self.spec_tokens = int(_flag("decode_spec_tokens", spec_tokens))
-        self._paged = self.block_size > 0
-        if self.spec_tokens > 1 and not self._paged:
-            raise ValueError(
-                "speculative decoding rides the paged runtime: set "
-                "decode_block_size > 0 alongside decode_spec_tokens"
-            )
-        self._spec_width = (
-            self.spec_tokens if self._paged and self.spec_tokens > 1 else 1
-        )
+        self._spec_width = max(self.spec_tokens, 1)
         self._pool_blocks_arg = int(pool_blocks or 0)
         if drafter is not None:
             self._drafter = drafter
@@ -1684,14 +1312,9 @@ class DecodeEngine(object):
                     % (name, sorted(_SPEC_DRAFTERS))
                 )
             self._drafter = _SPEC_DRAFTERS[name]
-        if self._paged:
-            # paged reuse granularity IS the KV block — the legacy
-            # prefix_block knob only sizes the contiguous store
-            self.prefix_block = self.block_size
-        self.prefix = None  # PrefixCache once started (store enabled)
-        self.pindex = None  # PagedPrefixIndex once started (paged mode)
-        self.allocator = None  # BlockAllocator once started (paged mode)
-        self._slot_blocks = {}  # slot_idx -> [pool block ids], paged mode
+        self.pindex = None  # PagedPrefixIndex once started (store enabled)
+        self.allocator = None  # BlockAllocator once started
+        self._slot_blocks = {}  # slot_idx -> [pool block ids]
         self.session = None
         self.started = False
         self.tick = 0
@@ -1728,7 +1351,7 @@ class DecodeEngine(object):
         self._sched_vclock = 0.0
         self._sched_weights = {}
         self._sched_weights_ver = None
-        # fleet KV tier (kv_tier.py): host-spill store behind the paged
+        # fleet KV tier (kv_tier.py): host-spill store behind the
         # prefix index. Evicted device blocks spill D2H off the tick
         # thread; a later admission whose chain outruns the device index
         # re-admits the spilled payload H2D instead of re-prefilling.
@@ -1770,62 +1393,42 @@ class DecodeEngine(object):
             raise RuntimeError(
                 "previous decode-engine loop thread has not exited yet"
             )
-        if self._paged:
-            self.session = DecodeSession(
-                self._cfg, place=self._place, scope=self._scope,
-                slots=self._slots_arg, max_len=self._max_len_arg,
-                prefill_buckets=self._buckets_arg,
-                block_size=self.block_size,
-                pool_blocks=self._pool_blocks_arg,
-                spec_tokens=self.spec_tokens,
-                window_cap=self.prefill_chunk,
-                tp=self.tp, model=self._model,
+        self.session = DecodeSession(
+            self._cfg, place=self._place, scope=self._scope,
+            slots=self._slots_arg, max_len=self._max_len_arg,
+            prefill_buckets=self._buckets_arg,
+            block_size=self.block_size,
+            pool_blocks=self._pool_blocks_arg,
+            spec_tokens=self.spec_tokens,
+            window_cap=self.prefill_chunk,
+            tp=self.tp, model=self._model,
+        )
+        self.allocator = BlockAllocator(self.session.pool_blocks)
+        self.pindex = None
+        if self.prefix_cache_mb > 0:
+            # the store is ZERO-copy (entries pin pool blocks slots
+            # already wrote), so the mb budget caps how many blocks the
+            # store may pin, not a separate allocation
+            cap = max(1, int(
+                self.prefix_cache_mb * 2 ** 20
+                // (self.kv_bytes_per_token * self.block_size)
+            ))
+            self.pindex = PagedPrefixIndex(
+                self.block_size, cap, self.allocator
             )
-            self.allocator = BlockAllocator(self.session.pool_blocks)
-            self.prefix = None
-            self.pindex = None
-            if self.prefix_cache_mb > 0:
-                # the paged store is ZERO-copy (entries pin pool blocks
-                # slots already wrote), so the mb budget caps how many
-                # blocks the store may pin, not a separate allocation
-                cap = max(1, int(
-                    self.prefix_cache_mb * 2 ** 20
-                    // (self.kv_bytes_per_token * self.block_size)
-                ))
-                self.pindex = PagedPrefixIndex(
-                    self.block_size, cap, self.allocator
+            if self.kv_host_mb > 0:
+                _require(self._model, "kv_host_tier")
+                # host tier behind the device index: eviction spills
+                # instead of vanishing, admission walks here when
+                # the device chain runs out
+                self.host_store = _kv_tier.HostBlockStore(
+                    int(self.kv_host_mb * 2 ** 20)
                 )
-                if self.kv_host_mb > 0:
-                    _require(self._model, "kv_host_tier")
-                    # host tier behind the device index: eviction spills
-                    # instead of vanishing, admission walks here when
-                    # the device chain runs out
-                    self.host_store = _kv_tier.HostBlockStore(
-                        int(self.kv_host_mb * 2 ** 20)
-                    )
-                    self.pindex.on_evict = self._on_index_evict
-                    self._spill_done.clear()
-                    self._spill_worker = _kv_tier.SpillWorker(
-                        self._spill_batch
-                    )
-        else:
-            blocks = 0
-            if self.prefix_cache_mb > 0:
-                blocks = max(1, int(
-                    self.prefix_cache_mb * 2 ** 20
-                    // self._model.prefix_block_bytes(self._cfg,
-                                                      self.prefix_block)
-                ))
-            self.session = DecodeSession(
-                self._cfg, place=self._place, scope=self._scope,
-                slots=self._slots_arg, max_len=self._max_len_arg,
-                prefill_buckets=self._buckets_arg, prefix_blocks=blocks,
-                prefix_block=self.prefix_block,
-                build_resume=bool(blocks or self.prefill_chunk),
-                tp=self.tp, model=self._model,
-            )
-            self.prefix = PrefixCache(blocks, self.prefix_block) \
-                if blocks else None
+                self.pindex.on_evict = self._on_index_evict
+                self._spill_done.clear()
+                self._spill_worker = _kv_tier.SpillWorker(
+                    self._spill_batch
+                )
         if self._param_program is not None:
             self.session.bind_params(self._param_program)
         self.session.while_device_runs = self._publish_overlapped
@@ -1850,21 +1453,20 @@ class DecodeEngine(object):
             _obs_registry.register_gauge(
                 "decode_queue_depth", self._queue_gauge
             )
-            if self.allocator is not None:
-                # pool pressure at a glance: free blocks left, and how
-                # many are multiply-referenced (prefix sharing at work)
-                self._blocks_free_gauge = lambda e=self: (
-                    e.allocator.free_blocks if e.allocator else 0
-                )
-                _obs_registry.register_gauge(
-                    "decode_blocks_free", self._blocks_free_gauge
-                )
-                self._blocks_shared_gauge = lambda e=self: (
-                    e.allocator.shared_blocks if e.allocator else 0
-                )
-                _obs_registry.register_gauge(
-                    "decode_blocks_shared", self._blocks_shared_gauge
-                )
+            # pool pressure at a glance: free blocks left, and how
+            # many are multiply-referenced (prefix sharing at work)
+            self._blocks_free_gauge = lambda e=self: (
+                e.allocator.free_blocks
+            )
+            _obs_registry.register_gauge(
+                "decode_blocks_free", self._blocks_free_gauge
+            )
+            self._blocks_shared_gauge = lambda e=self: (
+                e.allocator.shared_blocks
+            )
+            _obs_registry.register_gauge(
+                "decode_blocks_shared", self._blocks_shared_gauge
+            )
             if self._spec_width > 1:
                 self._spec_gauge = lambda e=self: (
                     e._counts["spec_accepted"]
@@ -1925,48 +1527,27 @@ class DecodeEngine(object):
                 setattr(self, attr, None)
 
     def _warmup(self):
-        """Compile every shape the steady state can touch: each prefill
-        bucket once, the decode step once (its compiled shape is
-        independent of WHICH slots are active, so one all-inactive step
-        covers every future mix). Cache state is reset afterwards."""
+        """Compile every shape the steady state can touch: each window
+        bucket, each step width (1 + the spec verify; a step's compiled
+        shape is independent of WHICH slots are active, so one
+        all-inactive step covers every future mix) and the COW block
+        copy. All-sink tables make every warmup write inert garbage in
+        reserved block 0 — nothing live to reset, but zeroing the pools
+        afterwards keeps tests deterministic."""
         sess = self.session
         with _xla_stats.warmup_window(), _trace.span(
             "decode_warmup", cat="serving"
         ):
-            if sess.paged:
-                # every paged shape: each window bucket, each step
-                # width (1 + the spec verify), and the COW block copy.
-                # All-sink tables make every warmup write inert garbage
-                # in reserved block 0 — nothing live to reset but the
-                # pool zeroing below keeps tests deterministic
-                sink = [0] * sess.max_blocks
-                for T in sess.buckets:
-                    sess.paged_window(sink, [0] * T, 0)
-                for w in sorted(sess._paged_step):
-                    sess.paged_step(
-                        np.zeros((sess.slots, w), "int64"),
-                        [0] * sess.slots, [()] * sess.slots,
-                        [False] * sess.slots, width=w,
-                    )
-                sess.block_copy([0], [0])
-                sess.reset_caches()
-                return
+            sink = [0] * sess.max_blocks
             for T in sess.buckets:
-                P = min(T, sess.max_len - 1)
-                sess.prefill(0, [0] * P)
-            # resume-prefill family + the block-copy programs are part
-            # of the steady state whenever prefix caching / chunking is
-            # armed: compile them here or the first hit/chunk trips the
-            # strict gate
-            if sess._resume:
-                for T in sess.buckets:
-                    sess.resume_prefill(0, [0] * T, 0)
-            if sess._copy_in is not None:
-                sess.prefix_copy_in(0, 0, 0)
-                sess.prefix_publish(0, 0, 0)
-            sess.decode_step(
-                [0] * sess.slots, [0] * sess.slots, [False] * sess.slots
-            )
+                sess.paged_window(sink, [0] * T, 0)
+            for w in sorted(sess._paged_step):
+                sess.paged_step(
+                    np.zeros((sess.slots, w), "int64"),
+                    [0] * sess.slots, [()] * sess.slots,
+                    [False] * sess.slots, width=w,
+                )
+            sess.block_copy([0], [0])
             sess.reset_caches()
 
     def stop(self):
@@ -2002,7 +1583,7 @@ class DecodeEngine(object):
             self._prefilling.clear()
             pending = list(self._pending)
             self._pending.clear()
-            # paged block ownership dies with the session+allocator the
+            # block ownership dies with the session+allocator the
             # next start() rebuilds — just drop the host-side tables
             self._slot_blocks.clear()
             self.started = False
@@ -2040,8 +1621,8 @@ class DecodeEngine(object):
         ``resume_tokens`` is the RESUME form: the suffix an interrupted
         run of this exact request (same prompt, knobs, seed) already
         emitted elsewhere. The engine re-prefills prompt + suffix — one
-        admission through the prefix-cache/chunked path, so the
-        re-prefill costs block copies plus bucket windows, never a
+        admission through the prefix-index/chunked path, so the
+        re-prefill costs table edits plus bucket windows, never a
         recompile — fast-forwards the request RNG past the replayed
         picks, and the returned stream emits exactly the tokens the
         uninterrupted run would have emitted from there on. A sampled
@@ -2096,11 +1677,8 @@ class DecodeEngine(object):
                 "prompt of %d tokens leaves no room to generate "
                 "(max_len %d)" % (len(prompt), self.session.max_len)
             )
-        # validates the FULL re-prefilled length against the ladder —
-        # legacy only: paged windows tile ANY prompt length under
-        # max_len (the ladder there only shapes window buckets)
-        if not self._paged:
-            self.session.bucket_for(len(prompt) + len(resume))
+        # windows tile ANY prompt length under max_len: the ladder only
+        # shapes window buckets, so there is no length to check against it
         if max_new_tokens is not None and max_new_tokens < 1:
             raise ValueError("max_new_tokens must be >= 1")
         stream = GenerationStream(prompt, max_new_tokens=max_new_tokens,
@@ -2141,8 +1719,8 @@ class DecodeEngine(object):
                            priority=priority, tenant=tenant)
 
     def set_spec_width(self, width):
-        """Runtime speculation toggle for a paged engine: switch the
-        fused step between its COMPILED widths — 1 (plain decode) and
+        """Runtime speculation toggle: switch the fused step
+        between its COMPILED widths — 1 (plain decode) and
         ``spec_tokens`` (the batched verify). Both programs are built
         and warmed at ``start()``, so this is an ops lever, not a
         recompile: a workload whose measured ``decode_spec_acceptance``
@@ -2150,8 +1728,6 @@ class DecodeEngine(object):
         restart (and back). Token streams are identical either way —
         the verify path's accept loop guarantees it."""
         w = int(width)
-        if not self._paged:
-            raise ValueError("spec width is a paged-engine knob")
         if w != 1 and w != max(self.spec_tokens, 1):
             raise ValueError(
                 "width %d not compiled (this engine has 1%s)"
@@ -2201,8 +1777,6 @@ class DecodeEngine(object):
             paged = self.allocator.stats()
             paged["block_size"] = self.block_size
             out["paged"] = paged
-        if self.prefix is not None:
-            out["prefix_store"] = self.prefix.stats()
         if self.pindex is not None:
             out["prefix_store"] = self.pindex.stats()
         if self.host_store is not None:
@@ -2255,8 +1829,8 @@ class DecodeEngine(object):
 
     def _tick(self):
         """One engine tick: reap cancellations, admit queued requests
-        (prefix-cache copy + their first window; short prompts finish
-        admission inline, long ones become chunked jobs), advance ONE
+        (prefix-index table edit + their first window; short prompts
+        finish admission inline, long ones become chunked jobs), advance ONE
         chunked-prefill window, then ONE fused decode step over every
         active slot. The chunk cap is the inter-token latency bound: a
         max-length prompt costs in-flight streams one bucket-shaped
@@ -2356,12 +1930,11 @@ class DecodeEngine(object):
             "queued": len(self._pending),
             "live_tokens": sum(s.next_pos for s in self._active.values()),
         }
-        if self.allocator is not None:
-            # block 0 is the sink: never handed out
-            total = self.allocator.blocks - 1
-            out["blocks_total"] = total
-            out["blocks_in_use"] = total - self.allocator.free_blocks
-            out["kv_bytes_per_token"] = self.kv_bytes_per_token
+        # block 0 is the sink: never handed out
+        total = self.allocator.blocks - 1
+        out["blocks_total"] = total
+        out["blocks_in_use"] = total - self.allocator.free_blocks
+        out["kv_bytes_per_token"] = self.kv_bytes_per_token
         return out
 
     def _reap_cancelled(self):
@@ -2426,7 +1999,7 @@ class DecodeEngine(object):
                 s += length
             if ok:
                 return prefix, wins
-            prefix -= self.prefix_block
+            prefix -= self.block_size
         return 0, [(0, prompt_len)]
 
     # -- scheduler (weighted-fair dequeue + priority preemption) -------------
@@ -2572,11 +2145,20 @@ class DecodeEngine(object):
         free slot, a pending interactive request evicts one batch
         stream). Dequeue order is the scheduler's (interactive class
         first, weighted-fair across tenants within a class), not raw
-        FIFO. Each admission first copies the longest cached prefix
-        into the slot row (O(copied bytes) block copies, no recompute),
-        then prefills the suffix: single-window prompts inline (the
-        PR 8 behavior), longer ones as a chunked ``_PrefillJob``
-        advanced one window per tick."""
+        FIFO.
+
+        A prefix hit EDITS the slot's block table (matched store blocks
+        incref'd straight in — no device copy, no recompute), fresh
+        blocks cover exactly ``ceil(len(prompt)/block)`` minus the hit,
+        and the suffix prefills through bucket-shaped windows fed the
+        table: single-window prompts inline, longer ones as a chunked
+        ``_PrefillJob`` advanced one window per tick. Slot HBM footprint
+        is the prompt's ceil, not max_len. Pool exhaustion (after
+        refcount-eviction of store-only blocks) sheds the request with
+        the overload contract instead of corrupting a neighbor. The
+        resume form re-prefills prompt + emitted suffix through the same
+        machinery, which is what makes a resumed re-prefill cost ~one
+        suffix window instead of a stall."""
         if not self._free:
             self._preempt_for_pending()
         while self._free:
@@ -2594,49 +2176,46 @@ class DecodeEngine(object):
                 stream._finish("cancelled", self._outbox)
                 continue
             slot_idx = self._free.pop()
-            if self._paged:
-                self._admit_paged(slot_idx, stream)
-                continue
-            # the resume form re-prefills prompt + emitted suffix — the
-            # same admission machinery (prefix copies, window planning)
-            # serves both, which is exactly what makes a resumed
-            # re-prefill cost ~one suffix window instead of a stall
             prompt = self._admission_prompt(stream)
             entries, hit_tokens = [], 0
-            if self.prefix is not None:
-                entries, hit_tokens = self.prefix.lookup(prompt)
-            prefix_tokens, wins = self._plan_windows(len(prompt),
-                                                     hit_tokens)
+            if self.pindex is not None:
+                # lookup increfs each matched block — those references ARE
+                # the slot's table entries on success
+                entries, hit_tokens = self.pindex.lookup(prompt)
+                if self.host_store is not None:
+                    # chain ran past the device index: spilled (or pulled)
+                    # blocks re-admit H2D instead of re-prefilling — each
+                    # re-admitted entry joins ``entries`` with the same
+                    # slot reference lookup hands out
+                    entries = self._readmit_from_host(prompt, entries)
+                    hit_tokens = len(entries) * self.block_size
+            prefix_tokens, wins = self._plan_windows(len(prompt), hit_tokens)
+            bs = self.block_size
             if prefix_tokens < hit_tokens:
-                # the planner gave blocks back (suffix bucket didn't
-                # fit): unpin what we won't copy
-                keep = prefix_tokens // self.prefix_block
-                self.prefix.release(entries[keep:])
+                keep = prefix_tokens // bs
+                self.allocator.decref([e.block_idx for e in entries[keep:]])
                 entries = entries[:keep]
-            try:
-                if entries:
-                    with _stream_scope(stream), \
-                            _xla_stats.serving_request_window():
-                        for j, e in enumerate(entries):
-                            self.session.prefix_copy_in(
-                                slot_idx, j * self.prefix_block,
-                                e.block_idx,
-                            )
-            except Exception as exc:  # noqa: BLE001 - per-request failure
+            blocks = [e.block_idx for e in entries]
+            need = -(-len(prompt) // bs) - len(blocks)
+            owned = self._alloc_blocks(need)
+            if owned is None:
+                if blocks:
+                    self.allocator.decref(blocks)
                 self._free.append(slot_idx)
-                stream._fail(exc, self._outbox)
+                _profiler.bump_counter("decode_paged_oom_sheds")
+                self._counts["oom_sheds"] += 1
+                stream._fail(ServerOverloadedError(
+                    "paged KV pool exhausted (%d blocks short after "
+                    "eviction)" % need, retry_after_ms=50,
+                ), self._outbox)
                 continue
-            finally:
-                # copy done (or failed): the store may evict these
-                # blocks again — the slot row now owns its bytes.
-                # (finally runs before the except-branch's continue, so
-                # failure paths unpin exactly once too)
-                if entries:
-                    self.prefix.release(entries)
+            self._slot_blocks[slot_idx] = blocks + owned
             stream.cached_prefix_tokens = prefix_tokens
+            # denominator for the fleet cached-token fraction: every prompt
+            # token admitted, hit or miss
             _profiler.bump_counter("decode_prompt_tokens", len(prompt))
             self._counts["prompt_tokens"] += len(prompt)
-            if self.prefix is not None:
+            if self.pindex is not None:
                 if prefix_tokens:
                     _profiler.bump_counter("decode_prefix_hits")
                     _profiler.bump_counter("decode_prefix_cached_tokens",
@@ -2652,94 +2231,21 @@ class DecodeEngine(object):
                 with _stream_scope(stream):
                     self._run_prefill_window(slot_idx, job)
             else:
-                # chunked: the first window runs via _advance_prefills
-                # on THIS tick; in-flight streams decode between windows.
-                # Same stop/drain re-check as _active insertion: if
-                # stop()'s drain ran while the copies above were in
-                # flight, parking the job now would strand the stream
-                # in a dead engine
+                # chunked: the first window runs via _advance_prefills on
+                # THIS tick; in-flight streams decode between windows. Same
+                # stop/drain re-check as _active insertion: if stop()'s
+                # drain ran meanwhile, parking the job now would strand the
+                # stream in a dead engine
                 with self._cond:
                     if self._stop or not self.started:
                         self._free.append(slot_idx)
+                        self._release_slot_blocks(slot_idx)
                         stream._fail(ServingError("decode engine stopped"),
                                      self._outbox)
                         continue
                     self._prefilling[slot_idx] = job
 
-    def _admit_paged(self, slot_idx, stream):
-        """Paged admission: a prefix hit EDITS the slot's block table
-        (matched store blocks incref'd straight in — no device copy),
-        fresh blocks cover exactly ``ceil(len(prompt)/block)`` minus the
-        hit, and the prompt prefills through bucket-shaped windows fed
-        the table. Slot HBM footprint is the prompt's ceil, not max_len.
-        Pool exhaustion (after refcount-eviction of store-only blocks)
-        sheds the request with the overload contract instead of
-        corrupting a neighbor."""
-        prompt = self._admission_prompt(stream)
-        entries, hit_tokens = [], 0
-        if self.pindex is not None:
-            # lookup increfs each matched block — those references ARE
-            # the slot's table entries on success
-            entries, hit_tokens = self.pindex.lookup(prompt)
-            if self.host_store is not None:
-                # chain ran past the device index: spilled (or pulled)
-                # blocks re-admit H2D instead of re-prefilling — each
-                # re-admitted entry joins ``entries`` with the same
-                # slot reference lookup hands out
-                entries = self._readmit_from_host(prompt, entries)
-                hit_tokens = len(entries) * self.block_size
-        prefix_tokens, wins = self._plan_windows(len(prompt), hit_tokens)
-        bs = self.block_size
-        if prefix_tokens < hit_tokens:
-            keep = prefix_tokens // bs
-            self.allocator.decref([e.block_idx for e in entries[keep:]])
-            entries = entries[:keep]
-        blocks = [e.block_idx for e in entries]
-        need = -(-len(prompt) // bs) - len(blocks)
-        owned = self._alloc_blocks(need)
-        if owned is None:
-            if blocks:
-                self.allocator.decref(blocks)
-            self._free.append(slot_idx)
-            _profiler.bump_counter("decode_paged_oom_sheds")
-            self._counts["oom_sheds"] += 1
-            stream._fail(ServerOverloadedError(
-                "paged KV pool exhausted (%d blocks short after "
-                "eviction)" % need, retry_after_ms=50,
-            ), self._outbox)
-            return
-        self._slot_blocks[slot_idx] = blocks + owned
-        stream.cached_prefix_tokens = prefix_tokens
-        # denominator for the fleet cached-token fraction: every prompt
-        # token admitted, hit or miss
-        _profiler.bump_counter("decode_prompt_tokens", len(prompt))
-        self._counts["prompt_tokens"] += len(prompt)
-        if self.pindex is not None:
-            if prefix_tokens:
-                _profiler.bump_counter("decode_prefix_hits")
-                _profiler.bump_counter("decode_prefix_cached_tokens",
-                                       prefix_tokens)
-                self._counts["prefix_hits"] += 1
-                self._counts["prefix_cached_tokens"] += prefix_tokens
-            else:
-                _profiler.bump_counter("decode_prefix_misses")
-                self._counts["prefix_misses"] += 1
-        stream.admit_windows = len(wins)
-        job = _PrefillJob(stream, wins, prefix_tokens)
-        if len(wins) == 1:
-            with _stream_scope(stream):
-                self._run_prefill_window(slot_idx, job)
-        else:
-            with self._cond:
-                if self._stop or not self.started:
-                    self._free.append(slot_idx)
-                    self._release_slot_blocks(slot_idx)
-                    stream._fail(ServingError("decode engine stopped"),
-                                 self._outbox)
-                    return
-                self._prefilling[slot_idx] = job
-
-    # -- paged block bookkeeping ---------------------------------------------
+    # -- block bookkeeping ---------------------------------------------------
     def _alloc_blocks(self, n):
         """Allocator take with prefix-store pressure relief: when the
         free list runs dry, evict store entries whose block the store
@@ -3041,10 +2547,10 @@ class DecodeEngine(object):
     def _release_slot_blocks(self, slot_idx):
         """Drop the slot's reference on every block its table holds —
         owned blocks free, prefix-shared blocks survive under the
-        store's (or another slot's) remaining references. The paged
-        retirement path; a no-op for legacy engines."""
+        store's (or another slot's) remaining references: retirement is
+        a refcount decrement."""
         blocks = self._slot_blocks.pop(slot_idx, None)
-        if blocks and self.allocator is not None:
+        if blocks:
             self.allocator.decref(blocks)
 
     def _ensure_writable(self, slot_idx, block_i):
@@ -3101,21 +2607,11 @@ class DecodeEngine(object):
         s, e = job.windows[job.wi]
         try:
             with _xla_stats.serving_request_window():
-                if self._paged:
-                    # every paged prefill is a table-fed window
-                    # (monolithic = a window at offset 0)
-                    logits = self.session.paged_window(
-                        self._slot_blocks[slot_idx], prompt[s:e], s
-                    )
-                elif s == 0 and e == len(prompt):
-                    # whole prompt in one window from position 0: the
-                    # monolithic prefill program (cheaper — window-local
-                    # [T, T] attention, flash-capable)
-                    logits = self.session.prefill(slot_idx, prompt)
-                else:
-                    logits = self.session.resume_prefill(
-                        slot_idx, prompt[s:e], s
-                    )
+                # every prefill is a table-fed window (a whole prompt =
+                # a window at offset 0)
+                logits = self.session.paged_window(
+                    self._slot_blocks[slot_idx], prompt[s:e], s
+                )
             job.wi += 1
             if job.wi < len(job.windows):
                 # re-park under the drain lock: a stop() whose
@@ -3144,15 +2640,11 @@ class DecodeEngine(object):
             stream._fail(exc, self._outbox)
             return
         self._prefilling.pop(slot_idx, None)
-        if self._paged:
-            if self.pindex is not None:
-                # zero-copy publish: the store indexes the slot's OWN
-                # blocks (one incref each) — no device program runs, so
-                # unlike the legacy copy path there is no failure mode
-                # to unwind
-                self.pindex.publish(prompt, self._slot_blocks[slot_idx])
-        elif self.prefix is not None:
-            self._publish_blocks(slot_idx, prompt)
+        if self.pindex is not None:
+            # zero-copy publish: the store indexes the slot's OWN blocks
+            # (one incref each) — no device program runs, so there is no
+            # failure mode to unwind
+            self.pindex.publish(prompt, self._slot_blocks[slot_idx])
         # a resume (or preemption re-) admission's budget accounting
         # continues the ORIGINAL request: every replayed token counts
         # as already generated — len(prompt) - len(prompt_ids) is the
@@ -3192,25 +2684,6 @@ class DecodeEngine(object):
         # fleet TTFT SLI with scheduler wait
         self._emit(slot_idx, slot, tok)
 
-    def _publish_blocks(self, slot_idx, prompt):
-        """Publish the finished prefill's full blocks to the prefix
-        store. Best-effort: a failed device copy unregisters the new
-        entries (a key must never point at bytes that were not written)
-        and the request streams on — publishing is an optimization,
-        never a correctness dependency."""
-        new = self.prefix.publish(prompt)
-        if not new:
-            return
-        try:
-            with _xla_stats.serving_request_window():
-                for entry, b in new:
-                    self.session.prefix_publish(
-                        slot_idx, b * self.prefix_block, entry.block_idx
-                    )
-        except Exception:  # noqa: BLE001 - publish is best-effort
-            for entry, _b in new:
-                self.prefix.forget(entry)
-
     def _emit(self, slot_idx, slot, tok):
         """Stream one generated token and retire the slot if finished:
         the one place a served token enters its stream. Everything the
@@ -3242,67 +2715,12 @@ class DecodeEngine(object):
             # drained _active concurrently
             self._active.pop(slot_idx, None)
             self._free.append(slot_idx)
-            # paged retirement is a refcount decrement: owned blocks
-            # free, published blocks live on under the store's reference
+            # retirement is a refcount decrement: owned blocks free,
+            # published blocks live on under the store's reference
             self._release_slot_blocks(slot_idx)
             _profiler.bump_counter("serving_slot_retirements")
             self._counts["retirements"] += 1
             stream._finish(reason, self._outbox)
-
-    def _step(self):
-        """One fused decode step over every active slot."""
-        if self._paged:
-            self._step_paged()
-            return
-        sess = self.session
-        with _trace.span("tick_build", cat="serving"):
-            tokens = [0] * sess.slots
-            positions = [0] * sess.slots
-            active = [False] * sess.slots
-            for idx, slot in self._active.items():
-                tokens[idx] = slot.pending_token
-                positions[idx] = slot.next_pos
-                active[idx] = True
-            for idx, job in self._prefilling.items():
-                # the fused program scatter-writes EVERY slot, active or
-                # not: a mid-chunked-prefill row is live (copied prefix
-                # + finished windows), so its masked write must land on
-                # the next window's start — the window overwrites that
-                # position before any attention reads it. The free-slot
-                # convention (position 0) would corrupt the row head and
-                # poison blocks later published to the prefix store.
-                positions[idx] = job.windows[job.wi][0]
-            tids = self._traced_ids()
-        if tids:
-            with _trace.span("decode_tick", cat="serving",
-                             tick=self.tick, trace_ids=tids), \
-                    _xla_stats.serving_request_window():
-                logits = sess.decode_step(tokens, positions, active)
-        else:
-            with _xla_stats.serving_request_window():
-                logits = sess.decode_step(tokens, positions, active)
-        self.tick += 1
-        cpu0 = time.thread_time()
-        with _trace.span("tick_sample_emit", cat="serving") as sp:
-            emitted = 0
-            for idx in list(self._active.keys()):
-                slot = self._active[idx]
-                try:
-                    tok = slot.stream.pick(logits[idx])
-                except Exception as e:  # noqa: BLE001 - THIS stream only
-                    self._active.pop(idx, None)
-                    self._free.append(idx)
-                    _profiler.bump_counter("serving_slot_retirements")
-                    self._counts["retirements"] += 1
-                    slot.stream._fail(e, self._outbox)
-                    continue
-                slot.next_pos += 1
-                slot.generated += 1
-                slot.pending_token = tok
-                self._emit(idx, slot, tok)
-                emitted += 1
-            sp.note(tokens=emitted,
-                    cpu_ms=(time.thread_time() - cpu0) * 1e3)
 
     def _traced_ids(self):
         """A fused tick decodes EVERY traced stream at once: its
@@ -3320,8 +2738,8 @@ class DecodeEngine(object):
             if getattr(s.stream, "trace_ctx", None)
         })
 
-    def _step_paged(self):
-        """One fused paged tick over every active slot — the plain
+    def _step(self):
+        """One fused tick over every active slot — the plain
         decode step when speculation is off, or the batched VERIFY when
         ``decode_spec_tokens`` = k > 1: each slot's window is its
         pending token plus a k-1-token draft, ONE program scores all k
@@ -3342,7 +2760,7 @@ class DecodeEngine(object):
         sess = self.session
         width = self._spec_width
         with _trace.span("tick_build", cat="serving"):
-            built = self._build_paged_step(width)
+            built = self._build_step(width)
             tids = self._traced_ids()
         if built is None:
             return
@@ -3403,8 +2821,8 @@ class DecodeEngine(object):
             sp.note(tokens=total,
                     cpu_ms=(time.thread_time() - cpu0) * 1e3)
 
-    def _build_paged_step(self, width):
-        """Before the device call of a paged tick: grow and unshare the
+    def _build_step(self, width):
+        """Before the device call of a tick: grow and unshare the
         slots' block tables, draft, and lay out the step's arguments.
         -> (tokens, positions, tables, active, windows), or None when
         every slot was shed."""
@@ -3459,6 +2877,6 @@ class DecodeEngine(object):
             active[idx] = True
             tables[idx] = self._slot_blocks[idx]
         # idle AND mid-prefill slots keep the all-sink default table:
-        # their scatter-writes land in reserved block 0, so unlike the
-        # legacy step there is no write position to aim
+        # their scatter-writes land in reserved block 0, so there is no
+        # write position to aim
         return tokens, positions, tables, active, windows

@@ -1366,10 +1366,10 @@ def _make_handler(gw):
                 "resumed_tokens": len(getattr(
                     stream, "resume_tokens", ()) or ()),
             }
-            # speculative-decoding facts (paged engine v2): drafted /
-            # accepted counts plus the per-request acceptance rate —
-            # only when the engine actually drafted, so legacy engines'
-            # payloads and log lines stay byte-identical
+            # speculative-decoding facts: drafted / accepted counts plus
+            # the per-request acceptance rate — only when the engine
+            # actually drafted, so the payloads and log lines of an
+            # engine without speculation stay byte-identical
             drafted = int(getattr(stream, "spec_drafted", 0) or 0)
             if drafted:
                 accepted = int(getattr(stream, "spec_accepted", 0) or 0)

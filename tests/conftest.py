@@ -61,3 +61,36 @@ def run_probe_subprocess(script, args=("--fast",), retry_prefix=None,
                     for f in report["failures"])):
         p, report = _run()
     return p, report
+
+
+def engine_free_oracle(model, prompt, n, max_len, sampling=None):
+    """``n`` tokens after ``prompt`` by the full [1, max_len] forward once
+    a token, nothing of the decode engine in it: the argmax, or
+    ``sample_token`` on one ``RandomState`` (one uniform a pick) for a
+    seeded request (``sampling``: temperature, top_k, seed). ``model``
+    holds ``exe``, ``infer``, ``logits`` and ``scope``."""
+    import numpy as np
+
+    from paddle_tpu.serving.decode import sample_token
+
+    ids = list(prompt)
+    rng = np.random.RandomState(sampling["seed"]) if sampling else None
+    pos_ids = np.arange(max_len).reshape(1, max_len, 1).astype("int64")
+    for _ in range(n):
+        cur = len(ids)
+        padded = np.zeros((1, max_len, 1), "int64")
+        padded[0, :cur, 0] = ids
+        (lv,) = model["exe"].run(
+            model["infer"], feed={
+                "ids": padded, "pos_ids": pos_ids,
+                "input_mask": (np.arange(max_len) < cur).astype(
+                    "float32").reshape(1, max_len, 1)},
+            fetch_list=[model["logits"]], scope=model["scope"])
+        row = np.asarray(lv)[0, cur - 1]
+        if sampling is None:
+            ids.append(int(row.argmax()))
+        else:
+            ids.append(sample_token(
+                row, temperature=sampling["temperature"],
+                top_k=sampling["top_k"], rng=rng))
+    return ids[len(prompt):]

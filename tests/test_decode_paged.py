@@ -1,4 +1,4 @@
-"""Decode engine v2: paged KV block tables + speculative decoding.
+"""The decode engine: paged KV block tables + speculative decoding.
 
 Covers the ISSUE-16 tentpole surfaces: the paged cache ops as units
 (permuted / shared / copy-on-write tables), the host-side block
@@ -234,7 +234,7 @@ def test_paged_prefix_index_lookup_publish_evict():
     ent, toks = ix.lookup(p1[:9])  # 9 tokens -> both blocks usable
     assert toks == 8 and [e.block_idx for e in ent] == owned[:2]
     assert al.refs(owned[0]) == 2  # lookup increfed for the caller
-    # full-block prompt caps at len-1 like the legacy cache
+    # full-block prompt caps at len-1: the last token is recomputed
     ent2, toks2 = ix.lookup(p1[:8])
     assert toks2 == 4 and len(ent2) == 1
     al.decref([e.block_idx for e in ent2])
@@ -251,6 +251,85 @@ def test_paged_prefix_index_lookup_publish_evict():
     assert ix.evictions >= 2
 
 
+def test_paged_prefix_index_refcount_blocks_eviction():
+    """An eviction forced while an admission still holds a looked-up
+    block must not free it under the slot: under allocator pressure
+    (need_free) blocks a caller references are skipped, and a store
+    whose every block is so held gives nothing back; the pin-budget
+    eviction drops the ENTRY but the block lives until the holder's
+    decref."""
+    al = sdecode.BlockAllocator(8)
+    ix = sdecode.PagedPrefixIndex(BLOCK, 2, al)
+    pa = list(range(8)) + [0]
+    owned = al.alloc(3)
+    ix.publish(pa, owned)  # 2 blocks -> store at its budget
+    al.decref(owned)       # the publishing slot retires
+    held, toks = ix.lookup(pa)  # an in-flight admission's references
+    assert toks == 8 and all(al.refs(e.block_idx) == 2 for e in held)
+    # everything held: allocator pressure cannot take a block back
+    assert ix.evict_one(need_free=True) is False
+    assert ix.evictions == 0
+    # publishing a new prefix at the budget drops the LRU entry, but the
+    # held block is not freed (and so cannot be handed out and rewritten)
+    free0 = al.free_blocks
+    fresh = al.alloc(2)
+    new = ix.publish(list(range(50, 54)) + [0], fresh)
+    assert len(new) == 1 and ix.evictions == 1
+    assert al.refs(held[0].block_idx) == 1  # the admission's own ref
+    assert al.free_blocks == free0 - 2
+    assert ix._entries.get(held[1].key) is held[1]  # newer entry kept
+    # release ONE: exactly that block returns to the free list
+    al.decref([held[0].block_idx])
+    assert al.free_blocks == free0 - 1
+    assert al.refs(held[1].block_idx) == 2
+    al.decref([held[1].block_idx])
+
+
+def test_paged_prefix_index_collision_verified_not_trusted(monkeypatch):
+    """A hash collision (equal chain key, different tokens) must stop
+    the chain at lookup AND at publish — the token tuples are compared,
+    never the key alone."""
+    monkeypatch.setattr(sdecode, "_block_hash", lambda prev, toks: 42)
+    al = sdecode.BlockAllocator(8)
+    ix = sdecode.PagedPrefixIndex(2, 4, al)
+    pa = [1, 2, 9]
+    pb = [3, 4, 9]  # different tokens, same (engineered) key
+    assert len(ix.publish(pa, al.alloc(2))) == 1
+    ent, toks = ix.lookup(pb)
+    assert toks == 0 and ent == []  # collision -> miss fallthrough
+    assert ix.publish(pb, al.alloc(2)) == []  # cannot chain past it
+    ent, toks = ix.lookup(pa)
+    assert toks == 2                # the real owner still hits
+    al.decref([e.block_idx for e in ent])
+
+
+def test_paged_prefix_index_verifies_chain_parent_not_just_tokens(
+        monkeypatch):
+    """Review regression: a key collision with EQUAL tokens but a
+    different parent (prefixes A||X vs B||X under a tokens-only hash)
+    must not splice A's X-block K/V into B's chain — the stored
+    (prev, tokens) link is verified, never the tokens alone."""
+    monkeypatch.setattr(sdecode, "_block_hash",
+                        lambda prev, toks: ("t", toks))  # ignores prev
+    al = sdecode.BlockAllocator(12)
+    ix = sdecode.PagedPrefixIndex(2, 4, al)
+    a, b, x = [1, 2], [3, 4], [7, 8]
+    assert len(ix.publish(a + x + [0], al.alloc(3))) == 2  # chain A -> X
+    # lookup B||X: block B misses; even a direct walk that reached the
+    # X entry must reject it (its parent is A's key, not B's)
+    ent, toks = ix.lookup(b + x + [0])
+    assert toks == 0 and ent == []
+    # publish B||X: B registers, but X's colliding entry (parent A)
+    # stops the chain — B's X-block is NOT registered under A's entry
+    slot_b = al.alloc(3)
+    new = ix.publish(b + x + [0], slot_b)
+    assert [e.block_idx for e in new] == slot_b[:1]
+    # the genuine A||X chain still hits end to end
+    ent, toks = ix.lookup(a + x + [0])
+    assert toks == 4
+    al.decref([e.block_idx for e in ent])
+
+
 def test_spec_drafters():
     """Built-in drafters: trailing-n-gram continuation (longest n wins,
     most recent earlier match) and last-token repetition; both pad to
@@ -263,18 +342,14 @@ def test_spec_drafters():
     with pytest.raises(ValueError):
         sdecode.DecodeEngine(gpt.GPTConfig.tiny(), spec_draft="nope",
                              block_size=BLOCK)
-    with pytest.raises(ValueError):
-        # speculation without the paged runtime is a config error
-        sdecode.DecodeEngine(gpt.GPTConfig.tiny(), spec_tokens=3)
 
 
 # -- engine end-to-end ------------------------------------------------------
 @pytest.fixture(scope="module")
 def pg():
-    """One model + oracle shared by a paged+speculative engine (k=4,
-    prefix index 4 blocks, chunked prefill 8) and a LEGACY engine on
-    the same params — the cross-engine sampled-parity reference. The
-    spec engine's drafter is swappable per-test via the dict."""
+    """One model + oracle and a speculative engine on it (k=4, prefix
+    index 4 blocks, chunked prefill 8). The engine's drafter is
+    swappable per-test via the dict."""
     cfg = gpt.GPTConfig.tiny(hidden_dropout=0.0, attention_dropout=0.0)
     cfg.max_position_embeddings = MAX_LEN + SPEC_K  # spec headroom
     with fluid.unique_name.guard():
@@ -291,10 +366,6 @@ def pg():
         prefix_cache_mb=4 * gpt.paged_block_bytes(cfg, BLOCK) / 2.0 ** 20,
         drafter=lambda h, k: draft["fn"](h, k),
     ).start()
-    legacy = sdecode.DecodeEngine(
-        cfg, scope=scope, slots=2, max_len=MAX_LEN,
-        prefill_buckets=[8, MAX_LEN], param_program=infer,
-    ).start()
 
     def oracle(prompt):
         return gpt._reference_generate(
@@ -302,10 +373,9 @@ def pg():
         )
 
     yield {"cfg": cfg, "infer": infer, "exe": exe, "scope": scope,
-           "engine": engine, "legacy": legacy, "oracle": oracle,
+           "logits": logits, "engine": engine, "oracle": oracle,
            "draft": draft}
     engine.stop()
-    legacy.stop()
 
 
 def _simulate_spec(prompt, full, max_new, width, drafter):
@@ -373,10 +443,10 @@ def test_paged_spec_parity_every_split_point(pg):
 
 
 def test_set_spec_width_runtime_toggle(pg):
-    """set_spec_width flips a paged engine between its two compiled
+    """set_spec_width flips an engine between its two compiled
     verify widths without a restart: width 1 runs token-exact with
     ZERO drafting (the drafter is never consulted), width k restores
-    speculation, and uncompiled widths or legacy engines refuse."""
+    speculation, and uncompiled widths refuse."""
     engine, oracle = pg["engine"], pg["oracle"]
     rs = np.random.RandomState(7)
     p = list(rs.randint(0, pg["cfg"].vocab_size, 6))
@@ -400,15 +470,13 @@ def test_set_spec_width_runtime_toggle(pg):
     for bad in (0, 2, SPEC_K + 1):
         with pytest.raises(ValueError):
             engine.set_spec_width(bad)
-    with pytest.raises(ValueError):
-        pg["legacy"].set_spec_width(1)
 
 
 def test_paged_greedy_parity_and_prefix_hit(pg):
     """Greedy parity across prompt lengths through the spec engine
     (acceptance rate must never perturb tokens), then a re-submitted
     long prompt rides the ZERO-COPY prefix index: cached whole blocks,
-    token-exact, no device copy programs in the paged session."""
+    token-exact, no device copy programs in the session."""
     engine, oracle = pg["engine"], pg["oracle"]
     rs = np.random.RandomState(0)
     for n in (1, 3, 9, MAX_LEN - 6):
@@ -457,27 +525,30 @@ def test_paged_chunked_resume_and_eviction(pg):
     assert s3.tokens(timeout=120) == full[13:18]
 
 
-def test_paged_sampled_parity_vs_legacy_engine(pg):
+def test_paged_sampled_parity_vs_seeded_oracle(pg):
     """Seeded sampling through the spec verify path must reproduce the
-    LEGACY engine's stream bit-for-bit: each consumed verify row is the
-    sequential logits row, and one uniform per emitted token keeps the
-    PR-13 resume contract (fast_forward_rng) intact."""
-    engine, legacy = pg["engine"], pg["legacy"]
+    engine-free oracle's stream bit-for-bit: each consumed verify row is
+    the sequential logits row, and one uniform per emitted token keeps
+    the PR-13 resume contract (fast_forward_rng) intact."""
+    from conftest import engine_free_oracle
+
+    engine = pg["engine"]
     pg["draft"]["fn"] = sdecode._ngram_draft
     p = [2, 9, 4, 9, 4]
-    kw = dict(max_new_tokens=10, temperature=0.8, top_k=32, seed=123)
-    want = legacy.generate(p, **kw).tokens(timeout=120)
-    got = engine.generate(p, **kw).tokens(timeout=120)
+    knobs = dict(temperature=0.8, top_k=32, seed=123)
+    want = engine_free_oracle(pg, p, 10, MAX_LEN, knobs)
+    got = engine.generate(p, max_new_tokens=10, **knobs).tokens(timeout=120)
     assert got == want
     # and the sampled stream replays deterministically on the spec path
-    assert engine.generate(p, **kw).tokens(timeout=120) == want
+    assert engine.generate(p, max_new_tokens=10,
+                           **knobs).tokens(timeout=120) == want
 
 
 def test_paged_zero_steady_recompiles_and_gauges(pg):
     """Churn through the warmed engine (its strict gate armed at
     start): block-table admissions, spec verify ticks, prefix hits and
     retirements cause ZERO steady-state compiles (tables/positions are
-    runtime data), and the v2 gauges are live."""
+    runtime data), and the pool gauges are live."""
     engine = pg["engine"]
     pg["draft"]["fn"] = sdecode._ngram_draft
     c0 = profiler.get_counters()
@@ -504,6 +575,48 @@ def test_paged_zero_steady_recompiles_and_gauges(pg):
     assert st["paged"]["free"] + (len(engine._active)
                                   + len(engine._prefilling)) >= 0
     assert st["paged"]["blocks"] == engine.session.pool_blocks
+
+
+def test_engine_copies_a_shared_block_before_it_writes_it():
+    """Copy-on-write through the engine: a block the slot is about to
+    write that someone else also references (here a reference taken by
+    hand, standing for an index entry or a second slot) is first copied
+    into a fresh block and the table entry swapped, so the other holder's
+    bytes never change and the stream stays token-exact."""
+    cfg = gpt.GPTConfig.tiny(hidden_dropout=0.0, attention_dropout=0.0)
+    cfg.max_position_embeddings = MAX_LEN
+    with fluid.unique_name.guard():
+        infer, startup, _names, logits = gpt.build_gpt_infer(cfg, MAX_LEN)
+    exe = fluid.Executor(fluid.CPUPlace())
+    scope = fluid.core.Scope()
+    with fluid.executor.scope_guard(scope):
+        exe.run(startup)
+    engine = sdecode.DecodeEngine(
+        cfg, scope=scope, slots=1, max_len=MAX_LEN, param_program=infer,
+        block_size=BLOCK,
+    ).start(loop=False)
+    try:
+        p = [3, 1, 4, 1, 5, 9]  # 6 tokens: the second block is half full
+        want = gpt._reference_generate(
+            exe, infer, logits, cfg, p, MAX_LEN, scope=scope)[len(p):]
+        s = engine.submit(p, max_new_tokens=5)
+        engine._tick()  # admission, and the first step into block 1
+        shared = engine._slot_blocks[0][1]
+        engine.allocator.incref([shared])
+        names = [n for layer in engine.session.pool_names() for n in layer]
+        before = [np.asarray(scope.get(n))[shared].copy() for n in names]
+        engine._tick()
+        assert engine._slot_blocks[0][1] != shared
+        assert engine.allocator.refs(shared) == 1  # ours alone now
+        for n, b in zip(names, before):
+            np.testing.assert_array_equal(np.asarray(scope.get(n))[shared], b)
+        while not s.done:
+            engine._tick()
+        assert s.tokens(timeout=1) == want[:5]
+        engine.allocator.decref([shared])
+        assert engine.allocator.free_blocks == engine.session.pool_blocks - 1
+    finally:
+        engine.stop()
 
 
 def test_paged_pool_oom_sheds_not_wedges():

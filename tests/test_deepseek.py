@@ -499,8 +499,6 @@ def test_latent_cache_kind_is_one_640_lane_pool_a_layer():
 # -- (h) modes that are not built for a latent cache -----------------------------------
 
 @pytest.mark.parametrize("mode,kwargs", [
-    ("contiguous", dict(block_size=0)),
-    ("prefix_store", dict(block_size=4, prefix_blocks=2)),
     ("spec_tokens", dict(block_size=4, spec_tokens=3)),
     ("tp", dict(block_size=4, tp=2)),
 ])

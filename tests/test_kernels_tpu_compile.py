@@ -74,16 +74,6 @@ def _train(batch, seq, causal, key_bias=False, bias=None, dropout=0.0,
     return jax.grad(loss, argnums=(0, 1, 2)), shapes, 3
 
 
-def _decode(seq, dtype):
-    def fn(q, k, v, kb):
-        return fa.flash_decode_attention(q, k, v, key_bias=kb,
-                                         interpret=False)
-
-    cache = ((SLOTS, HEADS, seq, D_HEAD), dtype)
-    return fn, [((SLOTS, HEADS, 1, D_HEAD), dtype), cache, cache,
-                ((SLOTS, seq), F32)], 1
-
-
 def _paged(max_blocks, dtype, slots=SLOTS):
     def fn(q, kp, vp, tables, kb, lengths):
         return fa.flash_decode_paged_attention(q, kp, vp, tables,
@@ -124,10 +114,6 @@ CASES = {
     "flash_lse_b8_s1024": lambda: _train(8, 1024, True, lse=True),
     # the engines' KV pools are float32 today (models/gpt.py); bf16 is the
     # dtype queue 1 moves them to
-    "decode_s1024_f32": lambda: _decode(1024, F32),
-    "decode_s4096_f32": lambda: _decode(4096, F32),
-    "decode_s1024_bf16": lambda: _decode(1024, BF16),
-    "decode_s4096_bf16": lambda: _decode(4096, BF16),
     "paged_64blocks_f32": lambda: _paged(64, F32),
     "paged_64blocks_bf16": lambda: _paged(64, BF16),
     "paged_256blocks_f32": lambda: _paged(256, F32),

@@ -539,7 +539,6 @@ def test_failed_stream_leaves_a_record_too(gen_server):
 
 # -- kernels -----------------------------------------------------------------
 KERNELS = {
-    "flash_decode_attention": "flash_decode",
     "flash_decode_paged_attention": "flash_decode_paged",
     "mla_decode_paged_attention": "mla_decode_paged",
     "_flash_fwd_impl": "flash_fwd",
@@ -576,7 +575,7 @@ def test_each_pallas_call_passes_its_own_name(site):
     found = _pallas_call_names()
     assert found[site] == want
     every = [n for names in found.values() for n in names]
-    assert None not in every and len(set(every)) == len(every) == 6
+    assert None not in every and len(set(every)) == len(every) == 5
 
 
 def test_kernel_name_reaches_the_lowered_program():

@@ -9,8 +9,8 @@ how many tokens went out with a device call in flight.
 import threading
 import time
 
-import numpy as np
 import pytest
+from conftest import engine_free_oracle
 
 import paddle_tpu.fluid as fluid
 from paddle_tpu.fluid import profiler
@@ -22,7 +22,9 @@ from paddle_tpu.serving.batcher import ServerOverloadedError, ServingError
 MAX_LEN = 20
 BLOCK = 4
 KINDS = {
-    "contiguous": dict(prefill_buckets=[8, MAX_LEN]),
+    # windows between steps, kanana's regime; a chunk of one block so
+    # that the 5- and 7-token prompts below take two windows each
+    "chunked": dict(block_size=BLOCK, prefill_chunk=BLOCK),
     "paged": dict(block_size=BLOCK),
     "spec2": dict(block_size=BLOCK, spec_tokens=2),
 }
@@ -64,30 +66,7 @@ def engines(model):
 
 
 def _oracle(model, prompt, n, sampling=None):
-    """The full [1, MAX_LEN] forward once a token, nothing of the engine
-    in it: the argmax, or ``sample_token`` on one ``RandomState`` (one
-    uniform a pick) for a seeded request."""
-    ids = list(prompt)
-    rng = np.random.RandomState(sampling["seed"]) if sampling else None
-    pos_ids = np.arange(MAX_LEN).reshape(1, MAX_LEN, 1).astype("int64")
-    for _ in range(n):
-        cur = len(ids)
-        padded = np.zeros((1, MAX_LEN, 1), "int64")
-        padded[0, :cur, 0] = ids
-        (lv,) = model["exe"].run(
-            model["infer"], feed={
-                "ids": padded, "pos_ids": pos_ids,
-                "input_mask": (np.arange(MAX_LEN) < cur).astype(
-                    "float32").reshape(1, MAX_LEN, 1)},
-            fetch_list=[model["logits"]], scope=model["scope"])
-        row = np.asarray(lv)[0, cur - 1]
-        if sampling is None:
-            ids.append(int(row.argmax()))
-        else:
-            ids.append(sdecode.sample_token(
-                row, temperature=sampling["temperature"],
-                top_k=sampling["top_k"], rng=rng))
-    return ids[len(prompt):]
+    return engine_free_oracle(model, prompt, n, MAX_LEN, sampling)
 
 
 def _drain(stream, timeout=120):
@@ -140,6 +119,8 @@ def test_outputs_token_for_token(model, engines, kind, sampling):
     for p, s in zip(PROMPTS, streams):
         assert s.tokens(timeout=120) == _oracle(model, p, n, sampling)
         assert s.finish_reason == "length"
+    if kind == "chunked":
+        assert [s.admit_windows for s in streams] == [1, 2, 2]
 
 
 def test_pick_runs_once_a_token_in_slot_order(model, engines, monkeypatch):
@@ -191,7 +172,7 @@ def _ends_by_cancel(model, engines):
 def _ends_by_shed(model, engines):
     """A pool that holds one full-length stream and a block: two streams
     grow until the pool cannot cover both, and one is shed in
-    ``_build_paged_step`` with tokens already out."""
+    ``_build_step`` with tokens already out."""
     engine = _engine(model, "paged", slots=2,
                      pool_blocks=1 + MAX_LEN // BLOCK + 1).start()
     try:
@@ -250,7 +231,7 @@ def test_all_tokens_then_the_end(model, engines, ending):
 
 
 # -- (c) a caller that drives the ticks itself ----------------------------
-@pytest.mark.parametrize("kind", ["contiguous", "paged"])
+@pytest.mark.parametrize("kind", ["chunked", "paged"])
 def test_hand_driven_tick_publishes_before_it_returns(model, kind):
     engine = _engine(model, kind, slots=2).start(loop=False)
     try:

@@ -179,7 +179,7 @@ def run_generate_failover_trial(tmp, model_dir, report, failures, fast):
         # full prefill
         "FLAGS_decode_prefill_chunk": "8",
         "FLAGS_decode_prefix_cache_mb": "2",
-        "FLAGS_decode_prefix_block": "8",
+        "FLAGS_decode_block_size": "8",
         # the deterministic mid-stream fault: replica 0 SIGKILLs itself
         # after its 6th stream token hits the wire
         "FLAGS_chaos_die_after_tokens": "6",

@@ -151,7 +151,7 @@ def run_probe(fast=True, verbose=False):
         "FLAGS_serving_strict_compiles": "1",
         "FLAGS_decode_prefill_chunk": "8",
         "FLAGS_decode_prefix_cache_mb": "2",
-        "FLAGS_decode_prefix_block": "8",
+        "FLAGS_decode_block_size": "8",
         # replica 0 SIGKILLs itself after its 6th stream token — the
         # mid-stream chaos seam the merged trace must survive
         "FLAGS_chaos_die_after_tokens": "6",
