@@ -1384,7 +1384,8 @@ def flash_decode_paged_attention(q, k, v, tables, key_bias=None,
                                  name=None):
     """Decode-mode single-query fused attention THROUGH a block table:
     ``q`` [N, heads, 1, d_head] against the shared paged pool ``k``/``v``
-    [blocks, heads, block, d_head], with ``tables`` [N, max_blocks]
+    [blocks, 1, block, heads*d_head] (a token's keys are one row, its
+    heads side by side in ``q``'s order), with ``tables`` [N, max_blocks]
     int32 mapping each slot's logical blocks to physical pool blocks.
     ``key_bias`` [N, max_blocks*block] masks positions at/beyond each
     slot's live length (and any sink-block garbage). ``lengths`` [N]
@@ -1411,9 +1412,12 @@ def flash_decode_paged_attention(q, k, v, tables, key_bias=None,
 
 def kv_cache_write_paged(cache, new, tables, pos, name=None):
     """Block-table KV write: lands each slot's token window into ONE
-    shared [blocks, heads, block, d_head] pool through its fed
-    [slots, max_blocks] int32 block table. ``new`` [slots, heads, T,
-    d_head]; ``pos`` [slots] logical start positions — token j of slot
+    shared [blocks, r0, block, r1] pool through its fed
+    [slots, max_blocks] int32 block table. What a token's ``[r0, r1]``
+    row holds is the model's to say (``models/cache_kinds.py``: all the
+    heads' keys side by side, ``[1, hidden]``, for ``models/gpt.py``; one
+    latent row for ``models/deepseek.py``). ``new`` [slots, r0, T, r1];
+    ``pos`` [slots] logical start positions — token j of slot
     s goes to pool block ``tables[s, (pos[s]+j)//block]`` at offset
     ``(pos[s]+j)%block``. Tables and positions are runtime DATA; one
     compiled program serves every table layout at 0 recompiles.
@@ -1430,10 +1434,11 @@ def kv_cache_write_paged(cache, new, tables, pos, name=None):
 
 
 def kv_cache_gather_paged(cache, tables, name=None):
-    """Materialize each slot's logical [heads, max_blocks*block, d_head]
-    cache row by gathering pool blocks through its fed block table —
-    the read half of the paged step/window programs. Out
-    [slots, heads, max_blocks*block, d_head]; positions past a slot's
+    """Materialize each slot's logical [r0, max_blocks*block, r1]
+    cache row by gathering the [blocks, r0, block, r1] pool's blocks
+    through its fed block table — the read half of the paged
+    step/window programs. Out
+    [slots, r0, max_blocks*block, r1]; positions past a slot's
     live length carry whatever the mapped blocks hold and MUST be
     masked by the caller's additive key bias. Inference-only."""
     helper = LayerHelper("kv_cache_gather_paged", **locals())

@@ -882,8 +882,10 @@ def _per_shard(fn, args, in_dims, out_dims, mesh, lead):
     """``fn(*args)`` on each device's own block, under the
     ``_shard_axes`` result ``(mesh, lead)``. ``in_dims`` gives, per
     operand, how many of its leading dims are (batch, heads): 2 =
-    [B, N, ...], 1 = [B, ...], 0 = replicated; ``out_dims`` gives
-    (that count, rank) per result. No mesh: a plain call."""
+    [B, N, ...], 1 = [B, ...], 0 = replicated, -1 = the heads lie side
+    by side on its LAST dim (a paged pool's row) and nothing else is
+    split; ``out_dims`` gives (that count, rank) per result. No mesh: a
+    plain call."""
     if mesh is None:
         return fn(*args)
     from jax.sharding import PartitionSpec as P
@@ -891,6 +893,8 @@ def _per_shard(fn, args, in_dims, out_dims, mesh, lead):
     from ...parallel.mesh import shard_map
 
     def spec(ndims, rank):
+        if ndims < 0:
+            return P(*((None,) * (rank - 1) + (lead[1],)))
         return P(*(lead[:ndims] + (None,) * (rank - ndims)))
 
     out_specs = tuple(spec(n, rank) for n, rank in out_dims)
@@ -998,7 +1002,8 @@ def _flash_decode_paged_attention(ctx, op_):
     """Paged decode-mode attention (kernels/flash_attention.py
     flash_decode_paged_attention): one live token per slot reads K/V
     THROUGH a fed [slots, max_blocks] block table over the shared
-    [blocks, heads, block, d_head] pool — on TPU the table rides scalar
+    [blocks, 1, block, heads*d_head] pool (a token's keys one row, its
+    heads side by side) — on TPU the table rides scalar
     prefetch so the kernel's DMA chases the indirection without ever
     materializing the logical rows. The optional ``Lengths`` [slots]
     (live keys a slot) rides beside it: table entries past a slot's
@@ -1007,8 +1012,8 @@ def _flash_decode_paged_attention(ctx, op_):
     from ...kernels.flash_attention import flash_decode_paged_attention
 
     q = ctx.in1(op_, "Q")
-    k = ctx.in1(op_, "K")
-    v = ctx.in1(op_, "V")
+    k, v = (pool.reshape(pool.shape[0], pool.shape[2], pool.shape[3])
+            for pool in (ctx.in1(op_, "K"), ctx.in1(op_, "V")))
     tables = ctx.in1(op_, "Tables")
     kb_names = op_.inputs.get("KeyBias") or []
     key_bias = ctx.in1(op_, "KeyBias") if kb_names else None
@@ -1019,12 +1024,13 @@ def _flash_decode_paged_attention(ctx, op_):
     B, N = q.shape[:2]
     key_bias, kb_dims = _key_bias_dims(key_bias, B, N)
     # heads only: the pool's block dim belongs to no slot, so slots (and
-    # with them tables, lengths and a per-slot mask) stay whole
+    # with them tables, lengths and a per-slot mask) stay whole; a shard
+    # holds its heads' lanes of every pool row
     ctx.out(op_, "Out", _per_shard(
         lambda q, k, v, tables, kb, lengths: flash_decode_paged_attention(
             q, k, v, tables, key_bias=kb, lengths=lengths,
             scale=float(scale) if scale else None, interpret=interpret),
-        (q, k, v, tables, key_bias, lengths), (2, 2, 2, 0, kb_dims, 0),
+        (q, k, v, tables, key_bias, lengths), (2, -1, -1, 0, kb_dims, 0),
         ((2, 4),), *_shard_axes(B, N, interpret, batch_axis=False),
     ))
 
@@ -1036,9 +1042,11 @@ def _kv_cache_write_paged_infer(op_, block):
 
 @op("kv_cache_write_paged", infer_shape=_kv_cache_write_paged_infer)
 def _kv_cache_write_paged(ctx, op_):
-    """Block-table KV scatter. ``Cache`` is ONE shared [blocks, heads,
-    block, d_head] pool for every slot AND the prefix index; ``New`` carries
-    each slot's token window [slots, heads, T, d_head]; ``Tables``
+    """Block-table KV scatter. ``Cache`` is ONE shared [blocks, r0,
+    block, r1] pool for every slot AND the prefix index, a token's row
+    ``[r0, r1]`` being what the model says it is (``cache_kinds.py``:
+    ``[1, hidden]`` for GPT, ``[1, 640]`` latent); ``New`` carries
+    each slot's token window [slots, r0, T, r1]; ``Tables``
     [slots, max_blocks] int32 maps a slot's logical block number to a
     physical pool block; ``Pos`` [slots] is each slot's logical start
     position. Token j of slot s lands at pool block
@@ -1082,7 +1090,8 @@ def _kv_cache_gather_paged_infer(op_, block):
 def _kv_cache_gather_paged(ctx, op_):
     """Materialize each slot's logical cache row THROUGH its block
     table: Out[s] = concat(pool[tables[s, b]] for b) reshaped to
-    [slots, heads, max_blocks*block, d_head] — the read half of the
+    [slots, r0, max_blocks*block, r1] (``[r0, r1]`` a token's row, as in
+    ``kv_cache_write_paged``) — the read half of the
     paged step/window programs. Tables are runtime data; O(gathered
     bytes). Positions beyond a slot's live length read whatever the
     mapped blocks hold (sink garbage included) — the caller's additive

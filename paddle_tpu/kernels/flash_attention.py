@@ -407,34 +407,43 @@ def _held_blocks(tables, lengths, block, pages):
 
 
 def _decode_paged_kernel(held_ref, lengths_ref, q_ref, *refs, scale,
-                         pages):
+                         pages, d_head, per_head):
     """Paged decode step, one (slot, group of ``pages`` logical blocks)
-    program over ALL heads of the slot. The pool rides in ``pages`` times
-    for K and ``pages`` times for V; operand j's index map looks up the
-    slot's block table (scalar prefetch, as ``_held_blocks`` laid it out)
-    for logical block i*pages + j, so the pipeline's own DMAs chase the
-    indirection and each pulls the [heads, block, d_head] of one physical
-    block in one piece. The slot's live length rides beside the table: a
-    logical block past the last live one is not fetched (its entry names
-    a block the operand already holds) and not computed — a program with
-    no live block does nothing, and inside the last live program the dead
-    columns are masked to ``_NEG`` before the key bias could matter.
-    Inside the live blocks the per-slot key bias carries ALL masking, the
-    last block's unfilled tail included (positions at or beyond the
-    slot's length ride in at -1e4; no causal flag, no dropout, no lse:
-    nothing differentiates through decode). Online softmax state (m, l, acc per head) lives in
-    VMEM scratch across a slot's programs and is touched once per
-    program; the output block is written on the slot's last program. The
-    bias rides as the slot's whole [programs, pages*block] table (a block
-    whose trailing dims equal the array's, which the Mosaic (8, 128) rule
-    admits where a lone (1, block) strip is refused); the program picks
-    its own row."""
+    program over ALL heads of the slot. A token's keys (and values) are
+    ONE row of the pool, its heads side by side on the lanes, so the
+    heads are the ROWS of one product, as in ``_mla_decode_paged_kernel``:
+    the block-diagonal query [heads, hidden] (row h holds ``q_h`` on head
+    h's lanes, zeros elsewhere) against the program's keys [keys, hidden]
+    gives the [heads, keys] scores, and ``p . V`` [heads, hidden] holds
+    head h's answer on row h's own ``d_head`` lanes (the other lanes of a
+    row mix heads and are dropped in ``_emit``). The zeros add nothing to
+    any sum. The pool rides in ``pages`` times for K and ``pages`` times
+    for V; operand j's index map looks up the slot's block table (scalar
+    prefetch, as ``_held_blocks`` laid it out) for logical block
+    i*pages + j, so the pipeline's own DMAs chase the indirection and
+    each pulls the [block, hidden] of one physical block in one piece.
+    The slot's live length rides beside the table: a logical block past
+    the last live one is not fetched (its entry names a block the operand
+    already holds) and not computed — a program with no live block does
+    nothing, and inside the last live program the dead columns are masked
+    to ``_NEG`` before the key bias could matter. Inside the live blocks
+    the key bias carries ALL masking, the last block's unfilled tail
+    included (positions at or beyond the slot's length ride in at -1e4;
+    no causal flag, no dropout, no lse: nothing differentiates through
+    decode). Online softmax state (m, l, acc a head) lives in VMEM scratch
+    across a slot's programs and is touched once per program; the output
+    row is written on the slot's last program. A per-slot bias rides as
+    the slot's whole [programs, pages*block] table (a block whose
+    trailing dims equal the array's, which the Mosaic (8, 128) rule
+    admits where a lone (1, block) strip is refused) and the program
+    picks its own row; a per-head bias (``per_head``) as
+    [programs, heads, pages*block], one row a head."""
     from jax.experimental import pallas as pl
 
     k_refs, v_refs = refs[:pages], refs[pages:2 * pages]
     kb_ref, o_ref, m_ref, l_ref, acc_ref = refs[2 * pages:]
     b, i = pl.program_id(0), pl.program_id(1)
-    block = k_refs[0].shape[2]
+    block = k_refs[0].shape[1]
     keys = pages * block
 
     @pl.when(i == 0)
@@ -448,42 +457,52 @@ def _decode_paged_kernel(held_ref, lengths_ref, q_ref, *refs, scale,
 
     @pl.when(live > 0)
     def _attend():
-        # heads are the batch dimension of both products: [N, BQ, D] x
-        # [N, keys, D] -> [N, BQ, keys], operands in their INPUT dtype and
-        # the accumulator fp32, as in ``_scores``
-        kblk = jnp.concatenate([r[0] for r in k_refs], axis=1)
-        vblk = jnp.concatenate([r[0] for r in v_refs], axis=1)
+        # operands in their INPUT dtype, the accumulator fp32, as in
+        # ``_scores``
+        kblk = (k_refs[0][0] if pages == 1 else
+                jnp.concatenate([r[0] for r in k_refs], axis=0))
+        vblk = (v_refs[0][0] if pages == 1 else
+                jnp.concatenate([r[0] for r in v_refs], axis=0))
         dead = jax.lax.broadcasted_iota(
-            jnp.int32, (1, 1, keys), 2) >= live * block
+            jnp.int32, (1, keys), 1) >= live * block
         s = jax.lax.dot_general(
-            q_ref[0], kblk, (((2,), (2,)), ((0,), (0,))),
+            q_ref[0], kblk, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
-        s = s * scale + jnp.where(dead, _NEG, kb_ref[:, pl.ds(i, 1), :])
+        bias = kb_ref[0, i] if per_head else kb_ref[0, pl.ds(i, 1), :]
+        s = s * scale + jnp.where(dead, _NEG, bias)
         m = m_ref[...]
         m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
         alpha = jnp.exp(m - m_new)
         p = jnp.exp(s - m_new)
         l_ref[...] = l_ref[...] * alpha + p.sum(axis=-1, keepdims=True)
         acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            p.astype(vblk.dtype), vblk, (((2,), (1,)), ((0,), (0,))),
+            p.astype(vblk.dtype), vblk, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
         m_ref[...] = m_new
 
     @pl.when(i == pl.num_programs(1) - 1)
     def _emit():
-        o_ref[0] = (acc_ref[...]
-                    / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
+        # row h keeps its own head's lanes; the rows then add up to the
+        # merged-heads [1, hidden] row
+        out = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+        first = jax.lax.broadcasted_iota(jnp.int32, out.shape, 0) * d_head
+        lane = jax.lax.broadcasted_iota(jnp.int32, out.shape, 1)
+        own = (lane >= first) & (lane < first + d_head)
+        o_ref[0] = jnp.where(own, out, 0.0).sum(
+            axis=0, keepdims=True).astype(o_ref.dtype)
 
 
 def flash_decode_paged_attention(q, k_pool, v_pool, tables, key_bias=None,
                                  scale=None, interpret=None, lengths=None):
     """Decode-mode attention reading K/V THROUGH a block table: ``q``
     [B, N, 1, D] (one live token per slot) against a shared paged pool
-    ``k_pool``/``v_pool`` [blocks, N, block, D], with ``tables``
-    [B, max_blocks] int32 mapping each slot's logical block number to a
-    physical pool block. ``key_bias`` [B, S] (S = max_blocks*block)
+    ``k_pool``/``v_pool`` [blocks, block, N*D] — a token's keys are one
+    row, head h on lanes h*D .. (h+1)*D, the order ``q``'s heads have —
+    with ``tables`` [B, max_blocks] int32 mapping each slot's logical
+    block number to a physical pool block. -> [B, N, 1, D]. ``key_bias``
+    [B, S] (S = max_blocks*block; [B*N, S] for one mask a head)
     additively masks positions at/beyond the slot's live length — which
     also covers any garbage the mapped blocks hold (the serving layer
     parks idle table entries on a sink block). ``lengths`` [B] int32,
@@ -503,7 +522,7 @@ def flash_decode_paged_attention(q, k_pool, v_pool, tables, key_bias=None,
     from jax.experimental.pallas import tpu as pltpu
 
     B, N, Sq, D = q.shape
-    blocks, Np, blk, Dp = k_pool.shape
+    blocks, blk, H = k_pool.shape
     MB = tables.shape[1]
     S = MB * blk
     if Sq != 1:
@@ -511,10 +530,10 @@ def flash_decode_paged_attention(q, k_pool, v_pool, tables, key_bias=None,
             "flash_decode_paged_attention is the single-query path, "
             "got Sq=%d" % Sq
         )
-    if (Np, Dp) != (N, D):
+    if H != N * D or v_pool.shape != k_pool.shape:
         raise ValueError(
-            "pool geometry %r does not match q heads/depth (%d, %d)"
-            % (k_pool.shape, N, D)
+            "pool geometry %r / %r does not match q heads x depth (%d x %d)"
+            % (k_pool.shape, v_pool.shape, N, D)
         )
     scale = scale if scale is not None else 1.0 / float(np.sqrt(D))
     kb = _normalize_key_bias(key_bias, B, N, S)
@@ -532,13 +551,9 @@ def flash_decode_paged_attention(q, k_pool, v_pool, tables, key_bias=None,
             dead = jnp.repeat(
                 jnp.arange(MB)[None, :]
                 >= _live_blocks(lengths, blk)[:, None], blk, axis=1)
-        rows_k = k_pool[tables].transpose(0, 2, 1, 3, 4).reshape(
-            B, N, S, D
-        )
-        rows_v = v_pool[tables].transpose(0, 2, 1, 3, 4).reshape(
-            B, N, S, D
-        )
-        s = jnp.einsum("bnqd,bnkd->bnqk", q, rows_k).astype(
+        rows_k = k_pool[tables].reshape(B, S, N, D)
+        rows_v = v_pool[tables].reshape(B, S, N, D)
+        s = jnp.einsum("bnqd,bknd->bnqk", q, rows_k).astype(
             jnp.float32
         ) * scale
         if kb is not None:
@@ -546,66 +561,77 @@ def flash_decode_paged_attention(q, k_pool, v_pool, tables, key_bias=None,
         if dead is not None:
             s = jnp.where(dead[:, None, None, :], _NEG, s)
         p = jax.nn.softmax(s, axis=-1)
-        return jnp.einsum("bnqk,bnkd->bnqd", p.astype(q.dtype), rows_v)
+        return jnp.einsum("bnqk,bknd->bnqd", p.astype(q.dtype), rows_v)
     P = min(MB, -(-PAGED_KEYS // blk))         # logical blocks a program
     programs = -(-MB // P)
     if lengths is None:
         lengths = jnp.full((B,), S, jnp.int32)
-    # [G, programs, P*block] with G = B (one mask per slot, what the
-    # engine feeds) or B*N (per head): a per-slot mask is NOT expanded
-    # over heads — its block index ignores the program, so it is fetched
-    # once a slot
+    Np = _round_up(N, 8)                       # Mosaic sublane minimum
+    # one mask per slot (what the engine feeds) rides as
+    # [B, programs, P*block] and is NOT expanded over heads — its block
+    # index ignores the program, so it is fetched once a slot; one mask a
+    # head as [B, programs, Np, P*block]
     if key_bias is not None and key_bias.size == B * S:
         kb = key_bias.astype(jnp.float32)
     elif kb is None:
         kb = jnp.zeros((B, S), jnp.float32)
     per_head = kb.size != B * S
-    kb = kb.reshape(-1, S)
-    kb = jnp.pad(kb, ((0, 0), (0, programs * P * blk - S)))
-    kb = kb.reshape(-1, programs, P * blk)
-    BQ = _round_up(Sq, 8)                      # Mosaic sublane minimum
-    qp = jnp.pad(q, ((0, 0), (0, 0), (0, BQ - Sq), (0, 0)))
-    kernel = functools.partial(_decode_paged_kernel, scale=scale, pages=P)
+    kb = kb.reshape(B, -1, S)
+    kb = jnp.pad(kb, ((0, 0), (0, 0), (0, programs * P * blk - S)))
+    kb = kb.reshape(B, -1, programs, P * blk)
+    if per_head:
+        kb = jnp.pad(kb.transpose(0, 2, 1, 3),
+                     ((0, 0), (0, 0), (0, Np - N), (0, 0)))
+    else:
+        kb = kb.reshape(B, programs, P * blk)
+    kb_spec = pl.BlockSpec(
+        (1,) + kb.shape[1:],
+        lambda b, i, held, lens: (b,) + (0,) * (kb.ndim - 1),
+        memory_space=pltpu.VMEM)
+    # block-diagonal query: row h holds q_h on head h's lanes, so one
+    # [Np, H] x [keys, H] product scores every head against its own keys
+    qd = (q[:, :, 0, None, :]
+          * jnp.eye(N, dtype=q.dtype)[None, :, :, None]).reshape(B, N, H)
+    qd = jnp.pad(qd, ((0, 0), (0, Np - N), (0, 0))).astype(k_pool.dtype)
+    kernel = functools.partial(_decode_paged_kernel, scale=scale, pages=P,
+                               d_head=D, per_head=per_head)
 
     def pool_spec(j):
         # index maps receive the grid indices first, then the prefetched
         # scalar refs: operand j of program i pulls the physical block the
         # held table names for it
         return pl.BlockSpec(
-            (1, N, blk, D),
-            lambda b, i, held, lens: (held[b, i * P + j], 0, 0, 0),
+            (1, blk, H),
+            lambda b, i, held, lens: (held[b, i * P + j], 0, 0),
             memory_space=pltpu.VMEM,
         )
 
     pool_specs = [pool_spec(j) for j in range(P)]
-    slot = lambda b, i, held, lens: (b, 0, 0, 0)  # noqa: E731
+    slot = lambda b, i, held, lens: (b, 0, 0)  # noqa: E731
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(B, programs),
         in_specs=[
-            pl.BlockSpec((1, N, BQ, D), slot, memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, Np, H), slot, memory_space=pltpu.VMEM),
             *pool_specs, *pool_specs,
-            pl.BlockSpec((N if per_head else 1, programs, P * blk),
-                         lambda b, i, held, lens: (b, 0, 0),
-                         memory_space=pltpu.VMEM),
+            kb_spec,
         ],
-        out_specs=pl.BlockSpec((1, N, BQ, D), slot,
-                               memory_space=pltpu.VMEM),
+        out_specs=pl.BlockSpec((1, 1, H), slot, memory_space=pltpu.VMEM),
         scratch_shapes=[
-            pltpu.VMEM((N, BQ, 1), jnp.float32),
-            pltpu.VMEM((N, BQ, 1), jnp.float32),
-            pltpu.VMEM((N, BQ, D), jnp.float32),
+            pltpu.VMEM((Np, 1), jnp.float32),
+            pltpu.VMEM((Np, 1), jnp.float32),
+            pltpu.VMEM((Np, H), jnp.float32),
         ],
     )
     out = pl.pallas_call(
         kernel,
         name="flash_decode_paged",
-        out_shape=jax.ShapeDtypeStruct((B, N, BQ, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, 1, H), q.dtype),
         grid_spec=grid_spec,
         interpret=bool(interpret),
-    )(_held_blocks(tables, lengths, blk, P), lengths, qp,
+    )(_held_blocks(tables, lengths, blk, P), lengths, qd,
       *([k_pool] * P), *([v_pool] * P), kb)
-    return out[:, :, :1, :]
+    return out.reshape(B, N, 1, D)
 
 
 def _mla_decode_paged_kernel(held_ref, lengths_ref, q_ref, *refs, scale,

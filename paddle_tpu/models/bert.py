@@ -120,15 +120,20 @@ def flash_wanted(cfg, seq_len=None):
 
 
 def _apply_kv_cache(cache, k, v, cfg):
-    """Write this call's split-head K/V into the paged pool described by
-    ``cache`` (see ``multi_head_attention``) via ``kv_cache_write_paged``
-    — O(written bytes), with the block table and the write position as
-    runtime DATA, so one compiled program covers every admission
-    pattern. Returns (k, v) for the attention that follows."""
+    """Write this call's K/V projections, ``[N, T, hidden]`` as they
+    leave ``fc`` (heads side by side: a token's row of the pool), into
+    the paged pool described by ``cache`` (see ``multi_head_attention``)
+    via ``kv_cache_write_paged`` — O(written bytes), with the block table
+    and the write position as runtime DATA, so one compiled program
+    covers every admission pattern. Returns (k, v) for the attention that
+    follows: the split-head ``[1, heads, max_len, d_head]`` logical row
+    for a window, the updated pool vars for a step."""
     k_upd = fluid.layers.kv_cache_write_paged(
-        cache["k"], k, cache["tables"], cache["pos"])
+        cache["k"], fluid.layers.unsqueeze(k, axes=[1]), cache["tables"],
+        cache["pos"])
     v_upd = fluid.layers.kv_cache_write_paged(
-        cache["v"], v, cache["tables"], cache["pos"])
+        cache["v"], fluid.layers.unsqueeze(v, axes=[1]), cache["tables"],
+        cache["pos"])
     if cache["mode"] == "paged_window":
         # batch-1 window through the slot's block TABLE: the window's
         # K/V lands at logical positions pos..pos+T-1, scattered into
@@ -138,14 +143,24 @@ def _apply_kv_cache(cache, k, v, cfg):
         # the window's queries. Covers monolithic prefill (pos 0) and
         # chunked resume alike: offset, table, and positions are all
         # runtime data, so ONE program per bucket serves both.
-        return (fluid.layers.kv_cache_gather_paged(k_upd, cache["tables"]),
-                fluid.layers.kv_cache_gather_paged(v_upd, cache["tables"]))
+        return (_gather_heads(k_upd, cache["tables"], cfg),
+                _gather_heads(v_upd, cache["tables"], cfg))
     # paged_step, the fused multi-slot step (T=1 decode / T=k speculative
     # verify): each slot's T-token window scatters through its table
     # row; the attention branch reads the pool back through the tables
     # (paged flash kernel or gather+dense), so just return the updated
     # pool vars.
     return k_upd, v_upd
+
+
+def _gather_heads(pool, tables, cfg):
+    """Each slot's logical row read THROUGH its block table, heads split
+    after the gather: [S, 1, max_len, hidden] -> [S, heads, max_len,
+    d_head]."""
+    rows = fluid.layers.kv_cache_gather_paged(pool, tables)
+    rows = fluid.layers.reshape(
+        rows, shape=[0, -1, cfg.num_heads, cfg.hidden_size // cfg.num_heads])
+    return fluid.layers.transpose(rows, perm=[0, 2, 1, 3])
 
 
 def multi_head_attention(q_in, kv_in, attn_bias, cfg, name, key_bias=None,
@@ -166,7 +181,8 @@ def multi_head_attention(q_in, kv_in, attn_bias, cfg, name, key_bias=None,
 
     ``cache``: KV-cache plumbing for autoregressive serving (None for
     training/encoder use). A dict with ``k``/``v`` — persistable
-    [blocks, heads, block, d_head] pool vars — the fed block ``tables``,
+    [blocks, 1, block, hidden] pool vars, a token's keys (values) one
+    row with the heads side by side — the fed block ``tables``,
     the write position ``pos``, plus ``mode``:
 
     - ``"paged_window"``: one prompt window (batch 1) lands through its
@@ -193,10 +209,13 @@ def multi_head_attention(q_in, kv_in, attn_bias, cfg, name, key_bias=None,
         return fluid.layers.transpose(x, perm=[0, 2, 1, 3])
 
     q = _split_heads(_proj(q_in, "q"))
-    k = _split_heads(_proj(kv_in, "k"))
-    v = _split_heads(_proj(kv_in, "v"))
-    if cache is not None:
-        k, v = _apply_kv_cache(cache, k, v, cfg)
+    if cache is None:
+        k = _split_heads(_proj(kv_in, "k"))
+        v = _split_heads(_proj(kv_in, "v"))
+    else:
+        # the pool keeps a token's row unsplit: heads part after the read
+        k, v = _apply_kv_cache(
+            cache, _proj(kv_in, "k"), _proj(kv_in, "v"), cfg)
     if cache is not None and cache["mode"] == "paged_step":
         # unified paged step/verify: q [slots, heads, T, d_head] (T=1
         # decode, T=k speculative verify) against each slot's logical
@@ -223,10 +242,8 @@ def multi_head_attention(q_in, kv_in, attn_bias, cfg, name, key_bias=None,
                 interpret=getattr(cfg, "flash_interpret", False),
             )
         else:
-            rows_k = fluid.layers.kv_cache_gather_paged(
-                cache["k"], cache["tables"])
-            rows_v = fluid.layers.kv_cache_gather_paged(
-                cache["v"], cache["tables"])
+            rows_k = _gather_heads(cache["k"], cache["tables"], cfg)
+            rows_v = _gather_heads(cache["v"], cache["tables"], cfg)
             scores = fluid.layers.matmul(
                 q, rows_k, transpose_y=True, alpha=scale_
             )

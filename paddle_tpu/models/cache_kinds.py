@@ -3,8 +3,9 @@
 ``serving/decode.py`` sizes its block allocator, names and zeroes the
 pools, and copies blocks without knowing what a token's cache row holds:
 a model module's ``cache_kinds(cfg)`` gives, per layer, the pools that
-layer keeps (K and V of ``[heads, d_head]`` for ``models/gpt.py``; one
-latent pool for ``models/deepseek.py``).
+layer keeps (K and V for ``models/gpt.py``, a token's row the keys of
+all its heads side by side, ``[1, hidden]``; one latent pool for
+``models/deepseek.py``).
 """
 
 import collections
@@ -15,9 +16,13 @@ import paddle_tpu.fluid as fluid
 
 
 class CachePool(collections.namedtuple("CachePool", "prefix row dtype")):
-    """One paged pool of one layer. ``row``: the two dims a token holds
-    (``[heads, d_head]``; ``[1, width]`` for one shared row); the pool
-    var is ``[blocks, row[0], block, row[1]]`` of ``dtype``."""
+    """One paged pool of one layer. ``row``: the two dims a token holds,
+    ``[r0, r1]``; the pool var is ``[blocks, r0, block, r1]`` of
+    ``dtype``. Both served models keep ``[1, width]``, one row a token:
+    ``r1`` lies on the lanes, and a width that is a multiple of 128 is
+    the device's own tiling, so scatter, gather and the paged kernels
+    take the pool as it lies (a ``[heads, 64]`` row cost three copies of
+    every pool a step)."""
 
     __slots__ = ()
 

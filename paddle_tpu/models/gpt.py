@@ -20,7 +20,7 @@ import numpy as np
 import paddle_tpu.fluid as fluid
 
 from . import bert as _bert
-from .cache_kinds import CachePool
+from . import cache_kinds as _kinds
 
 
 class GPTConfig(object):
@@ -229,45 +229,42 @@ def build_gpt_infer(cfg, seq_len):
 # ---------------------------------------------------------------------------
 
 
+def cache_kinds(cfg):
+    """Per layer, the pools the decode runtime keeps (``cache_kinds.py``):
+    K and V, float32. A token's keys (and values) are ONE row,
+    ``[1, heads * d_head]``: the heads side by side on the lanes, in the
+    order ``multi_head_attention`` splits them. A row whose width is a
+    multiple of 128 lanes lies in the device's own tiling, so the scatter,
+    the gather and the paged kernel take the pool as it lies; with the
+    heads as a dim of their own (64-lane rows) every program copied every
+    pool whole, three times a step."""
+    row = [1, cfg.hidden_size]
+    return [
+        (_kinds.CachePool("gpt_paged_k_%d" % i, row, "float32"),
+         _kinds.CachePool("gpt_paged_v_%d" % i, row, "float32"))
+        for i in range(cfg.num_layers)
+    ]
+
+
 def paged_pool_names(cfg, blocks, block):
     """Per-layer (K, V) paged-pool var names. Pool geometry is part of
     the name: two sessions sharing one scope (a 1-slot greedy_generate
     session next to a serving engine) must never read each other's
     differently-shaped pools."""
-    return [
-        ("gpt_paged_k_%d_n%dx%d" % (i, blocks, block),
-         "gpt_paged_v_%d_n%dx%d" % (i, blocks, block))
-        for i in range(cfg.num_layers)
-    ]
+    return [tuple(p.name(blocks, block) for p in layer)
+            for layer in cache_kinds(cfg)]
 
 
 def paged_pool_shape(cfg, blocks, block):
-    return [
-        int(blocks), cfg.num_heads, int(block),
-        cfg.hidden_size // cfg.num_heads,
-    ]
+    """``[blocks, 1, block, hidden]``: every pool of ``cache_kinds``."""
+    return cache_kinds(cfg)[0][0].shape(blocks, block)
 
 
 def paged_block_bytes(cfg, block):
     """Device bytes one pool block costs across all layers (K + V,
     fp32) — what sizes the allocator and the HBM-footprint accounting
     (a slot costs ``ceil(len/block)`` of these, not ``max_len``)."""
-    d_head = cfg.hidden_size // cfg.num_heads
-    return cfg.num_layers * 2 * cfg.num_heads * int(block) * d_head * 4
-
-
-def _declare_paged_pool_vars(cfg, blocks, block):
-    main_block = fluid.default_main_program().global_block()
-    shape = paged_pool_shape(cfg, blocks, block)
-    return [
-        tuple(
-            main_block.create_var(
-                name=n, shape=shape, dtype="float32", persistable=True
-            )
-            for n in names
-        )
-        for names in paged_pool_names(cfg, blocks, block)
-    ]
+    return _kinds.bytes_per_token(cache_kinds(cfg)) * int(block)
 
 
 def build_gpt_paged_window(cfg, blocks, block, max_blocks, seq_len):
@@ -308,7 +305,8 @@ def build_gpt_paged_window(cfg, blocks, block, max_blocks, seq_len):
         )
         kv_cache = {
             "mode": "paged_window",
-            "caches": _declare_paged_pool_vars(cfg, blocks, block),
+            "caches": _kinds.declare_pools(
+                cache_kinds(cfg), blocks, block),
             "tables": table,
             "pos": window_pos,
             "resume_bias": resume_bias,
@@ -367,7 +365,8 @@ def build_gpt_paged_step(cfg, slots, blocks, block, max_blocks, step_w=1):
         )
         kv_cache = {
             "mode": "paged_step",
-            "caches": _declare_paged_pool_vars(cfg, blocks, block),
+            "caches": _kinds.declare_pools(
+                cache_kinds(cfg), blocks, block),
             "tables": tables,
             "pos": write_pos,
             "step_bias": step_bias,
@@ -395,24 +394,12 @@ def build_gpt_paged_block_copy(cfg, blocks, block, npairs):
     with fluid.program_guard(main, startup):
         src = fluid.layers.data(name="src", shape=[npairs], dtype="int64")
         dst = fluid.layers.data(name="dst", shape=[npairs], dtype="int64")
-        for pk, pv in _declare_paged_pool_vars(cfg, blocks, block):
+        for pk, pv in _kinds.declare_pools(
+                cache_kinds(cfg), blocks, block):
             fluid.layers.kv_cache_block_copy(pk, src, dst)
             fluid.layers.kv_cache_block_copy(pv, src, dst)
         ok = fluid.layers.fill_constant(shape=[1], dtype="int32", value=1)
     return main, startup, ["src", "dst"], ok
-
-
-def cache_kinds(cfg):
-    """Per layer, the pools the decode runtime keeps (``cache_kinds.py``):
-    K and V, ``[heads, d_head]`` float32 a token — the names, shapes and
-    bytes of ``paged_pool_names`` / ``paged_pool_shape`` /
-    ``paged_block_bytes``."""
-    row = [cfg.num_heads, cfg.hidden_size // cfg.num_heads]
-    return [
-        (CachePool("gpt_paged_k_%d" % i, row, "float32"),
-         CachePool("gpt_paged_v_%d" % i, row, "float32"))
-        for i in range(cfg.num_layers)
-    ]
 
 
 # what ``serving/decode.py`` asks a served model's module for, under the

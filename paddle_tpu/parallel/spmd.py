@@ -98,11 +98,11 @@ TP_RULES = tuple(
         (r".*_ln\d+\.(w_0|b_0)$", ()),
         (r".*emb_ln\.(w_0|b_0)$", ()),
         (r".*(pooler|cls)\.(w_0|b_0)$", ()),
-        # KV geometry is [slots|blocks, heads, len, d_head] for the
-        # contiguous caches, the paged pools, AND the prefix store:
-        # heads-partition dim 1, replicate addressing (block tables /
-        # slot indices ride the feed, replicated)
-        (r"gpt_(cache|paged|prefix)_[kv]_.*", (None, MODEL_AXIS, None, None)),
+        # the paged KV pools are [blocks, 1, block, hidden], a token's
+        # heads side by side on the last dim: a shard holds its heads'
+        # hidden / tp lanes of every row; addressing (block tables ride
+        # the feed) is replicated
+        (r"gpt_paged_[kv]_.*", (None, None, None, MODEL_AXIS)),
     )
 )
 

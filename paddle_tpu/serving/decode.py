@@ -6,8 +6,10 @@ forwards; a GPT completion served that way recomputes the full
 batch 1. This module replaces that with the production decode shape.
 
   cache    ONE pool per layer of ``block_size``-token blocks
-           ([blocks, heads, block, d_head] persistable scope vars,
-           device-resident between steps), addressed through per-slot
+           ([blocks, r0, block, r1] persistable scope vars, a token's
+           row [r0, r1] what the model's ``cache_kinds`` says: GPT's
+           keys of all heads side by side, [1, hidden]; device-resident
+           between steps), addressed through per-slot
            BLOCK TABLES that ride every program as fed data. A slot holds
            ceil(len/block) blocks, not a max_len row; ``BlockAllocator``
            hands them out by refcount.
@@ -444,7 +446,7 @@ class DecodeSession(object):
         # tensor-parallel serving (parallel/spmd.py): tp > 1 runs every
         # session program through the GSPMD mesh path over a
         # {"model": tp} mesh — weights Megatron column/row-sharded, KV
-        # pools/stores heads-partitioned on dim 1, slot indices and
+        # pools split by heads (each row's last dim), slot indices and
         # block tables replicated. The host-side runtime (slot
         # management, block tables, prefix index) is unchanged: only
         # placement differs, and every device step stays ONE
@@ -2462,6 +2464,13 @@ class DecodeEngine(object):
             cached += bs
             prev = key
         return cached
+
+    def block_row_shape(self):
+        """[r0, block, r1]: one block of a pool as it lies on the device
+        (``models/cache_kinds.py``) — the geometry exported chain blocks
+        are advertised under, and the one a pulled blob must name."""
+        pool = self.session.model.cache_kinds(self.session.cfg)[0][0]
+        return pool.shape(1, self.block_size)[1:]
 
     def offer_blocks(self, entries):
         """Inject chain blocks pulled from a prefill-role peer

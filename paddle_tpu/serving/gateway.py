@@ -1248,9 +1248,12 @@ def _make_handler(gw):
                 doc = json.loads(raw.decode("utf-8"))
                 if int(doc.get("block") or 0) != bs:
                     raise ServingError("kv pull block-size mismatch")
-                cfg = eng.session.cfg
-                row_shape = [cfg.num_heads, bs,
-                             cfg.hidden_size // cfg.num_heads]
+                # a block of the pool as it lies here; a peer whose
+                # rows are laid out otherwise (or that does not say) is
+                # refused, never re-read under this layout
+                row_shape = eng.block_row_shape()
+                if doc.get("row_shape") != row_shape:
+                    raise ServingError("kv pull row-layout mismatch")
                 entries = _kv_tier.decode_entries(
                     doc.get("blocks") or [], row_shape
                 )
@@ -1304,6 +1307,7 @@ def _make_handler(gw):
                     entries = eng.request_export(prompt, timeout=5.0) or []
                 self._send_json(200, {
                     "block": bs,
+                    "row_shape": eng.block_row_shape(),
                     "count": len(entries),
                     "served_ms": round((time.monotonic() - t0) * 1e3, 3),
                     "blocks": _kv_tier.encode_entries(entries),

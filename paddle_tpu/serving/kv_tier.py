@@ -109,8 +109,9 @@ class _HostEntry(object):
         self.prev = prev
         self.tokens = tuple(int(t) for t in tokens)
         # payload: [(k_row, v_row)] per layer, each a float32
-        # [heads, block, d_head] HOST array — the exact bytes the pool
-        # row held on device
+        # [r0, block, r1] HOST array ([1, block, hidden] for GPT: a
+        # token's heads side by side) — the exact bytes the pool block
+        # held on device
         self.payload = payload
         self.nbytes = sum(k.nbytes + v.nbytes for k, v in payload)
 
@@ -321,8 +322,10 @@ def encode_entries(entries):
 
 def decode_entries(blob, row_shape):
     """Inverse of ``encode_entries``: returns [(key, prev, tokens,
-    payload)] with every array reshaped to ``row_shape``
-    ([heads, block, d_head]) and every chain link RE-VERIFIED — an
+    payload)] with every array reshaped to ``row_shape`` (a pool block,
+    ``DecodeEngine.block_row_shape()``; the caller has checked that the
+    sender advertised the same one: two layouts of one size cannot be
+    told apart from the bytes) and every chain link RE-VERIFIED — an
     entry whose key does not hash from its own (prev, tokens) is
     dropped along with everything chained after it (a decode replica
     must never admit a block under a name its content doesn't earn)."""

@@ -54,9 +54,8 @@ PAGED_KERNEL_CASES = {
 def test_paged_flash_kernel_matches_gather_reference(case, mask, dtype, tol):
     """flash_decode_paged_attention's Pallas kernel (interpret mode)
     against its gather-then-softmax reference, through permuted tables,
-    with the key bias in each layout the kernel's [G, programs,
-    pages*block] table admits: one mask per slot (what the engine feeds,
-    G = slots), one per head (G = slots*heads), and none. With Lengths
+    with the key bias in each layout the kernel admits: one mask per slot
+    (what the engine feeds), one per head, and none. With Lengths
     the reference masks the dead table entries itself and reads clean
     tables, so a kernel (or fallback) that let a dead block into the
     result — the poisoned cases aim them at NaN keys and inf values —
@@ -72,8 +71,9 @@ def test_paged_flash_kernel_matches_gather_reference(case, mask, dtype, tol):
     NB = S * MB + 1
     rs = np.random.RandomState(0)
     q = jnp.asarray(rs.randn(S, H, 1, D), dtype)
-    k_pool = rs.randn(NB + 1, H, block, D)
-    v_pool = rs.randn(NB + 1, H, block, D)
+    # a token's keys are one row of the pool, its heads side by side
+    k_pool = rs.randn(NB + 1, block, H * D)
+    v_pool = rs.randn(NB + 1, block, H * D)
     k_pool[NB], v_pool[NB] = np.nan, np.inf      # no clean table names it
     k_pool, v_pool = jnp.asarray(k_pool, dtype), jnp.asarray(v_pool, dtype)
     clean = rs.permutation(NB - 1)[:S * MB].reshape(S, MB) + 1
@@ -107,6 +107,200 @@ def test_paged_flash_kernel_matches_gather_reference(case, mask, dtype, tol):
                                             key_bias=kb, **kwargs)
     np.testing.assert_allclose(np.asarray(dense, "float32"),
                                np.asarray(want, "float32"), atol=tol)
+
+
+# name -> (heads, d_head, block, table entries): the toy rows of the
+# tests' models (hidden 32 and 64: a padded row on the chip) and the
+# serve cell's 12 x 64 = 768 lanes
+PAGED_ROW_GEOMETRY = {
+    "toy_hidden32": (2, 16, BLOCK, 5),
+    "toy_hidden64": (4, 16, BLOCK, 40),
+    "real_12x64": (12, 64, 16, 12),
+}
+
+
+@pytest.mark.parametrize("geometry", sorted(PAGED_ROW_GEOMETRY))
+@pytest.mark.parametrize("mask", ["per_slot", "per_head"])
+@pytest.mark.parametrize("dtype,tol", [("float32", 2e-5), ("bfloat16", 3e-2)])
+def test_paged_kernel_matches_reference_attention(geometry, mask, dtype, tol):
+    """The kernel (interpret mode) and its fallback against
+    ``reference_attention`` over rows gathered BY HAND from the
+    ``[blocks, block, heads * d_head]`` pool, head h read from lanes
+    h*d_head .. (h+1)*d_head: permuted tables, one physical block shared
+    by two slots, dead table entries aimed at a block of NaN keys and inf
+    values, a slot of one key, and an inactive slot parked on the sink
+    block (table all 0, position 0)."""
+    import importlib
+
+    import jax.numpy as jnp
+
+    fa = importlib.import_module("paddle_tpu.kernels.flash_attention")
+    N, D, block, MB = PAGED_ROW_GEOMETRY[geometry]
+    H, S = N * D, MB * block
+    B = 5
+    rs = np.random.RandomState(1)
+    live = np.array([1, block + 1, S - 3, S, 1])    # the last slot: inactive
+    NB = B * MB + 2
+    poison = NB - 1
+    k_pool = rs.randn(NB, block, H)
+    v_pool = rs.randn(NB, block, H)
+    k_pool[poison], v_pool[poison] = np.nan, np.inf
+    tables = rs.permutation(np.arange(1, NB - 1))[:B * MB].reshape(B, MB)
+    tables[1, 0] = tables[2, 0]                     # a shared first block
+    tables[4] = 0                                   # the sink block
+    entries = -(-live // block)
+    for b in range(B - 1):
+        tables[b, entries[b]:] = poison
+    q = jnp.asarray(rs.randn(B, N, 1, D), dtype)
+    k_pool, v_pool = jnp.asarray(k_pool, dtype), jnp.asarray(v_pool, dtype)
+    cols = np.arange(S)[None]
+    kb = np.where(cols < live[:, None], 0.0, -1e4)             # [B, S]
+    if mask == "per_head":
+        kb = (kb[:, None] + 0.5 * rs.randn(B, N, S)).reshape(B * N, S)
+    kb = jnp.asarray(kb, "float32")
+
+    # the oracle: rows by hand, dead entries read block 0 and are masked
+    clean = np.where(np.arange(MB)[None] < entries[:, None], tables, 0)
+    kf, vf = (np.asarray(p, "float32") for p in (k_pool, v_pool))
+    rows_k = kf[clean].reshape(B, S, N, D).transpose(0, 2, 1, 3)
+    rows_v = vf[clean].reshape(B, S, N, D).transpose(0, 2, 1, 3)
+    dead = cols >= (entries * block)[:, None]
+    bias = np.asarray(kb).reshape(B, -1, 1, S) + np.where(
+        dead, -1e30, 0.0)[:, None, None, :]
+    want = np.asarray(fa.reference_attention(
+        jnp.asarray(q, "float32"), jnp.asarray(rows_k), jnp.asarray(rows_v),
+        bias=jnp.asarray(bias, "float32")))
+
+    for interpret in (True, None):
+        got = fa.flash_decode_paged_attention(
+            q, k_pool, v_pool, jnp.asarray(tables), key_bias=kb,
+            lengths=jnp.asarray(live, "int32"), interpret=interpret)
+        assert got.shape == (B, N, 1, D) and got.dtype == q.dtype
+        got = np.asarray(got, "float32")
+        assert np.isfinite(got).all()
+        np.testing.assert_allclose(got, want, atol=tol)
+
+
+def test_paged_kernel_refuses_a_pool_of_another_row():
+    """Heads as a dim of the pool (the layout before PR 30) or a row of
+    another width is an error, not a reinterpretation."""
+    import importlib
+
+    import jax.numpy as jnp
+
+    fa = importlib.import_module("paddle_tpu.kernels.flash_attention")
+    q = jnp.zeros((2, 2, 1, 16), "float32")
+    tables = jnp.zeros((2, 3), "int32")
+    for shape in [(7, 2, BLOCK, 16), (7, BLOCK, 16), (7, BLOCK, 64)]:
+        pool = jnp.zeros(shape, "float32")
+        with pytest.raises(ValueError):
+            fa.flash_decode_paged_attention(q, pool, pool, tables)
+
+
+def test_pool_row_round_trip_puts_each_head_back():
+    """The model side of the row: projections [S, T, hidden] are written
+    as they are (``_apply_kv_cache``), and what a window or a verify
+    reads back through the table (``_gather_heads``) holds head h's
+    values in head h, position by position."""
+    from paddle_tpu.models import bert as _bert
+
+    cfg = gpt.GPTConfig.tiny()
+    heads, hidden = cfg.num_heads, cfg.hidden_size
+    d_head = hidden // heads
+    S, T, MB, NB = 2, 6, 3, 8
+    rs = np.random.RandomState(4)
+    k_new = rs.randn(S, T, hidden).astype("f4")
+    v_new = rs.randn(S, T, hidden).astype("f4")
+    tables = np.array([[5, 2, 6], [3, 1, 4]], "int64")
+    pos = np.array([2, 0], "int64")
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        kinds = gpt.cache_kinds(cfg)[:1]
+        assert kinds[0][0].shape(NB, BLOCK) == [NB, 1, BLOCK, hidden]
+        from paddle_tpu.models import cache_kinds
+        ((pk, pv),) = cache_kinds.declare_pools(kinds, NB, BLOCK)
+        kv = fluid.layers.data(name="k", shape=[T, hidden], dtype="float32")
+        vv = fluid.layers.data(name="v", shape=[T, hidden], dtype="float32")
+        tb = fluid.layers.data(name="tb", shape=[MB], dtype="int64")
+        ps = fluid.layers.data(name="ps", shape=[], dtype="int64")
+        k_upd, v_upd = _bert._apply_kv_cache(
+            {"k": pk, "v": pv, "tables": tb, "pos": ps,
+             "mode": "paged_step"}, kv, vv, cfg)
+        rows = [_bert._gather_heads(p, tb, cfg) for p in (k_upd, v_upd)]
+    exe = fluid.Executor(fluid.CPUPlace())
+    scope = fluid.core.Scope()
+    for p in (pk, pv):
+        scope.set(p.name, np.zeros([NB, 1, BLOCK, hidden], "f4"))
+    got_k, got_v = exe.run(
+        main, feed={"k": k_new, "v": v_new, "tb": tables, "ps": pos},
+        fetch_list=rows, scope=scope)
+    assert got_k.shape == (S, heads, MB * BLOCK, d_head)
+    for got, new in ((got_k, k_new), (got_v, v_new)):
+        for s in range(S):
+            for h in range(heads):
+                np.testing.assert_array_equal(
+                    got[s, h, pos[s]:pos[s] + T],
+                    new[s, :, h * d_head:(h + 1) * d_head])
+    # and the bytes lie in the pool as the projection left them
+    pool = np.asarray(scope.get(pk.name))
+    np.testing.assert_array_equal(pool[tables[1, 0], 0, 0], k_new[1, 0])
+
+
+def test_greedy_generate_through_the_interpreted_paged_kernel():
+    """Token-exact against the full-forward oracle with the T = 1 step
+    through the Pallas kernel (interpreter) over the [1, hidden] rows."""
+    cfg = gpt.GPTConfig.tiny(hidden_dropout=0.0, attention_dropout=0.0,
+                             use_flash_attention=True)
+    cfg.flash_interpret = True
+    dense = gpt.GPTConfig.tiny(hidden_dropout=0.0, attention_dropout=0.0)
+    with fluid.unique_name.guard():
+        infer, startup, _n, logits = gpt.build_gpt_infer(dense, 10)
+    infer.random_seed = startup.random_seed = 9
+    exe = fluid.Executor(fluid.CPUPlace())
+    scope = fluid.core.Scope()
+    with fluid.executor.scope_guard(scope):
+        exe.run(startup)
+        want = gpt._reference_generate(exe, infer, logits, dense, [3, 7, 5],
+                                       10, scope=scope)
+        got = gpt.greedy_generate(exe, infer, logits, cfg, [3, 7, 5], 10,
+                                  scope=scope)
+    assert got == want and len(got) == 10
+
+
+def test_tp_engine_runs_the_paged_kernel_on_its_shard_of_every_row():
+    """Under a {"model": 2} mesh the pools split on their LAST dim (a
+    shard holds its heads' lanes of every row, ``parallel/spmd.py``) and
+    the kernel runs per shard on them (``nn_ops._per_shard``): the same
+    tokens as one device through the dense branch."""
+    outs = {}
+    for tp, flash in ((1, False), (2, True)):
+        cfg = gpt.GPTConfig.tiny(hidden_dropout=0.0, attention_dropout=0.0,
+                                 use_flash_attention=flash)
+        cfg.max_position_embeddings = 16
+        cfg.flash_interpret = True
+        with fluid.unique_name.guard():
+            infer, startup, _n, _logits = gpt.build_gpt_infer(cfg, 16)
+        infer.random_seed = startup.random_seed = 11
+        exe = fluid.Executor(fluid.CPUPlace())
+        scope = fluid.core.Scope()
+        with fluid.executor.scope_guard(scope):
+            exe.run(startup)
+        engine = sdecode.DecodeEngine(
+            cfg, scope=scope, slots=2, max_len=16, prefill_buckets=[16],
+            param_program=infer, block_size=BLOCK, tp=tp).start()
+        try:
+            outs[tp] = [engine.generate(p, max_new_tokens=6).tokens(
+                timeout=240) for p in ([3, 7, 5], [9, 1, 2, 4, 8])]
+            if tp == 2:
+                pool = scope.get(engine.session.pool_names()[0][0])
+                assert tuple(pool.sharding.spec) == (None, None, None,
+                                                     "model")
+                assert pool.addressable_shards[0].data.shape == (
+                    engine.session.pool_blocks, 1, BLOCK,
+                    cfg.hidden_size // 2)
+        finally:
+            engine.stop()
+    assert outs[2] == outs[1]
 
 
 def test_kv_cache_paged_write_gather_ops():

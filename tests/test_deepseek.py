@@ -469,16 +469,23 @@ def test_step_span_carries_the_expert_counts(seeded):
 # -- (g) the cache interface of models/gpt.py ----------------------------------------
 
 def test_gpt_cache_kinds_are_todays_pools():
+    """K and V a layer, a token's heads side by side in one row: the
+    names, bytes and allocator arithmetic of the ``[heads, d_head]`` row
+    these pools had before PR 30, another shape."""
     cfg = gpt.GPTConfig.tiny()
     kinds = gpt.cache_kinds(cfg)
     blocks, block = 9, 4
     assert [tuple(p.name(blocks, block) for p in layer) for layer in kinds] \
-        == gpt.paged_pool_names(cfg, blocks, block)
+        == gpt.paged_pool_names(cfg, blocks, block) \
+        == [("gpt_paged_k_%d_n9x4" % i, "gpt_paged_v_%d_n9x4" % i)
+            for i in range(cfg.num_layers)]
     for layer in kinds:
         for pool in layer:
             assert pool.shape(blocks, block) == gpt.paged_pool_shape(
-                cfg, blocks, block)
+                cfg, blocks, block) == [blocks, 1, block, cfg.hidden_size]
             assert pool.dtype == "float32"
+    assert gpt.paged_block_bytes(cfg, block) \
+        == cfg.num_layers * 2 * block * cfg.hidden_size * 4
     assert cache_kinds.bytes_per_token(kinds) * block \
         == gpt.paged_block_bytes(cfg, block)
     real = gpt.GPTConfig()

@@ -16,6 +16,7 @@ os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 fa = importlib.import_module("paddle_tpu.kernels.flash_attention")
@@ -80,7 +81,8 @@ def _paged(max_blocks, dtype, slots=SLOTS):
                                                key_bias=kb, lengths=lengths,
                                                interpret=False)
 
-    pool = ((slots * max_blocks + 1, HEADS, BLOCK, D_HEAD), dtype)
+    # a token's keys are one row of the pool: 12 heads x 64 = 768 lanes
+    pool = ((slots * max_blocks + 1, BLOCK, HEADS * D_HEAD), dtype)
     return fn, [((slots, HEADS, 1, D_HEAD), dtype), pool, pool,
                 ((slots, max_blocks), jnp.int32),
                 ((slots, max_blocks * BLOCK), F32),
@@ -137,27 +139,26 @@ def test_kernel_compiles_for_v5e(chip, case):
     assert compiled.as_text().count("tpu_custom_call") >= n_kernels
 
 
-def _compile_latent_program(chip, monkeypatch, which, feed_shapes):
-    """One paged program of the cell ``kanana2-serve-chat4k`` (its
-    configuration file, 64 slots of 4352 positions, blocks of 128), built
-    by ``models/deepseek.py`` (``which(cfg, blocks, block, max_blocks,
-    slots)`` -> main, fetch names) and lowered as the executor lowers it,
-    for the described v5e. -> (cfg, blocks, block, compiled)."""
+def _compile_paged_program(chip, monkeypatch, config_file, cfg_of, which,
+                           feed_shapes):
+    """One paged program of a serve cell (its configuration file under
+    ``benchmark/configs``: slots, max_len and block of its ``serve``),
+    built by the model's module (``which(cfg, blocks, block, max_blocks,
+    slots)`` -> main, feeds, fetch names) and lowered as the executor
+    lowers it, for the described v5e. -> (cfg, blocks, block, compiled)."""
     import json
     import os
 
     import paddle_tpu.fluid as fluid
     from paddle_tpu.fluid import executor
     from paddle_tpu.fluid.ops import registry
-    from paddle_tpu.models import deepseek
 
     monkeypatch.setattr(registry, "lowering_backend", lambda: "tpu")
     path = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "benchmark", "configs",
-        "kanana-2-30b-a3b.json")
+        os.path.abspath(__file__))), "benchmark", "configs", config_file)
     with open(path) as f:
         config = json.load(f)
-    cfg = deepseek.DeepseekConfig.from_config(config, dtype="bfloat16")
+    cfg = cfg_of(config)
     serve = config["serve"]
     slots, block = serve["slots"], serve["block_size"]
     max_blocks = serve["max_len"] // block
@@ -169,22 +170,38 @@ def _compile_latent_program(chip, monkeypatch, which, feed_shapes):
     (plan,) = [p for kind, _seg, p in compiled._plans if kind == "xla"]
     declared = main.global_block()
 
+    def dtype_of(name):
+        dtype = np.dtype(fluid.core.dtype_to_np(
+            declared._find_var_recursive(name).dtype))
+        # the executor feeds int64 as int32 (x64 is off)
+        return jnp.int32 if dtype == np.int64 else dtype
+
     def state(name):
         var = declared._find_var_recursive(name)
         return jax.ShapeDtypeStruct(
-            tuple(int(d) for d in var.shape),
-            fluid.core.dtype_to_np(var.dtype), sharding=chip)
+            tuple(int(d) for d in var.shape), dtype_of(name), sharding=chip)
 
-    shapes = feed_shapes(slots, max_blocks)
-    args = ([jax.ShapeDtypeStruct(
-                shapes[n], jnp.float32 if n == "last_onehot" else jnp.int32,
-                sharding=chip) for n in plan["feeds"]],
+    shapes = feed_shapes(slots, max_blocks, block)
+    args = ([jax.ShapeDtypeStruct(shapes[n], dtype_of(n), sharding=chip)
+             for n in plan["feeds"]],
             [state(n) for n in plan["mutable"]],
             [state(n) for n in plan["sharded_const"]],
             {n: state(n) for n in plan["const"]}, None)
     built = jax.jit(plan["raw_fn"], donate_argnums=(1,)).lower(
         *args).compile()
     return cfg, blocks, block, built
+
+
+def _compile_latent_program(chip, monkeypatch, which, feed_shapes):
+    """A program of the cell ``kanana2-serve-chat4k`` (64 slots of 4352
+    positions, blocks of 128), built by ``models/deepseek.py``."""
+    from paddle_tpu.models import deepseek
+
+    return _compile_paged_program(
+        chip, monkeypatch, "kanana-2-30b-a3b.json",
+        lambda config: deepseek.DeepseekConfig.from_config(
+            config, dtype="bfloat16"),
+        which, feed_shapes)
 
 
 def _latent_pool_checks(cfg, blocks, block, built):
@@ -217,7 +234,7 @@ def test_latent_step_program_takes_the_pool_as_it_lies(chip, monkeypatch):
         return main, feeds, [logits.name] + main._step_stats
 
     cfg, blocks, block, built = _compile_latent_program(
-        chip, monkeypatch, step, lambda slots, max_blocks: {
+        chip, monkeypatch, step, lambda slots, max_blocks, block: {
             "step_ids": (slots, 1, 1), "step_pos": (slots, 1, 1),
             "tables": (slots, max_blocks)})
     text = _latent_pool_checks(cfg, blocks, block, built)
@@ -245,10 +262,104 @@ def test_latent_window_program_loops_over_the_keys_it_can_see(
         return main, feeds, [logits.name]
 
     cfg, blocks, block, built = _compile_latent_program(
-        chip, monkeypatch, window, lambda slots, max_blocks: {
+        chip, monkeypatch, window, lambda slots, max_blocks, block: {
             "ids": (1, t, 1), "pos_ids": (1, t, 1), "table": (1, max_blocks),
             "window_pos": (1, 1), "last_onehot": (1, t, 1)})
     text = _latent_pool_checks(cfg, blocks, block, built)
     assert len(re.findall(r" while\(", text)) == 2 * cfg.num_hidden_layers
     assert not re.findall(r" conditional\(", text)
     assert built.memory_analysis().temp_size_in_bytes < 0.5e9
+
+
+def _compile_gpt_program(chip, monkeypatch, which, feed_shapes):
+    """A program of the cell ``gpt2s-serve-chat`` (64 slots of 1024
+    positions, blocks of 16: 4097 blocks a pool), built by
+    ``models/gpt.py``."""
+    from paddle_tpu.models import gpt
+
+    return _compile_paged_program(
+        chip, monkeypatch, "gpt2-small.json",
+        lambda config: gpt.GPTConfig(
+            vocab_size=config["vocab_size"], hidden_size=config["n_embd"],
+            num_layers=config["n_layer"], num_heads=config["n_head"],
+            intermediate_size=config["n_inner"],
+            max_position_embeddings=config["n_positions"],
+            hidden_dropout=0.0, attention_dropout=0.0,
+            use_flash_attention=config["use_flash_attention"]),
+        which, feed_shapes)
+
+
+def _gpt_pool_checks(cfg, blocks, block, built):
+    """No GPT pool is copied whole into another layout (with the heads a
+    dim of their own, 64 lanes a row, the step held 72 such copies and a
+    window 48: 1.77 GB of temporaries and nearly all of the program's
+    time); the 24 pools are updated in place. -> the program's text."""
+    import re
+
+    from paddle_tpu.models import gpt
+
+    shape = gpt.paged_pool_shape(cfg, blocks, block)
+    assert shape == [blocks, 1, block, cfg.hidden_size]
+    memory = built.memory_analysis()
+    pool_bytes = 2 * cfg.num_layers * int(np.prod(shape)) * 4
+    assert pool_bytes > 4.8e9                              # the cell's pools
+    assert memory.alias_size_in_bytes >= pool_bytes        # updated in place
+    assert memory.temp_size_in_bytes < 0.3e9
+    text = built.as_text()
+    rows = r"f32\[%d,(1,)?%d,%d\]" % (blocks, block, cfg.hidden_size)
+    assert not re.findall(r"%%copy[.\d]* = %s" % rows, text)
+    return text
+
+
+def test_gpt_step_program_takes_the_pool_as_it_lies(chip, monkeypatch):
+    """The whole T = 1 step of ``gpt2s-serve-chat``: one paged kernel a
+    layer, and nothing re-tiles a pool on its way in or out."""
+    import re
+
+    from paddle_tpu.models import gpt
+
+    def step(cfg, blocks, block, max_blocks, slots):
+        main, _s, feeds, logits = gpt.build_paged_step(
+            cfg, slots, blocks, block, max_blocks)
+        return main, feeds, [logits.name]
+
+    cfg, blocks, block, built = _compile_gpt_program(
+        chip, monkeypatch, step, lambda slots, max_blocks, block: {
+            "step_ids": (slots, 1, 1), "step_pos": (slots, 1, 1),
+            "tables": (slots, max_blocks),
+            "step_bias": (slots, 1, max_blocks * block)})
+    text = _gpt_pool_checks(cfg, blocks, block, built)
+    assert len(re.findall(r"%flash_decode_paged[.\d]* = ", text)) \
+        == cfg.num_layers
+
+
+GPT_BUCKETS = [64, 128, 256, 512]
+
+
+@pytest.mark.parametrize("bucket", GPT_BUCKETS)
+def test_gpt_window_program_takes_the_pool_as_it_lies(chip, monkeypatch,
+                                                      bucket):
+    """Every prefill bucket of the cell's ``serve``: the window scatters
+    into the pools and gathers its row back with no pool copied whole."""
+    import json
+    import os
+
+    from paddle_tpu.models import gpt
+
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "benchmark", "configs",
+            "gpt2-small.json")) as f:
+        assert json.load(f)["serve"]["prefill_buckets"] == GPT_BUCKETS
+
+    def window(cfg, blocks, block, max_blocks, slots):
+        main, _s, feeds, logits = gpt.build_paged_window(
+            cfg, blocks, block, max_blocks, bucket)
+        return main, feeds, [logits.name]
+
+    cfg, blocks, block, built = _compile_gpt_program(
+        chip, monkeypatch, window, lambda slots, max_blocks, block: {
+            "ids": (1, bucket, 1), "pos_ids": (1, bucket, 1),
+            "table": (1, max_blocks), "window_pos": (1, 1),
+            "resume_bias": (1, bucket, max_blocks * block),
+            "last_onehot": (1, bucket, 1)})
+    _gpt_pool_checks(cfg, blocks, block, built)

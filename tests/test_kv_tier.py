@@ -29,7 +29,9 @@ BLOCK = 8
 SPEC = {"seed": 5, "vocab_size": 50, "hidden_size": 16, "num_layers": 1,
         "num_heads": 2, "intermediate_size": 32, "max_len": 32,
         "slots": 4, "prefill_buckets": [8, 32]}
-ROW = [SPEC["num_heads"], BLOCK, SPEC["hidden_size"] // SPEC["num_heads"]]
+# one block of a GPT pool as it lies: [1, block, hidden], a token's heads
+# side by side (``DecodeEngine.block_row_shape()``)
+ROW = [1, BLOCK, SPEC["hidden_size"]]
 
 
 def _payload(seed, layers=1):
@@ -300,6 +302,10 @@ def test_engine_export_offer_cross_engine_token_exact():
         expect = warm.generate(prompt, max_new_tokens=3).tokens(timeout=60)
         entries = warm.request_export(prefix, timeout=5.0)
         assert len(entries) == 2
+        # what a replica advertises beside its blocks is what it sends
+        assert warm.block_row_shape() == cold.block_row_shape() == ROW
+        assert {a.shape for _k, _p, _t, payload in entries
+                for kv in payload for a in kv} == {tuple(ROW)}
         blob = encode_entries(entries)
         back = decode_entries(blob, ROW)
         assert cold.offer_blocks(back) == 2

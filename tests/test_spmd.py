@@ -49,11 +49,13 @@ def _axes(model=1, data=1):
         ("pos_embedding", (32, 64), ()),
         ("gpt_0_ln0.w_0", (64,), ()),
         ("emb_ln.b_0", (64,), ()),
-        # KV geometry [slots|blocks, heads, len, d_head]: heads-partition
-        # dim 1, addressing replicated
-        ("gpt_cache_k_0", (4, 2, 32, 32), (None, "model")),
-        ("gpt_paged_v_3", (16, 2, 4, 32), (None, "model")),
-        ("gpt_prefix_k_1", (8, 2, 4, 32), (None, "model")),
+        # paged KV pools [blocks, 1, block, hidden]: a token's heads lie
+        # side by side on the last dim, which the model axis splits;
+        # addressing replicated
+        ("gpt_paged_k_0_n4x32", (4, 1, 32, 64), (None, None, None, "model")),
+        ("gpt_paged_v_3_n16x4", (16, 1, 4, 64), (None, None, None, "model")),
+        # a row the axis does not divide stays whole
+        ("gpt_paged_k_1_n8x4", (8, 1, 4, 33), ()),
     ],
 )
 def test_tp_policy_table(name, shape, want):
