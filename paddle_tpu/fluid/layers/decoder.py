@@ -1,5 +1,6 @@
-"""Layer functions of the latent-attention / routed-expert decoder ops
-(``fluid/ops/decoder_ops.py``). All inference only."""
+"""Layer functions of the decoder ops (``fluid/ops/decoder_ops.py``):
+latent attention, routed experts, the gated delta rule (KDA) and
+grouped-query window attention. All inference only."""
 
 from ..layer_helper import LayerHelper
 
@@ -10,6 +11,9 @@ __all__ = [
     "moe_ffn",
     "mla_window_attention",
     "mla_decode_paged_attention",
+    "kda_window",
+    "kda_step",
+    "gqa_window_attention",
 ]
 
 
@@ -94,3 +98,56 @@ def mla_decode_paged_attention(q, pool, tables, lengths, wkvb, num_heads,
          "Lengths": [lengths], "Wkvb": [wkvb]},
         dict(_mla_attrs(num_heads, nope_dim, rope_dim, v_dim),
              interpret=bool(interpret)), q.dtype, name)
+
+
+def _kda(op_type, qkv, f, b, conv_w, a_log, dt_bias, num_heads, head_dim,
+         state, feeds, attrs, name):
+    """``state``: None or the layer's (S var, conv-tail var), both
+    rewritten in place (the outputs alias the inputs)."""
+    helper = LayerHelper(op_type, name=name)
+    out = helper.create_variable_for_type_inference(dtype=qkv.dtype)
+    inputs = {"QKV": [qkv], "F": [f], "B": [b], "ConvW": [conv_w],
+              "ALog": [a_log], "DtBias": [dt_bias]}
+    outputs = {"Out": [out]}
+    if state is not None:
+        inputs.update(State=[state[0]], Conv=[state[1]],
+                      **{k: [v] for k, v in feeds.items()})
+        outputs.update(StateOut=[state[0]], ConvOut=[state[1]])
+    helper.append_op(type=op_type, inputs=inputs, outputs=outputs,
+                     attrs=dict(attrs, num_heads=int(num_heads),
+                                head_dim=int(head_dim)))
+    return out
+
+
+def kda_window(qkv, f, b, conv_w, a_log, dt_bias, num_heads, head_dim,
+               state=None, row=None, start=None, length=None, name=None):
+    """The gated delta-rule mixing of a window (chunked): ``qkv``
+    [N, T, 3*H*D] before the short convolution, ``f`` [N, T, H*D] and
+    ``b`` [N, T, H] the decay and write pre-activations. With ``state``
+    (S var, conv-tail var; N = 1) the window continues the slot's fed
+    ``row`` unless ``start`` is 0, stops changing the state at ``length``
+    real tokens, and rewrites the row. -> [N, T, H*D]."""
+    return _kda("kda_window", qkv, f, b, conv_w, a_log, dt_bias, num_heads,
+                head_dim, state,
+                {"Row": row, "Start": start, "Length": length}, {}, name)
+
+
+def kda_step(qkv, f, b, conv_w, a_log, dt_bias, num_heads, head_dim, state,
+             rows, interpret=False, name=None):
+    """The gated delta-rule mixing of one token a slot: slot i reads and
+    rewrites row ``rows[i]`` of ``state`` (S var, conv-tail var).
+    -> [slots, 1, H*D]."""
+    return _kda("kda_step", qkv, f, b, conv_w, a_log, dt_bias, num_heads,
+                head_dim, state, {"Row": rows},
+                {"interpret": bool(interpret)}, name)
+
+
+def gqa_window_attention(q, k, v, qpos, num_kv_heads, head_dim, name=None):
+    """Grouped-query causal softmax attention of ``q`` [N, T, heads*D]
+    over ``k``/``v`` [N, S, kv_heads*D]; key j visible to query i iff
+    j <= qpos[i]. -> [N, T, heads*D]."""
+    return _one_out(
+        "gqa_window_attention",
+        {"Q": [q], "K": [k], "V": [v], "QPos": [qpos]},
+        {"num_kv_heads": int(num_kv_heads), "head_dim": int(head_dim)},
+        q.dtype, name)

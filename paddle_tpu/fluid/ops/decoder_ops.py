@@ -311,3 +311,324 @@ def _mla_decode_paged_attention(ctx, op_):
             int(op_.attr("v_dim")),
             interpret=bool(op_.attr("interpret", False)) or None)
     ctx.out(op_, "Out", out)
+
+
+# --------------------------------------------------------------------------
+# gated delta rule (Kimi Delta Attention) and grouped-query softmax layers
+# --------------------------------------------------------------------------
+
+_KDA_CHUNK = 64       # tokens a chunk of ``kda_chunked``
+_KDA_SUB = 16         # rows a sub-block of a chunk's triangular matrices
+
+
+def short_conv(x, tail, w):
+    """Depthwise causal convolution over time, no bias: ``x`` [T, C] the
+    new rows, ``tail`` [K-1, C] the K-1 rows before them (zeros at the
+    start of a sequence), ``w`` [K, C] (tap K-1 multiplies the current
+    row). float32. -> (y [T, C], padded [T+K-1, C], the rows the next
+    call's tail is cut from)."""
+    import jax.numpy as jnp
+
+    f32 = jnp.float32
+    taps = w.shape[0]
+    padded = jnp.concatenate([tail.astype(f32), x.astype(f32)], axis=0)
+    t = x.shape[0]
+    y = sum(w[j].astype(f32)[None, :] * padded[j:j + t] for j in range(taps))
+    return y, padded
+
+
+def kda_inputs(y, f, bt, a_log, dt_bias, heads, head_dim):
+    """From the convolved rows ``y`` [..., 3*H*D] (q' ‖ k' ‖ v' before the
+    SiLU), the decay pre-activation ``f`` [..., H*D] and the write
+    pre-activation ``bt`` [..., H], all float32:
+    q = L2norm(SiLU q') * D^-1/2, k = L2norm(SiLU k'), v = SiLU v',
+    g = -exp(A_log) * softplus(f + dt_bias) (log of the per-key-channel
+    decay), b = 2 sigmoid(bt). -> (q, k, v, g [..., H, D], b [..., H])."""
+    import jax
+    import jax.numpy as jnp
+
+    lead = y.shape[:-1]
+    y = jax.nn.silu(y).reshape(lead + (3, heads, head_dim))
+    q, k, v = y[..., 0, :, :], y[..., 1, :, :], y[..., 2, :, :]
+
+    def l2(x):
+        return x * jax.lax.rsqrt((x * x).sum(-1, keepdims=True) + 1e-6)
+
+    g = -jnp.exp(a_log.astype(jnp.float32))[:, None] * jax.nn.softplus(
+        (f + dt_bias.astype(jnp.float32)).reshape(lead + (heads, head_dim)))
+    return (l2(q) * head_dim ** -0.5, l2(k), v, g,
+            2.0 * jax.nn.sigmoid(bt))
+
+
+def kda_chunked(q, k, v, g, b, s0, chunk=_KDA_CHUNK):
+    """The gated delta rule over ``T`` tokens in chunks (never token by
+    token): ``q``, ``k``, ``g`` [T, H, dk], ``v`` [T, H, dv], ``b``
+    [T, H], ``s0`` [H, dk, dv], float32; ``g <= 0`` is the log decay.
+
+        S_t = Diag(e^g_t) S_{t-1} + k_t u_t^T,   o_t = S_t^T q_t,
+        u_t = b_t (v_t - S_{t-1}^T (e^g_t . k_t))
+
+    Inside a chunk, with G the running sum of g: u solves the unit lower
+    triangular system (I + Diag(b) A) U = Diag(b)(V - (K e^G) S_0), where
+    A_ts = sum_d k_td k_sd e^(G_td - G_sd) for s < t, then
+    O = (Q e^G) S_0 + A^q U (A^q with q for the row's k, s <= t) and one
+    state pass S_C = Diag(e^G_C) S_0 + (K e^(G_C - G))^T U. Every exponent
+    is <= 0: the triangular matrices are built in sub-blocks of
+    ``_KDA_SUB`` rows, a diagonal sub-block elementwise over the key
+    channels, an off-diagonal one as a product of two factors each
+    relative to the row block's start. A token with ``g = 0, b = 0``
+    (padding past a window's last real token) leaves the state as it is.
+    -> (o [T, H, dv], S_T)."""
+    import jax
+    import jax.numpy as jnp
+
+    t, heads, dk = q.shape
+    c = math.gcd(t, chunk)
+    sub = math.gcd(c, _KDA_SUB)
+    m = c // sub
+    hi = jax.lax.Precision.HIGHEST
+    f32 = jnp.float32
+
+    def chunks(x):                    # [T, H, ...] -> [n, H, c, ...]
+        return jnp.swapaxes(x.reshape((t // c, c) + x.shape[1:]), 1, 2)
+
+    lower = jnp.tril(jnp.ones((sub, sub), bool))            # s <= r
+    strict = jnp.tril(jnp.ones((sub, sub), bool), -1)
+    block_of = jnp.arange(c) // sub
+    before = block_of[None, :] < block_of[:, None]          # [c, c]
+    eye_m = jnp.eye(m, dtype=f32)
+
+    def step(s, xs):
+        q, k, v, g, b = xs            # [H, c, dk] .. [H, c]
+        G = jnp.cumsum(g, axis=1)
+        # G just before each sub-block's first row
+        start = jnp.concatenate(
+            [jnp.zeros_like(G[:, :1]), G[:, sub - 1:c - 1:sub]], axis=1)
+        Gb = G.reshape(heads, m, sub, dk)
+        rel = jnp.exp(Gb - start[:, :, None, :])            # row side, <= 1
+        # column side for row block i: e^(start_i - G_s), s before block i
+        col = jnp.exp(jnp.minimum(
+            start[:, :, None, :] - G[:, None, :, :], 0.0))  # [H, m, c, dk]
+        kcol = k[:, None] * col
+        kb, qb = k.reshape(heads, m, sub, dk), q.reshape(heads, m, sub, dk)
+        # diagonal sub-blocks: [H, m, r, s, dk], exponent <= 0 where s <= r
+        e = jnp.exp(jnp.minimum(
+            Gb[:, :, :, None, :] - Gb[:, :, None, :, :], 0.0))
+        ek = e * kb[:, :, None, :, :]
+
+        def tri(rows, keep):
+            diag = jnp.where(keep, (rows[:, :, :, None, :] * ek).sum(-1), 0.0)
+            off = jnp.einsum("hird,hisd->hirs", rows * rel, kcol,
+                             precision=hi).reshape(heads, c, c)
+            full = jnp.einsum("hirs,ij->hirjs", diag, eye_m)
+            return jnp.where(before, off, full.reshape(heads, c, c))
+
+        a_k, a_q = tri(kb, strict), tri(qb, lower)
+        decay = jnp.exp(G)
+        rhs = b[..., None] * (v - jnp.einsum(
+            "hcd,hdv->hcv", k * decay, s, precision=hi))
+        system = jnp.eye(c, dtype=f32) + b[..., None] * a_k
+        u = jax.lax.linalg.triangular_solve(
+            system, rhs, left_side=True, lower=True, unit_diagonal=True)
+        o = (jnp.einsum("hcd,hdv->hcv", q * decay, s, precision=hi)
+             + jnp.einsum("hcs,hsv->hcv", a_q, u, precision=hi))
+        to_end = jnp.exp(G[:, -1:, :] - G)
+        s = (decay[:, -1, :, None] * s
+             + jnp.einsum("hcd,hcv->hdv", k * to_end, u, precision=hi))
+        return s, o
+
+    s, o = jax.lax.scan(
+        step, s0.astype(f32),
+        tuple(chunks(x.astype(f32)) for x in (q, k, v, g, b)))
+    return jnp.swapaxes(o, 1, 2).reshape(t, heads, -1), s
+
+
+def kda_window(qkv, f, bt, conv_w, a_log, dt_bias, heads, head_dim,
+               state=None, conv=None, row=None, start=None, length=None):
+    """A KDA layer's mixing over ONE window ``qkv`` [T, 3*H*D] (q~ ‖ k~ ‖
+    v~ before the convolution), ``f`` [T, H*D], ``bt`` [T, H]. With the
+    state vars (``state`` [R, H, D, D] float32, ``conv`` [R, K-1, 3*H*D]):
+    the window starts from zeros where ``start`` is 0 and from row ``row``
+    otherwise, tokens at or past ``length`` are padding (``b = 0, g = 0``,
+    the tail cut at the real end), and the row is rewritten. Without:
+    zeros, every token real. -> (o [T, H*D] in qkv's dtype, state, conv)."""
+    import jax
+    import jax.numpy as jnp
+
+    f32 = jnp.float32
+    t = qkv.shape[0]
+    taps = conv_w.shape[0]
+    s0 = jnp.zeros((heads, head_dim, head_dim), f32)
+    tail = jnp.zeros((taps - 1, qkv.shape[1]), f32)
+    if state is not None:
+        fresh = start <= 0
+        s0 = jnp.where(fresh, s0, state[row])
+        tail = jnp.where(fresh, tail, conv[row].astype(f32))
+    y, padded = short_conv(qkv, tail, conv_w)
+    q, k, v, g, b = kda_inputs(y, f.astype(f32), bt.astype(f32), a_log,
+                               dt_bias, heads, head_dim)
+    if length is not None:
+        real = (jnp.arange(t) < length)[:, None]
+        g = jnp.where(real[..., None], g, 0.0)
+        b = jnp.where(real, b, 0.0)
+    o, s1 = kda_chunked(q, k, v, g, b, s0)
+    o = o.reshape(t, heads * head_dim).astype(qkv.dtype)
+    if state is None:
+        return o, None, None
+    # rows length .. length + K - 2 of ``padded`` are the last K-1 real ones
+    new_tail = jax.lax.dynamic_slice_in_dim(padded, length, taps - 1, axis=0)
+    return (o, state.at[row].set(s1),
+            conv.at[row].set(new_tail.astype(conv.dtype)))
+
+
+def _kda_attrs(op_):
+    return int(op_.attr("num_heads")), int(op_.attr("head_dim"))
+
+
+def _kda_infer(op_, block):
+    f = in_var(op_, block, "F")
+    set_out(op_, block, "Out", list(f.shape), in_var(op_, block, "QKV").dtype)
+    for slot, out in (("State", "StateOut"), ("Conv", "ConvOut")):
+        if op_.inputs.get(slot):
+            v = in_var(op_, block, slot)
+            set_out(op_, block, out, list(v.shape), v.dtype)
+
+
+def _scalar(x):
+    import jax.numpy as jnp
+
+    return x.reshape(-1)[0].astype(jnp.int32)
+
+
+@op("kda_window", infer_shape=_kda_infer)
+def _kda_window(ctx, op_):
+    """The gated delta-rule (KDA) mixing of a window, chunked
+    (``kda_chunked``): ``QKV`` [N, T, 3*H*D], ``F`` [N, T, H*D], ``B``
+    [N, T, H], ``ConvW`` [K, 3*H*D], ``ALog`` [H], ``DtBias`` [H*D]. With
+    ``State``/``Conv`` (N = 1; a prefill window of the decode engine) the
+    slot's ``Row`` of both is read unless ``Start`` is 0, tokens at or
+    past ``Length`` change nothing, and the row is rewritten in place;
+    without them every row of the batch starts from zeros (the export).
+    Inference only (no grad op)."""
+    import jax
+
+    heads, head_dim = _kda_attrs(op_)
+    qkv, f, bt = (ctx.in1(op_, n) for n in ("QKV", "F", "B"))
+    params = (ctx.in1(op_, "ConvW"), ctx.in1(op_, "ALog"),
+              ctx.in1(op_, "DtBias"), heads, head_dim)
+    with jax.named_scope("kda_window"):
+        if not op_.inputs.get("State"):
+            out = jax.vmap(lambda a, b_, c: kda_window(a, b_, c, *params)[0])(
+                qkv, f, bt)
+            ctx.out(op_, "Out", out)
+            return
+        if qkv.shape[0] != 1:
+            raise ValueError("kda_window with a state takes one window, "
+                             "got a batch of %d" % qkv.shape[0])
+        o, state, conv = kda_window(
+            qkv[0], f[0], bt[0], *params, state=ctx.in1(op_, "State"),
+            conv=ctx.in1(op_, "Conv"), row=_scalar(ctx.in1(op_, "Row")),
+            start=_scalar(ctx.in1(op_, "Start")),
+            length=_scalar(ctx.in1(op_, "Length")))
+    ctx.out(op_, "Out", o[None])
+    ctx.out(op_, "StateOut", state)
+    ctx.out(op_, "ConvOut", conv)
+
+
+@op("kda_step", infer_shape=_kda_infer)
+def _kda_step(ctx, op_):
+    """The KDA mixing of ONE token a slot (the T = 1 step): ``QKV``
+    [slots, 1, 3*H*D], ``F`` [slots, 1, H*D], ``B`` [slots, 1, H]; slot i
+    reads and rewrites row ``Rows[i]`` of ``State`` (kernel
+    ``kernels/kda.py::kda_decode``, in place) and of ``Conv``. An idle
+    slot feeds row 0, the sink. Inference only (no grad op)."""
+    import jax
+    import jax.numpy as jnp
+
+    from ...kernels.kda import kda_decode
+
+    heads, head_dim = _kda_attrs(op_)
+    qkv, conv = ctx.in1(op_, "QKV"), ctx.in1(op_, "Conv")
+    rows = ctx.in1(op_, "Row").reshape(-1).astype(jnp.int32)
+    w = ctx.in1(op_, "ConvW").astype(jnp.float32)
+    with jax.named_scope("kda_step"):
+        window = jnp.concatenate([conv[rows], qkv.astype(conv.dtype)], axis=1)
+        y = (w[None] * window.astype(jnp.float32)).sum(1)
+        q, k, v, g, b = kda_inputs(
+            y, ctx.in1(op_, "F")[:, 0].astype(jnp.float32),
+            ctx.in1(op_, "B")[:, 0].astype(jnp.float32),
+            ctx.in1(op_, "ALog"), ctx.in1(op_, "DtBias"), heads, head_dim)
+        o, state = kda_decode(
+            ctx.in1(op_, "State"), rows, q, k, v, jnp.exp(g), b,
+            interpret=bool(op_.attr("interpret", False)) or None)
+    ctx.out(op_, "Out", o.reshape(qkv.shape[0], 1, -1).astype(qkv.dtype))
+    ctx.out(op_, "StateOut", state)
+    ctx.out(op_, "ConvOut", conv.at[rows].set(window[:, 1:]))
+
+
+def gqa_window(q, k, v, qpos, kv_heads, head_dim):
+    """Grouped-query causal softmax attention of a window of queries, the
+    blocking of ``mla_window`` on plain K/V: ``q`` [N, T, heads*D], ``k``,
+    ``v`` [N, S, kv_heads*D] (query head h reads key head h // (heads /
+    kv_heads)), ``qpos`` [N, T]: query i sees the keys at positions
+    <= qpos[i]. No positions are encoded. -> [N, T, heads*D]."""
+    import jax
+    import jax.numpy as jnp
+
+    n, t, width = q.shape
+    s = k.shape[1]
+    grp = width // head_dim // kv_heads
+    tq, tk = math.gcd(t, _WINDOW_BLOCK), math.gcd(s, _WINDOW_BLOCK)
+    f32 = jnp.float32
+    scale = head_dim ** -0.5
+    q = q.reshape(n, t // tq, tq, kv_heads, grp, head_dim).swapaxes(0, 1)
+    qpos = qpos.reshape(n, t // tq, 1, 1, tq, 1).astype(jnp.int32)
+    qpos = qpos.swapaxes(0, 1)
+    k = k.reshape(n, s, kv_heads, head_dim)
+    v = v.reshape(n, s, kv_heads, head_dim)
+
+    def attend(queries):
+        q, qpos = queries        # [N, tq, G, grp, D], [N, 1, 1, tq, 1]
+
+        def chunk(i, carry):
+            top, total, acc = carry
+            kc = jax.lax.dynamic_slice_in_dim(k, i * tk, tk, axis=1)
+            vc = jax.lax.dynamic_slice_in_dim(v, i * tk, tk, axis=1)
+            sc = jnp.einsum("ntgrd,nsgd->ngrts", q, kc,
+                            preferred_element_type=f32)
+            seen = i * tk + jnp.arange(tk)[None, None, None, None, :] <= qpos
+            sc = jnp.where(seen, sc * scale, _NEG)
+            new_top = jnp.maximum(top, sc.max(-1, keepdims=True))
+            p = jnp.exp(sc - new_top)
+            keep = jnp.exp(top - new_top)
+            pv = jnp.einsum("ngrts,nsgd->ngrtd", p.astype(q.dtype), vc,
+                            preferred_element_type=f32)
+            return (new_top, keep * total + p.sum(-1, keepdims=True),
+                    keep * acc + pv)
+
+        init = (jnp.full((n, kv_heads, grp, tq, 1), _NEG, f32),
+                jnp.zeros((n, kv_heads, grp, tq, 1), f32),
+                jnp.zeros((n, kv_heads, grp, tq, head_dim), f32))
+        _top, total, acc = jax.lax.fori_loop(
+            0, qpos.max() // tk + 1, chunk, init)
+        return (acc / total).astype(q.dtype)
+
+    o = jax.lax.map(attend, (q, qpos))      # [blocks, N, G, grp, tq, D]
+    return o.transpose(1, 0, 4, 2, 3, 5).reshape(n, t, width)
+
+
+@op("gqa_window_attention", infer_shape=same_shape_infer("Q"))
+def _gqa_window_attention(ctx, op_):
+    """Grouped-query softmax attention of a window (a prefill window over
+    the slot's gathered K/V rows, or a whole prompt without a cache):
+    ``Q`` [N, T, heads*D], ``K``/``V`` [N, S, kv_heads*D], ``QPos`` [N, T]
+    (or [N, T, 1]): key j visible to query i iff j <= QPos[i]. See
+    ``gqa_window``. Inference only (no grad op)."""
+    import jax
+
+    with jax.named_scope("gqa_window"):
+        out = gqa_window(
+            ctx.in1(op_, "Q"), ctx.in1(op_, "K"), ctx.in1(op_, "V"),
+            ctx.in1(op_, "QPos"), int(op_.attr("num_kv_heads")),
+            int(op_.attr("head_dim")))
+    ctx.out(op_, "Out", out)

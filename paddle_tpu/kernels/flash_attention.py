@@ -515,7 +515,11 @@ def flash_decode_paged_attention(q, k_pool, v_pool, tables, key_bias=None,
     before each DMA and ONE compiled kernel serves every table layout
     and every mix of lengths. The tiling comes from the shapes: all N
     heads of a slot and ``PAGED_KEYS`` keys (or the whole table, where it
-    is shorter) a program. Forward-only; dense gather-then-softmax
+    is shorter) a program. GROUPED heads: a pool row of fewer key
+    heads than ``q`` has query heads ([blocks, block, G*D], G dividing N;
+    query head h reads key head h // (N / G)) goes to
+    ``_decode_paged_grouped`` (``lengths`` required, no ``key_bias``).
+    Forward-only; dense gather-then-softmax
     fallback off TPU — bit-compatible math with ``reference_attention``
     over the gathered logical rows."""
     from jax.experimental import pallas as pl  # noqa: F401 (dispatch)
@@ -530,12 +534,21 @@ def flash_decode_paged_attention(q, k_pool, v_pool, tables, key_bias=None,
             "flash_decode_paged_attention is the single-query path, "
             "got Sq=%d" % Sq
         )
-    if H != N * D or v_pool.shape != k_pool.shape:
+    if (H % D or N % (H // D) or H > N * D
+            or v_pool.shape != k_pool.shape):
         raise ValueError(
             "pool geometry %r / %r does not match q heads x depth (%d x %d)"
             % (k_pool.shape, v_pool.shape, N, D)
         )
     scale = scale if scale is not None else 1.0 / float(np.sqrt(D))
+    if H != N * D:
+        # grouped queries: fewer key heads than query heads
+        if key_bias is not None or lengths is None:
+            raise ValueError(
+                "grouped heads (%d queries on %d key heads) mask by "
+                "lengths= alone" % (N, H // D))
+        return _decode_paged_grouped(q, k_pool, v_pool, tables, lengths,
+                                     scale, interpret)
     kb = _normalize_key_bias(key_bias, B, N, S)
     on_tpu = lowers_for_tpu()
     tables = tables.astype(jnp.int32)
@@ -632,6 +645,144 @@ def flash_decode_paged_attention(q, k_pool, v_pool, tables, key_bias=None,
     )(_held_blocks(tables, lengths, blk, P), lengths, qd,
       *([k_pool] * P), *([v_pool] * P), kb)
     return out.reshape(B, N, 1, D)
+
+
+def _decode_paged_grouped_kernel(held_ref, lengths_ref, q_ref, *refs, scale,
+                                 pages):
+    """Grouped-query paged decode step, one (slot, group of ``pages``
+    logical blocks) program over all heads of the slot. A token's keys
+    (and values) are one pool row of G key heads side by side, ``D`` lanes
+    each; the ``N / G`` query heads that read key head g are the ROWS of
+    one product against that head's lanes of the program's keys
+    (``q_ref`` [1, G, rows, D], the rows padded to the operand's sublane
+    tile with zeros), so a key is fetched once for all its queries and
+    nothing of another head's lanes is multiplied. Dead table entries are
+    neither fetched nor computed (``_held_blocks``); inside the live
+    blocks the slot's live length masks, as in
+    ``_mla_decode_paged_kernel``. Online softmax state lives in VMEM
+    scratch across a slot's programs."""
+    from jax.experimental import pallas as pl
+
+    k_refs, v_refs = refs[:pages], refs[pages:2 * pages]
+    o_ref, m_ref, l_ref, acc_ref = refs[2 * pages:]
+    b, i = pl.program_id(0), pl.program_id(1)
+    block = k_refs[0].shape[1]
+    keys = pages * block
+    kv_heads, rows, d = q_ref.shape[1:]
+
+    @pl.when(i == 0)
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, _NEG)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    length = lengths_ref[b]
+    live = _live_blocks(length, block) - i * pages
+
+    @pl.when(live > 0)
+    def _attend():
+        kblk = (k_refs[0][0] if pages == 1 else
+                jnp.concatenate([r[0] for r in k_refs], axis=0))
+        vblk = (v_refs[0][0] if pages == 1 else
+                jnp.concatenate([r[0] for r in v_refs], axis=0))
+        s = jnp.concatenate([
+            jax.lax.dot_general(
+                q_ref[0, g], kblk[:, g * d:(g + 1) * d],
+                (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+            for g in range(kv_heads)], axis=0) * scale
+        col = i * keys + jax.lax.broadcasted_iota(jnp.int32, (1, keys), 1)
+        s = jnp.where(col < length, s, _NEG)
+        m = m_ref[...]
+        m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
+        alpha = jnp.exp(m - m_new)
+        p = jnp.exp(s - m_new)
+        l_ref[...] = l_ref[...] * alpha + p.sum(axis=-1, keepdims=True)
+        pv = jnp.concatenate([
+            jax.lax.dot_general(
+                p[g * rows:(g + 1) * rows].astype(vblk.dtype),
+                vblk[:, g * d:(g + 1) * d], (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            for g in range(kv_heads)], axis=0)
+        acc_ref[...] = acc_ref[...] * alpha + pv
+        m_ref[...] = m_new
+
+    @pl.when(i == pl.num_programs(1) - 1)
+    def _emit():
+        o_ref[0] = (acc_ref[...]
+                    / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
+
+
+def _decode_paged_grouped(q, k_pool, v_pool, tables, lengths, scale,
+                          interpret):
+    """``flash_decode_paged_attention`` for grouped heads: ``q``
+    [B, N, 1, D], pools [blocks, block, G*D], ``lengths`` [B] live keys a
+    slot (held to 1 .. the table's keys). -> [B, N, 1, D]."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, N, _, D = q.shape
+    blocks, blk, H = k_pool.shape
+    MB = tables.shape[1]
+    G = H // D
+    grp = N // G
+    tables = tables.astype(jnp.int32)
+    lengths = jnp.clip(lengths.astype(jnp.int32).reshape(B), 1, MB * blk)
+    qg = q.reshape(B, G, grp, D).astype(k_pool.dtype)
+    if interpret is None and not lowers_for_tpu():
+        held = _held_blocks(tables, lengths, blk, MB)
+        rows_k = k_pool[held].reshape(B, MB * blk, G, D)
+        rows_v = v_pool[held].reshape(B, MB * blk, G, D)
+        s = jnp.einsum("bgrd,bkgd->bgrk", qg, rows_k,
+                       preferred_element_type=jnp.float32) * scale
+        seen = jnp.arange(MB * blk)[None, :] < lengths[:, None]
+        p = jax.nn.softmax(
+            jnp.where(seen[:, None, None, :], s, _NEG), axis=-1)
+        out = jnp.einsum("bgrk,bkgd->bgrd", p.astype(rows_v.dtype), rows_v,
+                         preferred_element_type=jnp.float32)
+        return out.astype(q.dtype).reshape(B, N, 1, D)
+    P = min(MB, -(-PAGED_KEYS // blk))
+    programs = -(-MB // P)
+    # the sublane tile of the query operand: 8 rows of 32 bits
+    tile = 8 * 4 // jnp.dtype(k_pool.dtype).itemsize
+    rows = _round_up(grp, tile)
+    qg = jnp.pad(qg, ((0, 0), (0, 0), (0, rows - grp), (0, 0)))
+    kernel = functools.partial(_decode_paged_grouped_kernel, scale=scale,
+                               pages=P)
+
+    def pool_spec(j):
+        return pl.BlockSpec(
+            (1, blk, H), lambda b, i, held, lens: (held[b, i * P + j], 0, 0),
+            memory_space=pltpu.VMEM,
+        )
+
+    pool_specs = [pool_spec(j) for j in range(P)]
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(B, programs),
+        in_specs=[
+            pl.BlockSpec((1, G, rows, D),
+                         lambda b, i, held, lens: (b, 0, 0, 0),
+                         memory_space=pltpu.VMEM),
+            *pool_specs, *pool_specs,
+        ],
+        out_specs=pl.BlockSpec((1, G * rows, D),
+                               lambda b, i, held, lens: (b, 0, 0),
+                               memory_space=pltpu.VMEM),
+        scratch_shapes=[
+            pltpu.VMEM((G * rows, 1), jnp.float32),
+            pltpu.VMEM((G * rows, 1), jnp.float32),
+            pltpu.VMEM((G * rows, D), jnp.float32),
+        ],
+    )
+    out = pl.pallas_call(
+        kernel,
+        name="flash_decode_paged_gqa",
+        out_shape=jax.ShapeDtypeStruct((B, G * rows, D), q.dtype),
+        grid_spec=grid_spec,
+        interpret=bool(interpret),
+    )(_held_blocks(tables, lengths, blk, P), lengths, qg,
+      *([k_pool] * P), *([v_pool] * P))
+    return out.reshape(B, G, rows, D)[:, :, :grp].reshape(B, N, 1, D)
 
 
 def _mla_decode_paged_kernel(held_ref, lengths_ref, q_ref, *refs, scale,

@@ -1,0 +1,107 @@
+"""The pieces the pre-norm expert decoders share (``models/deepseek.py``,
+``models/solar_open2.py``): parameters in ``cfg.dtype``, bias-free
+linears, RMSNorm, the gated SiLU MLP, the routed-expert layer over the
+experts HELD here with its shared experts, the untied float32 head, and a
+fresh pair of programs. A model passes its own name prefix, so parameter
+names are each model's own.
+
+A config gives ``dtype``, ``rms_norm_eps``, ``hidden_size``,
+``vocab_size``, ``moe_intermediate_size``, ``n_shared_experts``,
+``num_experts_per_tok``, ``routed_scaling_factor`` and, for the expert
+layer, ``n_routed_experts`` (the router's width: every expert of the
+model), ``experts_held`` and ``expert_offset`` (the experts whose weights
+lie here: global numbers ``expert_offset .. expert_offset + experts_held
+- 1``).
+"""
+
+import paddle_tpu.fluid as fluid
+from paddle_tpu.fluid.layer_helper import LayerHelper
+
+
+def param(name, shape, cfg, dtype=None, value=None):
+    init = None if value is None else fluid.initializer.Constant(value)
+    return fluid.layers.create_parameter(
+        shape=shape, dtype=dtype or cfg.dtype, name=name,
+        default_initializer=init)
+
+
+def linear(x, size, name):
+    """x W, no bias; W is ``<name>.w_0`` in x's dtype."""
+    return fluid.layers.fc(input=x, size=size, num_flatten_dims=2,
+                           bias_attr=False, name=name)
+
+
+def norm(x, cfg, name):
+    return fluid.layers.rms_norm(
+        x, param(name, [x.shape[-1]], cfg, value=1.0),
+        epsilon=cfg.rms_norm_eps)
+
+
+def gated_mlp(x, width, hidden, name):
+    h = fluid.layers.swiglu(linear(x, width, name + "_w1"),
+                            linear(x, width, name + "_w3"))
+    return linear(h, hidden, name + "_w2")
+
+
+def expert_layer(x, cfg, name):
+    """Routed experts (the router over all ``n_routed_experts``, the
+    grouped products over the ``experts_held`` here) + the shared experts
+    as one MLP. -> (y, counts int32 [experts_held])."""
+    e, held, h, i = (cfg.n_routed_experts, cfg.experts_held,
+                     cfg.hidden_size, cfg.moe_intermediate_size)
+    routed, counts = fluid.layers.moe_ffn(
+        x, param(name + "_router.w_0", [h, e], cfg),
+        param(name + "_router_bias", [e], cfg, dtype="float32", value=0.0),
+        param(name + "_experts_w1", [held, h, i], cfg),
+        param(name + "_experts_w3", [held, h, i], cfg),
+        param(name + "_experts_w2", [held, i, h], cfg),
+        num_experts=e, experts_per_token=cfg.num_experts_per_tok,
+        expert_offset=cfg.expert_offset, scaling=cfg.routed_scaling_factor)
+    shared = gated_mlp(x, cfg.n_shared_experts * i, h, name + "_shared")
+    return fluid.layers.elementwise_add(routed, shared), counts
+
+
+def lm_head(h, cfg, prefix):
+    """Final RMSNorm (``<prefix>_norm``) and the untied head
+    (``<prefix>_head.w_0``): float32 logits."""
+    x = norm(h, cfg, prefix + "_norm")
+    helper = LayerHelper(prefix + "_head")
+    w = param(prefix + "_head.w_0", [cfg.hidden_size, cfg.vocab_size], cfg)
+    out = helper.create_variable_for_type_inference("float32")
+    helper.append_op(
+        type="mul", inputs={"X": [x], "Y": [w]}, outputs={"Out": [out]},
+        attrs={"x_num_col_dims": len(x.shape) - 1, "y_num_col_dims": 1,
+               "out_dtype": fluid.core.np_to_dtype("float32")})
+    return out
+
+
+def programs(donate=False):
+    main, startup = fluid.Program(), fluid.Program()
+    main._donate_mutable = donate
+    return main, startup
+
+
+def last_row_logits(h, last_onehot, cfg, prefix):
+    """A prefill window's next-token logits: the last real token's hidden
+    row is picked (``last_onehot`` [N, T, 1]) BEFORE the head, so the head
+    runs on one row. -> [N, vocab] float32."""
+    last = fluid.layers.reduce_sum(fluid.layers.elementwise_mul(
+        fluid.layers.cast(h, "float32"), last_onehot), dim=1, keep_dim=True)
+    return fluid.layers.reshape(
+        lm_head(fluid.layers.cast(last, cfg.dtype), cfg, prefix),
+        shape=[-1, cfg.vocab_size])
+
+
+def expert_step_stats(counts):
+    """What one T = 1 step's expert counts (``[expert layers, experts
+    held]`` int32, as fetched) say, for the ``decode_paged_step`` span
+    and ``/metrics``."""
+    from paddle_tpu.fluid import profiler
+
+    out = dict(assignments=int(counts.sum()),
+               experts_hit=int((counts > 0).sum()),
+               expert_load_max=int(counts.max()))
+    profiler.bump_counter("moe_assignments", out["assignments"])
+    profiler.bump_counter("moe_experts_hit", out["experts_hit"])
+    profiler.bump_histogram("moe_expert_load_max", out["expert_load_max"])
+    return out

@@ -28,9 +28,12 @@ The module answers ``serving/decode.py``'s questions under the names
 """
 
 import paddle_tpu.fluid as fluid
-from paddle_tpu.fluid.layer_helper import LayerHelper
 
 from . import cache_kinds as _kinds
+from . import decoder_common as _dc
+from .decoder_common import (gated_mlp as _gated_mlp, linear as _linear,
+                             norm as _norm, param as _param,
+                             programs as _programs)
 
 CONFIG_KEYS = (
     "vocab_size", "hidden_size", "num_hidden_layers", "num_attention_heads",
@@ -63,6 +66,8 @@ class DeepseekConfig(object):
         self.dtype = dtype
         self.flash_interpret = flash_interpret
         self.is_test = True
+        # every routed expert lies here (``decoder_common.expert_layer``)
+        self.experts_held, self.expert_offset = n_routed_experts, 0
 
     @classmethod
     def from_config(cls, config, **kw):
@@ -108,31 +113,6 @@ UNSUPPORTED = {
     "tp": "tensor-parallel serving (tp > 1)",
     "kv_host_tier": "the host KV tier (kv_tier_host_mb)",
 }
-
-
-def _param(name, shape, cfg, dtype=None, value=None):
-    init = None if value is None else fluid.initializer.Constant(value)
-    return fluid.layers.create_parameter(
-        shape=shape, dtype=dtype or cfg.dtype, name=name,
-        default_initializer=init)
-
-
-def _linear(x, size, name):
-    """x W, no bias; W is ``<name>.w_0`` in x's dtype."""
-    return fluid.layers.fc(input=x, size=size, num_flatten_dims=2,
-                           bias_attr=False, name=name)
-
-
-def _norm(x, cfg, name):
-    return fluid.layers.rms_norm(
-        x, _param(name, [x.shape[-1]], cfg, value=1.0),
-        epsilon=cfg.rms_norm_eps)
-
-
-def _gated_mlp(x, width, hidden, name):
-    h = fluid.layers.swiglu(_linear(x, width, name + "_w1"),
-                            _linear(x, width, name + "_w3"))
-    return _linear(h, hidden, name + "_w2")
 
 
 def mla_attention(x, pos, cfg, name, cache=None):
@@ -182,23 +162,6 @@ def mla_attention(x, pos, cfg, name, cache=None):
     return _linear(ctxt, cfg.hidden_size, name + "_o")
 
 
-def _expert_layer(x, cfg, name):
-    """Routed experts (all held here: offset 0) + the shared experts as
-    one MLP. -> (y, counts int32 [n_routed_experts])."""
-    e, h, i = (cfg.n_routed_experts, cfg.hidden_size,
-               cfg.moe_intermediate_size)
-    routed, counts = fluid.layers.moe_ffn(
-        x, _param(name + "_router.w_0", [h, e], cfg),
-        _param(name + "_router_bias", [e], cfg, dtype="float32", value=0.0),
-        _param(name + "_experts_w1", [e, h, i], cfg),
-        _param(name + "_experts_w3", [e, h, i], cfg),
-        _param(name + "_experts_w2", [e, i, h], cfg),
-        num_experts=e, experts_per_token=cfg.num_experts_per_tok,
-        expert_offset=0, scaling=cfg.routed_scaling_factor)
-    shared = _gated_mlp(x, cfg.n_shared_experts * i, h, name + "_shared")
-    return fluid.layers.elementwise_add(routed, shared), counts
-
-
 def decoder(ids, pos, cfg, cache=None):
     """[N, T, 1] ids at positions ``pos`` [N, T, 1] -> (hidden [N, T, H]
     before the final norm, [per expert layer: counts])."""
@@ -217,7 +180,7 @@ def decoder(ids, pos, cfg, cache=None):
             ff = _gated_mlp(x, cfg.intermediate_size, cfg.hidden_size,
                             name + "_ffn")
         else:
-            ff, c = _expert_layer(x, cfg, name + "_moe")
+            ff, c = _dc.expert_layer(x, cfg, name + "_moe")
             counts.append(c)
         h = fluid.layers.elementwise_add(h, ff)
     return h, counts
@@ -225,21 +188,7 @@ def decoder(ids, pos, cfg, cache=None):
 
 def lm_head(h, cfg):
     """Final RMSNorm and the untied head: float32 logits."""
-    x = _norm(h, cfg, "ds_norm")
-    helper = LayerHelper("ds_head")
-    w = _param("ds_head.w_0", [cfg.hidden_size, cfg.vocab_size], cfg)
-    out = helper.create_variable_for_type_inference("float32")
-    helper.append_op(
-        type="mul", inputs={"X": [x], "Y": [w]}, outputs={"Out": [out]},
-        attrs={"x_num_col_dims": len(x.shape) - 1, "y_num_col_dims": 1,
-               "out_dtype": fluid.core.np_to_dtype("float32")})
-    return out
-
-
-def _programs(donate=False):
-    main, startup = fluid.Program(), fluid.Program()
-    main._donate_mutable = donate
-    return main, startup
+    return _dc.lm_head(h, cfg, "ds")
 
 
 def build_deepseek_infer(cfg, seq_len):
@@ -257,7 +206,8 @@ def build_deepseek_infer(cfg, seq_len):
     return main, startup, ["ids", "pos_ids"], logits
 
 
-def build_deepseek_paged_window(cfg, blocks, block, max_blocks, seq_len):
+def build_deepseek_paged_window(cfg, blocks, block, max_blocks, seq_len,
+                                slots=None):
     """Paged prefill-window graph, the contract of
     ``gpt.build_gpt_paged_window`` without the fed bias: ONE prompt window
     lands through the slot's fed ``table`` at ``window_pos``, and its
@@ -282,12 +232,7 @@ def build_deepseek_paged_window(cfg, blocks, block, max_blocks, seq_len):
                  "pools": _kinds.declare_pools(cache_kinds(cfg), blocks,
                                                block)}
         h, _counts = decoder(ids, pos_ids, cfg, cache=cache)
-        last = fluid.layers.reduce_sum(fluid.layers.elementwise_mul(
-            fluid.layers.cast(h, "float32"), last_onehot), dim=1,
-            keep_dim=True)
-        next_logits = fluid.layers.reshape(
-            lm_head(fluid.layers.cast(last, cfg.dtype), cfg),
-            shape=[-1, cfg.vocab_size])
+        next_logits = _dc.last_row_logits(h, last_onehot, cfg, "ds")
     return (main, startup,
             ["ids", "pos_ids", "table", "window_pos", "last_onehot"],
             next_logits)
@@ -341,22 +286,13 @@ def build_deepseek_paged_block_copy(cfg, blocks, block, npairs):
     return main, startup, ["src", "dst"], ok
 
 
-def step_stats(fetched, live_rows):
+def step_stats(fetched, live_rows, **_unused):
     """What one T = 1 step's expert counts say, for the
     ``decode_paged_step`` span and ``/metrics``: ``fetched`` is
     ``main._step_stats`` as fetched ([expert layers, experts] int32)."""
-    from paddle_tpu.fluid import profiler
-
     out = {"latent_rows_live": int(live_rows)}
     if fetched:
-        counts = fetched[0]
-        out.update(assignments=int(counts.sum()),
-                   experts_hit=int((counts > 0).sum()),
-                   expert_load_max=int(counts.max()))
-        profiler.bump_counter("moe_assignments", out["assignments"])
-        profiler.bump_counter("moe_experts_hit", out["experts_hit"])
-        profiler.bump_histogram("moe_expert_load_max",
-                                out["expert_load_max"])
+        out.update(_dc.expert_step_stats(fetched[0]))
     return out
 
 

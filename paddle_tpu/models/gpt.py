@@ -267,7 +267,8 @@ def paged_block_bytes(cfg, block):
     return _kinds.bytes_per_token(cache_kinds(cfg)) * int(block)
 
 
-def build_gpt_paged_window(cfg, blocks, block, max_blocks, seq_len):
+def build_gpt_paged_window(cfg, blocks, block, max_blocks, seq_len,
+                           slots=None):
     """Paged prefill-window graph: ONE prompt window (batch 1, padded to
     the ``seq_len`` bucket) lands THROUGH the slot's fed block table —
     the runtime's only prefill form (a whole prompt is a window at
@@ -278,7 +279,8 @@ def build_gpt_paged_window(cfg, blocks, block, max_blocks, seq_len):
     ``resume_bias`` [seq_len, max_blocks*block] (offset-shifted causal;
     -1e4 also buries sink-block garbage past the live length). Table,
     position, and bias are all runtime data: one program per bucket, 0
-    steady-state recompiles.
+    steady-state recompiles. ``slots`` sizes a model's per-slot states
+    (``cache_kinds.CacheState``); this model keeps none.
 
     Returns (main, startup, feed names, next_logits [1, vocab])."""
     import copy

@@ -539,7 +539,7 @@ class DecodeSession(object):
             with fluid.unique_name.guard():
                 main, _s, feeds, nl = self.model.build_paged_window(
                     self.cfg, self.pool_blocks, self.block_size,
-                    self.max_blocks, seq_len,
+                    self.max_blocks, seq_len, slots=self.slots,
                 )
             self._paged_window[seq_len] = (self._maybe_tp(main), nl.name)
             self._window_feeds = frozenset(feeds)
@@ -583,25 +583,42 @@ class DecodeSession(object):
         )
 
     # -- state ---------------------------------------------------------------
-    def pool_names(self):
-        """Per layer, the scope names of its pools."""
-        return [tuple(p.name(self.pool_blocks, self.block_size)
-                      for p in layer)
+    def cache_names(self):
+        """Per layer, the scope names of the vars it keeps (pools and
+        per-slot states, in ``cache_kinds``' order)."""
+        return [_cache_kinds.names(layer, self.pool_blocks,
+                                   self.block_size, self.slots)
                 for layer in self.model.cache_kinds(self.cfg)]
 
+    def pool_names(self):
+        """Per layer, the scope names of its paged pools (none for a
+        layer that keeps only per-slot state)."""
+        return [tuple(p.name(self.pool_blocks, self.block_size)
+                      for p in _cache_kinds.pools(layer))
+                for layer in self.model.cache_kinds(self.cfg)]
+
+    def kv_pool_names(self):
+        """Per layer its (K pool, V pool) names, for the modes that move
+        a block as a pair of equal rows; ``TypeError`` where a layer
+        keeps anything else."""
+        return [tuple(p.name(self.pool_blocks, self.block_size)
+                      for p in pair)
+                for pair in _cache_kinds.kv_pools(
+                    self.model.cache_kinds(self.cfg))]
+
     def reset_caches(self):
-        """Zero every pool in the scope (host-side: no program, no
-        param re-init). Correctness never depends on this — nothing
-        attends to a position its slot has not written — but fresh
-        buffers make warmup and tests deterministic."""
-        geometry = (self.pool_blocks, self.block_size)
+        """Zero every pool and every per-slot state in the scope
+        (host-side: no program, no param re-init). Correctness never
+        depends on this — nothing attends to a position its slot has not
+        written, and a prompt's first window starts its state from zeros
+        itself — but fresh buffers make warmup and tests deterministic."""
+        geometry = (self.pool_blocks, self.block_size, self.slots)
         for layer in self.model.cache_kinds(self.cfg):
-            for pool in layer:
-                self.scope.set(
-                    pool.name(*geometry),
-                    np.zeros(pool.shape(*geometry),
-                             fluid.core.dtype_to_np(pool.dtype)),
-                )
+            for kind, name, shape in zip(
+                    layer, _cache_kinds.names(layer, *geometry),
+                    _cache_kinds.shapes(layer, *geometry)):
+                self.scope.set(name, np.zeros(
+                    shape, fluid.core.dtype_to_np(kind.dtype)))
 
     def bind_params(self, program):
         """Alias ``program``'s parameters onto this session's canonical
@@ -646,12 +663,16 @@ class DecodeSession(object):
                              while_device_runs=self.while_device_runs)
 
     # -- paged device steps --------------------------------------------------
-    def paged_window(self, table, window_ids, offset):
+    def paged_window(self, table, window_ids, offset, slot=0):
         """Prefill one prompt window (batch 1) THROUGH a fed block
         table: window token i lands at logical position ``offset + i``,
         which ``table`` maps to a physical pool block — the only
         prefill form (offset 0 = the whole prompt, later offsets the
         suffix after a shared prefix or one chunk of a chunked prefill).
+        A model that keeps per-slot state is fed ``slot``'s state row
+        (``slot + 1``; row 0 is the sink) and the window's real length:
+        at offset 0 the state starts from zeros, later windows continue
+        the row.
         The window pads to its bucket; the offset rides the feed, so the
         bucket ladder's compiled programs cover every placement. Returns
         the logits [vocab] at the window's last real token (the
@@ -692,10 +713,15 @@ class DecodeSession(object):
                          <= (offset + np.arange(T))[:, None])
                 feed["resume_bias"] = np.where(
                     allow, 0.0, -1e4).astype("float32")[None]
+            if "state_row" in self._window_feeds:
+                feed["state_row"] = np.array([[int(slot) + 1]], "int64")
+                feed["window_len"] = np.array([[P]], "int64")
         t0 = time.perf_counter()
         with _trace.span("decode_paged_window", cat="serving",
-                         bucket=T, rows=P, offset=offset):
+                         bucket=T, rows=P, offset=offset) as sp:
             (lv,) = self._run(main, feed, [fetch_name])
+            if hasattr(self.model, "window_stats"):
+                sp.note(**self.model.window_stats(self.cfg, offset, P, T))
         _profiler.bump_counter("decode_prefills")
         self.prefills += 1
         _profiler.bump_histogram(
@@ -748,6 +774,13 @@ class DecodeSession(object):
                     ((self._cols[None, None, :] > qpos[:, :, None])
                      | ~act[:, None, None]).astype("float32") * -1e4
                 )
+            if "state_rows" in self._step_feeds:
+                # an active slot steps its own state row; an idle or
+                # prefilling one the sink row 0, so the fused step cannot
+                # clobber a state that is between two prefill windows
+                feed["state_rows"] = np.where(
+                    act, np.arange(self.slots) + 1, 0
+                ).astype("int64").reshape(self.slots, 1)
             # of the slots x max_blocks table entries, the ones that hold
             # a live key after this window's writes: the share of the
             # table the T = 1 kernel fetches and computes
@@ -763,7 +796,8 @@ class DecodeSession(object):
             if len(fetches) > 1:
                 sp.note(**self.model.step_stats(
                     [np.asarray(v) for v in stats],
-                    live_rows=int((pos[act] + width).sum())))
+                    live_rows=int((pos[act] + width).sum()),
+                    live_slots=int(act.sum()), cfg=self.cfg))
         _profiler.bump_counter("decode_steps")
         self.steps += 1
         _profiler.bump_histogram(
@@ -1265,11 +1299,14 @@ class DecodeEngine(object):
                  pool_blocks=0, drafter=None, tp=None, model=None):
         self._cfg = cfg
         # the served model's module: ``models/gpt.py`` unless told. Its
-        # ``cache_kinds`` give the cache bytes a token costs over all
-        # layers, which is what sizes the pool's accounting
+        # ``cache_kinds`` give the paged cache bytes a token costs over
+        # all layers, which is what sizes the pool's accounting
         self._model = model if model is not None else _gpt
-        self.kv_bytes_per_token = _cache_kinds.bytes_per_token(
-            self._model.cache_kinds(cfg))
+        kinds = self._model.cache_kinds(cfg)
+        self.kv_bytes_per_token = _cache_kinds.bytes_per_token(kinds)
+        # what a slot keeps whatever its length (a recurrent state):
+        # counted beside the pools, never through the block allocator
+        self.state_bytes_per_slot = _cache_kinds.state_bytes_per_slot(kinds)
         self._place = (place if place is not None
                        else fluid.core.default_place())
         # a Place that names no device of this process fails here, not
@@ -1374,6 +1411,8 @@ class DecodeEngine(object):
         self._blocks_free_gauge = None
         self._blocks_shared_gauge = None
         self._spec_gauge = None
+        self._state_slots_gauge = None
+        self._state_bytes_gauge = None
         self._host_blocks_gauge = None
         self._host_bytes_gauge = None
 
@@ -1408,6 +1447,9 @@ class DecodeEngine(object):
         self.allocator = BlockAllocator(self.session.pool_blocks)
         self.pindex = None
         if self.prefix_cache_mb > 0:
+            if self.kv_host_mb > 0:
+                _require(self._model, "kv_host_tier")
+            _require(self._model, "prefix_cache")
             # the store is ZERO-copy (entries pin pool blocks slots
             # already wrote), so the mb budget caps how many blocks the
             # store may pin, not a separate allocation
@@ -1419,7 +1461,6 @@ class DecodeEngine(object):
                 self.block_size, cap, self.allocator
             )
             if self.kv_host_mb > 0:
-                _require(self._model, "kv_host_tier")
                 # host tier behind the device index: eviction spills
                 # instead of vanishing, admission walks here when
                 # the device chain runs out
@@ -1469,6 +1510,19 @@ class DecodeEngine(object):
             _obs_registry.register_gauge(
                 "decode_blocks_shared", self._blocks_shared_gauge
             )
+            if self.state_bytes_per_slot:
+                # per-slot recurrent state: rows in use (decoding or
+                # between prefill windows) and the bytes they hold
+                self._state_slots_gauge = lambda e=self: (
+                    len(e._active) + len(e._prefilling))
+                _obs_registry.register_gauge(
+                    "decode_state_slots_live", self._state_slots_gauge
+                )
+                self._state_bytes_gauge = lambda e=self: (
+                    e._state_slots_gauge() * e.state_bytes_per_slot)
+                _obs_registry.register_gauge(
+                    "decode_state_bytes", self._state_bytes_gauge
+                )
             if self._spec_width > 1:
                 self._spec_gauge = lambda e=self: (
                     e._counts["spec_accepted"]
@@ -1520,6 +1574,8 @@ class DecodeEngine(object):
             ("decode_blocks_free", "_blocks_free_gauge"),
             ("decode_blocks_shared", "_blocks_shared_gauge"),
             ("decode_spec_acceptance", "_spec_gauge"),
+            ("decode_state_bytes", "_state_bytes_gauge"),
+            ("decode_state_slots_live", "_state_slots_gauge"),
             ("kv_tier_host_blocks", "_host_blocks_gauge"),
             ("kv_tier_host_bytes", "_host_bytes_gauge"),
         ):
@@ -1533,16 +1589,18 @@ class DecodeEngine(object):
         bucket, each step width (1 + the spec verify; a step's compiled
         shape is independent of WHICH slots are active, so one
         all-inactive step covers every future mix) and the COW block
-        copy. All-sink tables make every warmup write inert garbage in
-        reserved block 0 — nothing live to reset, but zeroing the pools
-        afterwards keeps tests deterministic."""
+        copy. All-sink tables (and the sink state row) make every warmup
+        write inert garbage in reserved block 0 / row 0 — nothing live
+        to reset, but zeroing the caches afterwards keeps tests
+        deterministic."""
         sess = self.session
         with _xla_stats.warmup_window(), _trace.span(
             "decode_warmup", cat="serving"
         ):
             sink = [0] * sess.max_blocks
             for T in sess.buckets:
-                sess.paged_window(sink, [0] * T, 0)
+                # slot -1: the sink state row
+                sess.paged_window(sink, [0] * T, 0, slot=-1)
             for w in sorted(sess._paged_step):
                 sess.paged_step(
                     np.zeros((sess.slots, w), "int64"),
@@ -1775,6 +1833,8 @@ class DecodeEngine(object):
                 / self._counts["spec_drafted"]
             )
         out["prompt_tokens"] = self._counts["prompt_tokens"]
+        out["kv_bytes_per_token"] = self.kv_bytes_per_token
+        out["state_bytes_per_slot"] = self.state_bytes_per_slot
         if self.allocator is not None:
             paged = self.allocator.stats()
             paged["block_size"] = self.block_size
@@ -1937,6 +1997,8 @@ class DecodeEngine(object):
         out["blocks_total"] = total
         out["blocks_in_use"] = total - self.allocator.free_blocks
         out["kv_bytes_per_token"] = self.kv_bytes_per_token
+        if self.state_bytes_per_slot:
+            out["state_bytes_per_slot"] = self.state_bytes_per_slot
         return out
 
     def _reap_cancelled(self):
@@ -2281,7 +2343,7 @@ class DecodeEngine(object):
         scope value (post reset/readmit) it is a zero-copy view."""
         sess = self.session
         out = []
-        for k_name, v_name in sess.pool_names():
+        for k_name, v_name in sess.kv_pool_names():
             out.append((np.asarray(sess.scope.get(k_name)),
                         np.asarray(sess.scope.get(v_name))))
         return out
@@ -2374,7 +2436,7 @@ class DecodeEngine(object):
         if not hits:
             return entries
         sess = self.session
-        names = sess.pool_names()
+        names = sess.kv_pool_names()
         idx = np.array([blk for _he, blk in hits], np.int32)
         for li, (k_name, v_name) in enumerate(names):
             k_rows = np.stack([he.payload[li][0] for he, _b in hits])
@@ -2469,8 +2531,14 @@ class DecodeEngine(object):
         """[r0, block, r1]: one block of a pool as it lies on the device
         (``models/cache_kinds.py``) — the geometry exported chain blocks
         are advertised under, and the one a pulled blob must name."""
-        pool = self.session.model.cache_kinds(self.session.cfg)[0][0]
-        return pool.shape(1, self.block_size)[1:]
+        _require(self._model, "block_export")
+        rows = {tuple(pool.shape(1, self.block_size)[1:])
+                for pair in _cache_kinds.kv_pools(
+                    self._model.cache_kinds(self._cfg)) for pool in pair}
+        if len(rows) != 1:
+            raise TypeError("the layers' pools differ in their row: %s"
+                            % sorted(rows))
+        return list(rows.pop())
 
     def offer_blocks(self, entries):
         """Inject chain blocks pulled from a prefill-role peer
@@ -2478,6 +2546,7 @@ class DecodeEngine(object):
         the very next admission whose chain reaches them re-admits
         H2D through the standard spilled-block path, with the same
         verification. Returns the number of blocks accepted."""
+        _require(self._model, "block_export")
         if self.host_store is None:
             return 0
         n = 0
@@ -2493,6 +2562,7 @@ class DecodeEngine(object):
         thread — the single mutator — so this parks a job the tick
         serves and waits (bounded). Returns [(key, prev, tokens,
         payload)] in chain order, or None on timeout/stopped."""
+        _require(self._model, "block_export")
         if not self.started or self.pindex is None:
             return None
         ev = threading.Event()
@@ -2619,7 +2689,8 @@ class DecodeEngine(object):
                 # every prefill is a table-fed window (a whole prompt =
                 # a window at offset 0)
                 logits = self.session.paged_window(
-                    self._slot_blocks[slot_idx], prompt[s:e], s
+                    self._slot_blocks[slot_idx], prompt[s:e], s,
+                    slot=slot_idx,
                 )
             job.wi += 1
             if job.wi < len(job.windows):

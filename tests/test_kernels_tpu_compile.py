@@ -104,6 +104,34 @@ def _latent(block, dtype, slots=64, max_len=4352, heads=32, row=640,
                 ((slots,), jnp.int32)], 1
 
 
+def _grouped(block=128, slots=64, max_len=5632, heads=64, kv_heads=8, d=128):
+    """The paged T = 1 kernel on grouped heads at the widths of the cell
+    ``solar2-serve-reason4k``: 64 query heads on 8 key heads of 128, a
+    1024-lane bfloat16 pool row."""
+    def fn(q, kp, vp, tables, lengths):
+        return fa.flash_decode_paged_attention(
+            q, kp, vp, tables, lengths=lengths, interpret=False)
+
+    max_blocks = max_len // block
+    pool = ((slots * max_blocks + 1, block, kv_heads * d), BF16)
+    return fn, [((slots, heads, 1, d), BF16), pool, pool,
+                ((slots, max_blocks), jnp.int32),
+                ((slots,), jnp.int32)], 1
+
+
+def _kda_decode(slots=64, heads=64, d=128):
+    """The delta-rule T = 1 kernel at the same cell's widths: 64 heads
+    of a 128 x 128 float32 state a slot, rewritten in place."""
+    from paddle_tpu.kernels import kda
+
+    def fn(state, rows, q, k, v, a, b):
+        return kda.kda_decode(state, rows, q, k, v, a, b, interpret=False)
+
+    vec = ((slots, heads, d), F32)
+    return fn, [((slots + 1, heads, d, d), F32), ((slots,), jnp.int32),
+                vec, vec, vec, vec, ((slots, heads), F32)], 1
+
+
 CASES = {
     "flash_causal_b8_s1024": lambda: _train(8, 1024, True),
     "flash_causal_b4_s4096": lambda: _train(4, 4096, True),
@@ -127,6 +155,8 @@ CASES = {
     "latent_block128_bf16": lambda: _latent(128, BF16),
     "latent_block128_f32": lambda: _latent(128, F32),
     "latent_block16_bf16": lambda: _latent(16, BF16),
+    "grouped_64q_8kv_block128_bf16": _grouped,
+    "kda_decode_64slots_64heads": _kda_decode,
 }
 
 
@@ -363,3 +393,84 @@ def test_gpt_window_program_takes_the_pool_as_it_lies(chip, monkeypatch,
             "resume_bias": (1, bucket, max_blocks * block),
             "last_onehot": (1, bucket, 1)})
     _gpt_pool_checks(cfg, blocks, block, built)
+
+
+def _compile_hybrid_program(chip, monkeypatch, which, feed_shapes):
+    """A program of the cell ``solar2-serve-reason4k`` (64 slots of 5632
+    positions, blocks of 128; 2 softmax + 6 delta-rule layers, 20 of 320
+    experts held), built by ``models/solar_open2.py``."""
+    from paddle_tpu.models import solar_open2
+
+    return _compile_paged_program(
+        chip, monkeypatch, "solar-open2-250b.json",
+        lambda config: solar_open2.SolarOpen2Config.from_config(
+            config, dtype="bfloat16"),
+        which, feed_shapes)
+
+
+def _hybrid_cache_checks(cfg, blocks, block, built, slots=64):
+    """The program fits the chip beside nothing else, updates pools AND
+    states in place, and copies neither whole. -> its text."""
+    import re
+
+    from paddle_tpu.models import cache_kinds, solar_open2
+
+    kinds = solar_open2.cache_kinds(cfg)
+    held = (cache_kinds.bytes_per_token(kinds) * blocks * block
+            + cache_kinds.state_bytes_per_slot(kinds) * (slots + 1))
+    memory = built.memory_analysis()
+    assert held > 4.5e9                                  # the cell's caches
+    assert memory.alias_size_in_bytes >= held            # updated in place
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            < 15.5e9)
+    text = built.as_text()
+    pool = r"bf16\[%d,1,%d,1024\]" % (blocks, block)
+    state = r"f32\[%d,64,128,128\]" % (slots + 1)
+    assert not re.findall(r"%%copy[.\d]* = (%s|%s)" % (pool, state), text)
+    return text
+
+
+def test_hybrid_step_program_steps_states_and_pools_in_place(
+        chip, monkeypatch):
+    """The whole T = 1 step: one grouped paged kernel a softmax layer, one
+    delta-rule kernel a delta-rule layer, three grouped products a layer
+    over the 20 experts held."""
+    import re
+
+    from paddle_tpu.models import solar_open2
+
+    def step(cfg, blocks, block, max_blocks, slots):
+        main, _s, feeds, logits = solar_open2.build_paged_step(
+            cfg, slots, blocks, block, max_blocks)
+        return main, feeds, [logits.name] + main._step_stats
+
+    cfg, blocks, block, built = _compile_hybrid_program(
+        chip, monkeypatch, step, lambda slots, max_blocks, block: {
+            "step_ids": (slots, 1, 1), "step_pos": (slots, 1, 1),
+            "tables": (slots, max_blocks), "state_rows": (slots, 1)})
+    text = _hybrid_cache_checks(cfg, blocks, block, built)
+    assert len(re.findall(r"%flash_decode_paged_gqa[.\d]* = ", text)) == 2
+    assert len(re.findall(r"%kda_decode[.\d]* = ", text)) == 6
+    assert len(re.findall(r"%ragged-dot-none[.\d]* = ", text)) == 3 * 8
+    assert built.memory_analysis().temp_size_in_bytes < 0.5e9
+
+
+def test_hybrid_window_program_scans_chunks_and_loops_over_keys(
+        chip, monkeypatch):
+    """The largest prefill window (2048 tokens) from a fed state row."""
+    from paddle_tpu.models import solar_open2
+
+    t = 2048
+
+    def window(cfg, blocks, block, max_blocks, slots):
+        main, _s, feeds, logits = solar_open2.build_paged_window(
+            cfg, blocks, block, max_blocks, t, slots=slots)
+        return main, feeds, [logits.name]
+
+    cfg, blocks, block, built = _compile_hybrid_program(
+        chip, monkeypatch, window, lambda slots, max_blocks, block: {
+            "ids": (1, t, 1), "pos_ids": (1, t, 1), "table": (1, max_blocks),
+            "window_pos": (1, 1), "last_onehot": (1, t, 1),
+            "state_row": (1, 1), "window_len": (1, 1)})
+    _hybrid_cache_checks(cfg, blocks, block, built)
+    assert built.memory_analysis().temp_size_in_bytes < 2.5e9

@@ -540,6 +540,7 @@ def test_failed_stream_leaves_a_record_too(gen_server):
 # -- kernels -----------------------------------------------------------------
 KERNELS = {
     "flash_decode_paged_attention": "flash_decode_paged",
+    "_decode_paged_grouped": "flash_decode_paged_gqa",
     "mla_decode_paged_attention": "mla_decode_paged",
     "_flash_fwd_impl": "flash_fwd",
     "_flash_bwd_core": ("flash_bwd_dq", "flash_bwd_dkv"),
@@ -575,7 +576,7 @@ def test_each_pallas_call_passes_its_own_name(site):
     found = _pallas_call_names()
     assert found[site] == want
     every = [n for names in found.values() for n in names]
-    assert None not in every and len(set(every)) == len(every) == 5
+    assert None not in every and len(set(every)) == len(every) == 6
 
 
 def test_kernel_name_reaches_the_lowered_program():
