@@ -49,9 +49,11 @@ tick:
 ``engine_tick`` holding ``tick_reap``, ``tick_admit``, ``tick_prefill``,
 ``tick_build``, ``decode_tick``, ``tick_sample_emit``; ``engine_wait``
 while the loop has nothing to do. ``decode_tick`` is NOT the tick: it is
-the fused device call of a tick (``step_feed``, ``decode_paged_step``,
-``step_logits``), annotated with the trace ids of the streams it
-decoded. A request leaves one ``decode_request`` instant
+the fused device call of a tick (``step_feed``, ``decode_paged_step``),
+annotated with the trace ids of the streams it decoded; ``step_logits``
+is the copy of logits to the host (a window's row; of a step the rows of
+a sampled stream, inside ``tick_sample_emit``). A request leaves one
+``decode_request`` instant
 (its times on this clock) and one ``gateway_request`` span.
 """
 

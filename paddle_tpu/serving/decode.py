@@ -25,6 +25,9 @@ batch 1. This module replaces that with the production decode shape.
            step over ALL slots: each active slot contributes its pending
            token (plus a k-1 draft under ``decode_spec_tokens`` = k, ONE
            batched verify) against its table, masked by its own length.
+           The program ends in the greedy pick (``step_tail``): a tick
+           fetches [slots, width] ids, the logits stay on the device for
+           the streams that sample.
   prefix   ``PagedPrefixIndex`` maps hash-chained prompt-token blocks to
            the pool blocks a finished prefill already wrote: ZERO-copy.
            A hit puts the block into the admitted slot's table and
@@ -79,6 +82,7 @@ __all__ = [
     "prefill_ladder",
     "sample_token",
     "session_for_generate",
+    "step_tail",
 ]
 
 
@@ -420,6 +424,24 @@ class PagedPrefixIndex(object):
         }
 
 
+def step_tail(main, startup, logits, width):
+    """The tail the session gives every family's step program of
+    ``width``: the greedy pick is made where the logits are
+    (``argmax`` over the vocabulary of the [slots * width, vocab] float32
+    ``logits``; equal logits go to the lowest id), and the logits stay on
+    the device as a persistable scope var, the way the pools do. So a
+    step brings slots * width ids to the host, and a sampled stream's
+    rows are read from the scope (``DecodeSession.step_logits``).
+    -> (the ids' name, the kept logits' name)."""
+    with fluid.program_guard(main, startup):
+        pick = fluid.layers.argmax(logits, axis=-1)
+        kept = main.global_block().create_var(
+            name="decode_step_logits.w%d" % width, shape=logits.shape,
+            dtype=logits.dtype, persistable=True)
+        fluid.layers.assign(logits, kept)
+    return pick.name, kept.name
+
+
 class DecodeSession(object):
     """Synchronous KV-cache decode core over one Executor + scope.
 
@@ -548,15 +570,17 @@ class DecodeSession(object):
             widths.append(self.spec_tokens)
         for w in widths:
             with fluid.unique_name.guard():
-                main, _s, feeds, sl = self.model.build_paged_step(
+                main, startup, feeds, sl = self.model.build_paged_step(
                     self.cfg, self.slots, self.pool_blocks,
                     self.block_size, self.max_blocks, step_w=w,
                 )
-            # a program may name values to fetch beside the logits
+                pick, kept = step_tail(main, startup, sl, w)
+            # a program may name values to fetch beside the ids
             # (``_step_stats``), which the model's ``step_stats`` reads
             self._paged_step[w] = (
                 self._maybe_tp(main),
-                [sl.name] + list(getattr(main, "_step_stats", ())),
+                [pick] + list(getattr(main, "_step_stats", ())),
+                kept,
             )
             self._step_feeds = frozenset(feeds)
         with fluid.unique_name.guard():
@@ -731,6 +755,24 @@ class DecodeSession(object):
             return np.asarray(lv)[0]
 
     def paged_step(self, tokens, positions, tables, active, width=1):
+        """``paged_step_ids`` for a caller that compares logits (the
+        reference paths, the probes): the same one device call, then the
+        whole of what it left in the scope. Returns logits
+        [slots, width, vocab]."""
+        self.paged_step_ids(tokens, positions, tables, active, width=width)
+        return self.step_logits(width=width)
+
+    def step_logits(self, slot=None, width=1):
+        """The logits the last step of ``width`` left on the device:
+        [slots, width, vocab], or ``slot``'s [width, vocab] rows alone,
+        sliced there, so a sampled stream costs the host its own rows."""
+        with _trace.span("step_logits", cat="serving"):
+            lv = self.scope.get(self._paged_step[width][2])
+            if slot is None:
+                return np.asarray(lv).reshape(self.slots, width, -1)
+            return np.asarray(lv[slot * width:(slot + 1) * width])
+
+    def paged_step_ids(self, tokens, positions, tables, active, width=1):
         """ONE fused paged step over all slots: slot s advances the
         ``width``-token window ``tokens[s]`` at contiguous logical
         positions ``positions[s] .. positions[s]+width-1`` through its
@@ -739,8 +781,11 @@ class DecodeSession(object):
         in one call). Inactive slots feed an inert zero token and an
         all-sink table, so their unconditional scatter-writes land in
         reserved block 0 and can never corrupt a live block; their
-        attention output is fully masked and ignored. Returns logits
-        [slots, width, vocab]."""
+        attention output is fully masked and ignored. Returns each
+        query's greedy token, [slots, width] integers: the argmax of its
+        float32 logits row, taken on the device (equal logits go to the
+        lowest id, as ``numpy.argmax`` has it). The logits stay there:
+        ``step_logits`` reads them."""
         if width not in self._paged_step:
             raise ValueError(
                 "no paged step program of width %d (built: %s)"
@@ -758,7 +803,7 @@ class DecodeSession(object):
                 row = tables[s] if tables is not None else ()
                 if len(row):
                     tbl[s, :len(row)] = row
-            main, fetches = self._paged_step[width]
+            main, fetches, _kept = self._paged_step[width]
             feed = {
                 "step_ids": tok.reshape(self.slots, width, 1),
                 "step_pos": qpos.reshape(self.slots, width, 1)
@@ -792,7 +837,7 @@ class DecodeSession(object):
                          active=int(act.sum()), width=width,
                          blocks_live=blocks_live,
                          blocks_table=self.slots * self.max_blocks) as sp:
-            lv, *stats = self._run(main, feed, fetches)
+            picked, *stats = self._run(main, feed, fetches)
             if len(fetches) > 1:
                 sp.note(**self.model.step_stats(
                     [np.asarray(v) for v in stats],
@@ -803,8 +848,7 @@ class DecodeSession(object):
         _profiler.bump_histogram(
             "decode_step_ms", (time.perf_counter() - t0) * 1e3
         )
-        with _trace.span("step_logits", cat="serving"):
-            return np.asarray(lv).reshape(self.slots, width, -1)
+        return np.asarray(picked).reshape(self.slots, width)
 
     def block_copy(self, src_blocks, dst_blocks):
         """Pool-internal block copy (all layers, K and V):
@@ -868,17 +912,20 @@ def session_for_generate(exe, cfg, scope, max_len, param_program):
 
 
 # ---------------------------------------------------------------------------
-# sampling — host-side, over the decode step's FETCHED logits
+# sampling — host-side, over a logits row FETCHED for the stream (greedy
+# streams' step tokens are picked on the device: ``step_tail``)
 # ---------------------------------------------------------------------------
 
 
 def sample_token(logits, temperature=0.0, top_k=0, top_p=0.0, rng=None):
     """Pick one token id from a ``[vocab]`` logits row.
 
-    Host-side by design: the compiled prefill/decode programs already
-    fetch the logits, so sampling over them adds zero graph surface — no
-    new compiled program, no shape change, the strict-compile gate never
-    sees it. ``temperature <= 0`` is GREEDY (argmax), the default
+    Host-side by design: a prefill window fetches its row and a step
+    keeps its logits where a sampled stream's rows can be read
+    (``DecodeSession.step_logits``), so sampling over them adds zero
+    graph surface — no new compiled program, no shape change, the
+    strict-compile gate never sees it. ``temperature <= 0`` is GREEDY
+    (argmax), the default
     everywhere, which keeps every token-exact parity contract intact;
     ``top_k``/``top_p`` only apply when temperature sampling is on.
     ``rng`` is a ``np.random.RandomState`` (seeded per request by the
@@ -1375,7 +1422,8 @@ class DecodeEngine(object):
                         "oom_sheds": 0,
                         "kv_readmits": 0, "kv_readmit_tokens": 0,
                         "preemptions": 0, "preempt_replayed_tokens": 0,
-                        "published_overlapped": 0, "published_exposed": 0}
+                        "published_overlapped": 0, "published_exposed": 0,
+                        "picks_on_device": 0, "picks_on_host": 0}
         # what the loop thread has decided and not yet handed to the
         # streams' consumers: (stream, token or sentinel) in order.
         # ``_publish`` empties it once the next device call is
@@ -1602,11 +1650,13 @@ class DecodeEngine(object):
                 # slot -1: the sink state row
                 sess.paged_window(sink, [0] * T, 0, slot=-1)
             for w in sorted(sess._paged_step):
-                sess.paged_step(
+                sess.paged_step_ids(
                     np.zeros((sess.slots, w), "int64"),
                     [0] * sess.slots, [()] * sess.slots,
                     [False] * sess.slots, width=w,
                 )
+                # the slice a sampled stream's rows are read through
+                sess.step_logits(0, width=w)
             sess.block_copy([0], [0])
             sess.reset_caches()
 
@@ -1826,6 +1876,8 @@ class DecodeEngine(object):
                 self._counts["preempt_replayed_tokens"],
             "published_overlapped": self._counts["published_overlapped"],
             "published_exposed": self._counts["published_exposed"],
+            "picks_on_device": self._counts["picks_on_device"],
+            "picks_on_host": self._counts["picks_on_host"],
         }
         if self._counts["spec_drafted"]:
             out["spec_acceptance"] = (
@@ -2832,9 +2884,13 @@ class DecodeEngine(object):
         position next_pos+j+1 (draft j+1) equals the one it just
         emitted — so every consumed logits row is bitwise the row the
         sequential engine would have produced. Each EMITTED token costs
-        exactly one ``pick`` (greedy: zero RNG draws; sampled: the PR 13
-        one-uniform inverse-CDF draw), so ``fast_forward_rng`` resume
-        and seeded replay hold unchanged. The rejected tail's K/V is
+        exactly one pick (greedy: the step's own argmax of that row,
+        fetched as an id, zero RNG draws; sampled, which is a stream
+        that holds an RNG: ``pick`` over the row read back from the
+        device, the PR 13 one-uniform inverse-CDF draw), so
+        ``fast_forward_rng`` resume and seeded replay hold unchanged;
+        ``decode_picks_on_device`` / ``_on_host`` count which way the
+        step's tokens were picked. The rejected tail's K/V is
         dead weight the step bias never exposes; ``_trim_blocks`` rolls
         whole rejected blocks back by table edit."""
         sess = self.session
@@ -2849,24 +2905,32 @@ class DecodeEngine(object):
             with _trace.span("decode_tick", cat="serving",
                              tick=self.tick, trace_ids=tids), \
                     _xla_stats.serving_request_window():
-                logits = sess.paged_step(tokens, positions, tables,
-                                         active, width=width)
+                ids = sess.paged_step_ids(tokens, positions, tables,
+                                          active, width=width)
         else:
             with _xla_stats.serving_request_window():
-                logits = sess.paged_step(tokens, positions, tables,
-                                         active, width=width)
+                ids = sess.paged_step_ids(tokens, positions, tables,
+                                          active, width=width)
         self.tick += 1
         cpu0 = time.thread_time()
         with _trace.span("tick_sample_emit", cat="serving") as sp:
-            total = 0
+            ids = ids.tolist()
+            total = on_host = 0
             for idx in list(self._active.keys()):
                 slot = self._active[idx]
                 win = windows[idx]
                 emitted = 0
                 failed = False
+                rows = None
                 for j in range(width):
                     try:
-                        tok = slot.stream.pick(logits[idx, j])
+                        if slot.stream._rng is None:
+                            tok = ids[idx][j]
+                        else:
+                            if rows is None:
+                                rows = sess.step_logits(idx, width=width)
+                            tok = slot.stream.pick(rows[j])
+                            on_host += 1
                     except Exception as e:  # noqa: BLE001 - this stream
                         self._active.pop(idx, None)
                         self._free.append(idx)
@@ -2898,6 +2962,13 @@ class DecodeEngine(object):
                 if idx in self._active:
                     self._trim_blocks(idx, slot.next_pos)
                 total += emitted
+            on_device = total - on_host
+            if on_device:
+                _profiler.bump_counter("decode_picks_on_device", on_device)
+                self._counts["picks_on_device"] += on_device
+            if on_host:
+                _profiler.bump_counter("decode_picks_on_host", on_host)
+                self._counts["picks_on_host"] += on_host
             sp.note(tokens=total,
                     cpu_ms=(time.thread_time() - cpu0) * 1e3)
 

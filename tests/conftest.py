@@ -94,3 +94,35 @@ def engine_free_oracle(model, prompt, n, max_len, sampling=None):
                 row, temperature=sampling["temperature"],
                 top_k=sampling["top_k"], rng=rng))
     return ids[len(prompt):]
+
+
+def record_picked_rows(monkeypatch, engine):
+    """{id(stream): [the [vocab] logits row each of its tokens was picked
+    from, in order]} for ``engine`` (plain width-1 steps): a row handed to
+    ``pick`` on the host (a window's; a sampled stream's step) as it is
+    handed, a greedy stream's step row as the step left it on the device
+    beside the id the engine fetched (``step_logits``)."""
+    import numpy as np
+
+    from paddle_tpu.serving.decode import GenerationStream
+
+    seen = {}
+    pick = GenerationStream.pick
+    sess = engine.session
+    step = sess.paged_step_ids
+
+    def recording_pick(self, logits):
+        seen.setdefault(id(self), []).append(np.array(logits))
+        return pick(self, logits)
+
+    def recording_step(tokens, positions, tables, active, width=1):
+        ids = step(tokens, positions, tables, active, width=width)
+        rows = sess.step_logits(width=width)
+        for idx, slot in engine._active.items():
+            if slot.stream._rng is None:
+                seen.setdefault(id(slot.stream), []).append(rows[idx, 0])
+        return ids
+
+    monkeypatch.setattr(GenerationStream, "pick", recording_pick)
+    monkeypatch.setattr(sess, "paged_step_ids", recording_step)
+    return seen

@@ -612,7 +612,7 @@ def test_engine_step_failure_retires_slots_and_recovers(rig):
     the engine serving subsequent requests."""
     engine = rig["engine"]
     session = engine.session
-    real_step = session.paged_step
+    real_step = session.paged_step_ids
     boom = {"armed": True}
 
     def failing_step(*a, **kw):
@@ -622,13 +622,13 @@ def test_engine_step_failure_retires_slots_and_recovers(rig):
         return real_step(*a, **kw)
 
     c0 = profiler.get_counters()
-    session.paged_step = failing_step
+    session.paged_step_ids = failing_step
     try:
         s = engine.generate([1, 2], max_new_tokens=4)
         with pytest.raises(RuntimeError, match="injected step failure"):
             s.tokens(timeout=120)
     finally:
-        session.paged_step = real_step
+        session.paged_step_ids = real_step
     c1 = profiler.get_counters()
     assert c1.get("serving_slot_retirements", 0) >= c0.get(
         "serving_slot_retirements", 0

@@ -26,6 +26,7 @@ from benchmark.references import deepseek as ref
 from paddle_tpu.fluid.ops import decoder_ops as ops
 from paddle_tpu.models import cache_kinds, deepseek, gpt
 from paddle_tpu.serving import decode
+from conftest import record_picked_rows
 
 fa = importlib.import_module("paddle_tpu.kernels.flash_attention")
 
@@ -404,20 +405,14 @@ def test_engine_windows_then_steps_are_the_reference_forward(
         seeded, monkeypatch, kernel):
     """A 37-token prompt is prefilled in three windows (16, 16, 5) over
     ten blocks of 4, then 9 tokens are decoded by T = 1 steps next to a
-    second, shorter stream. Every logits row the engine samples from is
-    compared with the reference's full forward over prompt + tokens."""
+    second, shorter stream. Every logits row a token is picked from (on
+    the host for a window, on the device for a step) is compared with the
+    reference's full forward over prompt + tokens."""
     params = seeded[0]
     cfg = deepseek.DeepseekConfig.from_config(
         CFG, dtype="float32", flash_interpret=kernel)
-    seen = {}
-    pick = decode.GenerationStream.pick
-
-    def recording_pick(self, logits):
-        seen.setdefault(id(self), []).append(np.array(logits))
-        return pick(self, logits)
-
-    monkeypatch.setattr(decode.GenerationStream, "pick", recording_pick)
     eng = _engine(cfg, params).start(loop=False)
+    seen = record_picked_rows(monkeypatch, eng)
     try:
         prompts = [list(_rng(41).integers(0, 211, 37)),
                    list(_rng(42).integers(0, 211, 6))]

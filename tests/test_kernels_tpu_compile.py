@@ -222,6 +222,38 @@ def _compile_paged_program(chip, monkeypatch, config_file, cfg_of, which,
     return cfg, blocks, block, built
 
 
+def _picked_step(module):
+    """``which`` for a family's T = 1 step as the session runs it: the
+    module's program with the session's tail (``decode.step_tail``),
+    fetching the picked ids and the program's stats."""
+    from paddle_tpu.serving import decode
+
+    def step(cfg, blocks, block, max_blocks, slots):
+        main, startup, feeds, logits = module.build_paged_step(
+            cfg, slots, blocks, block, max_blocks)
+        pick, _kept = decode.step_tail(main, startup, logits, 1)
+        return main, feeds, [pick] + list(getattr(main, "_step_stats", ()))
+
+    return step
+
+
+def _pick_checks(built, text, vocab, slots=64):
+    """The step hands out [slots] integer ids beside its [slots, vocab]
+    float32 logits, and the pick costs no copy of the logits: they leave
+    the head's fusion for HBM once (the parent's fusion wrote them there
+    itself; with a reader on the chip the compiler may keep them in fast
+    memory and send them out with one ``copy-start``)."""
+    import re
+
+    outs = [(tuple(o.shape), np.dtype(o.dtype))
+            for o in jax.tree_util.tree_leaves(built.out_info)]
+    assert outs.count(((slots,), np.dtype("int32"))) == 1
+    assert outs.count(((slots, vocab), np.dtype("float32"))) == 1
+    logits = r"f32\[%d,%d\]" % (slots, vocab)
+    assert not re.findall(r"%%copy[.\d]* = %s" % logits, text)
+    assert len(re.findall(r"%%copy-start[.\d]* = \(%s" % logits, text)) <= 1
+
+
 def _compile_latent_program(chip, monkeypatch, which, feed_shapes):
     """A program of the cell ``kanana2-serve-chat4k`` (64 slots of 4352
     positions, blocks of 128), built by ``models/deepseek.py``."""
@@ -258,16 +290,13 @@ def test_latent_step_program_takes_the_pool_as_it_lies(chip, monkeypatch):
 
     from paddle_tpu.models import deepseek
 
-    def step(cfg, blocks, block, max_blocks, slots):
-        main, _s, feeds, logits = deepseek.build_paged_step(
-            cfg, slots, blocks, block, max_blocks)
-        return main, feeds, [logits.name] + main._step_stats
-
     cfg, blocks, block, built = _compile_latent_program(
-        chip, monkeypatch, step, lambda slots, max_blocks, block: {
+        chip, monkeypatch, _picked_step(deepseek),
+        lambda slots, max_blocks, block: {
             "step_ids": (slots, 1, 1), "step_pos": (slots, 1, 1),
             "tables": (slots, max_blocks)})
     text = _latent_pool_checks(cfg, blocks, block, built)
+    _pick_checks(built, text, cfg.vocab_size)
     layers = cfg.num_hidden_layers
     assert len(re.findall(r"%mla_decode_paged[.\d]* = ", text)) == layers
     assert len(re.findall(r"%ragged-dot-none[.\d]* = ", text)) == 3 * (
@@ -348,17 +377,14 @@ def test_gpt_step_program_takes_the_pool_as_it_lies(chip, monkeypatch):
 
     from paddle_tpu.models import gpt
 
-    def step(cfg, blocks, block, max_blocks, slots):
-        main, _s, feeds, logits = gpt.build_paged_step(
-            cfg, slots, blocks, block, max_blocks)
-        return main, feeds, [logits.name]
-
     cfg, blocks, block, built = _compile_gpt_program(
-        chip, monkeypatch, step, lambda slots, max_blocks, block: {
+        chip, monkeypatch, _picked_step(gpt),
+        lambda slots, max_blocks, block: {
             "step_ids": (slots, 1, 1), "step_pos": (slots, 1, 1),
             "tables": (slots, max_blocks),
             "step_bias": (slots, 1, max_blocks * block)})
     text = _gpt_pool_checks(cfg, blocks, block, built)
+    _pick_checks(built, text, cfg.vocab_size)
     assert len(re.findall(r"%flash_decode_paged[.\d]* = ", text)) \
         == cfg.num_layers
 
@@ -439,16 +465,13 @@ def test_hybrid_step_program_steps_states_and_pools_in_place(
 
     from paddle_tpu.models import solar_open2
 
-    def step(cfg, blocks, block, max_blocks, slots):
-        main, _s, feeds, logits = solar_open2.build_paged_step(
-            cfg, slots, blocks, block, max_blocks)
-        return main, feeds, [logits.name] + main._step_stats
-
     cfg, blocks, block, built = _compile_hybrid_program(
-        chip, monkeypatch, step, lambda slots, max_blocks, block: {
+        chip, monkeypatch, _picked_step(solar_open2),
+        lambda slots, max_blocks, block: {
             "step_ids": (slots, 1, 1), "step_pos": (slots, 1, 1),
             "tables": (slots, max_blocks), "state_rows": (slots, 1)})
     text = _hybrid_cache_checks(cfg, blocks, block, built)
+    _pick_checks(built, text, cfg.vocab_size)
     assert len(re.findall(r"%flash_decode_paged_gqa[.\d]* = ", text)) == 2
     assert len(re.findall(r"%kda_decode[.\d]* = ", text)) == 6
     assert len(re.findall(r"%ragged-dot-none[.\d]* = ", text)) == 3 * 8

@@ -415,11 +415,11 @@ def test_tick_says_what_it_held(ticks):
 
 def test_decode_tick_keeps_its_extent(ticks):
     """``decode_tick`` stays the fused device call: it holds the step's
-    feed building, ``decode_paged_step`` and the logits' reshape, and no
-    sampling."""
+    feed building and ``decode_paged_step``, whose fetch brings the
+    picked ids, and no sampling."""
     tick = [s for s in ticks if s["name"] == "decode_tick"][-1]
     names = {k["name"] for k in _children(tick, ticks)}
-    assert names == {"step_feed", "decode_paged_step", "step_logits"}
+    assert names == {"step_feed", "decode_paged_step"}
 
 
 def test_paged_step_says_how_much_of_the_table_is_live(gen_server):
@@ -429,7 +429,7 @@ def test_paged_step_says_how_much_of_the_table_is_live(gen_server):
     handed to the active slots at that call."""
     engine = gen_server._decode_engine
     sess, held = engine.session, []
-    step = sess.paged_step
+    step = sess.paged_step_ids
 
     def counting(tokens, positions, tables, active, width=1):
         held.append(sum(len(tables[s]) for s in range(sess.slots)
@@ -437,14 +437,14 @@ def test_paged_step_says_how_much_of_the_table_is_live(gen_server):
         return step(tokens, positions, tables, active, width=width)
 
     trace.reset()
-    sess.paged_step = counting
+    sess.paged_step_ids = counting
     try:
         streams = [gen_server.generate([5 + i] * (3 + 4 * i),
                                        max_new_tokens=9) for i in range(3)]
         for s in streams:
             s.tokens(timeout=120)
     finally:
-        del sess.paged_step
+        del sess.paged_step_ids
     args = [s["args"] for s in trace.get_spans()
             if s["name"] == "decode_paged_step"]
     assert len(args) == len(held) >= 8

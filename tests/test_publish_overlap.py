@@ -124,6 +124,8 @@ def test_outputs_token_for_token(model, engines, kind, sampling):
 
 
 def test_pick_runs_once_a_token_in_slot_order(model, engines, monkeypatch):
+    """One pick a token: a greedy stream's first on the host from its
+    window's row, its steps' on the device."""
     engine = engines("paged")
     calls = []
     real = sdecode.GenerationStream.pick
@@ -133,12 +135,16 @@ def test_pick_runs_once_a_token_in_slot_order(model, engines, monkeypatch):
         return real(self, logits)
 
     monkeypatch.setattr(sdecode.GenerationStream, "pick", counting)
+    before = engine.stats()
     streams = [engine.submit(p, max_new_tokens=6) for p in PROMPTS]
     for s in streams:
         s.tokens(timeout=120)
-    assert len(calls) == 18
+    assert len(calls) == 3
     for s in streams:
-        assert calls.count(s) == len(s._tokens) == 6
+        assert calls.count(s) == 1 and len(s._tokens) == 6
+    after = engine.stats()
+    assert after["picks_on_device"] - before["picks_on_device"] == 15
+    assert after["picks_on_host"] == before["picks_on_host"]
 
 
 # -- (b) a stream's events keep their order, however it ends --------------
@@ -195,7 +201,7 @@ def _ends_by_a_failing_tick(model, engines):
     its admission and of three steps, then the error."""
     engine = engines("paged")
     sess, calls = engine.session, []
-    step = sess.paged_step
+    step = sess.paged_step_ids
 
     def failing(*a, **kw):
         calls.append(1)
@@ -203,7 +209,7 @@ def _ends_by_a_failing_tick(model, engines):
             raise RuntimeError("step failed")
         return step(*a, **kw)
 
-    sess.paged_step = failing
+    sess.paged_step_ids = failing
     try:
         # the first tick admits both, so both ride every step
         with engine._cond:
@@ -215,7 +221,7 @@ def _ends_by_a_failing_tick(model, engines):
             assert isinstance(error, RuntimeError) and len(got) == 4
             out.append((s, got))
     finally:
-        del sess.paged_step
+        del sess.paged_step_ids
     # the engine is up for the next request
     assert engine.submit(PROMPTS[0], max_new_tokens=2).tokens(
         timeout=120) == _oracle(model, PROMPTS[0], 2)
