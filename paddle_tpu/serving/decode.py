@@ -1091,7 +1091,9 @@ class GenerationStream(object):
         self.t_dequeue = None
         self.t_finish = None
         self._emit_times = []
-        self._q = queue.Queue()
+        # SimpleQueue: a put is one C call that runs no Python code and
+        # takes no Python-level lock (the loop thread does one a token)
+        self._q = queue.SimpleQueue()
         self._tokens = []
         self._done = threading.Event()
         self._error = None
@@ -2012,27 +2014,30 @@ class DecodeEngine(object):
         box = self._outbox
         if not box:
             return
+        key = "published_overlapped" if overlapped else "published_exposed"
+        counts = self._counts
         with self._publish_lock, \
                 _trace.span("tick_publish", cat="serving",
                             overlapped=overlapped) as sp:
             tokens, streams = 0, set()
             while box:
                 stream, item = box.popleft()
+                if item is not _SENTINEL:
+                    # counted before it is handed over: a reader that
+                    # holds a token finds it in ``stats()``
+                    counts[key] += 1
+                    tokens += 1
                 stream._publish(item)
                 streams.add(stream)
-                if item is not _SENTINEL:
-                    tokens += 1
             sp.note(tokens=tokens, streams=len(streams))
         if not tokens:
             return
         if overlapped:
             _profiler.bump_counter("decode_tokens_published_overlapped",
                                    tokens)
-            self._counts["published_overlapped"] += tokens
         else:
             _profiler.bump_counter("decode_tokens_published_exposed",
                                    tokens)
-            self._counts["published_exposed"] += tokens
 
     def _occupancy(self):
         """What a tick leaves behind, for its span: streams by state,

@@ -449,6 +449,35 @@ def _tenant_slug(tenant):
     return _tenants.slug(tenant)
 
 
+def _frame(data):
+    """``data`` as one chunk of a chunked body: size line, payload and
+    trailer in one ``bytes``, so that a chunk is one send (the handler's
+    ``wfile`` is unbuffered: every write is a ``sendall``)."""
+    return b"%x\r\n%s\r\n" % (len(data), data)
+
+
+def _token_chunk(tok):
+    """The chunk that carries one token's SSE event."""
+    return _frame(b'data: {"token": %d}\n\n' % tok)
+
+
+# The SSE writer counts its events every this many tokens and when the
+# stream ends, not a token: a bump takes the profiler's one lock, from
+# every handler thread a tick (PERF.md §6, PR 36), and ``/metrics`` stays
+# live to within this many tokens a stream.
+_COUNT_EVERY = 16
+
+
+def _count_events(tokens, last):
+    """``tokens`` token events, each a chunk and a send, and ``last``
+    terminal ones (done or in-band error, with the body's last chunk)
+    reached the wire."""
+    if tokens:
+        _profiler.bump_counter("gateway_stream_tokens", tokens)
+    if tokens + last:
+        _profiler.bump_counter("gateway_stream_sends", tokens + last)
+
+
 # -- the gateway -------------------------------------------------------------
 
 
@@ -755,6 +784,10 @@ def _make_handler(gw):
         # socket timeout: a client that trickles its body (or stalls a
         # read) is disconnected instead of pinning a handler thread
         timeout = 60.0
+        # Nagle stays on (no ``disable_nagle_algorithm``): a chunk is one
+        # send, so a token is one small segment either way, and on the
+        # chip the serve cell read no better without it (PERF.md §6,
+        # PR 36)
 
         def log_message(self, *args):  # access log is ours, not stderr's
             pass
@@ -1399,7 +1432,9 @@ def _make_handler(gw):
             """Chunked SSE: headers now, one data event per token as the
             engine emits it, a final done event carrying finish_reason.
             Errors after headers ride an in-band ``{"error": ...}``
-            event (the 200 is already on the wire)."""
+            event (the 200 is already on the wire). An event is a chunk
+            and a chunk one send; the terminal event goes out with the
+            body's last chunk, in one more."""
             self.send_response(200)
             self.send_header("Content-Type", "text/event-stream")
             self.send_header("Cache-Control", "no-cache")
@@ -1410,125 +1445,128 @@ def _make_handler(gw):
             for k, v in gw.extra_headers.items():
                 self.send_header(k, v)
             self.end_headers()
-            sent = 0
+            sent = last = 0
             first_tok_ms = None
             t0 = time.monotonic()
             # per token, flush time minus the engine's emit stamp (both
             # perf_counter): how long a made token waits for this thread
             emitted_at = getattr(stream, "_emit_times", None)
             lags = []
+            # the chaos seam costs a token two module locks: paid only
+            # where a plan that dies at a token count is armed, and such a
+            # plan is armed before its streams start
+            dies_at_a_token = _chaos.dies_at_a_token()
             # ENGINE exceptions (deadline, stream failure) and CLIENT
             # write exceptions must be told apart by SOURCE, not type:
             # on py3.10+ socket.timeout IS TimeoutError, so a write to
             # a stalled client that times out is type-identical to the
             # generation deadline — only next(it) can raise the
-            # deadline, only _chunk can raise the socket
+            # deadline, only a write to wfile can raise the socket
             it = iter(stream.stream_tokens(timeout=timeout))
-            while True:
-                try:
-                    tok = next(it)
-                except StopIteration:
-                    break
-                except TimeoutError:
-                    stream.cancel()  # free the decode slot — see above
-                    _profiler.bump_counter("gateway_shed_dispatch")
-                    _profiler.bump_counter("gateway_tenant_shed_"
-                                           + _tenant_slug(tenant))
+            try:
+                while True:
                     try:
-                        # carries the reconstruction state (emitted
-                        # count, seed, knobs) like every terminal
-                        # generate event — a caller can resume even a
-                        # deadline-cut stream with a fresh budget
-                        self._chunk('data: %s\n\n' % json.dumps(
-                            dict({"error": "deadline",
-                                  "request_id": rid},
-                                 **self._resume_state(stream, sent))
-                        ))
-                        self._chunk_end()
-                    except OSError:
-                        return 499, "client_stalled", sent
-                    return 504, "deadline", sent
-                except Exception as e:  # noqa: BLE001
-                    # the 200 + chunked framing is already on the
-                    # wire: ANY stream failure (the engine fails
-                    # streams with the original exception type, not
-                    # just ServingError) must ride an in-band error
-                    # event — a late _send_json(500) would inject a
-                    # raw status line into the chunked body
+                        tok = next(it)
+                    except StopIteration:
+                        break
+                    except TimeoutError:
+                        stream.cancel()  # free the decode slot — see above
+                        _profiler.bump_counter("gateway_shed_dispatch")
+                        _profiler.bump_counter("gateway_tenant_shed_"
+                                               + _tenant_slug(tenant))
+                        try:
+                            # carries the reconstruction state (emitted
+                            # count, seed, knobs) like every terminal
+                            # generate event — a caller can resume even a
+                            # deadline-cut stream with a fresh budget
+                            self._last_event(
+                                dict({"error": "deadline",
+                                      "request_id": rid},
+                                     **self._resume_state(stream, sent)))
+                            last = 1
+                        except OSError:
+                            return 499, "client_stalled", sent
+                        return 504, "deadline", sent
+                    except Exception as e:  # noqa: BLE001
+                        # the 200 + chunked framing is already on the
+                        # wire: ANY stream failure (the engine fails
+                        # streams with the original exception type, not
+                        # just ServingError) must ride an in-band error
+                        # event — a late _send_json(500) would inject a
+                        # raw status line into the chunked body
+                        try:
+                            self._last_event(
+                                dict({"error": str(e) or repr(e),
+                                      "request_id": rid},
+                                     **self._resume_state(stream, sent)))
+                            last = 1
+                        except OSError:
+                            stream.cancel()
+                            return 499, "client_stalled", sent
+                        return 500, "stream_error", sent
+                    if first_tok_ms is None:
+                        first_tok_ms = (time.monotonic() - t0) * 1e3
+                        _profiler.bump_histogram("gateway_ttft_ms",
+                                                 first_tok_ms)
                     try:
-                        self._chunk('data: %s\n\n' % json.dumps(
-                            dict({"error": str(e) or repr(e),
-                                  "request_id": rid},
-                                 **self._resume_state(stream, sent))
-                        ))
-                        self._chunk_end()
-                    except OSError:
+                        self.wfile.write(_token_chunk(tok))
+                    except OSError as e:
+                        # client went away (reset/pipe) or STALLED (write
+                        # timeout) mid-stream: nothing left to write to,
+                        # and nobody left to decode for. A ConnectionError
+                        # re-raises into _serve's 499 mapping; a write
+                        # timeout must NOT re-raise — the generic handler
+                        # would _send_json(500) into the open chunked body
                         stream.cancel()
+                        if isinstance(e, ConnectionError):
+                            raise
                         return 499, "client_stalled", sent
-                    return 500, "stream_error", sent
-                if first_tok_ms is None:
-                    first_tok_ms = (time.monotonic() - t0) * 1e3
-                    _profiler.bump_histogram("gateway_ttft_ms",
-                                             first_tok_ms)
+                    if emitted_at is not None and sent < len(emitted_at):
+                        lags.append(time.perf_counter() - emitted_at[sent])
+                    sent += 1
+                    if sent % _COUNT_EVERY == 0:
+                        _count_events(_COUNT_EVERY, 0)
+                    if dies_at_a_token:
+                        # the process dies AFTER this token hit the wire,
+                        # pinning replica-death trials to an exact token
+                        # boundary
+                        _chaos.on_stream_token()
+                # the done event carries the engine-stamped TTFT (falling
+                # back to the gateway-side first-chunk wall) and the
+                # prefix-cache reuse fact, so a streaming client sees its
+                # amortization — same dict the access log records
+                facts = self._stash_gen_facts(
+                    stream, fallback_ttft_ms=first_tok_ms)
+                if lags:
+                    lags.sort()
+                    self._span_extra = {
+                        "sse_lag_ms_p50": 1e3 * lags[len(lags) // 2],
+                        "sse_lag_ms_max": 1e3 * lags[-1],
+                        "tokens": sent,
+                    }
                 try:
-                    self._chunk('data: {"token": %d}\n\n' % tok)
+                    self._last_event(
+                        dict({"done": True,
+                              "finish_reason": stream.finish_reason,
+                              "tokens": sent, "request_id": rid}, **facts,
+                             **self._resume_state(stream, sent)),
+                        sort_keys=True)
+                    last = 1
                 except OSError as e:
-                    # client went away (reset/pipe) or STALLED (write
-                    # timeout) mid-stream: nothing left to write to,
-                    # and nobody left to decode for. A ConnectionError
-                    # re-raises into _serve's 499 mapping; a write
-                    # timeout must NOT re-raise — the generic handler
-                    # would _send_json(500) into the open chunked body
-                    stream.cancel()
                     if isinstance(e, ConnectionError):
                         raise
                     return 499, "client_stalled", sent
-                if emitted_at is not None and sent < len(emitted_at):
-                    lags.append(time.perf_counter() - emitted_at[sent])
-                sent += 1
-                _profiler.bump_counter("gateway_stream_tokens")
-                # chaos seam (no-op unless FLAGS_chaos_die_after_tokens
-                # is armed): the process dies AFTER this token hit the
-                # wire, pinning replica-death trials to an exact token
-                # boundary
-                _chaos.on_stream_token()
-            # the done event carries the engine-stamped TTFT (falling
-            # back to the gateway-side first-chunk wall) and the
-            # prefix-cache reuse fact, so a streaming client sees its
-            # amortization — same dict the access log records
-            facts = self._stash_gen_facts(stream,
-                                          fallback_ttft_ms=first_tok_ms)
-            if lags:
-                lags.sort()
-                self._span_extra = {
-                    "sse_lag_ms_p50": 1e3 * lags[len(lags) // 2],
-                    "sse_lag_ms_max": 1e3 * lags[-1],
-                    "tokens": sent,
-                }
-            try:
-                self._chunk('data: %s\n\n' % json.dumps(
-                    dict({"done": True,
-                          "finish_reason": stream.finish_reason,
-                          "tokens": sent, "request_id": rid}, **facts,
-                         **self._resume_state(stream, sent)),
-                    sort_keys=True,
-                ))
-                self._chunk_end()
-            except OSError as e:
-                if isinstance(e, ConnectionError):
-                    raise
-                return 499, "client_stalled", sent
-            return 200, None, sent
+                return 200, None, sent
+            finally:
+                # what the loop has not counted yet, on every way out
+                _count_events(sent % _COUNT_EVERY, last)
 
-        def _chunk(self, text):
-            data = text.encode("utf-8")
-            self.wfile.write(b"%x\r\n" % len(data))
-            self.wfile.write(data)
-            self.wfile.write(b"\r\n")
-            self.wfile.flush()
-
-        def _chunk_end(self):
-            self.wfile.write(b"0\r\n\r\n")
-            self.wfile.flush()
+        def _last_event(self, event, **dumps):
+            """A stream's terminal event and the chunked body's last
+            chunk, in one send."""
+            self.wfile.write(
+                _frame(b"data: %s\n\n"
+                       % json.dumps(event, **dumps).encode("utf-8"))
+                + b"0\r\n\r\n")
 
     return _Handler

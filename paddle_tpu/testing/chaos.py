@@ -349,9 +349,21 @@ def on_step(step):
                 time.sleep(0.25)
 
 
+def dies_at_a_token():
+    """True where a plan that dies at a token count (``die_after_tokens``)
+    is armed and aimed at this process: what the gateway's SSE writer asks
+    ONCE, when a stream starts, so that the seam below costs a token
+    nothing where no such plan is. A plan is therefore armed before the
+    streams it is to count start; one armed later is seen by later
+    streams only."""
+    plan = active_plan()
+    return plan is not None and plan.dies_me()
+
+
 def on_stream_token():
     """Serving-gateway hook, called after each SSE stream token is
-    written to the wire: SIGKILL this process the moment its
+    written to the wire, by the streams that began under
+    ``dies_at_a_token()``: SIGKILL this process the moment its
     process-wide emitted-token count reaches the armed
     ``die_after_tokens`` — a replica death pinned to a token boundary,
     so failover trials replay deterministically. SIGKILL (not exit):

@@ -242,6 +242,7 @@ def test_hand_driven_tick_publishes_before_it_returns(model, kind):
     engine = _engine(model, kind, slots=2).start(loop=False)
     try:
         streams = [engine.submit(p, max_new_tokens=5) for p in PROMPTS[:2]]
+        handed = {s: [] for s in streams}
         ticks = 0
         while not all(s.done for s in streams):
             engine._tick()
@@ -249,7 +250,9 @@ def test_hand_driven_tick_publishes_before_it_returns(model, kind):
             assert ticks < 40
             assert not engine._outbox
             for s in streams:
-                held = list(s._q.queue)
+                held = handed[s]
+                while not s._q.empty():
+                    held.append(s._q.get_nowait())
                 ended = bool(held) and held[-1] is sdecode._SENTINEL
                 assert [t for t in held if t is not sdecode._SENTINEL] \
                     == s._tokens

@@ -397,21 +397,23 @@ def run_probe(fast=True, verbose=False):
             except Exception as e:  # noqa: BLE001
                 drain_errors.append(e)
 
-        tok_base = profiler.get_counters().get("gateway_stream_tokens", 0)
+        # the engine's count of decided tokens: the gateway's own
+        # (gateway_stream_tokens) rises every 16 tokens of a stream and
+        # when it ends, and these streams are shorter
+        tok_base = profiler.get_counter("decode_tokens")
         streams = [threading.Thread(target=drain_client, args=(i,))
                    for i in range(4)]
         for t in streams:
             t.start()
         # SIGTERM only once every stream is demonstrably mid-flight: all
         # 4 admitted (the gateway's inflight accounting) AND tokens
-        # already on the wire — otherwise a not-yet-admitted client
+        # already flowing — otherwise a not-yet-admitted client
         # would correctly get the drain 503 and fail the completeness
         # check for the wrong reason
         wait_deadline = time.monotonic() + 60
         while time.monotonic() < wait_deadline and (
             gw.admission.total_inflight < 4
-            or profiler.get_counters().get("gateway_stream_tokens", 0)
-            <= tok_base
+            or profiler.get_counter("decode_tokens") <= tok_base
         ):
             time.sleep(0.01)
         gw.install_sigterm()
