@@ -1572,13 +1572,13 @@ class Executor(object):
         # the step-loop span: one per run(), nesting under the trainer's
         # train_step span and over any RecordEvents ops open inside
         with _obs_trace.span(
-            "executor_run", cat="exec", plan_hit=plan_hit,
+            "executor_run", cat="exec", cpu=True, plan_hit=plan_hit,
             prepare_ms=(time.perf_counter() - t_in) * 1e3,
         ) as sp:
             outs = compiled.run(scope, feed, rng_key, self.place, sp)
         if while_device_runs is not None:
             while_device_runs()
-        with _obs_trace.span("executor_fetch", cat="exec") as sp:
+        with _obs_trace.span("executor_fetch", cat="exec", cpu=True) as sp:
             outs = [
                 None if o is None else np.asarray(_fetch_to_host(o))
                 for o in outs

@@ -37,6 +37,21 @@ Design constraints, in order:
   into child spans when the buffer is READ (``chrome_trace()`` always
   does). A millisecond
   ``Executor.run`` so makes two records, not seven.
+- **A thread's CPU time, where a wall time alone misleads**:
+  ``span(name, cpu=True)`` also reads ``time.thread_time()`` as it opens
+  and closes (inside the wall clock's two reads, so never more than the
+  wall time) and records ``cpu_ms`` and ``cpu_at`` (the thread's CPU
+  clock, seconds, as it opened); its phases carry a ``cpu_at`` stamp in
+  their args and ``with_phases`` makes a ``cpu_ms`` of each. Two more
+  clock reads a span and one a phase; none where ``cpu`` is not asked
+  for, none with tracing off. The clock is a system call, and under a
+  sandbox's kernel a dear and a coarse one (20 us a read, steps of
+  10 ms), so of the outermost such spans of a name that a thread opens
+  one in ``CPU_EVERY`` reads it, the first among them, with every
+  ``cpu=True`` span and phase inside it; the others make the record a
+  span without ``cpu`` makes. What a reader sums over a window is a
+  sample of the ticks. ``set_cpu_every(1)`` has every one read it, for
+  a measuring run (``tools/cpu_clocks.py``).
 
 Names on the two hot paths (PERF.md section 3 lists each with the
 benchmark metric that reads it). One ``Executor.run`` or
@@ -54,7 +69,27 @@ annotated with the trace ids of the streams it decoded; ``step_logits``
 is the copy of logits to the host (a window's row; of a step the rows of
 a sampled stream, inside ``tick_sample_emit``). A request leaves one
 ``decode_request`` instant
-(its times on this clock) and one ``gateway_request`` span.
+(its times on this clock, and the engine's step counter at its submit
+and at its dequeue) and one ``gateway_request`` span.
+
+Every span of the engine's loop thread carries ``cpu_ms`` (on the ticks
+that read the clock, one in ``CPU_EVERY``):
+``engine_tick`` (with ``process_cpu_ms``: ``time.process_time()`` over
+the tick, all the process's threads), ``tick_reap``,
+``tick_admit``, ``tick_prefill``, ``tick_build``, ``step_feed``,
+``decode_paged_step``, ``decode_paged_window``, ``tick_publish``,
+``tick_sample_emit``, ``engine_wait``, and the executor's
+``executor_run`` (so its phases) and ``executor_fetch``, on the training
+path too. A span's wall time less its ``cpu_ms`` is time its thread
+held no CPU. In ``executor_fetch`` and ``engine_wait`` that is the wait
+they exist for (the device, a request). Anywhere else, on a thread that
+shares the interpreter with a gateway's handler threads, it is the wait
+for the interpreter lock (or, where the host is short of them, for a
+core): a phase that reads 6 ms of wall and 0.4 ms of CPU did 0.4 ms of
+work and queued for the rest, and is sped up by having fewer threads
+ask for the lock, not by doing less in it. The handlers' own CPU is
+counted once a stream, where it ends (``gateway_handler_cpu_us``,
+``gateway._count_events``).
 """
 
 from __future__ import annotations
@@ -76,6 +111,7 @@ __all__ = [
     "instant",
     "enabled",
     "force_enable",
+    "set_cpu_every",
     "gang_rank",
     "get_spans",
     "with_phases",
@@ -151,6 +187,41 @@ def _apply_buffer_bound():
     if _buf.maxlen != n:
         with _lock:
             _buf = deque(_buf, maxlen=n)
+
+
+# A thread's CPU clock is a system call: half a microsecond on a plain
+# kernel, but under a sandbox's (gVisor, where the benchmark's chips are)
+# 25 us among the 140 threads of a serving process, on a clock that moves
+# in steps of 10 ms (PERF.md section 6, PR 37): the 36 reads of a serve
+# tick were 5 % of the tick there. So of the outermost ``cpu=True`` spans
+# of a name that a thread opens, one in ``CPU_EVERY`` reads it, with every
+# ``cpu=True`` span and phase inside it: a serve tick then pays about the
+# four reads its two hand-read spans paid before. Counted, so the same
+# ticks whatever the host; a name, so that two outermost spans taking
+# turns (``executor_run``, ``executor_fetch``) are both read, in the same
+# step; a prime, so that no tick in two or in four is the only kind read.
+CPU_EVERY = 11
+_cpu_every = CPU_EVERY
+
+
+def set_cpu_every(n):
+    """Read the CPU clock in one outermost ``cpu=True`` span of a name in
+    ``n`` (``CPU_EVERY`` until asked otherwise): 1 for a measuring run
+    that wants every tick's split and pays for it. -> the rate before."""
+    global _cpu_every
+    before, _cpu_every = _cpu_every, max(1, int(n))
+    return before
+
+
+def _cpu_sampled(name):
+    """Whether the outermost ``cpu=True`` span of this name now opening
+    on this thread is one that reads the CPU clock."""
+    counts = getattr(_tls, "cpu_n", None)
+    if counts is None:
+        counts = _tls.cpu_n = {}
+    n = counts.get(name, 0)
+    counts[name] = n + 1
+    return n % _cpu_every == 0
 
 
 def force_enable(on):
@@ -267,19 +338,25 @@ class span(object):
     ``with span("ckpt_snapshot", cat="ckpt", step=7): ...`` — kwargs
     land in the Chrome event's ``args``. Nesting is tracked per thread:
     a span opened inside another becomes its child (``parent``/``depth``
-    in the record, time containment in Perfetto). Disabled tracing makes
-    enter/exit a near-no-op."""
+    in the record, time containment in Perfetto). ``cpu=True`` adds the
+    thread's CPU time (``cpu_ms``, ``cpu_at``; see the module's header)
+    where the open span's ``cpu`` says so.
+    Disabled tracing makes enter/exit a near-no-op."""
 
     __slots__ = ("name", "cat", "args", "_t0", "_armed", "_parent",
                  "trace_id", "span_id", "_parent_hex", "_ctx_pushed",
-                 "_stack", "_phases")
+                 "_stack", "_phases", "_cpu", "cpu", "_c0")
 
-    def __init__(self, name, cat="host", **args):
+    def __init__(self, name, cat="host", cpu=False, **args):
         self.name = name
         self.cat = cat
         self.args = args or None
         self._armed = False
         self._phases = None
+        # asked for the thread's CPU time; ``cpu``: whether this span,
+        # once open, is one that reads it (``_cpu_sampled``)
+        self._cpu = cpu
+        self.cpu = False
         # distributed identity, populated at __enter__ when an ambient
         # trace_scope is active on this thread (None otherwise). span_id
         # is readable the moment the span opens — a hop forwards it in
@@ -305,6 +382,9 @@ class span(object):
         if self._armed:
             if self._phases is None:
                 self._phases = []
+            if self.cpu:
+                # beside the mark, not in it: a mark stays three fields
+                args["cpu_at"] = time.thread_time()
             self._phases.append((name, time.perf_counter(), args))
         return args
 
@@ -333,13 +413,29 @@ class span(object):
             self.span_id = _span_hex(next(_ids))
             ctx.append((trace_id, self.span_id))
             self._ctx_pushed = True
+        if self._cpu:
+            # the outermost cpu=True span of a thread decides for those
+            # inside it
+            held = getattr(_tls, "cpu_open", 0)
+            if not held:
+                _tls.cpu_stamp = _cpu_sampled(self.name)
+            _tls.cpu_open = held + 1
+            self.cpu = _tls.cpu_stamp
         self._t0 = time.perf_counter()
+        if self.cpu:
+            self._c0 = time.thread_time()
         return self
 
     def __exit__(self, *exc):
         if not self._armed:
             return False
+        if self.cpu:
+            # read inside the wall interval, so cpu_ms never exceeds it
+            c0 = self._c0
+            self.note(cpu_ms=(time.thread_time() - c0) * 1e3, cpu_at=c0)
         t1 = time.perf_counter()
+        if self._cpu:
+            _tls.cpu_open -= 1
         self._armed = False
         stack = self._stack
         if stack:
@@ -440,7 +536,9 @@ def with_phases(spans):
     """``spans`` (dicts as ``get_spans`` gives them) and, after each span
     that marked phases, one child span a phase: from its mark to the
     next one or to the parent's end, on the parent's thread, one level
-    deeper, with the phase's own ``args``. The children were never
+    deeper, with the phase's own ``args``. A phase of a ``cpu=True`` span
+    gets its own ``cpu_ms``, from its ``cpu_at`` stamp to the next
+    phase's or to the parent's last. The children were never
     records (``id`` None); inside a trace scope each gets a span id of its
     own under its parent's, so a merged trace keeps its tree."""
     out = []
@@ -450,10 +548,16 @@ def with_phases(spans):
         if not marks:
             continue
         ends = [m[1] for m in marks[1:]] + [s["end"]]
-        for (name, start, args), end in zip(marks, ends):
+        own = s["args"]
+        cpu_ends = [(m[2] or {}).get("cpu_at") for m in marks[1:]] + [
+            own["cpu_at"] + own["cpu_ms"] / 1e3 if "cpu_at" in own else None]
+        for (name, start, args), end, cpu_end in zip(marks, ends, cpu_ends):
+            args = dict(args or {})
+            if args.get("cpu_at") is not None and cpu_end is not None:
+                args["cpu_ms"] = (cpu_end - args["cpu_at"]) * 1e3
             out.append(dict(
                 s, name=name, start=start, end=end, depth=s["depth"] + 1,
-                parent=s["name"], id=None, args=dict(args or {}),
+                parent=s["name"], id=None, args=args,
                 span_id=_span_hex(next(_ids)) if s["span_id"] else None,
                 parent_span_id=s["span_id"]))
     return out

@@ -713,7 +713,7 @@ class DecodeSession(object):
                 % (offset, offset + T, span)
             )
         main, fetch_name = self._paged_window[T]
-        with _trace.span("step_feed", cat="serving"):
+        with _trace.span("step_feed", cat="serving", cpu=True):
             ids = np.zeros((1, T, 1), "int64")
             ids[0, :P, 0] = window_ids
             last_onehot = np.zeros((1, T, 1), "float32")
@@ -741,7 +741,7 @@ class DecodeSession(object):
                 feed["state_row"] = np.array([[int(slot) + 1]], "int64")
                 feed["window_len"] = np.array([[P]], "int64")
         t0 = time.perf_counter()
-        with _trace.span("decode_paged_window", cat="serving",
+        with _trace.span("decode_paged_window", cat="serving", cpu=True,
                          bucket=T, rows=P, offset=offset) as sp:
             (lv,) = self._run(main, feed, [fetch_name])
             if hasattr(self.model, "window_stats"):
@@ -791,7 +791,7 @@ class DecodeSession(object):
                 "no paged step program of width %d (built: %s)"
                 % (width, sorted(self._paged_step))
             )
-        with _trace.span("step_feed", cat="serving"):
+        with _trace.span("step_feed", cat="serving", cpu=True):
             act = np.asarray(active, bool)
             pos = np.asarray(positions, "int64")
             tok = np.where(act[:, None],
@@ -826,17 +826,9 @@ class DecodeSession(object):
                 feed["state_rows"] = np.where(
                     act, np.arange(self.slots) + 1, 0
                 ).astype("int64").reshape(self.slots, 1)
-            # of the slots x max_blocks table entries, the ones that hold
-            # a live key after this window's writes: the share of the
-            # table the T = 1 kernel fetches and computes
-            blocks_live = int(
-                (-(-(pos[act] + width) // self.block_size)).sum()
-            )
         t0 = time.perf_counter()
-        with _trace.span("decode_paged_step", cat="serving",
-                         active=int(act.sum()), width=width,
-                         blocks_live=blocks_live,
-                         blocks_table=self.slots * self.max_blocks) as sp:
+        with _trace.span("decode_paged_step", cat="serving", cpu=True,
+                         active=int(act.sum()), width=width) as sp:
             picked, *stats = self._run(main, feed, fetches)
             if len(fetches) > 1:
                 sp.note(**self.model.step_stats(
@@ -1088,6 +1080,11 @@ class GenerationStream(object):
         # beside _tokens; the first is the first token), finish. They go
         # out in the one decode_request record the stream leaves
         self.t_submit = time.perf_counter()
+        # the engine's step counter as ``submit`` read it on the caller's
+        # thread and as ``_admit`` read it at the dequeue: how many steps
+        # the loop made while the request waited in the queue
+        self.submit_tick = None
+        self.dequeue_tick = None
         self.t_dequeue = None
         self.t_finish = None
         self._emit_times = []
@@ -1183,6 +1180,8 @@ class GenerationStream(object):
                                else (deq - self.t_submit) * 1e3),
                 first_token_ms=(None if deq is None or first is None
                                 else (first - deq) * 1e3),
+                submit_tick=self.submit_tick,
+                dequeue_tick=self.dequeue_tick,
                 prefill_windows=self.admit_windows,
                 tokens=len(self._tokens), finish_reason=reason,
                 preempted=self.preemptions,
@@ -1798,6 +1797,7 @@ class DecodeEngine(object):
                                   top_k=top_k, top_p=top_p, seed=seed,
                                   resume_tokens=resume,
                                   priority=priority, tenant=tenant)
+        stream.submit_tick = self.tick
         with self._cond:
             # re-checked under the lock stop() drains under: after the
             # drain, started is already False here and the stream can
@@ -1906,7 +1906,8 @@ class DecodeEngine(object):
         while True:
             with self._cond:
                 if self._idle():
-                    with _trace.span("engine_wait", cat="serving"):
+                    with _trace.span("engine_wait", cat="serving",
+                                     cpu=True):
                         while self._idle():
                             self._cond.wait()
                 if self._stop:
@@ -1962,30 +1963,35 @@ class DecodeEngine(object):
         dispatched after the decision, between its ``executor_run`` and
         its ``executor_fetch``, which for the step's own tokens is the
         next tick's. Where no device call follows, at the tick's end."""
-        cpu0 = time.thread_time()
         counts = self._counts
         pub0 = (counts["published_overlapped"], counts["published_exposed"])
-        with _trace.span("engine_tick", cat="serving",
+        with _trace.span("engine_tick", cat="serving", cpu=True,
                          tick=self.tick) as sp:
-            with _trace.span("tick_reap", cat="serving"):
+            # on the ticks whose spans read their thread's CPU clock, the
+            # CPU clock of ALL the process's threads over the tick too
+            process0 = time.process_time() if sp.cpu else None
+            with _trace.span("tick_reap", cat="serving", cpu=True):
                 self._drain_spill_done()
                 self._serve_export_jobs()
                 self._reap_cancelled()
-            with _trace.span("tick_admit", cat="serving"):
+            with _trace.span("tick_admit", cat="serving", cpu=True):
                 self._admit()
-            with _trace.span("tick_prefill", cat="serving"):
+            with _trace.span("tick_prefill", cat="serving", cpu=True):
                 self._advance_prefills()
             if self._active:
                 self._step()
             if not self._next_tick_carries():
                 self._publish()
             if _trace.enabled():
-                sp.note(cpu_ms=(time.thread_time() - cpu0) * 1e3,
-                        published_overlapped=(
+                facts = self._occupancy()
+                if process0 is not None:
+                    facts["process_cpu_ms"] = (
+                        time.process_time() - process0) * 1e3
+                sp.note(published_overlapped=(
                             counts["published_overlapped"] - pub0[0]),
                         published_exposed=(
                             counts["published_exposed"] - pub0[1]),
-                        **self._occupancy())
+                        **facts)
 
     def _next_tick_carries(self):
         """Whether what this tick decided after its last device call may
@@ -2017,7 +2023,7 @@ class DecodeEngine(object):
         key = "published_overlapped" if overlapped else "published_exposed"
         counts = self._counts
         with self._publish_lock, \
-                _trace.span("tick_publish", cat="serving",
+                _trace.span("tick_publish", cat="serving", cpu=True,
                             overlapped=overlapped) as sp:
             tokens, streams = 0, set()
             while box:
@@ -2291,6 +2297,7 @@ class DecodeEngine(object):
                 # the first dequeue only: a preempted stream's second
                 # wait is the scheduler's doing, not the queue's
                 stream.t_dequeue = time.perf_counter()
+                stream.dequeue_tick = self.tick
             if stream._cancelled:
                 # cancelled while queued: never admitted, so no slot,
                 # no retirement tally — just finish the dead handle
@@ -2900,7 +2907,7 @@ class DecodeEngine(object):
         whole rejected blocks back by table edit."""
         sess = self.session
         width = self._spec_width
-        with _trace.span("tick_build", cat="serving"):
+        with _trace.span("tick_build", cat="serving", cpu=True):
             built = self._build_step(width)
             tids = self._traced_ids()
         if built is None:
@@ -2917,8 +2924,7 @@ class DecodeEngine(object):
                 ids = sess.paged_step_ids(tokens, positions, tables,
                                           active, width=width)
         self.tick += 1
-        cpu0 = time.thread_time()
-        with _trace.span("tick_sample_emit", cat="serving") as sp:
+        with _trace.span("tick_sample_emit", cat="serving", cpu=True) as sp:
             ids = ids.tolist()
             total = on_host = 0
             for idx in list(self._active.keys()):
@@ -2974,8 +2980,7 @@ class DecodeEngine(object):
             if on_host:
                 _profiler.bump_counter("decode_picks_on_host", on_host)
                 self._counts["picks_on_host"] += on_host
-            sp.note(tokens=total,
-                    cpu_ms=(time.thread_time() - cpu0) * 1e3)
+            sp.note(tokens=total)
 
     def _build_step(self, width):
         """Before the device call of a tick: grow and unshare the

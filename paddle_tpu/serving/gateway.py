@@ -468,14 +468,18 @@ def _token_chunk(tok):
 _COUNT_EVERY = 16
 
 
-def _count_events(tokens, last):
+def _count_events(tokens, last, cpu_s=None):
     """``tokens`` token events, each a chunk and a send, and ``last``
     terminal ones (done or in-band error, with the body's last chunk)
-    reached the wire."""
+    reached the wire; where the stream has ended, ``cpu_s``: the seconds
+    its handler's thread held a CPU over it (``gateway_handler_cpu_us``,
+    whole microseconds)."""
     if tokens:
         _profiler.bump_counter("gateway_stream_tokens", tokens)
     if tokens + last:
         _profiler.bump_counter("gateway_stream_sends", tokens + last)
+    if cpu_s is not None:
+        _profiler.bump_counter("gateway_handler_cpu_us", int(cpu_s * 1e6))
 
 
 # -- the gateway -------------------------------------------------------------
@@ -1434,7 +1438,11 @@ def _make_handler(gw):
             Errors after headers ride an in-band ``{"error": ...}``
             event (the 200 is already on the wire). An event is a chunk
             and a chunk one send; the terminal event goes out with the
-            body's last chunk, in one more."""
+            body's last chunk, in one more. The handler counts the CPU
+            its thread held over the stream (``gateway_handler_cpu_us``),
+            once, on every way out: the thread's CPU clock is a system
+            call, and a dear one under a sandbox's kernel."""
+            cpu0 = time.thread_time()
             self.send_response(200)
             self.send_header("Content-Type", "text/event-stream")
             self.send_header("Cache-Control", "no-cache")
@@ -1559,7 +1567,8 @@ def _make_handler(gw):
                 return 200, None, sent
             finally:
                 # what the loop has not counted yet, on every way out
-                _count_events(sent % _COUNT_EVERY, last)
+                _count_events(sent % _COUNT_EVERY, last,
+                              time.thread_time() - cpu0)
 
         def _last_event(self, event, **dumps):
             """A stream's terminal event and the chunked body's last

@@ -406,6 +406,39 @@ def test_stream_counters_on_every_way_out(tmp_path, way):
     assert g.body_writes()[-1].endswith(b"\r\n0\r\n\r\n")
 
 
+@pytest.mark.parametrize("traced", [True, False],
+                         ids=["tracing_on", "tracing_off"])
+@pytest.mark.parametrize("way", sorted(WAYS_OUT))
+def test_handler_cpu_is_counted_on_every_way_out(tmp_path, monkeypatch, way,
+                                                 traced):
+    """``gateway_handler_cpu_us``: the CPU time the handler's thread held
+    over the stream, in whole microseconds, bumped once, where the stream
+    ends, on every way out of the writer: two reads of the thread's CPU
+    clock a stream, however many tokens. It is a counter (``/metrics``),
+    so it counts with the tracer off too."""
+    held, end, fail, extras, status, _reason, _wire, _sends = WAYS_OUT[way]
+    before = profiler.get_counter("gateway_handler_cpu_us")
+    reads = []
+    real = time.thread_time
+    fluid.set_flags({"FLAGS_obs_trace": traced})
+    try:
+        g = _Gateway(_Scripted(_stream(held, end=end)), tmp_path, fail=fail)
+        monkeypatch.setattr(time, "thread_time",
+                            lambda: (reads.append(1), real())[1])
+        try:
+            _raw_post(g.port, dict({"prompt_ids": [1]}, **extras))
+            assert g.status()["status"] == status
+        finally:
+            g.stop()
+    finally:
+        fluid.set_flags({"FLAGS_obs_trace": True})
+    rose = profiler.get_counter("gateway_handler_cpu_us") - before
+    # headers, a few events and a JSON dump: tens of microseconds at the
+    # least, and nowhere near the second the deadline's wait lasts asleep
+    assert 10 <= rose < 100_000
+    assert len(reads) == 2
+
+
 def test_counters_rise_while_a_long_stream_is_open(tmp_path):
     """``/metrics`` does not wait for a stream's end: the counters rise
     every ``_COUNT_EVERY`` tokens, and the end adds the rest."""

@@ -11,6 +11,7 @@ import http.client
 import json
 import os
 import statistics
+import threading
 import time
 
 import jax
@@ -26,6 +27,211 @@ from paddle_tpu.serving.decode import DecodeEngine
 
 PHASES = ("executor_prepare", "executor_run", "executor_marshal",
           "executor_dispatch", "executor_writeback", "executor_fetch")
+
+
+# -- a span's thread CPU time ------------------------------------------------
+@pytest.fixture
+def every_span_reads_its_clock():
+    """The tracer reads the CPU clock in one outermost ``cpu=True`` span
+    of a name in ``CPU_EVERY``; the tests of what a reading span records
+    ask for every one, as a measuring run does."""
+    before = trace.set_cpu_every(1)
+    yield
+    assert trace.set_cpu_every(before) == 1
+
+
+def _busy(seconds):
+    end = time.perf_counter() + seconds
+    while time.perf_counter() < end:
+        pass
+
+
+def _one_span(work, **kw):
+    trace.reset()
+    with trace.span("cpu_probe", **kw) as sp:
+        work(sp)
+    (rec,) = trace.get_spans()
+    return rec
+
+
+@pytest.mark.parametrize("work,lo,hi", [
+    (lambda sp: _busy(0.05), 0.8, 1.0),
+    (lambda sp: time.sleep(0.05), 0.0, 0.1),
+], ids=["busy", "asleep"])
+def test_cpu_span_reads_the_threads_cpu_time(every_span_reads_its_clock,
+                                             work, lo, hi):
+    """``cpu=True``: a busy loop's ``cpu_ms`` is within 20 % of its wall
+    time and a sleep's under 10 %; never more than the wall, because the
+    CPU clock is read inside the wall clock's two reads. The best of five
+    tries: another process may take the core from a busy loop."""
+    shares = []
+    for _ in range(5):
+        rec = _one_span(work, cpu=True)
+        wall = 1e3 * (rec["end"] - rec["start"])
+        assert 0.0 <= rec["args"]["cpu_ms"] <= wall
+        assert rec["args"]["cpu_at"] >= 0.0
+        shares.append(rec["args"]["cpu_ms"] / wall)
+        if lo <= shares[-1] <= hi:
+            break
+    assert lo <= shares[-1] <= hi, shares
+
+
+def test_phases_of_a_cpu_span_get_a_cpu_ms_each(every_span_reads_its_clock):
+    """A mark stays (name, start, args) and carries the thread's CPU
+    clock in its args; ``with_phases`` gives each phase the CPU time up to
+    the next mark (the last: up to the span's end). With the first mark
+    made as the span opens, they add up to the span's."""
+    def work(sp):
+        sp.phase("probe_busy", n=1)
+        _busy(0.02)
+        own = sp.phase("probe_asleep")
+        time.sleep(0.02)
+        own["late"] = True
+        sp.phase("probe_busy_again")
+        _busy(0.01)
+
+    rec = _one_span(work, cpu=True)
+    marks = rec["args"]["phases"]
+    assert [len(m) for m in marks] == [3, 3, 3]
+    assert all("cpu_at" in m[2] for m in marks)
+    parent, busy, asleep, again = trace.with_phases([rec])
+    assert parent is rec and busy["args"]["n"] == 1
+    assert asleep["args"]["late"] is True
+    parts = [c["args"]["cpu_ms"] for c in (busy, asleep, again)]
+    walls = [1e3 * (c["end"] - c["start"]) for c in (busy, asleep, again)]
+    assert all(0.0 <= c <= w + 1.0 for c, w in zip(parts, walls))
+    assert sum(parts) <= sum(walls) + 0.15
+    assert parts[1] < 0.25 * walls[1]      # asleep: next to no CPU
+    before_first = 1e3 * (marks[0][2]["cpu_at"] - rec["args"]["cpu_at"])
+    assert sum(parts) + before_first == pytest.approx(
+        rec["args"]["cpu_ms"], abs=1e-6)
+    # and the exported trace shows them, without the marks
+    events = {e["name"]: e for e in trace.chrome_trace()["traceEvents"]
+              if e.get("ph") == "X"}
+    assert "phases" not in events["cpu_probe"]["args"]
+    assert events["probe_asleep"]["args"]["cpu_ms"] == pytest.approx(parts[1])
+
+
+def test_span_without_cpu_makes_the_record_it_made_before():
+    def work(sp):
+        sp.phase("probe_phase", k=2)
+        sp.note(seen=True)
+
+    rec = _one_span(work, cat="probe", step=7)
+    ((name, at, own),) = rec["args"].pop("phases")
+    assert (name, own) == ("probe_phase", {"k": 2})
+    assert rec["start"] <= at <= rec["end"]
+    assert rec["args"] == {"step": 7, "seen": True}
+    rec["args"]["phases"] = [(name, at, own)]
+    assert sorted(rec) == ["args", "cat", "depth", "end", "id", "instant",
+                           "name", "parent", "parent_span_id", "span_id",
+                           "start", "tid", "trace_id"]
+    (_p, child) = trace.with_phases([rec])
+    assert child["args"] == {"k": 2}
+
+
+def test_cpu_span_reads_no_clock_with_tracing_off(every_span_reads_its_clock,
+                                                  monkeypatch):
+    reads = []
+    real = time.thread_time
+    monkeypatch.setattr(time, "thread_time",
+                        lambda: (reads.append(1), real())[1])
+    fluid.set_flags({"FLAGS_obs_trace": False})
+    try:
+        trace.reset()
+        with trace.span("cpu_probe", cpu=True) as sp:
+            sp.phase("probe_phase")
+        assert trace.get_spans() == [] and reads == []
+    finally:
+        fluid.set_flags({"FLAGS_obs_trace": True})
+    # on: a read as it opens, one a phase, one as it closes
+    with trace.span("cpu_probe", cpu=True) as sp:
+        sp.phase("probe_phase")
+    assert len(reads) == 3
+
+
+def _on_a_new_thread(fn):
+    """``fn()`` on a thread of its own: the tracer counts a thread's
+    spans from its first."""
+    box = []
+    t = threading.Thread(target=lambda: box.append(fn()))
+    t.start()
+    t.join()
+    return box[0]
+
+
+def test_one_outermost_span_of_a_name_in_cpu_every_reads_the_clock(
+        monkeypatch):
+    """At the rate the program runs at (``CPU_EVERY``, no calibration, the
+    same ticks on every host): of the outermost ``cpu=True`` spans of a
+    name that a thread opens, the first and then every ``CPU_EVERY``-th
+    read the CPU clock, with the ``cpu=True`` spans and phases inside
+    them; the others and all inside them read nothing and make the record
+    a span without ``cpu`` makes. Two outermost names that take turns (a
+    training step's ``executor_run`` and ``executor_fetch``) are counted
+    each for itself, so both read it, in the same round."""
+    every = trace.CPU_EVERY
+    assert every == 11
+    reads = []
+    real = time.thread_time
+    monkeypatch.setattr(time, "thread_time",
+                        lambda: (reads.append(1), real())[1])
+    rounds = 2 * every + 3
+
+    def steps():
+        said = []
+        for i in range(rounds):
+            with trace.span("cpu_probe_outer", cpu=True, i=i) as outer:
+                outer.phase("probe_phase")
+                with trace.span("cpu_probe_inner", cpu=True) as inner:
+                    assert inner.cpu == outer.cpu
+                with trace.span("probe_plain") as plain:
+                    assert plain.cpu is False
+            with trace.span("cpu_probe_other", cpu=True, i=i) as other:
+                assert other.cpu == outer.cpu
+            said.append(outer.cpu)
+        return said
+
+    trace.reset()
+    said = _on_a_new_thread(steps)
+    assert said == [i % every == 0 for i in range(rounds)]
+    # an outer span: open, phase, close, and the inner's two; the other: 2
+    assert len(reads) == 7 * said.count(True)
+    spans = trace.get_spans()
+    for s in spans:
+        stamped = s["name"] != "probe_plain" and said[[
+            o["args"]["i"] for o in spans
+            if o["name"] in ("cpu_probe_outer", "cpu_probe_other")
+            and o["start"] <= s["start"] and s["end"] <= o["end"]][0]]
+        assert ("cpu_ms" in s["args"]) == ("cpu_at" in s["args"]) == stamped
+    outers = [s for s in spans if s["name"] == "cpu_probe_outer"]
+    assert [("cpu_at" in s["args"]["phases"][0][2]) for s in outers] == said
+    # the phases of a span that read nothing get no cpu_ms
+    kids = [s for s in trace.with_phases(outers) if s["name"] == "probe_phase"]
+    assert [("cpu_ms" in k["args"]) for k in kids] == said
+    # another thread counts its own: its first reads
+    assert _on_a_new_thread(steps)[:2] == [True, False]
+
+
+def test_a_measuring_run_may_ask_for_every_span():
+    """``set_cpu_every``: the rate a measuring run asks for (every tick's
+    split, ``tools/cpu_clocks.py``), and back; it gives the rate before,
+    and nothing under 1."""
+    def three():
+        said = []
+        for _ in range(3):
+            with trace.span("cpu_probe", cpu=True) as sp:
+                said.append(sp.cpu)
+        return said
+
+    assert trace.set_cpu_every(1) == trace.CPU_EVERY
+    try:
+        assert _on_a_new_thread(three) == [True, True, True]
+        assert trace.set_cpu_every(0) == 1
+        assert _on_a_new_thread(three) == [True, True, True]
+    finally:
+        trace.set_cpu_every(trace.CPU_EVERY)
+    assert _on_a_new_thread(three) == [True, False, False]
 
 
 # -- executor ----------------------------------------------------------------
@@ -403,14 +609,118 @@ def test_tick_says_what_it_held(ticks):
     stepped = [s for s in ticks if s["name"] == "engine_tick"
                and s["args"]["active"]]
     a = stepped[len(stepped) // 2]["args"]
-    assert set(a) >= {"tick", "active", "prefilling", "queued", "cpu_ms",
+    assert set(a) >= {"tick", "active", "prefilling", "queued",
                       "blocks_in_use", "blocks_total", "live_tokens"}
     assert 0 < a["blocks_in_use"] <= a["blocks_total"]
     # a block holds 4 tokens: the live tokens fit the blocks handed out
     assert a["live_tokens"] <= 4 * a["blocks_in_use"]
     emit = [s for s in ticks if s["name"] == "tick_sample_emit"]
-    assert all(s["args"]["tokens"] >= 1 and s["args"]["cpu_ms"] >= 0
-               for s in emit)
+    assert all(s["args"]["tokens"] >= 1 for s in emit)
+
+
+LOOP_SPANS = ("engine_wait", "engine_tick", "tick_reap", "tick_admit",
+              "tick_prefill", "tick_build", "step_feed", "decode_paged_step",
+              "decode_paged_window", "executor_run", "executor_marshal",
+              "executor_dispatch", "executor_writeback", "executor_fetch",
+              "tick_publish", "tick_sample_emit")
+
+
+@pytest.fixture(scope="module")
+def cpu_ticks(gen_server):
+    """Spans of served ticks at the rate the program runs at: one tick in
+    ``CPU_EVERY`` reads the CPU clocks. Rounds of three short streams (a
+    round's length moves the read tick through the round) until a read
+    tick has held every kind of span the loop thread makes, a prompt's
+    window and the idle wait among them."""
+    trace.reset()
+    spans = []
+    for r in range(60):
+        streams = [gen_server.generate([3 + i, 7, 11][:1 + (r + i) % 3],
+                                       max_new_tokens=9 + r % 4)
+                   for i in range(3)]
+        for st in streams:
+            st.tokens(timeout=120)
+        spans = trace.with_phases(trace.get_spans())
+        read = {s["name"] for s in spans if "cpu_ms" in s["args"]}
+        if r >= 3 and read >= set(LOOP_SPANS):
+            break
+    return spans
+
+
+def _loop_ticks(spans):
+    loop = [s for s in spans if s["name"] == "engine_tick"][0]["tid"]
+    return sorted((s for s in spans if s["tid"] == loop
+                   and s["name"] == "engine_tick"), key=lambda s: s["start"])
+
+
+def test_one_served_tick_in_cpu_every_reads_the_clocks(cpu_ticks):
+    """The loop thread's ticks read their CPU clocks one in ``CPU_EVERY``,
+    counted: between two ticks that did lie ``CPU_EVERY`` - 1 that did
+    not. A tick that did not reads none in any span inside it and says no
+    ``process_cpu_ms``; one that did reads them in every ``cpu=True`` span
+    inside it."""
+    ticks_ = _loop_ticks(cpu_ticks)
+    read = [i for i, s in enumerate(ticks_) if "cpu_ms" in s["args"]]
+    assert len(read) >= 3
+    assert {b - a for a, b in zip(read, read[1:])} == {trace.CPU_EVERY}
+    assert [("process_cpu_ms" in s["args"]) for s in ticks_] == [
+        i in read for i in range(len(ticks_))]
+    tid = ticks_[0]["tid"]
+    inside = [s for s in cpu_ticks if s["tid"] == tid and not s["instant"]
+              and s["name"] in LOOP_SPANS and s["name"] != "engine_wait"]
+    for s in inside:
+        (held,) = [i for i, t in enumerate(ticks_)
+                   if t["start"] <= s["start"] and s["end"] <= t["end"]]
+        assert ("cpu_ms" in s["args"]) == (held in read), s["name"]
+
+
+@pytest.mark.parametrize("name", LOOP_SPANS)
+def test_loop_thread_span_says_how_long_it_held_a_cpu(cpu_ticks, name):
+    """Every kind of span of the loop thread, the executor's phases among
+    them, carries ``cpu_ms`` on the ticks that read the clock: the
+    thread's CPU time inside it, no more than its wall time. The two
+    clocks are read one after the other, so a stall between a pair of
+    reads (the machine's, not the program's) shows in one and not the
+    other: 0.05 ms a span over all of a name's spans, and no single one
+    off by a millisecond."""
+    found = [s for s in cpu_ticks
+             if s["name"] == name and "cpu_ms" in s["args"]]
+    assert found
+    walls = [1e3 * (s["end"] - s["start"]) for s in found]
+    cpus = [s["args"]["cpu_ms"] for s in found]
+    assert all(0.0 <= c <= w + 1.0 for c, w in zip(cpus, walls)), name
+    assert sum(cpus) <= sum(walls) + 0.05 * len(found), name
+    if name == "engine_wait":
+        # asleep on the condition until the next round's first request
+        assert all(s["args"]["cpu_ms"] < 5.0 for s in found)
+
+
+def test_tick_says_what_the_whole_process_used(cpu_ticks):
+    """``process_cpu_ms``: the CPU time of all the process's threads over
+    the tick, on every tick that reads its thread's CPU clock: no less
+    than the loop thread's own."""
+    both = [s["args"] for s in _loop_ticks(cpu_ticks)
+            if "process_cpu_ms" in s["args"]]
+    assert len(both) >= 3
+    assert all(a["process_cpu_ms"] >= a["cpu_ms"] - 1.0 for a in both)
+    assert (sum(a["process_cpu_ms"] for a in both)
+            >= sum(a["cpu_ms"] for a in both) - 0.05 * len(both))
+
+
+def test_request_record_says_which_tick_took_it(ticks):
+    """``submit_tick`` (the step counter as ``submit`` read it) and
+    ``dequeue_tick`` (as ``_admit`` read it when it took the request
+    off the queue): their difference is the steps the loop made while
+    the request waited."""
+    recs = [s["args"] for s in ticks if s["name"] == "decode_request"]
+    assert len(recs) == 3
+    steps = len([s for s in ticks if s["name"] == "decode_paged_step"])
+    for a in recs:
+        assert 0 <= a["submit_tick"] <= a["dequeue_tick"]
+        assert a["dequeue_tick"] - a["submit_tick"] <= steps
+    # the three were submitted one after another to an idle engine: the
+    # last was dequeued no earlier than the first
+    assert recs[0]["submit_tick"] <= recs[-1]["dequeue_tick"]
 
 
 def test_decode_tick_keeps_its_extent(ticks):
@@ -420,38 +730,6 @@ def test_decode_tick_keeps_its_extent(ticks):
     tick = [s for s in ticks if s["name"] == "decode_tick"][-1]
     names = {k["name"] for k in _children(tick, ticks)}
     assert names == {"step_feed", "decode_paged_step"}
-
-
-def test_paged_step_says_how_much_of_the_table_is_live(gen_server):
-    """``decode_paged_step`` carries ``blocks_live`` and ``blocks_table``:
-    of the slots x max_blocks table entries the T = 1 kernel is handed,
-    the ones that hold a live key — which is the blocks the allocator has
-    handed to the active slots at that call."""
-    engine = gen_server._decode_engine
-    sess, held = engine.session, []
-    step = sess.paged_step_ids
-
-    def counting(tokens, positions, tables, active, width=1):
-        held.append(sum(len(tables[s]) for s in range(sess.slots)
-                        if active[s]))
-        return step(tokens, positions, tables, active, width=width)
-
-    trace.reset()
-    sess.paged_step_ids = counting
-    try:
-        streams = [gen_server.generate([5 + i] * (3 + 4 * i),
-                                       max_new_tokens=9) for i in range(3)]
-        for s in streams:
-            s.tokens(timeout=120)
-    finally:
-        del sess.paged_step_ids
-    args = [s["args"] for s in trace.get_spans()
-            if s["name"] == "decode_paged_step"]
-    assert len(args) == len(held) >= 8
-    assert [a["blocks_live"] for a in args] == held
-    assert {a["blocks_table"] for a in args} == {sess.slots * sess.max_blocks}
-    # streams of different lengths, each well short of its table row
-    assert 0 < max(held) < sess.slots * sess.max_blocks // 2
 
 
 def test_no_span_per_token_or_slot(ticks):
@@ -523,6 +801,7 @@ def test_request_records_share_a_trace_id_and_agree_with_the_client(
     g = gws["args"]
     assert g["status"] == 200 and g["tokens"] == 12
     assert 0 <= g["sse_lag_ms_p50"] <= g["sse_lag_ms_max"] < 2000.0
+    assert a["submit_tick"] <= a["dequeue_tick"]
 
 
 def test_failed_stream_leaves_a_record_too(gen_server):
@@ -598,7 +877,14 @@ def test_kernel_name_reaches_the_lowered_program():
 
 
 # -- off means off -----------------------------------------------------------
-def test_no_record_with_tracing_off(gen_server):
+def test_no_record_with_tracing_off(gen_server, monkeypatch):
+    """... and no span reads a CPU clock: neither the thread's nor the
+    process's, in a served request or an ``Executor.run``."""
+    reads = []
+    for clock in ("thread_time", "process_time"):
+        real = getattr(time, clock)
+        monkeypatch.setattr(
+            time, clock, lambda real=real: (reads.append(1), real())[1])
     fluid.set_flags({"FLAGS_obs_trace": False})
     try:
         # the idle loop's engine_wait was opened with tracing on: let a
@@ -610,7 +896,10 @@ def test_no_record_with_tracing_off(gen_server):
         scope = fluid.core.Scope()
         exe.run(startup, scope=scope)
         exe.run(_steps_main, feed=_feed(), fetch_list=[loss], scope=scope)
+        reads.clear()
+        exe.run(_steps_main, feed=_feed(), fetch_list=[loss], scope=scope)
         gen_server.generate([4, 5, 6], max_new_tokens=6).tokens(timeout=60)
         assert trace.get_spans() == []
+        assert reads == []
     finally:
         fluid.set_flags({"FLAGS_obs_trace": True})

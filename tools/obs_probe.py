@@ -251,7 +251,9 @@ def _measure_overhead(pairs=100, warmup=15, span_bench_n=20000):
 
     - **primary (the <2% gate)**: measured per-span cost (enabled
       enter/exit minus disabled, microbenchmarked over ``span_bench_n``
-      iterations) x spans actually recorded per step / the median
+      iterations) x spans actually recorded per step, plus the measured
+      cost of a ``time.thread_time()`` read x the reads the step's
+      ``cpu=True`` spans and their phases make, / the median
       untraced step time. Deterministic to well under 0.1% — the effect
       being gated is a few µs against a multi-ms step, far below this
       shared CPU box's run-to-run step variance.
@@ -298,10 +300,18 @@ def _measure_overhead(pairs=100, warmup=15, span_bench_n=20000):
     # spans per step on this path: count what one traced step records
     trace.reset()
     fluid.set_flags({"FLAGS_obs_trace": True})
-    n_probe = 10
+    # (a whole number of the rounds in which one span of a name reads its
+    # thread's CPU clock, so the reads a step come out as they average)
+    n_probe = 2 * trace.CPU_EVERY
     for _ in range(n_probe):
         one_step()
-    spans_per_step = len(trace.get_spans()) / float(n_probe)
+    recorded = trace.get_spans()
+    spans_per_step = len(recorded) / float(n_probe)
+    # two reads a cpu=True span, one a phase it marked
+    cpu_reads_per_step = sum(
+        2 + sum(1 for m in s["args"].get("phases", ())
+                if "cpu_at" in (m[2] or {}))
+        for s in recorded if "cpu_ms" in s["args"]) / float(n_probe)
     # paired A/B, order alternated within each pair to cancel drift +
     # position bias
     diffs, offs = [], []
@@ -317,12 +327,19 @@ def _measure_overhead(pairs=100, warmup=15, span_bench_n=20000):
     fluid.set_flags({"FLAGS_obs_trace": False})
     cost_off = span_cost(span_bench_n)
     fluid.set_flags({"FLAGS_obs_trace": True})
+    t0 = time.perf_counter()
+    for _ in range(span_bench_n):
+        time.thread_time()
+    clock = (time.perf_counter() - t0) / span_bench_n
     med_off = statistics.median(offs)
     span_us = max(cost_on - cost_off, 0.0)
-    overhead_pct = span_us * spans_per_step / med_off * 100.0
+    overhead_pct = (span_us * spans_per_step
+                    + clock * cpu_reads_per_step) / med_off * 100.0
     return {
         "span_cost_us": round(span_us * 1e6, 3),
         "spans_per_step": round(spans_per_step, 2),
+        "cpu_clock_us": round(clock * 1e6, 3),
+        "cpu_reads_per_step": round(cpu_reads_per_step, 2),
         "step_ms_untraced": round(med_off * 1e3, 4),
         "overhead_pct": round(overhead_pct, 3),
         "ab_paired_diff_ms": round(statistics.median(diffs) * 1e3, 4),
@@ -381,18 +398,21 @@ def run_probe(args):
     ov = report["overhead"]
     assert ov["overhead_pct"] < 2.0, (
         "tracer overhead %.3f%% >= 2%% (%.3fus/span x %.1f spans/step"
-        " on a %.3fms step)"
+        " + %.3fus x %.1f CPU clock reads/step on a %.3fms step)"
         % (ov["overhead_pct"], ov["span_cost_us"], ov["spans_per_step"],
+           ov["cpu_clock_us"], ov["cpu_reads_per_step"],
            ov["step_ms_untraced"])
     )
     print(
         "PROBE PASS: %d spans across %d layers nest cleanly, %d counters"
         " + %d histograms round-trip /metrics, tracer overhead %.2f%%"
-        " (%.2fus/span x %.1f spans/step on a %.2fms step; A/B paired"
+        " (%.2fus/span x %.1f spans/step + %.2fus x %.1f CPU clock"
+        " reads/step on a %.2fms step; A/B paired"
         " diff %.4fms)%s (%.1fs)"
         % (report["trace"]["spans"], len(EXPECTED_SPANS),
            report["metrics"]["counters"], report["metrics"]["histograms"],
            ov["overhead_pct"], ov["span_cost_us"], ov["spans_per_step"],
+           ov["cpu_clock_us"], ov["cpu_reads_per_step"],
            ov["step_ms_untraced"], ov["ab_paired_diff_ms"],
            "" if args.fast else "; gang report merged %d restarts"
            % report["gang"]["gang_restarts"],
