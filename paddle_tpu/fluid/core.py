@@ -320,17 +320,30 @@ class _ScopeVar(object):
 
 
 class Scope(object):
+    """Name -> ``_ScopeVar`` cell, with a parent to fall back on.
+
+    ``_structure`` counts what changes WHICH cell a name means here:
+    ``var`` creating a name (in a kid scope that shadows the parent's)
+    and ``erase`` removing one. Setting a cell's value is not counted.
+    Whoever keeps the cells ``find_var`` gave it (the executor's
+    resolved-argument record) keeps ``structure_stamp()`` beside them:
+    while the stamp reads the same, every name still means the cell it
+    meant."""
+
     def __init__(self, parent=None):
         self._vars = {}
         self._parent = parent
         self._kids = []
         self._lock = threading.Lock()
+        self._structure = 0
 
     def var(self, name):
         with self._lock:
-            if name not in self._vars:
-                self._vars[name] = _ScopeVar(name)
-            return self._vars[name]
+            v = self._vars.get(name)
+            if v is None:
+                v = self._vars[name] = _ScopeVar(name)
+                self._structure += 1
+            return v
 
     def find_var(self, name):
         s = self
@@ -341,10 +354,26 @@ class Scope(object):
             s = s._parent
         return None
 
+    def find_local_var(self, name):
+        """This scope's own cell of ``name`` (what ``set`` writes), or
+        None where the name is the parent's or nobody's."""
+        return self._vars.get(name)
+
+    def structure_stamp(self):
+        """The structure counters of this scope and of every scope
+        ``find_var`` falls back on, outermost last."""
+        stamp = [self._structure]
+        s = self._parent
+        while s is not None:
+            stamp.append(s._structure)
+            s = s._parent
+        return tuple(stamp)
+
     def erase(self, names):
         with self._lock:
             for n in names:
-                self._vars.pop(n, None)
+                if self._vars.pop(n, None) is not None:
+                    self._structure += 1
 
     def new_scope(self):
         kid = Scope(parent=self)

@@ -17,13 +17,24 @@ engine here is different by design:
 - scope variables mutated in place by the reference (parameters, optimizer
   accumulators, BN running stats) become donated XLA buffers — donation is
   the TPU-native replacement for the GC/inplace/memory-reuse pass stack
-  (framework/ir/memory_optimize_pass/).
+  (framework/ir/memory_optimize_pass/);
+- a step's outputs are the next step's inputs, so a run does not look its
+  state up again: the first run of a block against a scope resolves each
+  state name to its scope cell and its placement, once
+  (``_CompiledBlock._resolve``), and remembers beside the cell the array
+  it handed to the executable. Later runs hand ``cell.value`` over on an
+  ``is`` with that array; anything else (a value ``set`` from outside, a
+  host array, a ``LoDTensor``) is looked up and placed as on the first
+  run, that one value. Writeback stores the outputs into the cells and
+  into the record. ``Scope.structure_stamp`` says when the cells
+  themselves must be resolved again.
 """
 
 from __future__ import annotations
 
 import threading
 import time
+import weakref
 
 import numpy as np
 
@@ -894,6 +905,22 @@ class _CompiledBlock(object):
                 )
             )
             defined |= writes
+        # persistable names whose LAST writer in program order is an XLA
+        # segment: what writeback finds under them is an executable's
+        # output, resident where the next run wants it (a host op's
+        # write may sit anywhere)
+        self._device_outs = set()
+        for kind, seg, plan in self._plans:
+            if kind == "xla":
+                self._device_outs.update(
+                    n for n in plan["outs"] if n in persistable)
+            else:
+                self._device_outs.difference_update(seg.writes)
+        # scope -> _ArgRecord (see ``run``). Weakly keyed: a dropped
+        # scope drops its record, and the record holds the scope's
+        # cells, never the scope
+        self._records = weakref.WeakKeyDictionary()
+        self._records_lock = threading.Lock()
 
     def _check_tp_segment_safety(self):
         """Model-sharded ACTIVATIONS (between a column-parallel and the
@@ -1100,48 +1127,115 @@ class _CompiledBlock(object):
             )
             return ex
 
+    def _resolve(self, scope, place, old=None):
+        """The resolved-argument record of this block against ``scope``:
+        for every name an XLA segment takes from the scope, the cell
+        ``find_var`` returns and where its value has to sit (the
+        device, or the ``NamedSharding``, built once a name). Entries of
+        ``old`` whose name still means the same cell are kept, with the
+        value they remember."""
+        stamp = scope.structure_stamp()  # read first: a var made while
+        # this resolves is seen by the next run
+        if self.spmd is not None:
+            # GSPMD placement: state lands with its policy sharding.
+            # The committed inputs ARE the parallelism spec; the traced
+            # fn never saw a mesh.
+            feed_dev = None
+            target_of = self.spmd.sharding_of
+        elif self.mesh is not None:
+            # sharded H2D: feeds split over the data axis; state vars
+            # land with their dist_attr sharding (TP weights stay
+            # sharded between steps instead of being re-replicated)
+            from jax.sharding import NamedSharding, PartitionSpec as P
+
+            feed_dev = old.feed_dev if old is not None else NamedSharding(
+                self.mesh, P("data"))
+
+            def target_of(name):
+                return NamedSharding(self.mesh, self._dist_spec_of(name))
+        else:
+            feed_dev = core.get_jax_device(place)
+
+            def target_of(name):
+                return feed_dev
+
+        kept = old.entries if old is not None else {}
+        entries = {}
+
+        def entry(name):
+            e = entries.get(name)
+            if e is None:
+                cell = scope.find_var(name) or _NO_CELL
+                e = kept.get(name)
+                if e is None:
+                    e = _ArgEntry(name, cell, target_of(name))
+                elif e.cell is not cell:
+                    e = _ArgEntry(name, cell, e.target)
+                e.own = scope.find_local_var(name) is cell
+                entries[name] = e
+            return e
+
+        # by ``seg_index``: the XLA plans in order
+        plans = [
+            tuple([entry(n) for n in plan.get(group, ())]
+                  for group in ("mutable", "sharded_const", "const"))
+            for kind, _seg, plan in self._plans if kind == "xla"
+        ]
+        # where writeback stores what the executables return: the
+        # scope's own cell of a name (the parent's is never written: a
+        # kid scope shadows it, through ``scope.set``)
+        outs = {}
+        for n in self._device_outs:
+            e = entry(n)
+            if e.own:
+                outs[n] = e
+        return _ArgRecord(stamp, place, feed_dev, entries, plans, outs)
+
     def run(self, scope, feed, rng_key, place, span=None):
         """One run of the block. ``span`` is the caller's open
         ``executor_run`` span: the phases of each segment are marked on
         it (``executor_marshal``, ``executor_dispatch``,
         ``executor_host_ops``, then ``executor_writeback``), which costs
-        a clock read each where a span of its own would cost a record."""
+        a clock read each where a span of its own would cost a record.
+
+        What a run looks up and what it remembers. The first run
+        against a scope resolves every state name to its scope cell and
+        its placement (``_resolve``); the record is kept by scope,
+        weakly, and resolved again only when ``Scope.structure_stamp``
+        says a name may mean another cell (a var created or erased, a
+        kid scope shadowing its parent). Beside each cell the record
+        keeps a weak reference to the ``jax.Array`` last handed to an
+        executable, if the cell held that very object. A later run
+        hands the cell's value over on one ``is``: the array is
+        immutable, and resident because this code placed it or an
+        executable returned it. Every other
+        value takes ``lookup`` -> ``put``: one that somebody ``set``
+        since, a numpy array or a ``LoDTensor`` (mutable in place, so
+        never remembered and placed on every run), a name an earlier
+        segment left in ``local_env``, an optional constant that is
+        absent. Writeback stores a step's outputs into the cells and
+        into the record in one stroke. The record keeps no array alive:
+        what the scope lets go is freed. The feeds are new host
+        arrays every run and are placed as they come."""
         import jax
 
         phase = span.phase if span is not None else _no_phase
+        rec = self._records.get(scope)
+        if (rec is None or rec.stamp != scope.structure_stamp()
+                or (rec.place is not place and rec.place != place)):
+            rec = self._resolve(scope, place, rec)
+            with self._records_lock:
+                self._records[scope] = rec
+            _profiler.bump_counter("executor_arg_records_resolved")
+        feed_dev = rec.feed_dev
         if self.spmd is not None:
-            # GSPMD placement: feeds batch-shard over the data axis when
-            # their leading dim divides (replicate otherwise — decode's
-            # slot indices, block tables), state lands with its policy
-            # sharding. The committed inputs ARE the parallelism spec;
-            # the traced fn never saw a mesh.
-            spmd_plan = self.spmd
-            feed_dev = None
-            feed_dev_of = spmd_plan.feed_sharding
-
-            def state_dev_for(name):
-                return spmd_plan.sharding_of(name)
-        elif self.mesh is not None:
-            # sharded H2D: feeds split over the data axis; state vars land
-            # with their dist_attr sharding (TP weights stay sharded
-            # between steps instead of being re-replicated)
-            from jax.sharding import NamedSharding, PartitionSpec as P
-
-            feed_dev = NamedSharding(self.mesh, P("data"))
-
-            def feed_dev_of(val):
-                return feed_dev
-
-            def state_dev_for(name):
-                return NamedSharding(self.mesh, self._dist_spec_of(name))
+            # feeds batch-shard over the data axis when their leading
+            # dim divides (replicate otherwise: decode's slot indices,
+            # block tables)
+            feed_dev_of = self.spmd.feed_sharding
         else:
-            feed_dev = core.get_jax_device(place)
-
             def feed_dev_of(val):
                 return feed_dev
-
-            def state_dev_for(name):
-                return core.get_jax_device(place)
 
         results = {}
         local_env = {}
@@ -1167,16 +1261,7 @@ class _CompiledBlock(object):
                 v = feed[name]
             return v
 
-        def need(name):
-            v = lookup(name)
-            if v is None:
-                raise ValueError(
-                    "variable %r is not initialized (run the startup "
-                    "program first)" % name
-                )
-            return v
-
-        placed = 0
+        placed = looked_up = absent = 0
 
         def put(val, device):
             nonlocal placed
@@ -1185,10 +1270,23 @@ class _CompiledBlock(object):
             placed += 1
             return _to_device(val, device)
 
-        def state(name, v=None):
+        def state(e, optional):
+            """``lookup`` -> ``put`` for one value the identity check
+            does not cover, remembered if the scope holds the very
+            array that is handed over."""
+            nonlocal looked_up, absent
+            looked_up += 1
+            name = e.name
+            v = lookup(name)
             if v is None:
-                v = need(name)
-            out = put(v, state_dev_for(name))
+                if optional and _is_optional_missing(name):
+                    absent += 1
+                    return None  # absent key: lowering treats it as zeros
+                raise ValueError(
+                    "variable %r is not initialized (run the startup "
+                    "program first)" % name
+                )
+            out = put(v, e.target)
             if (self.spmd is not None and out is not v
                     and name in self._persistable
                     and name not in local_env):
@@ -1202,8 +1300,26 @@ class _CompiledBlock(object):
                 # writeback, through the first step's own temporaries
                 # (gpt2-large under FSDP: 6.7 GB of Adam moments on
                 # device 0, and the step did not load)
-                scope.set(name, out)
+                if e.own:
+                    e.cell.value = out
+                else:
+                    scope.set(name, out)  # the scope's own cell, made now
+            e.held = weakref.ref(out) if e.cell.value is out else _none_held
             return out
+
+        def gather(entries, optional=False):
+            """The values of one argument group, in order (None for an
+            absent optional constant)."""
+            shadowed = bool(local_env)
+            vals = []
+            for e in entries:
+                v = e.cell.value
+                # a dead reference gives None, as a cell never set does
+                if (v is not e.held() or v is None
+                        or (shadowed and e.name in local_env)):
+                    v = state(e, optional)
+                vals.append(v)
+            return vals
 
         for kind, seg, plan in self._plans:
             if kind == "host":
@@ -1217,7 +1333,8 @@ class _CompiledBlock(object):
             # (executor_marshal), then the call and keeping what it
             # returned (executor_dispatch)
             note = phase("executor_marshal", segment=plan["seg_index"])
-            placed = 0
+            mutable, sharded, const = rec.plans[plan["seg_index"]]
+            placed = looked_up = absent = 0
             feed_vals = []
             for n in plan["feeds"]:
                 val = feed.get(n)
@@ -1229,19 +1346,27 @@ class _CompiledBlock(object):
                 if val is None:
                     raise ValueError("feed variable %r was not provided" % n)
                 feed_vals.append(put(val, feed_dev_of(val)))
-            mutable_vals = [state(n) for n in plan["mutable"]]
-            sharded_vals = [state(n) for n in plan.get("sharded_const", ())]
-            const_map = {}
-            for n in plan["const"]:
-                v = lookup(n)
-                if v is None and _is_optional_missing(n):
-                    continue  # absent key: lowering treats it as zeros
-                const_map[n] = state(n, v)
+            mutable_vals = gather(mutable)
+            sharded_vals = gather(sharded)
+            const_vals = gather(const, optional=True)
+            if absent:
+                const_map = {
+                    n: v for n, v in zip(plan["const"], const_vals)
+                    if v is not None
+                }
+            else:
+                const_map = dict(zip(plan["const"], const_vals))
             note["values"] = (len(feed_vals) + len(mutable_vals)
                               + len(sharded_vals) + len(const_map))
             note["placed"] = placed
+            # handed over on the identity check alone (an absent
+            # optional constant is looked up and is no value)
+            note["reused"] = reused = len(mutable) + len(sharded) + len(
+                const) - looked_up
             if placed:
                 _profiler.bump_counter("executor_values_placed", placed)
+            if reused:
+                _profiler.bump_counter("executor_values_reused", reused)
             phase("executor_dispatch", segment=plan["seg_index"])
             outs = self._dispatch(
                 plan, tuple(feed_vals), tuple(mutable_vals),
@@ -1253,10 +1378,18 @@ class _CompiledBlock(object):
         # persist writes + collect fetches
         note = phase("executor_writeback")
         persistable = self._persistable
+        device_outs = rec.outs
         written = 0
         for n, v in local_env.items():
             if n in persistable:
-                scope.set(n, v)
+                e = device_outs.get(n)
+                if e is not None and isinstance(v, jax.Array):
+                    # an executable's output into its resolved cell and
+                    # into the record: the next run's check passes
+                    e.cell.value = v
+                    e.held = weakref.ref(v)
+                else:
+                    scope.set(n, v)
                 written += 1
         for n in self.fetch_names:
             v = local_env.get(n)
@@ -1267,6 +1400,54 @@ class _CompiledBlock(object):
         return [results[n] for n in self.fetch_names]
 
 
+class _ArgEntry(object):
+    """One state name of a block, resolved against one scope: the cell
+    the name means there (``_NO_CELL`` where it means none yet),
+    whether that cell is the scope's own (``set`` writes it) or a
+    parent's, where the value has to sit, and ``held``: a weak
+    reference to the ``jax.Array`` last handed to an executable, if the
+    cell held that very object (weak, so that the record keeps no array
+    alive that the scope has let go: a pool that ``reset_caches`` or a
+    reload replaced would otherwise stay on the device until this
+    block's next run)."""
+
+    __slots__ = ("name", "cell", "own", "target", "held")
+
+    def __init__(self, name, cell, target):
+        self.name = name
+        self.cell = cell
+        self.own = False
+        self.target = target
+        self.held = _none_held
+
+
+class _ArgRecord(object):
+    """What ``_CompiledBlock._resolve`` found: ``entries`` by name,
+    ``plans`` with the (mutable, sharded_const, const) entries of each
+    XLA segment in order, ``outs`` with the entries writeback stores
+    through, the scope's ``stamp``, and the ``place`` it holds for with
+    the feeds' device (None where a feed's sharding goes by its shape)."""
+
+    __slots__ = ("stamp", "place", "feed_dev", "entries", "plans", "outs")
+
+    def __init__(self, stamp, place, feed_dev, entries, plans, outs):
+        self.stamp = stamp
+        self.place = place
+        self.feed_dev = feed_dev
+        self.entries = entries
+        self.plans = plans
+        self.outs = outs
+
+
+def _none_held():
+    """``_ArgEntry.held`` of an entry that remembers no array."""
+    return _NOT_HELD
+
+
+_NOT_HELD = object()  # ``is`` no value
+_NO_CELL = core._ScopeVar("")  # a name the scope does not hold (yet)
+
+
 def _no_phase(name, **args):
     """``span.phase`` for a block run outside any span."""
     return args
@@ -1275,16 +1456,16 @@ def _no_phase(name, **args):
 def _is_resident(val, device):
     """Whether ``val`` is a device array that already sits where
     ``device`` says: on that one device, or laid out as that
-    ``Sharding``. State vars (params, KV caches, optimizer accumulators)
-    come back from every step as device arrays, so the steady-state walk
-    re-places values that never moved. jax.device_put would conclude the
-    same — at ~40-50 µs of dispatch per value, which for a ~40-param
-    program is a milliseconds-per-step tax (the decode probe measured it
-    at a third of the whole single-token step; gpt2-large under FSDP,
-    2,900 values: 138 ms of a 430 ms step). devices() is a stored set,
-    the compare ~0.1 µs; a step's outputs carry the plan's own sharding
-    objects (``out_shardings``), so under a mesh the compare is as
-    short."""
+    ``Sharding``. jax.device_put would conclude the same, at ~40-50 µs
+    of dispatch per value (gpt2-large under FSDP, 2,900 values: 138 ms
+    of a 430 ms step, PR 25). devices() is a stored set and a step's
+    outputs carry the plan's own sharding objects (``out_shardings``),
+    so the compare is short; with the lookup, the closure calls and the
+    sharding built a value around it, the walk over every state value
+    still cost ~5.3 µs a value on one chip and ~8 µs under the mesh
+    (5.3 / 23.6 ms a step, the ledger's PR 37 lines). Since PR 39 only
+    a value that fails ``run``'s identity check comes here: the feeds,
+    and whatever was set into the scope since the last run."""
     import jax
     from jax.sharding import Sharding
 
@@ -1612,8 +1793,6 @@ class Executor(object):
         sampled negatives vary per step yet replay identically across
         process restarts."""
         import jax
-
-        import weakref
 
         seed = program._seed or 0
         # counters live ON the program, weakly keyed by scope: no id()

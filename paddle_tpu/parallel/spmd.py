@@ -179,6 +179,8 @@ class SpmdPlan(object):
         )
         self.specs = dict(specs)
         self.fsdp = bool(fsdp)
+        # var name (or "@feed:...", no var's name) -> NamedSharding
+        self._shardings = {}
 
     def spec_of(self, name):
         from jax.sharding import PartitionSpec as P
@@ -186,21 +188,33 @@ class SpmdPlan(object):
         return P(*self.specs.get(name, ()))
 
     def sharding_of(self, name):
-        from jax.sharding import NamedSharding
+        """The ``NamedSharding`` of a state var, built once a name: the
+        executor asks for every var of a program, and a step's
+        ``out_shardings`` carry these same objects."""
+        return self._sharding_for(name)
 
-        return NamedSharding(self.mesh, self.spec_of(name))
+    def _sharding_for(self, key, spec=None):
+        s = self._shardings.get(key)
+        if s is None:
+            from jax.sharding import NamedSharding
+
+            s = self._shardings[key] = NamedSharding(
+                self.mesh, self.spec_of(key) if spec is None else spec)
+        return s
 
     def feed_sharding(self, value):
         """Feeds batch-shard dim 0 over ``data`` when the value's
         leading dim divides; everything else (decode's slot indices,
-        block tables, biases at odd batch) replicates."""
-        from jax.sharding import NamedSharding, PartitionSpec as P
+        block tables, biases at odd batch) replicates. Each of the two
+        shardings is built once, when a feed first takes it (a mesh
+        without a ``data`` axis only ever replicates)."""
+        from jax.sharding import PartitionSpec as P
 
         n = int(self.axis_sizes.get(DATA_AXIS, 1) or 1)
         shape = np.shape(value)
         if n > 1 and len(shape) >= 1 and shape[0] and shape[0] % n == 0:
-            return NamedSharding(self.mesh, P(DATA_AXIS))
-        return NamedSharding(self.mesh, P())
+            return self._sharding_for("@feed:split", P(DATA_AXIS))
+        return self._sharding_for("@feed:replicated", P())
 
     def sharded_params(self):
         return sorted(n for n, s in self.specs.items() if any(s))
