@@ -1,7 +1,8 @@
 """What the engine's loop thread did inside its spans: held a CPU, or
 waited for one (on a thread that shares an interpreter: for the
-interpreter lock, behind the handlers' and the in-process clients'
-threads), or waited for the device.
+interpreter lock, behind the handlers' threads; the benchmark's clients
+run in a process of their own, ``harness/loadgen.py``, and contend for a
+core at most), or waited for the device.
 
 A span the program opened with ``cpu=True`` carries ``cpu_ms`` (the
 thread's CPU time between its two ends, ``time.thread_time``) and

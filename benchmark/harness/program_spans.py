@@ -79,14 +79,45 @@ def ms(span):
     return 1e3 * (span["end"] - span["start"])
 
 
+# the lists ``inside`` was last asked about, each with its spans by thread
+# and start: a reader asks once a tick, and a scan of all spans a tick is
+# quadratic in the ticks of a window
+_INDEXED = []
+
+
+def _by_thread(spans):
+    """{tid: (sorted starts, positions in ``spans``)} of the spans that are
+    not instants, kept for the last few lists asked about."""
+    for kept, size, index in _INDEXED:
+        if kept is spans and size == len(spans):
+            return index
+    rows = {}
+    for at, s in enumerate(spans):
+        if not s.get("instant"):
+            rows.setdefault(s["tid"], []).append((s["start"], at))
+    index = {}
+    for tid, pairs in rows.items():
+        pairs.sort()
+        index[tid] = ([p[0] for p in pairs], [p[1] for p in pairs])
+    _INDEXED.append((spans, len(spans), index))
+    del _INDEXED[:-4]
+    return index
+
+
 def inside(parent, spans, name=None):
     """The spans of ``parent``'s thread that lie within it (itself left
-    out), optionally only those called ``name``."""
-    return [s for s in spans
-            if s is not parent and s["tid"] == parent["tid"]
-            and s["start"] >= parent["start"] and s["end"] <= parent["end"]
-            and not s.get("instant")
-            and (name is None or s["name"] == name)]
+    out), optionally only those called ``name``, in the order of
+    ``spans``."""
+    starts, positions = _by_thread(spans).get(parent["tid"], ((), ()))
+    lo = bisect.bisect_left(starts, parent["start"])
+    hi = bisect.bisect_right(starts, parent["end"])
+    out = []
+    for at in sorted(positions[lo:hi]):
+        s = spans[at]
+        if (s is not parent and s["end"] <= parent["end"]
+                and (name is None or s["name"] == name)):
+            out.append(s)
+    return out
 
 
 def covered_seconds(parent, spans):
@@ -157,7 +188,7 @@ TICK_PHASES = ("engine_tick", "tick_reap", "tick_admit", "tick_prefill",
                "tick_build", "decode_tick", "step_feed", "executor_prepare",
                "executor_marshal", "executor_dispatch", "executor_writeback",
                "executor_fetch", "step_logits", "tick_sample_emit",
-               "engine_wait")
+               "tick_publish", "engine_wait")
 
 
 def note_tick_self(ev):

@@ -7,6 +7,8 @@ returns None and the metric is left out of the line; it never returns 0
 for a share of a roofline or of a peak.
 """
 
+import time
+
 from . import xplane
 
 
@@ -118,10 +120,11 @@ class Evidence(object):
         plane = planes[0]
         tops = sorted(xplane.totals_by_name(plane.ops).items(),
                       key=lambda kv: -kv[1])[:10]
-        host = self.host_intervals()
+        gaps = xplane.gaps(plane.ops)
+        names = attribute_all([0.5 * (a + b) for a, b in gaps],
+                              self.host_intervals())
         idle = {}
-        for a, b in xplane.gaps(plane.ops):
-            name = attribute(0.5 * (a + b), host)
+        for (a, b), name in zip(gaps, names):
             idle[name] = idle.get(name, 0.0) + (b - a)
         gaps = sorted(idle.items(), key=lambda kv: -kv[1])[:10]
         return {"device_ops": [[short(n), s] for n, s in tops],
@@ -162,21 +165,36 @@ def short(name, width=96):
     return head[:width]
 
 
-def attribute(t, intervals):
-    """The name of the shortest interval that holds time ``t``."""
-    best, best_len = "host_no_span", None
-    for name, a, b in intervals:
-        if a <= t <= b and (best_len is None or b - a < best_len):
-            best, best_len = name, b - a
-    return best
+def attribute_all(times, intervals):
+    """For each of ``times``, which ascend, the name of the shortest
+    interval (name, start, end) that holds it, ``host_no_span`` where none
+    does: one sweep over the intervals, of which a traced serve window
+    holds tens of thousands, as it does of idle gaps."""
+    order = sorted(intervals, key=lambda iv: iv[1])
+    out, open_now, at = [], [], 0
+    for t in times:
+        while at < len(order) and order[at][1] <= t:
+            open_now.append(order[at])
+            at += 1
+        open_now = [iv for iv in open_now if iv[2] >= t]
+        best = min(open_now, key=lambda iv: iv[2] - iv[1], default=None)
+        out.append(best[0] if best else "host_no_span")
+    return out
 
 
 def read_layer_metrics(cell, evidence, log):
-    values = {}
+    """{metric: value or None} of the cell's per-layer metrics, and an
+    earlier line with the seconds each reader that took over a second
+    needed: a traced run has 360 s for everything."""
+    values, took = {}, {}
     for metric in cell.per_layer:
         reader = cell.module("layer_metrics", metric["name"])
+        t = time.perf_counter()
         value = reader.read(evidence)
+        took[metric["name"]] = time.perf_counter() - t
         if value is None:
             log("layer_metric_absent", name=metric["name"])
         values[metric["name"]] = value
+    log("layer_metric_seconds", total=sum(took.values()),
+        over_a_second={k: v for k, v in took.items() if v >= 1.0})
     return values

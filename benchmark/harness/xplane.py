@@ -7,6 +7,7 @@ event per operation run on that chip and ``XLA Modules`` one per program
 ``TraceAnnotation`` the benchmark wrote.
 """
 
+import bisect
 import glob
 import os
 import re
@@ -112,6 +113,20 @@ def busy_seconds(events, t0=None, t1=None):
     return total
 
 
+def busy_between(merged, starts, t0, t1):
+    """``busy_seconds`` inside [t0, t1] for intervals already merged
+    (``union_intervals``; ``starts`` their starts): a reader that asks once
+    a step merges a plane's events once, not once a step."""
+    total = 0.0
+    at = max(bisect.bisect_right(starts, t0) - 1, 0)
+    while at < len(merged) and merged[at][0] < t1:
+        a, b = max(merged[at][0], t0), min(merged[at][1], t1)
+        if b > a:
+            total += b - a
+        at += 1
+    return total
+
+
 def span_of(events):
     """(first start, last end) of a sorted, non-empty list of events."""
     return events[0].start, max(e.end for e in events)
@@ -142,6 +157,18 @@ def whole_modules(plane):
         return []
     t0, t1 = span_of(plane.ops)
     return [m for m in plane.modules if m.start >= t0 and m.end <= t1]
+
+
+def modules_running(plane, pattern):
+    """The plane's whole modules inside which an op named by ``pattern``
+    starts: one pass over the ops, not one a module."""
+    starts = [e.start for e in matching(plane.ops, pattern)]
+    out = []
+    for m in whole_modules(plane):
+        at = bisect.bisect_left(starts, m.start)
+        if at < len(starts) and starts[at] <= m.end:
+            out.append(m)
+    return out
 
 
 def ops_inside(plane, modules):

@@ -1,7 +1,7 @@
 """The Pallas kernels by the names the program gives them (``name=`` on
 each ``pallas_call`` of ``paddle_tpu/kernels/flash_attention.py``):
-``flash_fwd``, ``flash_bwd_dq``, ``flash_bwd_dkv``, ``flash_decode``,
-``flash_decode_paged``.
+``flash_fwd``, ``flash_bwd_dq``, ``flash_bwd_dkv``,
+``flash_decode_paged``, ``flash_decode_paged_gqa``.
 
 An op event's name on the device's "XLA Ops" line is the instruction's
 HLO text. A named kernel shows there twice: in the instruction's own name
@@ -17,6 +17,7 @@ def event_pattern(kernel):
     """A regular expression for the events of the Pallas kernel
     ``kernel``: a ``tpu_custom_call`` whose text carries the name, not
     followed by more of a longer name (``flash_bwd_dq`` against
-    ``flash_bwd_dkv``, ``flash_decode`` against ``flash_decode_paged``)."""
+    ``flash_bwd_dkv``, ``flash_decode_paged`` against
+    ``flash_decode_paged_gqa``)."""
     return r"(?s)^(?=.*%s)(?=.*\b%s(?![a-z]|_[a-z]))" % (
         flash_train.EVENT_PATTERN, kernel)

@@ -28,9 +28,7 @@ def _steps(ev):
     if not planes:
         return None, []
     plane = planes[0]
-    return plane, [
-        m for m in xplane.whole_modules(plane)
-        if xplane.matching(xplane.ops_inside(plane, [m]), KDA_PATTERN)]
+    return plane, xplane.modules_running(plane, KDA_PATTERN)
 
 
 def step_seconds(ev, pattern):

@@ -24,9 +24,7 @@ def _steps(ev):
     if not planes:
         return None, []
     plane = planes[0]
-    return plane, [
-        m for m in xplane.whole_modules(plane)
-        if xplane.matching(xplane.ops_inside(plane, [m]), MLA_PATTERN)]
+    return plane, xplane.modules_running(plane, MLA_PATTERN)
 
 
 def step_seconds(ev, pattern):

@@ -13,7 +13,9 @@ def read(ev):
     if len(steps) < 2:
         return None
     out = []
+    merged = xplane.union_intervals(planes[0].ops)
+    starts = [a for a, _b in merged]
     for a, b in zip(steps, steps[1:]):
-        busy = xplane.busy_seconds(planes[0].ops, a.start, b.start)
+        busy = xplane.busy_between(merged, starts, a.start, b.start)
         out.append(1e3 * ((b.start - a.start) - busy))
     return median(out)

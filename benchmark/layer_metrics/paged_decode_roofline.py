@@ -1,7 +1,10 @@
 """Share of the roofline the paged decode kernel reaches, by the LIVE
 keys and values of each stream (prompt plus tokens so far, as the client
-knows them at the middle of the profiled seconds), not the ``max_len``
-rectangle the kernel sweeps today."""
+knows them at the middle of the profiled seconds). The kernel
+(``flash_decode_paged``) walks a slot's block table and neither fetches
+nor computes a logical block past the slot's last live one, so what it
+moves is the live rows rounded up to whole blocks, not a ``max_len``
+rectangle; what separates it from 100 % is how fast it moves them."""
 
 from benchmark.harness import peaks
 from benchmark.kernels import paged_decode

@@ -3,10 +3,11 @@ CPU clocks (``engine_tick``'s ``process_cpu_ms``: ``time.process_time``
 over the tick, all threads), that neither the engine's loop thread
 (``engine_tick``'s ``cpu_ms``) nor the gateway's handlers
 (``gateway_handler_cpu_us`` over the window, a tick's share of it) used:
-in these cells the benchmark's in-process clients and the runtime's own
-threads, which is the most that moving the load generator out of the
-server's process can take out of the server's interpreter. The note gives
-the three in ms a tick."""
+the runtime's own threads (the device runtime's, the server's accept loop
+and workers, the profiler's in a traced run) and nothing of the benchmark,
+whose clients run in a process of their own (``harness/loadgen.py``); up
+to PR 40 the 64 in-process clients were counted here and made up most of
+it. The note gives the three in ms a tick."""
 
 from benchmark.harness import cpu_spans
 

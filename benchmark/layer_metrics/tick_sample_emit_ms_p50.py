@@ -1,6 +1,10 @@
 """Median milliseconds of ``tick_sample_emit``: after the fused step,
-one ``pick`` and one ``_emit`` a stream (each emit wakes an SSE handler
-thread), speculative accounting and block trimming."""
+for every stream its token taken from the step's fetched ids (or picked
+on the host from its logits row where it samples) and one ``_emit``,
+speculative accounting and block trimming. An emit only lays the token
+in the tick's outbox: the handlers' threads are woken later, in
+``tick_publish``, once the next device call is dispatched
+(``tick_publish_ms_p50``)."""
 
 from benchmark.harness import program_spans as ps
 from benchmark.harness.stats import median

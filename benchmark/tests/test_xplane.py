@@ -19,6 +19,12 @@ def test_union_busy_and_gaps_hand_made():
     assert xplane.union_intervals(ev) == [(0.0, 1.5), (3.0, 4.0)]
     assert xplane.busy_seconds(ev) == pytest.approx(2.5)
     assert xplane.busy_seconds(ev, 1.0, 3.5) == pytest.approx(1.0)
+    merged = xplane.union_intervals(ev)
+    starts = [a for a, _b in merged]
+    for t0, t1 in ((1.0, 3.5), (-1.0, 9.0), (0.0, 0.25), (2.0, 2.0),
+                   (8.0, 9.0), (0.75, 2.25)):
+        assert xplane.busy_between(merged, starts, t0, t1) == pytest.approx(
+            xplane.busy_seconds(ev, t0, t1))
     assert xplane.gaps(ev) == [(1.5, 3.0)]
     assert xplane.totals_by_name(ev) == {"a": 1.0, "b": 1.0, "c": 1.0}
 
@@ -30,13 +36,22 @@ def test_whole_modules_and_ops_inside():
     whole = xplane.whole_modules(plane)
     assert [m.start for m in whole] == [2.0]   # the others are cut
     assert [e.name for e in xplane.ops_inside(plane, whole)] == ["y"]
+    # the whole modules that run an op of a name: y starts in the second
+    assert xplane.modules_running(plane, "^y$") == whole
+    assert xplane.modules_running(plane, "^nothing$") == []
+    assert xplane.modules_running(plane, "^x$") == [
+        m for m in whole
+        if xplane.matching(xplane.ops_inside(plane, [m]), "^x$")]
 
 
 def test_attribute_takes_the_shortest_enclosing_span():
-    spans = [("tick", 0.0, 10.0), ("step", 2.0, 4.0)]
-    assert reduce.attribute(3.0, spans) == "step"
-    assert reduce.attribute(5.0, spans) == "tick"
-    assert reduce.attribute(11.0, spans) == "host_no_span"
+    spans = [("tick", 0.0, 10.0), ("step", 2.0, 4.0), ("next", 10.0, 12.0)]
+    times = [0.5, 2.0, 3.0, 4.0, 4.5, 9.9, 10.0, 11.0, 12.5]
+    assert reduce.attribute_all(times, spans) == [
+        "tick", "step", "step", "step", "tick", "tick", "next", "next",
+        "host_no_span"]
+    assert reduce.attribute_all([], spans) == []
+    assert reduce.attribute_all([3.0], []) == ["host_no_span"]
 
 
 @pytest.mark.skipif(not os.path.isfile(FIXTURE), reason="no recorded trace")

@@ -1,5 +1,9 @@
 """Closed-loop serving traffic: ``clients`` callers, each sending its next
-``POST /v1/generate`` (SSE) the moment the last one finished.
+``POST /v1/generate`` (SSE) the moment the last one finished. The callers
+run in a process of their own (``harness/loadgen.py``, started and
+stopped by ``drive``), as a user's clients do: not on the server's
+interpreter lock. Their records come back over a pipe once the window
+and its tail are over.
 
 The traffic file gives the prompt and output length distributions,
 ``size_set`` and ``schedule_seed``: that many (prompt length, output
@@ -29,185 +33,17 @@ making at the end can be shared out.
 """
 
 import gc
-import http.client
-import json
-import math
-import threading
 import time
 
 import numpy as np
 
-from benchmark.harness import checks, tracing
-from benchmark.harness.stats import percentile
+from benchmark.harness import checks, loadgen, tracing
+from benchmark.harness.loadgen import Plan, Record, size_set  # noqa: F401
+from benchmark.harness.stats import median, percentile
 
 
 def toy(traffic):
     return dict(traffic, **traffic.get("toy", {}))
-
-
-def _quantile(spec, u):
-    lo, hi = spec["lo"], spec["hi"]
-    if spec["dist"] == "log_uniform":
-        return int(round(math.exp(
-            math.log(lo) + u * (math.log(hi) - math.log(lo)))))
-    if spec["dist"] == "uniform":
-        return int(round(lo + u * (hi - lo)))
-    raise ValueError("unknown length distribution %r" % spec["dist"])
-
-
-def size_set(traffic):
-    """The fixed set of (prompt length, output length) pairs: evenly
-    spaced quantiles of each distribution, paired by a shuffle that the
-    traffic file's ``schedule_seed`` fixes."""
-    n = traffic["size_set"]
-    us = [(i + 0.5) / n for i in range(n)]
-    prompts = [_quantile(traffic["prompt_len"], u) for u in us]
-    outputs = [_quantile(traffic["output_len"], u) for u in us]
-    order = np.random.default_rng(
-        int(traffic["schedule_seed"])).permutation(n)
-    return [(prompts[i], outputs[int(j)]) for i, j in enumerate(order)]
-
-
-class Plan(object):
-    """The seeded sequence of requests, handed out under a lock."""
-
-    def __init__(self, traffic, vocab, seed):
-        self.sizes = size_set(traffic)
-        self.vocab = vocab
-        self.seed = int(seed)
-        self.schedule = int(traffic["schedule_seed"])
-        self._lock = threading.Lock()
-        self._next = 0
-        self._orders = {}
-
-    def _order(self, cycle):
-        if cycle not in self._orders:
-            self._orders[cycle] = np.random.default_rng(
-                [self.schedule, 1, cycle]).permutation(len(self.sizes))
-        return self._orders[cycle]
-
-    def take(self):
-        with self._lock:
-            idx = self._next
-            self._next += 1
-            cycle, at = divmod(idx, len(self.sizes))
-            plen, olen = self.sizes[int(self._order(cycle)[at])]
-        ids = np.random.default_rng([self.seed, 2, idx]).integers(
-            0, self.vocab, plen)
-        return idx, [int(t) for t in ids], olen
-
-    def first_cut(self, client, shortest):
-        """Where a client's first request is cut: uniform in
-        [1, shortest], the shortest output of the mix."""
-        return int(np.random.default_rng(
-            [self.schedule, 3, client]).integers(1, shortest + 1))
-
-
-class Record(object):
-    __slots__ = ("idx", "client", "prompt", "want", "sent", "times",
-                 "tokens", "done", "status", "error", "ended")
-
-    def __init__(self, idx, client, prompt, want):
-        self.idx, self.client, self.prompt, self.want = (
-            idx, client, prompt, want)
-        self.sent, self.times, self.tokens = None, [], []
-        self.done, self.status, self.error, self.ended = (
-            None, None, None, None)
-
-    @property
-    def ok(self):
-        return (self.status == 200 and self.done is not None
-                and self.done.get("finish_reason") == "length"
-                and len(self.tokens) == self.want)
-
-
-class Clients(object):
-    def __init__(self, stack, plan, n):
-        self.stack, self.plan = stack, plan
-        self.records = []
-        self._lock = threading.Lock()
-        self._stop = threading.Event()
-        # set at a client's first token of its second request
-        self.ramped = [threading.Event() for _ in range(n)]
-        self.threads = [threading.Thread(target=self._client, args=(i,),
-                                         name="bench-client-%d" % i,
-                                         daemon=True) for i in range(n)]
-
-    def start(self):
-        for t in self.threads:
-            t.start()
-
-    def all_past(self, t):
-        """Whether every client's newest request was sent after ``t`` or
-        has a token that arrived after it."""
-        with self._lock:
-            newest = {r.client: r for r in self.records}
-        return len(newest) == len(self.threads) and all(
-            r.sent is not None and (r.sent > t
-                                    or (r.times and r.times[-1] > t))
-            for r in newest.values())
-
-    def stop(self):
-        self._stop.set()
-        for t in self.threads:
-            t.join(timeout=60)
-        return [t.name for t in self.threads if t.is_alive()]
-
-    def _client(self, i):
-        sent = 0
-        shortest = min(o for _p, o in self.plan.sizes)
-        while not self._stop.is_set():
-            idx, prompt, olen = self.plan.take()
-            if sent == 0:
-                olen = self.plan.first_cut(i, shortest)
-            rec = Record(idx, i, prompt, olen)
-            with self._lock:
-                self.records.append(rec)
-            sent += 1
-            # ended stays None if the window's end cut the request
-            self._send(rec, self.ramped[i] if sent == 2 else None)
-
-    def _send(self, rec, on_first_token):
-        body = json.dumps({"prompt_ids": rec.prompt,
-                           "max_new_tokens": rec.want}).encode()
-        conn = http.client.HTTPConnection(self.stack.host, self.stack.port,
-                                          timeout=600)
-        try:
-            rec.sent = time.perf_counter()
-            conn.request("POST", "/v1/generate", body=body,
-                         headers={"Content-Type": "application/json"})
-            resp = conn.getresponse()
-            rec.status = resp.status
-            if resp.status != 200:
-                rec.error = resp.read(300).decode("utf-8", "replace")
-                rec.ended = time.perf_counter()
-                return
-            for line in resp:
-                if self._stop.is_set():
-                    return
-                if not line.startswith(b"data: "):
-                    continue
-                now = time.perf_counter()
-                event = json.loads(line[6:])
-                if "token" in event:
-                    rec.times.append(now)
-                    rec.tokens.append(int(event["token"]))
-                    if on_first_token is not None:
-                        on_first_token.set()
-                elif event.get("done"):
-                    rec.done = event
-                    break
-            rec.ended = time.perf_counter()
-        except (OSError, http.client.HTTPException, ValueError) as e:
-            if not self._stop.is_set():
-                rec.error = repr(e)
-                rec.ended = time.perf_counter()
-        finally:
-            conn.close()
-
-
-# how long past the window's end the clients may run for their next token
-TAIL_S = 5.0
 
 
 def tpot_ms(rec):
@@ -269,52 +105,123 @@ def in_order(finished):
 
 
 def drive(ctx, stack, seed, seconds, trace_s=None):
-    """Ramp (set-up), then a window of ``seconds``.
+    """Ramp (set-up), then a window of ``seconds``. The clients run in a
+    child process (``loadgen.Child``): it is started here, killed here
+    whatever happens, and hands back its records once the window and the
+    tail are over. The window, the tracer, the counters and the spans
+    are this process's.
     -> what the window left: records, clocks, counters, spans."""
     from paddle_tpu.observability import trace as program_trace
 
     config, traffic = ctx.config, ctx.traffic
     t = time.perf_counter()
     plan = Plan(traffic, config["vocab_size"], seed)
-    clients = Clients(stack, plan, traffic["clients"])
-    clients.start()
-    for ev in clients.ramped:
-        if not ev.wait(timeout=300):
-            raise RuntimeError("a client was not ramped in 300 s")
-    time.sleep(traffic["settle_s"])
-    ramp_s = time.perf_counter() - t
+    load = loadgen.Child(traffic, config["vocab_size"], seed,
+                         stack.host, stack.port)
+    try:
+        ctx.note("loadgen", pid=load.pid, **load.clock_facts())
+        load.start()
+        time.sleep(traffic["settle_s"])
+        ramp_s = time.perf_counter() - t
 
-    before = ctx.counters()
-    tracer = tracing.MidWindow(ctx, trace_s or traffic.get("trace_s", 1.5))
-    t0 = ctx.open_window()
-    t1 = t0 + seconds
-    while True:
-        now = time.perf_counter()
-        if now >= t1:
-            break
-        tracer.poll(now - t0)
-        time.sleep(min(0.02, t1 - now))
-    tracer.finish()
-    after = ctx.counters()
-    # the window is over; wait for each stream's next token, which was
-    # in the making at t1 (tokens_in_window)
-    tail_end = t1 + TAIL_S
-    while time.perf_counter() < tail_end and not clients.all_past(t1):
-        time.sleep(0.02)
-    tail_s = time.perf_counter() - t1
-    stuck = clients.stop()
+        before = ctx.counters()
+        tracer = tracing.MidWindow(ctx,
+                                   trace_s or traffic.get("trace_s", 1.5))
+        t0 = ctx.open_window()
+        t1 = t0 + seconds
+        overslept = []  # this loop's own, in the server's process
+        while True:
+            now = time.perf_counter()
+            if now >= t1:
+                break
+            tracer.poll(now - t0)
+            load.check()
+            a, nap = time.perf_counter(), min(0.02, t1 - now)
+            time.sleep(nap)
+            overslept.append([a, time.perf_counter() - a - nap])
+        tracer.finish()
+        after = ctx.counters()
+        # the window is over; wait for each stream's next token, which
+        # was in the making at t1 (tokens_in_window)
+        load.end(t1)
+        tail_s = time.perf_counter() - t1
+        left = load.stop()
+        records = load.records(plan)
+    finally:
+        load.close()
     spans = [s for s in program_trace.get_spans()
              if s["end"] >= t0 and s["start"] <= t1]
-    records = list(clients.records)
     in_window = [r for r in records if r.ended is not None
                  and t0 <= r.ended <= t1]
     return {"records": records, "window": (t0, t1), "ramp_s": ramp_s,
             "tail_s": tail_s,
-            "tracer": tracer, "spans": spans, "stuck": stuck,
+            "tracer": tracer, "spans": spans, "stuck": left["stuck"],
+            "loadgen": dict(load.clock_facts(), loadgen_cpu_s=left["cpu_s"],
+                            loadgen_wall_s=left["wall_s"]),
+            "stalls": {"loadgen": left["stalls"], "window_loop": overslept},
             "counters": {k: after.get(k, 0) - before.get(k, 0)
                          for k in after},
             "finished": [r for r in in_window if r.ok],
             "failed": [r for r in in_window if not r.ok]}
+
+
+def pace(got):
+    """What pace the window ran at, for an earlier line of every run: a
+    run far from its siblings can be explained after the fact. Ticks and
+    prompt windows from the program's spans, the child's clocks, and how
+    long after the engine made a request's first token (``decode_request``'s
+    ``first_token``, the one stamp a token has on the server's side) the
+    client had it: the flush, the wire and the client's read, on one
+    clock; and the longest POST-to-first-token of the window with the
+    count of those over a second (a connect that the server's listen
+    backlog dropped is sent again after 1 s: ``loadgen.START_GAP_S``).
+    The done event names the request, ``gateway_request`` ties the
+    name to the trace the engine's record lies under. Whose stall it was,
+    where a run lost seconds: the longest time between two decode ticks
+    (the server), the longest time in which no client got a token, with
+    where in the window it began, and the longest oversleep of a thread of
+    the child (``loadgen.Stalls``) and of the window's own loop in the
+    server's process: all four alike, and the machine stood still."""
+    t0, t1 = got["window"]
+    spans = [s for s in got["spans"] if s["start"] >= t0 and s["end"] <= t1]
+    starts = sorted(s["start"] for s in spans if s["name"] == "decode_tick")
+    gaps = [1e3 * (b - a) for a, b in zip(starts, starts[1:])]
+    trace_of = {s["args"].get("request_id"): s["trace_id"] for s in spans
+                if s["name"] == "gateway_request" and s.get("trace_id")}
+    made = {s["trace_id"]: s["args"].get("first_token") for s in spans
+            if s["name"] == "decode_request"}
+    waits = []
+    for r in got["finished"]:
+        at = made.get(trace_of.get(r.done.get("request_id")))
+        if at is not None and r.times:
+            waits.append(1e3 * (r.times[0] - at))
+    ttfts = [1e3 * (r.times[0] - r.sent) for r in got["records"]
+             if r.times and t0 <= r.times[0] <= t1]
+    heard = [t0] + sorted(x for r in got["records"] for x in r.times
+                          if t0 <= x <= t1) + [t1]
+    silence, silent_from = max((b - a, a) for a, b in zip(heard, heard[1:]))
+    return dict(
+        got["loadgen"],
+        tick_period_ms_max=max(gaps, default=None),
+        silence_ms_max=1e3 * silence,
+        silence_at_s=silent_from - t0,
+        loadgen_stall_ms_max=loadgen.longest_stall(
+            got["stalls"]["loadgen"], t0, t1),
+        window_loop_stall_ms_max=loadgen.longest_stall(
+            got["stalls"]["window_loop"], t0, t1),
+        ttft_ms_max=max(ttfts, default=None),
+        ttft_over_1s=sum(x >= 1e3 for x in ttfts),
+        tick_period_ms_p50=median(gaps) if gaps else None,
+        ticks=sum(s["name"] == "engine_tick" and not s.get("instant")
+                  for s in spans),
+        decode_ticks=len(starts),
+        prefill_windows=sum(s["name"] == "decode_paged_window"
+                            for s in spans),
+        requests_finished=len(got["finished"]),
+        first_token_arrival_after_emit_ms_p90=(
+            percentile(waits, 90) if waits else None),
+        first_token_arrival_after_emit_ms_min=min(waits, default=None),
+        first_tokens_matched=len(waits))
 
 
 def run(ctx):
@@ -327,8 +234,14 @@ def run(ctx):
     times["weights_s"] = time.perf_counter() - t
     stack = family.build_serve(config, ctx.place, params, ctx.rehearse, times)
     del params
-    got = drive(ctx, stack, ctx.seed, ctx.seconds)
+    try:
+        got = drive(ctx, stack, ctx.seed, ctx.seconds)
+    except BaseException:
+        # no result: the stack's threads are not left to hold the exit up
+        stack.close()
+        raise
     ctx.note("setup", ramp_s=got["ramp_s"], **times)
+    ctx.note("pace", **pace(got))
 
     t0, t1 = got["window"]
     finished, failed = got["finished"], got["failed"]
@@ -395,6 +308,7 @@ def calibrate(ctx, seeds):
         samples[seed] = in_order(got["finished"])
         ctx.note("calibrate_window", seed=seed,
                  finished=len(got["finished"]), failed=len(got["failed"]))
+        ctx.note("pace", seed=seed, **pace(got))
         stack.wait_idle()
     stack.close()
     del stack
