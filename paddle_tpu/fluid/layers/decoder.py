@@ -49,24 +49,40 @@ def swiglu(gate, up, name=None):
 
 
 def moe_ffn(x, router_w, router_bias, w1, w3, w2, num_experts,
-            experts_per_token, expert_offset=0, scaling=1.0, name=None):
+            experts_per_token, expert_offset=0, scaling=1.0,
+            scoring="sigmoid", norm_topk=True, zero_experts=0, name=None):
     """The routed part of a sparse expert layer over the experts held
     (``w1``/``w3`` [E_held, H, I], ``w2`` [E_held, I, H], global numbers
-    from ``expert_offset``). -> (out like ``x``, counts int32 [E_held])."""
+    from ``expert_offset``). -> (out like ``x``, counts int32 [E_held]),
+    and with ``zero_experts`` identity experts after the ``num_experts``
+    (``router_w`` that much wider) a third: the assignments that went to
+    them, int32 [1]. ``scoring``, ``norm_topk``: the op's."""
     helper = LayerHelper("moe_ffn", name=name)
     out = helper.create_variable_for_type_inference(dtype=x.dtype)
     counts = helper.create_variable_for_type_inference(dtype="int32")
+    outputs = {"Out": [out], "Counts": [counts]}
+    attrs = {"num_experts": int(num_experts),
+             "experts_per_token": int(experts_per_token),
+             "expert_offset": int(expert_offset),
+             "scaling": float(scaling)}
+    # an attribute at its default is left out: a program that asks for
+    # none of them is, op for op and byte for byte, what it was
+    for key, value, default in (("scoring", str(scoring), "sigmoid"),
+                                ("norm_topk", bool(norm_topk), True),
+                                ("zero_experts", int(zero_experts), 0)):
+        if value != default:
+            attrs[key] = value
+    if zero_experts:
+        outputs["ZeroCount"] = [
+            helper.create_variable_for_type_inference(dtype="int32")]
     helper.append_op(
         type="moe_ffn",
         inputs={"X": [x], "RouterW": [router_w],
                 "RouterBias": [router_bias], "W1": [w1], "W3": [w3],
                 "W2": [w2]},
-        outputs={"Out": [out], "Counts": [counts]},
-        attrs={"num_experts": int(num_experts),
-               "experts_per_token": int(experts_per_token),
-               "expert_offset": int(expert_offset),
-               "scaling": float(scaling)},
-    )
+        outputs=outputs, attrs=attrs)
+    if zero_experts:
+        return out, counts, outputs["ZeroCount"][0]
     return out, counts
 
 

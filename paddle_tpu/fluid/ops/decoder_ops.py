@@ -89,31 +89,41 @@ def _swiglu(ctx, op_):
                          * u.astype(jnp.float32)).astype(g.dtype))
 
 
-def route(x, wg, bias, k, scaling):
+def route(x, wg, bias, k, scaling, scoring="sigmoid", norm_topk=True):
     """-> (experts [T, k] int32, gates [T, k] float32). Scores are
-    sigmoid(x Wg) in float32 from the float32-cast input; the k experts
-    are the top k of score + bias; gates are the chosen SCORES (the bias
-    chooses and does not weigh), renormalised to sum 1 and scaled."""
+    sigmoid(x Wg), or with ``scoring="softmax"`` softmax(x Wg) over all
+    the router's outputs, in float32 from the float32-cast input; the k
+    experts are the top k of score + bias; gates are the chosen SCORES
+    (the bias chooses and does not weigh), renormalised to sum 1 unless
+    ``norm_topk`` is false, and scaled."""
     import jax
     import jax.numpy as jnp
 
-    s = jax.nn.sigmoid(jnp.dot(
+    score = {"sigmoid": jax.nn.sigmoid, "softmax": jax.nn.softmax}[scoring]
+    s = score(jnp.dot(
         x.astype(jnp.float32), wg.astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST))
     _, experts = jax.lax.top_k(s + bias.astype(jnp.float32)[None, :], k)
     chosen = jnp.take_along_axis(s, experts, axis=1)
-    gates = scaling * chosen / (chosen.sum(-1, keepdims=True) + 1e-20)
+    if norm_topk:
+        gates = scaling * chosen / (chosen.sum(-1, keepdims=True) + 1e-20)
+    else:
+        gates = scaling * chosen
     return experts.astype(jnp.int32), gates
 
 
-def grouped_experts(x, experts, gates, w1, w3, w2, expert_offset):
+def grouped_experts(x, experts, gates, w1, w3, w2, expert_offset,
+                    out_dtype=None):
     """The part of sum_e gate_e * E_e(x) that the HELD experts give
     (global numbers ``expert_offset`` .. ``expert_offset + E_held - 1``),
     and the assignments each held expert received. ``x`` [T, H],
     ``experts``/``gates`` [T, k], ``w1``/``w3`` [E_held, H, I], ``w2``
     [E_held, I, H]. Assignments are sorted by expert and each group runs
     as one product (``jax.lax.ragged_dot``): work follows the assignments,
-    nothing is dropped, and nothing of shape [T, E, I] exists."""
+    nothing is dropped, and nothing of shape [T, E, I] exists. An
+    assignment to any other number (an expert held elsewhere, an identity
+    expert) takes no row of a group. The sum comes in ``out_dtype``
+    (``x``'s unless told)."""
     import jax
     import jax.numpy as jnp
 
@@ -135,7 +145,18 @@ def grouped_experts(x, experts, gates, w1, w3, w2, expert_offset):
     y = jnp.where(weight[:, None] != 0.0, y * weight[:, None], 0.0)
     # back to assignment order, then the k parts of a token add up
     y = y[jnp.argsort(order)].reshape(t, k, -1).sum(1)
-    return y.astype(x.dtype), sizes
+    return y.astype(out_dtype or x.dtype), sizes
+
+
+def identity_experts(x, experts, gates, num_experts):
+    """What the IDENTITY experts give (router outputs ``num_experts`` and
+    above: ``E(x) = x``, no weights): ``(sum of their gates) * x`` a token,
+    float32, and how many assignments went to them."""
+    import jax.numpy as jnp
+
+    zero = experts >= num_experts
+    gate = jnp.where(zero, gates, 0.0).sum(-1, keepdims=True)
+    return gate * x.astype(jnp.float32), zero.sum().astype(jnp.int32)
 
 
 def _moe_ffn_infer(op_, block):
@@ -143,6 +164,8 @@ def _moe_ffn_infer(op_, block):
     w1 = in_var(op_, block, "W1")
     set_out(op_, block, "Out", list(x.shape), x.dtype)
     set_out(op_, block, "Counts", [int(w1.shape[0])], "int32")
+    if op_.outputs.get("ZeroCount"):
+        set_out(op_, block, "ZeroCount", [1], "int32")
 
 
 @op("moe_ffn", infer_shape=_moe_ffn_infer)
@@ -158,20 +181,39 @@ def _moe_ffn(ctx, op_):
     ``expert_offset + E_held - 1`` — which is what expert parallelism
     asks of one shard; shards' results add up to the whole layer. No
     capacity, no token dropped. ``Counts`` int32 [E_held]: assignments
-    each held expert received. Inference only: no grad op is registered
-    (training needs one for the grouped product and the router)."""
+    each held expert received. Attributes whose defaults are the above:
+    ``scoring`` (``sigmoid`` | ``softmax`` over all the router's outputs),
+    ``norm_topk`` (false: the chosen scores weigh as they are) and
+    ``zero_experts``: ``RouterW``/``RouterBias`` are then ``num_experts +
+    zero_experts`` wide and the last ``zero_experts`` outputs are IDENTITY
+    experts, which add ``gate * x``, hold no weights and take no row of a
+    grouped product; their part is computed for EVERY token given, as a
+    shared expert's would be (under expert parallelism a token's home
+    shard adds it, no exchange), and ``ZeroCount`` int32 [1] gives the
+    assignments that went to them. Inference only: no grad op is
+    registered (training needs one for the grouped product and the
+    router)."""
     import jax
+    import jax.numpy as jnp
 
     x = ctx.in1(op_, "X")
+    n, zeros = int(op_.attr("num_experts")), int(op_.attr("zero_experts", 0))
     with jax.named_scope("moe_ffn"):
         x2 = x.reshape(-1, x.shape[-1])
         experts, gates = route(
             x2, ctx.in1(op_, "RouterW"), ctx.in1(op_, "RouterBias"),
             int(op_.attr("experts_per_token")),
-            float(op_.attr("scaling", 1.0)))
+            float(op_.attr("scaling", 1.0)),
+            scoring=op_.attr("scoring", "sigmoid"),
+            norm_topk=bool(op_.attr("norm_topk", True)))
         y, counts = grouped_experts(
             x2, experts, gates, ctx.in1(op_, "W1"), ctx.in1(op_, "W3"),
-            ctx.in1(op_, "W2"), int(op_.attr("expert_offset", 0)))
+            ctx.in1(op_, "W2"), int(op_.attr("expert_offset", 0)),
+            out_dtype=jnp.float32 if zeros else None)
+        if zeros:
+            same, zero_count = identity_experts(x2, experts, gates, n)
+            y = (y + same).astype(x.dtype)
+            ctx.out(op_, "ZeroCount", zero_count.reshape(1))
     ctx.out(op_, "Out", y.reshape(x.shape))
     ctx.out(op_, "Counts", counts)
 

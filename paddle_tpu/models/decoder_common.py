@@ -8,10 +8,13 @@ names are each model's own.
 A config gives ``dtype``, ``rms_norm_eps``, ``hidden_size``,
 ``vocab_size``, ``moe_intermediate_size``, ``n_shared_experts``,
 ``num_experts_per_tok``, ``routed_scaling_factor`` and, for the expert
-layer, ``n_routed_experts`` (the router's width: every expert of the
-model), ``experts_held`` and ``expert_offset`` (the experts whose weights
-lie here: global numbers ``expert_offset .. expert_offset + experts_held
-- 1``).
+layer, ``n_routed_experts`` (every expert of the model that has
+weights), ``experts_held`` and ``expert_offset`` (the experts whose
+weights lie here: global numbers ``expert_offset .. expert_offset +
+experts_held - 1``). A config may also give ``router_scoring``
+(``softmax`` for ``sigmoid``), ``norm_topk_prob`` (false: gates not
+renormalised) and ``zero_experts`` (identity experts, router outputs
+after the ``n_routed_experts``).
 """
 
 import paddle_tpu.fluid as fluid
@@ -43,21 +46,36 @@ def gated_mlp(x, width, hidden, name):
     return linear(h, hidden, name + "_w2")
 
 
-def expert_layer(x, cfg, name):
-    """Routed experts (the router over all ``n_routed_experts``, the
-    grouped products over the ``experts_held`` here) + the shared experts
-    as one MLP. -> (y, counts int32 [experts_held])."""
+def routed_experts(x, cfg, name):
+    """The routed part alone: the router over all ``n_routed_experts``
+    (and the config's identity experts after them), the grouped products
+    over the ``experts_held`` here. -> (routed, counts int32
+    [experts_held], identity assignments int32 [1] or None). A caller may
+    add the result where it was computed (``expert_layer``) or carry it
+    past other layers as a shortcut (``models/longcat_flash.py``)."""
     e, held, h, i = (cfg.n_routed_experts, cfg.experts_held,
                      cfg.hidden_size, cfg.moe_intermediate_size)
-    routed, counts = fluid.layers.moe_ffn(
-        x, param(name + "_router.w_0", [h, e], cfg),
-        param(name + "_router_bias", [e], cfg, dtype="float32", value=0.0),
+    zeros = getattr(cfg, "zero_experts", 0)
+    out = fluid.layers.moe_ffn(
+        x, param(name + "_router.w_0", [h, e + zeros], cfg),
+        param(name + "_router_bias", [e + zeros], cfg, dtype="float32",
+              value=0.0),
         param(name + "_experts_w1", [held, h, i], cfg),
         param(name + "_experts_w3", [held, h, i], cfg),
         param(name + "_experts_w2", [held, i, h], cfg),
         num_experts=e, experts_per_token=cfg.num_experts_per_tok,
-        expert_offset=cfg.expert_offset, scaling=cfg.routed_scaling_factor)
-    shared = gated_mlp(x, cfg.n_shared_experts * i, h, name + "_shared")
+        expert_offset=cfg.expert_offset, scaling=cfg.routed_scaling_factor,
+        scoring=getattr(cfg, "router_scoring", "sigmoid"),
+        norm_topk=getattr(cfg, "norm_topk_prob", True), zero_experts=zeros)
+    return out if zeros else out + (None,)
+
+
+def expert_layer(x, cfg, name):
+    """Routed experts + the shared experts as one MLP. -> (y, counts
+    int32 [experts_held])."""
+    routed, counts, _zero = routed_experts(x, cfg, name)
+    shared = gated_mlp(x, cfg.n_shared_experts * cfg.moe_intermediate_size,
+                       cfg.hidden_size, name + "_shared")
     return fluid.layers.elementwise_add(routed, shared), counts
 
 
@@ -92,10 +110,12 @@ def last_row_logits(h, last_onehot, cfg, prefix):
         shape=[-1, cfg.vocab_size])
 
 
-def expert_step_stats(counts):
+def expert_step_stats(counts, zero_counts=None):
     """What one T = 1 step's expert counts (``[expert layers, experts
     held]`` int32, as fetched) say, for the ``decode_paged_step`` span
-    and ``/metrics``."""
+    and ``/metrics``; ``zero_counts`` ([expert layers, 1]): the
+    assignments that went to identity experts, which are in no expert's
+    count."""
     from paddle_tpu.fluid import profiler
 
     out = dict(assignments=int(counts.sum()),
@@ -104,4 +124,7 @@ def expert_step_stats(counts):
     profiler.bump_counter("moe_assignments", out["assignments"])
     profiler.bump_counter("moe_experts_hit", out["experts_hit"])
     profiler.bump_histogram("moe_expert_load_max", out["expert_load_max"])
+    if zero_counts is not None:
+        out["zero_assignments"] = int(zero_counts.sum())
+        profiler.bump_counter("moe_zero_assignments", out["zero_assignments"])
     return out

@@ -38,10 +38,10 @@ from .decoder_common import (gated_mlp as _gated_mlp, linear as _linear,
 CONFIG_KEYS = (
     "vocab_size", "hidden_size", "num_hidden_layers", "num_attention_heads",
     "qk_nope_head_dim", "qk_rope_head_dim", "v_head_dim", "kv_lora_rank",
-    "intermediate_size", "moe_intermediate_size", "n_routed_experts",
-    "num_experts_per_tok", "n_shared_experts", "first_k_dense_replace",
-    "routed_scaling_factor", "rope_theta", "rope_interleave",
-    "rms_norm_eps", "max_position_embeddings",
+    "q_lora_rank", "intermediate_size", "moe_intermediate_size",
+    "n_routed_experts", "num_experts_per_tok", "n_shared_experts",
+    "first_k_dense_replace", "routed_scaling_factor", "rope_theta",
+    "rope_interleave", "rms_norm_eps", "max_position_embeddings",
 )
 
 
@@ -54,7 +54,7 @@ class DeepseekConfig(object):
     def __init__(self, vocab_size=128256, hidden_size=2048,
                  num_hidden_layers=48, num_attention_heads=32,
                  qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
-                 kv_lora_rank=512, intermediate_size=6144,
+                 kv_lora_rank=512, q_lora_rank=None, intermediate_size=6144,
                  moe_intermediate_size=768, n_routed_experts=128,
                  num_experts_per_tok=6, n_shared_experts=2,
                  first_k_dense_replace=1, routed_scaling_factor=2.448,
@@ -68,6 +68,8 @@ class DeepseekConfig(object):
         self.is_test = True
         # every routed expert lies here (``decoder_common.expert_layer``)
         self.experts_held, self.expert_offset = n_routed_experts, 0
+        # no scale on the query or on the normed latent (``mla_attention``)
+        self.q_lora_scale = self.kv_lora_scale = 1.0
 
     @classmethod
     def from_config(cls, config, **kw):
@@ -92,10 +94,14 @@ class DeepseekConfig(object):
 
     @property
     def latent_row(self):
-        """Lanes of a token's pool row: latent, rope key, zeros up to a
-        multiple of 128 (a narrower row would be copied whole into a
-        padded layout before every kernel call)."""
-        return -(-(self.kv_lora_rank + self.qk_rope_head_dim) // 128) * 128
+        return latent_row(self)
+
+
+def latent_row(cfg):
+    """Lanes of a token's pool row: latent, rope key, zeros up to a
+    multiple of 128 (a narrower row would be copied whole into a padded
+    layout before every kernel call)."""
+    return -(-(cfg.kv_lora_rank + cfg.qk_rope_head_dim) // 128) * 128
 
 
 def cache_kinds(cfg):
@@ -115,27 +121,47 @@ UNSUPPORTED = {
 }
 
 
+def _times(x, factor, cfg):
+    """``factor * x`` with the product taken in float32 (in bfloat16 the
+    factor itself would be rounded: 3.464 to 3.469); no op for 1."""
+    if factor == 1.0:
+        return x
+    return fluid.layers.cast(fluid.layers.scale(
+        fluid.layers.cast(x, "float32"), scale=factor), cfg.dtype)
+
+
 def mla_attention(x, pos, cfg, name, cache=None):
     """Latent attention on ``x`` [N, T, hidden] at the fed positions
     ``pos`` [N, T, 1]. Without a cache, and for a prefill window
     (``cache["mode"] == "paged_window"``), the UP-PROJECTED form over the
     latent rows (the window's own, or the slot's whole row gathered
     through its table after the window's rows were written); for the
-    T = 1 step (``"paged_step"``) the ABSORBED form against the pool."""
+    T = 1 step (``"paged_step"``) the ABSORBED form against the pool.
+
+    ``cfg.q_lora_rank``: None, ``q = x Wq``; a rank, the query LoRA ``q =
+    RMSNorm(x Wqa) Wqb``. ``cfg.q_lora_scale`` multiplies the query,
+    ``cfg.kv_lora_scale`` the normed latent (1: no op); the pool keeps the
+    SCALED latent, so both forms read one row."""
     heads = cfg.num_attention_heads
     nope, rope, vdim = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
                         cfg.v_head_dim)
     latent = cfg.kv_lora_rank
     rot = dict(rope_dim=rope, theta=cfg.rope_theta,
                interleaved=cfg.rope_interleave)
+    if cfg.q_lora_rank is None:
+        q = _linear(x, heads * (nope + rope), name + "_q")
+    else:
+        q = _linear(_norm(_linear(x, cfg.q_lora_rank, name + "_qa"), cfg,
+                          name + "_q_norm"),
+                    heads * (nope + rope), name + "_qb")
     q = fluid.layers.rotary_embedding(
-        _linear(x, heads * (nope + rope), name + "_q"), pos,
-        head_dim=nope + rope, **rot)
+        _times(q, cfg.q_lora_scale, cfg), pos, head_dim=nope + rope, **rot)
     kva = fluid.layers.rotary_embedding(
         _linear(x, latent + rope, name + "_kva"), pos,
         head_dim=latent + rope, **rot)
     c = _norm(fluid.layers.slice(kva, axes=[2], starts=[0], ends=[latent]),
               cfg, name + "_kv_norm")
+    c = _times(c, cfg.kv_lora_scale, cfg)
     k_rope = fluid.layers.slice(kva, axes=[2], starts=[latent],
                                 ends=[latent + rope])
     rows = fluid.layers.pad(

@@ -155,6 +155,10 @@ CASES = {
     "latent_block128_bf16": lambda: _latent(128, BF16),
     "latent_block128_f32": lambda: _latent(128, F32),
     "latent_block16_bf16": lambda: _latent(16, BF16),
+    # the cell ``longcat-serve-reason4k``: 64 heads (the query block is
+    # [64, 640]) over 64 slots of 5632 positions
+    "latent_64heads_block128_bf16": lambda: _latent(
+        128, BF16, max_len=5632, heads=64),
     "grouped_64q_8kv_block128_bf16": _grouped,
     "kda_decode_64slots_64heads": _kda_decode,
 }
@@ -497,3 +501,89 @@ def test_hybrid_window_program_scans_chunks_and_loops_over_keys(
             "state_row": (1, 1), "window_len": (1, 1)})
     _hybrid_cache_checks(cfg, blocks, block, built)
     assert built.memory_analysis().temp_size_in_bytes < 2.5e9
+
+
+# -- the cell ``longcat-serve-reason4k`` (models/longcat_flash.py) ------------
+
+def _compile_shortcut_program(chip, monkeypatch, which, feed_shapes):
+    """A program of the cell ``longcat-serve-reason4k`` (64 slots of 5632
+    positions, blocks of 128, eight latent pools), built by
+    ``models/longcat_flash.py``."""
+    from paddle_tpu.models import longcat_flash
+
+    return _compile_paged_program(
+        chip, monkeypatch, "longcat-flash-omni.json",
+        lambda config: longcat_flash.LongcatFlashConfig.from_config(
+            config, dtype="bfloat16"),
+        which, feed_shapes)
+
+
+def _shortcut_pool_checks(cfg, blocks, block, built):
+    """Eight pools updated in place, none copied whole, and weights,
+    pools and temporaries together inside the chip's 16.9 GB.
+    -> (text, memory)."""
+    import re
+
+    memory = built.memory_analysis()
+    pool_bytes = 2 * cfg.num_layers * blocks * block * cfg.latent_row * 2
+    assert memory.alias_size_in_bytes >= pool_bytes
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            < 16.5e9)
+    text = built.as_text()
+    pool = r"bf16\[%d,1,%d,%d\]" % (blocks, block, cfg.latent_row)
+    assert not re.findall(r"%%copy[.\d]* = %s" % pool, text)
+    return text, memory
+
+
+@pytest.mark.slow  # ~40 s beside 14 GB of described arguments: it tips tier-1's timing probes; fast equivalents: the case latent_64heads_block128_bf16 above (the kernel at these heads) + tests/test_longcat_flash.py (the step at toy widths). Run before a chip call: -k shortcut
+def test_shortcut_step_program_runs_the_latent_kernel_twice_a_layer(
+        chip, monkeypatch):
+    """The whole T = 1 step at the published widths: two latent kernel
+    calls and three grouped products a double layer, the router 768
+    wide, the pools taken as they lie."""
+    import re
+
+    from paddle_tpu.models import longcat_flash
+
+    cfg, blocks, block, built = _compile_shortcut_program(
+        chip, monkeypatch, _picked_step(longcat_flash),
+        lambda slots, max_blocks, block: {
+            "step_ids": (slots, 1, 1), "step_pos": (slots, 1, 1),
+            "tables": (slots, max_blocks)})
+    text, memory = _shortcut_pool_checks(cfg, blocks, block, built)
+    _pick_checks(built, text, cfg.vocab_size)
+    layers = cfg.num_layers
+    assert len(re.findall(r"%mla_decode_paged[.\d]* = ", text)) == 2 * layers
+    assert len(re.findall(r"%ragged-dot-none[.\d]* = ", text)) == 3 * layers
+    assert memory.temp_size_in_bytes < 0.5e9
+
+
+@pytest.mark.slow  # ~40 s, as the step above; run before a chip call: -k shortcut
+def test_shortcut_window_program_fits_beside_weights_and_pools(
+        chip, monkeypatch):
+    """The largest prefill window (``serve.prefill_chunk`` tokens): its
+    expert branch sorts 12 assignments a token of 6144-wide rows; the
+    program's arguments (10.35 GB of weights, 3.7 GB of pools) and its
+    temporaries fit the chip."""
+    import json
+    import os
+
+    from paddle_tpu.models import longcat_flash
+
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark", "configs",
+        "longcat-flash-omni.json")
+    with open(path) as f:
+        t = json.load(f)["serve"]["prefill_chunk"]
+
+    def window(cfg, blocks, block, max_blocks, slots):
+        main, _s, feeds, logits = longcat_flash.build_paged_window(
+            cfg, blocks, block, max_blocks, t)
+        return main, feeds, [logits.name]
+
+    cfg, blocks, block, built = _compile_shortcut_program(
+        chip, monkeypatch, window, lambda slots, max_blocks, block: {
+            "ids": (1, t, 1), "pos_ids": (1, t, 1), "table": (1, max_blocks),
+            "window_pos": (1, 1), "last_onehot": (1, t, 1)})
+    _text, memory = _shortcut_pool_checks(cfg, blocks, block, built)
+    print("window", t, memory)
