@@ -27,6 +27,35 @@ standard FlashAttention backward):
 Layout [B, N, S, D] (batch, heads, seq, head_dim); fp32 accumulation
 regardless of input dtype (MXU ``preferred_element_type``).
 
+Which (q-block ib, kv-block kb) pairs a kernel visits is decided when it
+is traced, from ``causal``, the padded lengths and the block sizes. Under
+a causal mask a pair is one of three classes (``_block_class``):
+- dead: the block's first column lies past its last row
+  (``kb·BK > ib·BQ + BQ − 1``). Not computed: it would add exact zeros
+  (p = e^(−1e30 − m) = 0), so leaving it out changes no bit of an output.
+- interior: the block's last column is at or before its first row
+  (``kb·BK + BK − 1 <= ib·BQ``). Computed with no causal mask at all.
+- diagonal: the rest (8 of the 36 live pairs at seq 1024 with
+  128-blocks). Computed with the mask.
+Without ``causal`` every pair is interior. Every sweep is unrolled with a
+Python-int trip count and holds no branch: a dynamic-trip loop compiles
+far worse here (PERF_HISTORY.md "Negative result"), and so does a block
+body under a predicate on a program id — the scheduler overlaps one
+block's matmul latency with its neighbours' softmax only inside
+straight-line code (PERF.md §6, PR 43). So a pair's class has to be known
+at trace time, which it is where BOTH its indices are Python ints: a
+causal call whose head has at most ``WHOLE_HEAD_BLOCKS`` blocks a side
+(and no general bias) runs one program a head over all the head's blocks
+(``_whole_head``) and unrolls the live pairs alone, 36 of 64 at seq 1024.
+Where one index is a program id (longer sequences, a general bias, no
+``causal``) a program holds one block as before and a causal pair is
+computed with the mask, whatever its class.
+One edge: a query row whose every visible key carries a key bias of
+−1e30 (a wholly padded row) has no defined attention. It spreads its
+weight over the keys of the blocks it visits, which with dead blocks
+skipped are fewer than all ``Sk``; garbage either way, which the caller
+slices or masks away.
+
 Bias comes in two flavors, usable together:
 - ``key_bias`` [B*N, Sk]: additive per KEY (BERT padding masks) —
   broadcast over query rows inside the kernel; gradient accumulated to
@@ -121,8 +150,9 @@ def _scores(q, kblk, scale, key_bias_row, bias_blk, row_off, col_off,
     of it, so the dot takes the raw operands and only the accumulator is
     fp32 (``preferred_element_type``); the softmax scale lands on the
     fp32 scores. ``key_bias_row`` is a [1, BK] row that broadcasts over
-    query rows. Shared by all three kernels so forward and backward can
-    never disagree on masking."""
+    query rows. ``causal`` says whether THIS block takes the causal mask
+    (``_block_class``: an interior block is told "no mask"). Shared by all
+    three kernels so forward and backward can never disagree on masking."""
     s = jax.lax.dot_general(
         q, kblk, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
@@ -159,125 +189,219 @@ def _hash_head(h, head_swap):
     return (h % B0) * N0 + h // B0
 
 
+DEAD, INTERIOR, DIAGONAL = "dead", "interior", "diagonal"
+
+# q blocks (kv blocks in the dkv kernel) one program may hold: with every
+# block of a head in one program both indices of a block pair are Python
+# ints, and the kernel unrolls the live pairs alone: 36 bodies at 8 x 8
+# blocks, about what the full 32-block sweep of a seq-4096 program unrolls
+WHOLE_HEAD_BLOCKS = 8
+# and the bytes of operands it may hold, half of what a v5e program may
+# claim by default (the pipeline holds every operand twice)
+WHOLE_HEAD_BYTES = 8 << 20
+
+
+def _block_class(causal, ib, kb, block_q, block_k):
+    """Class of the (q-block ``ib``, kv-block ``kb``) pair under a causal
+    mask (module docstring). An index that is not a Python int (a program
+    id) leaves the class open at trace time: the block is computed with
+    the mask, which is right for all three."""
+    if not causal:
+        return INTERIOR
+    if not (isinstance(ib, int) and isinstance(kb, int)):
+        return DIAGONAL
+    if kb * block_k > ib * block_q + block_q - 1:
+        return DEAD         # first column past the last row
+    if kb * block_k + block_k - 1 <= ib * block_q:
+        return INTERIOR     # last column at or before the first row
+    return DIAGONAL
+
+
+def block_classes(causal, q_len, kv_len, block_q, block_k):
+    """{class: number of (q-block, kv-block) pairs of one head in it}."""
+    counts = {DEAD: 0, INTERIOR: 0, DIAGONAL: 0}
+    for ib in range(q_len // block_q):
+        for kb in range(kv_len // block_k):
+            counts[_block_class(causal, ib, kb, block_q, block_k)] += 1
+    return counts
+
+
+def _whole_head(causal, bias, geom, d, itemsize):
+    """Whether a program holds every block of its head, so that the
+    kernels see each pair's class at trace time and visit the live pairs
+    alone. Decided from what the trace can see: only a causal mask leaves
+    dead blocks; the head's triangle has to be small enough to unroll; a
+    general bias would ride in as the head's whole [Sq, Sk] table; and
+    the head's rows have to fit the program's VMEM (the dkv program holds
+    the most: q, dO, k, v, dk, dv and the lse / delta columns, an
+    [S, 1] fp32 column padding to 512 B a row)."""
+    _, _, _, _, q_len, kv_len, block_q, block_k = geom
+    held = (2 * q_len + 4 * kv_len) * d * itemsize + 2 * q_len * 512
+    return (causal and bias is None
+            and 1 < q_len // block_q <= WHOLE_HEAD_BLOCKS
+            and 1 < kv_len // block_k <= WHOLE_HEAD_BLOCKS
+            and held <= WHOLE_HEAD_BYTES)
+
+
+def _count_blocks(causal, bias, q, k, kernels):
+    """Bookkeeping of one call of ``kernels`` kernels, made where the call
+    is traced: block pairs a head they visit / would visit with nothing
+    skipped. (A program a block visits every pair, whatever the mask.)"""
+    from ..observability import registry
+
+    geom = _geometry(q, k)
+    whole = _whole_head(causal, bias, geom, q.shape[-1], q.dtype.itemsize)
+    classes = block_classes(whole, *geom[4:])
+    total = sum(classes.values())
+    registry.counter("flash_blocks_visited").inc(
+        kernels * (total - classes[DEAD]))
+    registry.counter("flash_blocks_total").inc(kernels * total)
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, key_bias_ref, bias_ref, seed_ref,
                 o_ref, lse_ref, *, scale, causal, kv_len, block_q, block_k,
-                dropout_rate, head_swap=None):
-    """One (head, q-block) program: online softmax over kv blocks; also
-    writes the per-row logsumexp residual for the backward. Dropout masks
-    the accumulated probabilities only — ``l``/``lse`` stay unmasked, so
-    out = (1/keep)·Σ_j mask_ij·P_ij·V_j (standard non-renormalizing
-    dropout) and the backward's rowsum(dO∘O) trick still yields delta."""
+                dropout_rate, head_swap=None, q_blocks=1):
+    """One (head, q-block) program, or with ``q_blocks`` > 1 one program a
+    head over all its q blocks: online softmax over the kv blocks the
+    causal mask leaves a q block; also writes the per-row logsumexp
+    residual for the backward. Dropout masks the accumulated probabilities
+    only — ``l``/``lse`` stay unmasked, so out = (1/keep)·Σ_j
+    mask_ij·P_ij·V_j (standard non-renormalizing dropout) and the
+    backward's rowsum(dO∘O) trick still yields delta."""
     from jax.experimental import pallas as pl
 
-    q = q_ref[0]                              # [BQ, D], input dtype
-    h = pl.program_id(0)
-    qi = pl.program_id(1)
     n_kb = kv_len // block_k
-    # read the SMEM seed only when dropout is live: the rate-0 kernel
-    # traces to exactly the pre-dropout op stream (the operand is
-    # still bound, just never loaded)
-    seed_u = (seed_ref[0, 0].astype(jnp.int32).astype(jnp.uint32)
-              if dropout_rate > 0.0 else None)
+    # (statements keep the order they had before a program could hold more
+    # than one block, so a one-block program traces to the same op stream)
+    for a in range(q_blocks):
+        qs = slice(a * block_q, (a + 1) * block_q)
+        q = q_ref[0, qs, :]                       # [BQ, D], input dtype
+        h = pl.program_id(0)
+        # the q block's index: a Python int where the program holds them all
+        ib = a if q_blocks > 1 else pl.program_id(1)
+        # read the SMEM seed only when dropout is live: the rate-0 kernel
+        # traces to exactly the pre-dropout op stream (the operand is
+        # still bound, just never loaded)
+        seed_u = (seed_ref[0, 0].astype(jnp.int32).astype(jnp.uint32)
+                  if dropout_rate > 0.0 else None)
 
-    m = jnp.full((block_q, 1), _NEG, jnp.float32)
-    l = jnp.zeros((block_q, 1), jnp.float32)
-    acc = jnp.zeros((block_q, q.shape[-1]), jnp.float32)
+        m = jnp.full((block_q, 1), _NEG, jnp.float32)
+        l = jnp.zeros((block_q, 1), jnp.float32)
+        acc = jnp.zeros((block_q, q.shape[-1]), jnp.float32)
 
-    for kb in range(n_kb):
-        ks = slice(kb * block_k, (kb + 1) * block_k)
-        s = _scores(
-            q, k_ref[0, ks, :], scale, key_bias_ref[0, :, ks],
-            None if bias_ref is None else bias_ref[0, :, ks],
-            qi * block_q, kb * block_k, causal, block_q, block_k,
-        )
-        m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
-        alpha = jnp.exp(m - m_new)
-        p = jnp.exp(s - m_new)
-        l = l * alpha + p.sum(axis=-1, keepdims=True)
+        for kb in range(n_kb):
+            cls = _block_class(causal, ib, kb, block_q, block_k)
+            if cls == DEAD:
+                continue
+            ks = slice(kb * block_k, (kb + 1) * block_k)
+            s = _scores(
+                q, k_ref[0, ks, :], scale, key_bias_ref[0, :, ks],
+                None if bias_ref is None else bias_ref[0, :, ks],
+                ib * block_q, kb * block_k, cls == DIAGONAL,
+                block_q, block_k,
+            )
+            m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
+            alpha = jnp.exp(m - m_new)
+            p = jnp.exp(s - m_new)
+            l = l * alpha + p.sum(axis=-1, keepdims=True)
+            if dropout_rate > 0.0:
+                rows, cols = _block_coords(
+                    ib * block_q, kb * block_k, block_q, block_k
+                )
+                p = jnp.where(
+                    _hash_keep(rows, cols, _hash_head(h, head_swap), seed_u,
+                               dropout_rate),
+                    p, 0.0,
+                )
+            # p rounds to the value dtype for the MXU (as the dense
+            # reference does with p.astype(q.dtype) @ v); accumulation
+            # stays fp32
+            acc = acc * alpha + jax.lax.dot_general(
+                p.astype(v_ref.dtype), v_ref[0, ks, :],
+                (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            m = m_new
+        l_safe = jnp.maximum(l, 1e-30)
         if dropout_rate > 0.0:
-            rows, cols = _block_coords(
-                qi * block_q, kb * block_k, block_q, block_k
-            )
-            p = jnp.where(
-                _hash_keep(rows, cols, _hash_head(h, head_swap), seed_u,
-                           dropout_rate),
-                p, 0.0,
-            )
-        # p rounds to the value dtype for the MXU (as the dense reference
-        # does with p.astype(q.dtype) @ v); accumulation stays fp32
-        acc = acc * alpha + jax.lax.dot_general(
-            p.astype(v_ref.dtype), v_ref[0, ks, :], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        m = m_new
-    l_safe = jnp.maximum(l, 1e-30)
-    if dropout_rate > 0.0:
-        l_safe = l_safe * (1.0 - dropout_rate)
-    o_ref[0] = (acc / l_safe).astype(o_ref.dtype)
-    lse_ref[0] = m + jnp.log(jnp.maximum(l, 1e-30))
+            l_safe = l_safe * (1.0 - dropout_rate)
+        o_ref[0, qs, :] = (acc / l_safe).astype(o_ref.dtype)
+        lse_ref[0, qs, :] = m + jnp.log(jnp.maximum(l, 1e-30))
 
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, key_bias_ref, bias_ref, do_ref,
                    lse_ref, delta_ref, seed_ref, dq_ref, *, scale, causal,
-                   kv_len, block_q, block_k, dropout_rate, head_swap=None):
-    """One (head, q-block) program: dq = Σ_kv (p∘(dO V^T − delta)) K·scale.
-    With dropout the mask/keep lands on dp (= d out/d P path); p itself
-    stays unmasked — that IS the softmax jacobian of the dropped output."""
+                   kv_len, block_q, block_k, dropout_rate, head_swap=None,
+                   q_blocks=1):
+    """One (head, q-block) program, or one a head over all its q blocks
+    (``q_blocks`` > 1): dq = Σ_kv (p∘(dO V^T − delta)) K·scale over the kv
+    blocks the causal mask leaves. With dropout the mask/keep lands on dp
+    (= d out/d P path); p itself stays unmasked — that IS the softmax
+    jacobian of the dropped output."""
     from jax.experimental import pallas as pl
 
-    q = q_ref[0]                                # [BQ, D], input dtype
-    do = do_ref[0]                              # [BQ, D], input dtype
-    lse = lse_ref[0]                            # [BQ, 1]
-    delta = delta_ref[0]                        # [BQ, 1]
-    h = pl.program_id(0)
-    qi = pl.program_id(1)
     n_kb = kv_len // block_k
-    # read the SMEM seed only when dropout is live: the rate-0 kernel
-    # traces to exactly the pre-dropout op stream (the operand is
-    # still bound, just never loaded)
-    seed_u = (seed_ref[0, 0].astype(jnp.int32).astype(jnp.uint32)
-              if dropout_rate > 0.0 else None)
     inv_keep = 1.0 / (1.0 - dropout_rate) if dropout_rate > 0.0 else 1.0
+    for a in range(q_blocks):
+        qs = slice(a * block_q, (a + 1) * block_q)
+        q = q_ref[0, qs, :]                         # [BQ, D], input dtype
+        do = do_ref[0, qs, :]                       # [BQ, D], input dtype
+        lse = lse_ref[0, qs, :]                     # [BQ, 1]
+        delta = delta_ref[0, qs, :]                 # [BQ, 1]
+        h = pl.program_id(0)
+        ib = a if q_blocks > 1 else pl.program_id(1)
+        # read the SMEM seed only when dropout is live: the rate-0 kernel
+        # traces to exactly the pre-dropout op stream (the operand is
+        # still bound, just never loaded)
+        seed_u = (seed_ref[0, 0].astype(jnp.int32).astype(jnp.uint32)
+                  if dropout_rate > 0.0 else None)
 
-    dq = jnp.zeros((block_q, q.shape[-1]), jnp.float32)
-    for kb in range(n_kb):
-        ks = slice(kb * block_k, (kb + 1) * block_k)
-        kblk = k_ref[0, ks, :]                  # [BK, D], input dtype
-        s = _scores(
-            q, kblk, scale, key_bias_ref[0, :, ks],
-            None if bias_ref is None else bias_ref[0, :, ks],
-            qi * block_q, kb * block_k, causal, block_q, block_k,
-        )
-        p = jnp.exp(s - lse)                    # [BQ, BK]
-        dp = jax.lax.dot_general(               # dO @ V^T
-            do, v_ref[0, ks, :].astype(do.dtype), (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        if dropout_rate > 0.0:
-            rows, cols = _block_coords(
-                qi * block_q, kb * block_k, block_q, block_k
+        dq = jnp.zeros((block_q, q.shape[-1]), jnp.float32)
+        for kb in range(n_kb):
+            cls = _block_class(causal, ib, kb, block_q, block_k)
+            if cls == DEAD:
+                continue
+            ks = slice(kb * block_k, (kb + 1) * block_k)
+            kblk = k_ref[0, ks, :]                  # [BK, D], input dtype
+            s = _scores(
+                q, kblk, scale, key_bias_ref[0, :, ks],
+                None if bias_ref is None else bias_ref[0, :, ks],
+                ib * block_q, kb * block_k, cls == DIAGONAL,
+                block_q, block_k,
             )
-            dp = jnp.where(
-                _hash_keep(rows, cols, _hash_head(h, head_swap), seed_u,
-                           dropout_rate),
-                dp * inv_keep, 0.0,
+            p = jnp.exp(s - lse)                    # [BQ, BK]
+            dp = jax.lax.dot_general(               # dO @ V^T
+                do, v_ref[0, ks, :].astype(do.dtype),
+                (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
             )
-        # ds rounds to the key dtype for the MXU (standard flash backward);
-        # fp32 accumulation via preferred_element_type
-        ds = p * (dp - delta)
-        dq = dq + jax.lax.dot_general(          # ds @ K
-            ds.astype(kblk.dtype), kblk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-    dq_ref[0] = (dq * scale).astype(dq_ref.dtype)
+            if dropout_rate > 0.0:
+                rows, cols = _block_coords(
+                    ib * block_q, kb * block_k, block_q, block_k
+                )
+                dp = jnp.where(
+                    _hash_keep(rows, cols, _hash_head(h, head_swap), seed_u,
+                               dropout_rate),
+                    dp * inv_keep, 0.0,
+                )
+            # ds rounds to the key dtype for the MXU (standard flash
+            # backward); fp32 accumulation via preferred_element_type
+            ds = p * (dp - delta)
+            dq = dq + jax.lax.dot_general(          # ds @ K
+                ds.astype(kblk.dtype), kblk, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+        dq_ref[0, qs, :] = (dq * scale).astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, key_bias_ref, bias_ref, do_ref,
                     lse_ref, delta_ref, seed_ref, dk_ref, dv_ref, dkb_ref,
                     dbias_ref, *, scale, causal, q_len, block_q, block_k,
                     bias_group, dropout_rate, head_swap=None,
-                    head_major=False):
-    """One (kv-block, head) program. Two grid orders:
+                    head_major=False, kv_blocks=1):
+    """One (kv-block, head) program, or one a head over all its kv blocks
+    (``kv_blocks`` > 1, head-major), sweeping the q blocks the causal mask
+    leaves a kv block. Two grid orders:
 
     - shared-bias path (``head_major=False``): TRANSPOSED grid, kv axis
       outermost / head axis innermost, so the shared-bias gradient block
@@ -291,76 +415,84 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, key_bias_ref, bias_ref, do_ref,
 
     if head_major:
         h = pl.program_id(0)    # flat head index
-        kb = pl.program_id(1)   # kv-block index
+        kv = pl.program_id(1)   # kv-block index
     else:
-        kb = pl.program_id(0)   # kv-block index
+        kv = pl.program_id(0)   # kv-block index
         h = pl.program_id(1)    # flat head index
-    k = k_ref[0]                                # [BK, D], input dtype
-    v = v_ref[0]                                # [BK, D], input dtype
-    key_bias_row = key_bias_ref[0]              # [1, BK]
     n_qb = q_len // block_q
-    # read the SMEM seed only when dropout is live: the rate-0 kernel
-    # traces to exactly the pre-dropout op stream (the operand is
-    # still bound, just never loaded)
-    seed_u = (seed_ref[0, 0].astype(jnp.int32).astype(jnp.uint32)
-              if dropout_rate > 0.0 else None)
     inv_keep = 1.0 / (1.0 - dropout_rate) if dropout_rate > 0.0 else 1.0
+    for b in range(kv_blocks):
+        ks = slice(b * block_k, (b + 1) * block_k)
+        # the kv block's index: a Python int where the program holds them all
+        kj = b if kv_blocks > 1 else kv
+        k = k_ref[0, ks, :]                         # [BK, D], input dtype
+        v = v_ref[0, ks, :]                         # [BK, D], input dtype
+        key_bias_row = key_bias_ref[0, :, ks]       # [1, BK]
+        # read the SMEM seed only when dropout is live: the rate-0 kernel
+        # traces to exactly the pre-dropout op stream (the operand is
+        # still bound, just never loaded)
+        seed_u = (seed_ref[0, 0].astype(jnp.int32).astype(jnp.uint32)
+                  if dropout_rate > 0.0 else None)
 
-    dk = jnp.zeros((block_k, k.shape[-1]), jnp.float32)
-    dv = jnp.zeros((block_k, k.shape[-1]), jnp.float32)
-    dkb = jnp.zeros((1, block_k), jnp.float32)
-    # per-q-block ds tiles of the general-bias gradient column block;
-    # joined on the (tile-aligned) row axis at the end — Mosaic has no
-    # dynamic_update_slice on values
-    ds_blocks = []
+        dk = jnp.zeros((block_k, k.shape[-1]), jnp.float32)
+        dv = jnp.zeros((block_k, k.shape[-1]), jnp.float32)
+        dkb = jnp.zeros((1, block_k), jnp.float32)
+        # per-q-block ds tiles of the general-bias gradient column block;
+        # joined on the (tile-aligned) row axis at the end — Mosaic has no
+        # dynamic_update_slice on values
+        ds_blocks = []
 
-    for ib in range(n_qb):
-        qs = slice(ib * block_q, (ib + 1) * block_q)
-        q = q_ref[0, qs, :]                     # [BQ, D], input dtype
-        do = do_ref[0, qs, :]                   # [BQ, D], input dtype
-        lse = lse_ref[0, qs, :]                 # [BQ, 1]
-        delta = delta_ref[0, qs, :]             # [BQ, 1]
-        s = _scores(
-            q, k, scale, key_bias_row,
-            None if bias_ref is None else bias_ref[0, qs, :],
-            ib * block_q, kb * block_k, causal, block_q, block_k,
-        )
-        p = jnp.exp(s - lse)                    # [BQ, BK]
-        dp = jax.lax.dot_general(               # dO @ V^T
-            do, v.astype(do.dtype), (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        # fp32 intermediates round to the operand dtype for the MXU;
-        # accumulators (dk/dv/dkb/dbias) stay fp32
-        if dropout_rate > 0.0:
-            rows, cols = _block_coords(
-                ib * block_q, kb * block_k, block_q, block_k
+        for ib in range(n_qb):
+            cls = _block_class(causal, ib, kj, block_q, block_k)
+            if cls == DEAD:
+                continue
+            qs = slice(ib * block_q, (ib + 1) * block_q)
+            q = q_ref[0, qs, :]                     # [BQ, D], input dtype
+            do = do_ref[0, qs, :]                   # [BQ, D], input dtype
+            lse = lse_ref[0, qs, :]                 # [BQ, 1]
+            delta = delta_ref[0, qs, :]             # [BQ, 1]
+            s = _scores(
+                q, k, scale, key_bias_row,
+                None if bias_ref is None else bias_ref[0, qs, :],
+                ib * block_q, kj * block_k, cls == DIAGONAL,
+                block_q, block_k,
             )
-            keep = _hash_keep(rows, cols, _hash_head(h, head_swap),
-                              seed_u, dropout_rate)
-            dv = dv + jax.lax.dot_general(      # (mask∘p/keep)^T @ dO
-                jnp.where(keep, p * inv_keep, 0.0).astype(do.dtype), do,
-                (((0,), (0,)), ((), ())),
+            p = jnp.exp(s - lse)                    # [BQ, BK]
+            dp = jax.lax.dot_general(               # dO @ V^T
+                do, v.astype(do.dtype), (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )
-            dp = jnp.where(keep, dp * inv_keep, 0.0)
-        else:
-            dv = dv + jax.lax.dot_general(      # p^T @ dO
-                p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+            # fp32 intermediates round to the operand dtype for the MXU;
+            # accumulators (dk/dv/dkb/dbias) stay fp32
+            if dropout_rate > 0.0:
+                rows, cols = _block_coords(
+                    ib * block_q, kj * block_k, block_q, block_k
+                )
+                keep = _hash_keep(rows, cols, _hash_head(h, head_swap),
+                                  seed_u, dropout_rate)
+                dv = dv + jax.lax.dot_general(      # (mask∘p/keep)^T @ dO
+                    jnp.where(keep, p * inv_keep, 0.0).astype(do.dtype), do,
+                    (((0,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )
+                dp = jnp.where(keep, dp * inv_keep, 0.0)
+            else:
+                dv = dv + jax.lax.dot_general(      # p^T @ dO
+                    p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )
+            ds = p * (dp - delta)
+            dk = dk + jax.lax.dot_general(          # ds^T @ q (·scale at write)
+                ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )
-        ds = p * (dp - delta)
-        dk = dk + jax.lax.dot_general(          # ds^T @ q (·scale at write)
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        dkb = dkb + ds.sum(axis=0, keepdims=True)
-        if dbias_ref is not None:
-            ds_blocks.append(ds)
+            dkb = dkb + ds.sum(axis=0, keepdims=True)
+            if dbias_ref is not None:
+                ds_blocks.append(ds)
 
-    dk_ref[0] = (dk * scale).astype(dk_ref.dtype)
-    dv_ref[0] = dv.astype(dv_ref.dtype)
-    dkb_ref[0] = dkb
+        dk_ref[0, ks, :] = (dk * scale).astype(dk_ref.dtype)
+        dv_ref[0, ks, :] = dv.astype(dv_ref.dtype)
+        dkb_ref[0, :, ks] = dkb
     if dbias_ref is not None:
         dbias = jnp.concatenate(ds_blocks, axis=0)     # [q_len, BK]
 
@@ -927,14 +1059,20 @@ def _pad_to(S, block):
     return _round_up(Sp, min(block, Sp))
 
 
+def _geometry(q, k):
+    """(B, N, Sq, Sk, padded Sq, padded Sk, block_q, block_k) of a call."""
+    B, N, Sq, _ = q.shape
+    Sk = k.shape[2]
+    Sqp, Skp = _pad_to(Sq, BLOCK_Q), _pad_to(Sk, BLOCK_K)
+    return B, N, Sq, Sk, Sqp, Skp, min(BLOCK_Q, Sqp), min(BLOCK_K, Skp)
+
+
 def _prep(q, k, v, key_bias, bias, g=None):
     """Flatten heads, pad seq lens to tile multiples. Padded KEYS get
     key-bias −inf (never receive weight); padded QUERY rows are sliced
     away by the caller. Returns the padded operands + geometry."""
-    B, N, Sq, D = q.shape
-    Sk = k.shape[2]
-    Sqp, Skp = _pad_to(Sq, BLOCK_Q), _pad_to(Sk, BLOCK_K)
-    bq, bk = min(BLOCK_Q, Sqp), min(BLOCK_K, Skp)
+    B, N, Sq, Sk, Sqp, Skp, bq, bk = geom = _geometry(q, k)
+    D = q.shape[-1]
     qf = q.reshape(B * N, Sq, D)
     kf = k.reshape(B * N, Sk, D)
     vf = v.reshape(B * N, Sk, D)
@@ -955,18 +1093,19 @@ def _prep(q, k, v, key_bias, bias, g=None):
         g = jnp.pad(g.reshape(B * N, Sq, D), ((0, 0), (0, Sqp - Sq), (0, 0)))
     elif g is not None:
         g = g.reshape(B * N, Sq, D)
-    return qf, kf, vf, kb, bf, g, (B, N, Sq, Sk, Sqp, Skp, bq, bk)
+    return qf, kf, vf, kb, bf, g, geom
 
 
-def _common_in_specs(pl, pltpu, geom, G, D):
+def _common_in_specs(pl, pltpu, geom, G, D, rq):
     """in_specs for (q, k, v, key_bias[, bias]) shared by the two
-    (head, q-block)-grid kernels (forward and dq). Vector operands ride
+    (head, q-block)-grid kernels (forward and dq); a program holds ``rq``
+    query rows (one block, or the head's). Vector operands ride
     with an explicit singleton dim ([BN, 1, S] rows / [BN, S, 1] columns)
     so every block's trailing two dims satisfy the Mosaic (8, 128) tiling
     rule (a (1, S) block of a rank-2 array does not)."""
     B, N, Sq, Sk, Sqp, Skp, bq, bk = geom
     specs = [
-        pl.BlockSpec((1, bq, D), lambda h, i: (h, i, 0),
+        pl.BlockSpec((1, rq, D), lambda h, i: (h, i, 0),
                      memory_space=pltpu.VMEM),
         pl.BlockSpec((1, Skp, D), lambda h, i: (h, 0, 0),
                      memory_space=pltpu.VMEM),
@@ -995,8 +1134,21 @@ def _seed_spec(pl, pltpu):
     return pl.BlockSpec((1, 1), lambda *_: (0, 0), memory_space=pltpu.SMEM)
 
 
+def _flash_fwd(q, k, v, key_bias, bias, seed, causal, scale, dropout_rate,
+               interpret, head_swap=None):
+    """One forward call: counted here, a call, and handed to the memoized
+    ``_flash_fwd_impl``."""
+    _count_blocks(causal, bias, q, k, kernels=1)
+    return _flash_fwd_impl(q, k, v, key_bias, bias, seed, causal, scale,
+                           dropout_rate, interpret, head_swap)
+
+
+# jitted: the calls of one signature in a program (a model's layers) are
+# traced and lowered once and share one function of the module, where each
+# traced and lowered its own copy of the kernel
+@functools.partial(jax.jit, static_argnums=(6, 7, 8, 9, 10))
 def _flash_fwd_impl(q, k, v, key_bias, bias, seed, causal, scale,
-                    dropout_rate, interpret, head_swap=None):
+                    dropout_rate, interpret, head_swap):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -1005,12 +1157,15 @@ def _flash_fwd_impl(q, k, v, key_bias, bias, seed, causal, scale,
     D = q.shape[-1]
     G = None if bf is None else bf.shape[0]
 
+    whole = _whole_head(causal, bf, geom, D, q.dtype.itemsize)
+    rq = Sqp if whole else bq       # query rows a program holds
     kernel = functools.partial(
         _fwd_kernel if bf is not None else _no_bias(_fwd_kernel),
         scale=scale, causal=causal, kv_len=Skp, block_q=bq, block_k=bk,
-        dropout_rate=dropout_rate, head_swap=head_swap,
+        dropout_rate=dropout_rate, head_swap=head_swap, q_blocks=rq // bq,
     )
-    in_specs = _common_in_specs(pl, pltpu, geom, G, D) + [_seed_spec(pl, pltpu)]
+    in_specs = (_common_in_specs(pl, pltpu, geom, G, D, rq)
+                + [_seed_spec(pl, pltpu)])
     operands = (
         [qf, kf, vf, kb[:, None, :]]
         + ([bf] if bf is not None else []) + [seed]
@@ -1022,12 +1177,12 @@ def _flash_fwd_impl(q, k, v, key_bias, bias, seed, causal, scale,
             jax.ShapeDtypeStruct((B * N, Sqp, D), q.dtype),
             jax.ShapeDtypeStruct((B * N, Sqp, 1), jnp.float32),
         ],
-        grid=(B * N, Sqp // bq),
+        grid=(B * N, Sqp // rq),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, bq, D), lambda h, i: (h, i, 0),
+            pl.BlockSpec((1, rq, D), lambda h, i: (h, i, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, bq, 1), lambda h, i: (h, i, 0),
+            pl.BlockSpec((1, rq, 1), lambda h, i: (h, i, 0),
                          memory_space=pltpu.VMEM),
         ],
         interpret=interpret,
@@ -1044,6 +1199,16 @@ def _no_bias(kernel):
     return wrapped
 
 
+def _flash_bwd(causal, scale, dropout_rate, interpret, head_swap, res, g,
+               g_lse):
+    """One backward call (dq and dkv kernels), counted as ``_flash_fwd``."""
+    q, k, _, _, bias = res[:5]
+    _count_blocks(causal, bias, q, k, kernels=2)
+    return _flash_bwd_core(causal, scale, dropout_rate, interpret, head_swap,
+                           res, g, g_lse)
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3, 4))
 def _flash_bwd_core(causal, scale, dropout_rate, interpret, head_swap, res,
                     g, g_lse):
     """Shared backward. ``g_lse`` is the logsumexp cotangent from the
@@ -1073,15 +1238,19 @@ def _flash_bwd_core(causal, scale, dropout_rate, interpret, head_swap, res,
     else:
         lse_p = lse
 
+    # a program holds one block of a head, or (``_whole_head``) all of them
+    whole = _whole_head(causal, bf, geom, D, q.dtype.itemsize)
+    rq, rk = (Sqp, Skp) if whole else (bq, bk)
+
     # ---- dq: same (head, q-block) grid as the forward ----
     dq_kernel = functools.partial(
         _bwd_dq_kernel if bf is not None else _no_bias(_bwd_dq_kernel),
         scale=scale, causal=causal, kv_len=Skp, block_q=bq, block_k=bk,
-        dropout_rate=dropout_rate, head_swap=head_swap,
+        dropout_rate=dropout_rate, head_swap=head_swap, q_blocks=rq // bq,
     )
-    row_spec = pl.BlockSpec((1, bq, D), lambda h, i: (h, i, 0),
+    row_spec = pl.BlockSpec((1, rq, D), lambda h, i: (h, i, 0),
                             memory_space=pltpu.VMEM)
-    col_spec = pl.BlockSpec((1, bq, 1), lambda h, i: (h, i, 0),
+    col_spec = pl.BlockSpec((1, rq, 1), lambda h, i: (h, i, 0),
                             memory_space=pltpu.VMEM)
     kb3 = kb[:, None, :]                       # [BN, 1, Skp]
     lse3 = lse_p[:, :, None]                   # [BN, Sqp, 1]
@@ -1090,8 +1259,8 @@ def _flash_bwd_core(causal, scale, dropout_rate, interpret, head_swap, res,
         dq_kernel,
         name="flash_bwd_dq",
         out_shape=jax.ShapeDtypeStruct((B * N, Sqp, D), q.dtype),
-        grid=(B * N, Sqp // bq),
-        in_specs=_common_in_specs(pl, pltpu, geom, G, D)
+        grid=(B * N, Sqp // rq),
+        in_specs=_common_in_specs(pl, pltpu, geom, G, D, rq)
         + [row_spec, col_spec, col_spec, _seed_spec(pl, pltpu)],
         out_specs=row_spec,
         interpret=interpret,
@@ -1109,7 +1278,7 @@ def _flash_bwd_core(causal, scale, dropout_rate, interpret, head_swap, res,
         _bwd_dkv_kernel if bf is not None else _no_bias(_bwd_dkv_kernel),
         scale=scale, causal=causal, q_len=Sqp, block_q=bq, block_k=bk,
         bias_group=group or 1, dropout_rate=dropout_rate,
-        head_swap=head_swap, head_major=head_major,
+        head_swap=head_swap, head_major=head_major, kv_blocks=rk // bk,
     )
     if bf is None:
         # adapter also has to drop the dbias OUT ref
@@ -1132,12 +1301,12 @@ def _flash_bwd_core(causal, scale, dropout_rate, interpret, head_swap, res,
     in_specs = [
         pl.BlockSpec((1, Sqp, D), hj(lambda h, j: (h, 0, 0)),
                      memory_space=pltpu.VMEM),       # q (full rows)
-        pl.BlockSpec((1, bk, D), hj(lambda h, j: (h, j, 0)),
-                     memory_space=pltpu.VMEM),       # k block
-        pl.BlockSpec((1, bk, D), hj(lambda h, j: (h, j, 0)),
-                     memory_space=pltpu.VMEM),       # v block
-        pl.BlockSpec((1, 1, bk), hj(lambda h, j: (h, 0, j)),
-                     memory_space=pltpu.VMEM),       # key bias block
+        pl.BlockSpec((1, rk, D), hj(lambda h, j: (h, j, 0)),
+                     memory_space=pltpu.VMEM),       # k block(s)
+        pl.BlockSpec((1, rk, D), hj(lambda h, j: (h, j, 0)),
+                     memory_space=pltpu.VMEM),       # v block(s)
+        pl.BlockSpec((1, 1, rk), hj(lambda h, j: (h, 0, j)),
+                     memory_space=pltpu.VMEM),       # key bias block(s)
     ]
     if bf is not None:
         in_specs.append(
@@ -1159,11 +1328,11 @@ def _flash_bwd_core(causal, scale, dropout_rate, interpret, head_swap, res,
         jax.ShapeDtypeStruct((B * N, 1, Skp), jnp.float32),  # dkey_bias
     ]
     out_specs = [
-        pl.BlockSpec((1, bk, D), hj(lambda h, j: (h, j, 0)),
+        pl.BlockSpec((1, rk, D), hj(lambda h, j: (h, j, 0)),
                      memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, bk, D), hj(lambda h, j: (h, j, 0)),
+        pl.BlockSpec((1, rk, D), hj(lambda h, j: (h, j, 0)),
                      memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, 1, bk), hj(lambda h, j: (h, 0, j)),
+        pl.BlockSpec((1, 1, rk), hj(lambda h, j: (h, 0, j)),
                      memory_space=pltpu.VMEM),
     ]
     if bf is not None:
@@ -1177,7 +1346,7 @@ def _flash_bwd_core(causal, scale, dropout_rate, interpret, head_swap, res,
         name="flash_bwd_dkv",
         out_shape=out_shape,
         grid=(
-            (B * N, Skp // bk) if head_major else (Skp // bk, B * N)
+            (B * N, Skp // rk) if head_major else (Skp // bk, B * N)
         ),
         in_specs=in_specs,
         out_specs=out_specs,
@@ -1204,13 +1373,13 @@ def _flash_lse(q, k, v, key_bias, bias, seed, causal, scale, dropout_rate,
     """(out, lse) variant: lse [B*N, Sq] is the per-row logsumexp of the
     masked scores — the residual blockwise/ring attention needs to
     combine per-block outputs across hops without renormalizing."""
-    return _flash_fwd_impl(q, k, v, key_bias, bias, seed, causal, scale,
+    return _flash_fwd(q, k, v, key_bias, bias, seed, causal, scale,
                            dropout_rate, interpret, head_swap)
 
 
 def _flash_lse_fwd(q, k, v, key_bias, bias, seed, causal, scale,
                    dropout_rate, interpret, head_swap):
-    out, lse = _flash_fwd_impl(q, k, v, key_bias, bias, seed, causal, scale,
+    out, lse = _flash_fwd(q, k, v, key_bias, bias, seed, causal, scale,
                                dropout_rate, interpret, head_swap)
     return (out, lse), (q, k, v, key_bias, bias, seed, out, lse)
 
@@ -1218,7 +1387,7 @@ def _flash_lse_fwd(q, k, v, key_bias, bias, seed, causal, scale,
 def _flash_lse_bwd(causal, scale, dropout_rate, interpret, head_swap, res,
                    cotangents):
     g, g_lse = cotangents
-    return _flash_bwd_core(causal, scale, dropout_rate, interpret, head_swap,
+    return _flash_bwd(causal, scale, dropout_rate, interpret, head_swap,
                            res, g, g_lse)
 
 
@@ -1337,7 +1506,7 @@ def flash_attention_bwd_from_residuals(q, k, v, key_bias, seed, out, lse, g,
     seed = _norm_seed(seed)
     lse = lse.reshape(B * N, Sq)
     res = (q, k, v, kb, None, seed, out, lse)
-    dq, dk, dv, dkb, _dbias, _dseed = _flash_bwd_core(
+    dq, dk, dv, dkb, _dbias, _dseed = _flash_bwd(
         causal, scale, rate, bool(interpret), None, res, g, None
     )
     return dq, dk, dv, dkb

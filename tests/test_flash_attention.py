@@ -703,3 +703,211 @@ def test_residual_backward_matches_vjp_with_dropout():
     # returns the kernels' canonical [B*N, S] — same after head-summing
     np.testing.assert_allclose(
         np.asarray(dkb1).reshape(B, N, S).sum(1), dkb0, rtol=1e-5, atol=1e-5)
+
+
+# -- the sweeps visit only the blocks a causal mask leaves --------------------
+
+
+def flash_attention_module():
+    """The module itself: the package exports the function under the
+    module's name."""
+    import importlib
+
+    return importlib.import_module("paddle_tpu.kernels.flash_attention")
+
+
+def _causal_as_bias(S):
+    """The lower-triangular mask as a general bias: the ``causal=False``
+    call that carries it visits every block."""
+    return jnp.asarray(np.where(np.tril(np.ones((S, S), bool)), 0.0, -1e30),
+                       jnp.float32)
+
+
+@pytest.mark.parametrize("dropout", [0.0, 0.25], ids=["nodrop", "drop"])
+@pytest.mark.parametrize("with_key_bias", [False, True],
+                         ids=["nokb", "keybias"])
+@pytest.mark.parametrize("S", [256, 384, 1024])
+def test_causal_sweep_matches_every_block_sweep(S, with_key_bias, dropout):
+    """``causal=True`` (one program a head: dead blocks skipped, interior
+    blocks unmasked) against the same attention with the mask passed as a
+    bias, which visits all (S / 128)^2 blocks, a program a block: out,
+    lse and every gradient."""
+    fa = flash_attention_module()
+    q, k, v = _inputs(B=1, N=2, S=S, D=16, seed=21)
+    rs = np.random.RandomState(22)
+    kb = jnp.asarray(rs.randn(2, S).astype("float32") * 0.3
+                     if with_key_bias else np.zeros((2, S), "float32"))
+    w_out = jnp.asarray(rs.randn(1, 2, S, 16).astype("float32"))
+    w_lse = jnp.asarray(rs.randn(2, S).astype("float32"))
+    kw = dict(dropout_rate=dropout, dropout_seed=5 if dropout else None,
+              interpret=True)
+    tril = _causal_as_bias(S)
+
+    def both(causal):
+        def f(q, k, v, kb):
+            out, lse = fa.flash_attention_lse(
+                q, k, v, key_bias=kb, causal=causal,
+                bias=None if causal else tril, **kw)
+            return (out * w_out).sum() + (lse * w_lse).sum(), (out, lse)
+        grads, outs = jax.jit(jax.grad(
+            f, argnums=(0, 1, 2, 3), has_aux=True))(q, k, v, kb)
+        return outs + grads
+
+    names = ("out", "lse", "dq", "dk", "dv", "dkey_bias")
+    for name, a, b in zip(names, both(True), both(False)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=2e-4, atol=2e-5, err_msg=name)
+
+
+@pytest.mark.parametrize("bias_rows", ["2d", "full"])
+def test_causal_dbias_is_zero_on_dead_blocks(bias_rows):
+    """Causal + general bias: the dkv kernel writes the bias gradient's
+    column block whole, exact zeros on the rows of the q blocks above the
+    diagonal, and the rest is the reference's gradient."""
+    S, block = 384, 128
+    q, k, v = _inputs(B=1, N=2, S=S, D=16, seed=23)
+    rs = np.random.RandomState(24)
+    shape = (S, S) if bias_rows == "2d" else (1, 2, S, S)
+    bias = jnp.asarray(rs.randn(*shape).astype("float32") * 0.3)
+
+    def wide(b):
+        return jnp.broadcast_to(b.reshape((1,) * (4 - b.ndim) + b.shape),
+                                (1, 2, S, S))
+
+    got = jax.jit(jax.grad(lambda b: jnp.sum(flash_attention(
+        q, k, v, bias=b, causal=True, interpret=True) ** 2)))(bias)
+    want = jax.grad(lambda b: jnp.sum(reference_attention(
+        q, k, v, bias=wide(b), causal=True) ** 2))(bias)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-4, atol=2e-5)
+    got = np.asarray(got).reshape(-1, S, S)
+    for ib in range(S // block):
+        dead = got[:, ib * block:(ib + 1) * block, (ib + 1) * block:]
+        assert not dead.any(), ib
+    assert got[:, block:, :block].any()
+
+
+@pytest.mark.parametrize("causal,q_len,kv_len,block_q,block_k,want", [
+    (True, 1024, 1024, 128, 128, (28, 28, 8)),
+    (False, 1024, 1024, 128, 128, (0, 64, 0)),
+    (True, 4096, 4096, 128, 128, (496, 496, 32)),
+    (True, 512, 512, 128, 64, None),
+    (True, 512, 512, 64, 128, None),
+    (True, 256, 512, 128, 128, None),
+    (True, 40, 40, 40, 40, (0, 0, 1)),
+])
+def test_block_classes(causal, q_len, kv_len, block_q, block_k, want):
+    """dead / interior / diagonal counts of the (q-block, kv-block) pairs,
+    each pair checked against the dense lower-triangular mask; a pair one
+    of whose indices is a program id is computed with the mask."""
+    fa = flash_attention_module()
+    got = fa.block_classes(causal, q_len, kv_len, block_q, block_k)
+    if want is not None:
+        assert (got["dead"], got["interior"], got["diagonal"]) == want
+    mask = np.tril(np.ones((q_len, kv_len), bool)) if causal else \
+        np.ones((q_len, kv_len), bool)
+    dense = {"dead": 0, "interior": 0, "diagonal": 0}
+    for ib in range(q_len // block_q):
+        for kb in range(kv_len // block_k):
+            blk = mask[ib * block_q:(ib + 1) * block_q,
+                       kb * block_k:(kb + 1) * block_k]
+            cls = ("interior" if blk.all() else
+                   "diagonal" if blk.any() else "dead")
+            dense[cls] += 1
+            assert fa._block_class(causal, ib, kb, block_q, block_k) == cls
+    assert got == dense
+    traced = jnp.int32(0)
+    assert fa._block_class(causal, traced, 0, block_q, block_k) == (
+        "diagonal" if causal else "interior")
+
+
+def _equations(jaxpr):
+    """Every equation of ``jaxpr``, those of nested jaxprs included."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for value in eqn.params.values():
+            for sub in (value if isinstance(value, (list, tuple))
+                        else [value]):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _equations(sub)
+
+
+def _traced_pallas_calls(fn, *args):
+    """The ``pallas_call`` equations of ``fn`` traced on ``args``."""
+    return [eqn for eqn in _equations(jax.make_jaxpr(fn)(*args).jaxpr)
+            if eqn.primitive.name == "pallas_call"]
+
+
+@pytest.mark.parametrize("causal,S,visited,total", [
+    (True, 1024, 3 * 36, 3 * 64),
+    (True, 384, 3 * 6, 3 * 9),
+    (False, 384, 3 * 9, 3 * 9),
+    # more blocks than a program unrolls: every pair is visited, masked
+    (True, 2048, 3 * 256, 3 * 256),
+])
+def test_flash_blocks_counters(causal, S, visited, total):
+    """One traced forward + backward bumps the two counters by the block
+    pairs a head the three kernels visit / would visit unskipped."""
+    from paddle_tpu.observability import registry
+
+    seen = registry.counter("flash_blocks_visited")
+    whole = registry.counter("flash_blocks_total")
+    before = seen.value(), whole.value()
+    q = jnp.zeros((1, 2, S, 16), jnp.float32)
+    calls = _traced_pallas_calls(
+        jax.grad(lambda q, k, v: flash_attention(
+            q, k, v, causal=causal, interpret=True).sum(),
+            argnums=(0, 1, 2)), q, q, q)
+    assert [c.params["name"] for c in calls] == [
+        "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"]
+    assert seen.value() - before[0] == visited
+    assert whole.value() - before[1] == total
+
+
+@pytest.mark.parametrize("causal,S,grids", [
+    # BERT-shaped: key bias, no causal mask: a program a block
+    (False, 384, [(4, 3), (4, 3), (4, 3)]),
+    # one block: the diagonal one
+    (True, 128, [(4, 1), (4, 1), (4, 1)]),
+    # causal: a program holds its head's three blocks, classes are static
+    (True, 384, [(4, 1), (4, 1), (4, 1)]),
+    # more blocks than a program unrolls: a program a block, as before
+    (True, 2048, [(4, 16), (4, 16), (4, 16)]),
+])
+def test_sweeps_are_static(causal, S, grids):
+    """Every sweep's shape is fixed at trace time: no kernel holds a
+    ``cond`` or a loop, none takes a scratch operand. Without ``causal``
+    that is the op stream the kernels had before blocks were
+    classified."""
+    q = jnp.zeros((2, 2, S, 16), jnp.bfloat16)
+    kb = jnp.zeros((2, S), jnp.float32)
+    calls = _traced_pallas_calls(
+        jax.grad(lambda q, k, v, kb: flash_attention(
+            q, k, v, key_bias=kb, causal=causal,
+            interpret=True).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2, 3)), q, q, q, kb)
+    assert [tuple(c.params["grid_mapping"].grid) for c in calls] == grids
+    for call in calls:
+        prims = {eqn.primitive.name
+                 for eqn in _equations(call.params["jaxpr"])}
+        assert not prims & {"cond", "while", "scan"}, call.params["name"]
+        assert call.params["grid_mapping"].num_scratch_operands == 0
+
+
+@pytest.mark.parametrize("causal,bias,S,d,itemsize,want", [
+    (True, None, 1024, 64, 2, True),        # GPT-2: 8 x 8 blocks of bf16
+    (True, None, 256, 16, 4, True),
+    (False, None, 1024, 64, 2, False),      # nothing to skip
+    (True, "bias", 1024, 64, 2, False),     # the bias rides in a block a program
+    (True, None, 128, 64, 2, False),        # one block: one diagonal block
+    (True, None, 2048, 64, 2, False),       # 136 live pairs: too many to unroll
+    (True, None, 1024, 512, 4, False),      # a head's rows outgrow a program's VMEM
+])
+def test_whole_head_decision(causal, bias, S, d, itemsize, want):
+    """One program a head only where every pair's class can be static, the
+    head's triangle is small enough to unroll and its rows fit VMEM."""
+    fa = flash_attention_module()
+    block = min(S, fa.BLOCK_Q)
+    geom = (1, 1, S, S, S, S, block, block)
+    assert fa._whole_head(causal, bias, geom, d, itemsize) is want

@@ -171,6 +171,24 @@ def test_kernel_compiles_for_v5e(chip, case):
     compiled = jax.jit(fn).lower(*args).compile()
     # the kernel is in the program: it did not give way to the reference
     assert compiled.as_text().count("tpu_custom_call") >= n_kernels
+    if case == "flash_causal_b8_s1024":
+        _three_named_flash_kernels(compiled.as_text())
+
+
+def _three_named_flash_kernels(text):
+    """The causal sweep's predicates stay inside the kernels: the train
+    step still holds three custom calls, under the names the benchmark's
+    ``flash_names.event_pattern`` finds them by on the device trace."""
+    import re
+
+    from benchmark.kernels import flash_names
+
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 3
+    for kernel in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+        pattern = re.compile(flash_names.event_pattern(kernel))
+        assert sum(bool(pattern.search(c)) for c in calls) == 1, kernel
 
 
 def _compile_paged_program(chip, monkeypatch, config_file, cfg_of, which,
