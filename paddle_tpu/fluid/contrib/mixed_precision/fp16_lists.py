@@ -17,6 +17,10 @@ white_list = {
     "mul",
     "matmul",
     "bmm",
+    # the grouped products take bf16 rows and expert stacks and
+    # accumulate in fp32; the router's weight and bias stay fp32
+    # (fp16_utils._OP_FLOAT_SLOTS_SKIP) and its scores are fp32 inside
+    "moe_ffn",
 }
 
 black_list = {
@@ -48,6 +52,13 @@ gray_list = {
     # desc on a bf16 runtime value, skipping a protective cast at the
     # next black-list consumer
     "flash_attention",
+    # the decoder ops compute in fp32 inside (statistics, angles, the
+    # SiLU, the convolution) whatever comes in, and answer in their
+    # input's dtype; their gains and taps stay fp32
+    "rms_norm",
+    "rotary_embedding",
+    "swiglu",
+    "gated_short_conv",
     "elementwise_add",
     "elementwise_sub",
     "elementwise_mul",

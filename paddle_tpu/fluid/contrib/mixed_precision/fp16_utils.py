@@ -24,6 +24,9 @@ _FLOAT_SLOTS_SKIP = {"LearningRate", "Mean", "Variance", "Beta1Pow", "Beta2Pow"}
 _OP_FLOAT_SLOTS_SKIP = {
     "batch_norm": {"Scale", "Bias", "Mean", "Variance"},
     "flash_attention": {"KeyBias", "Bias"},
+    "rms_norm": {"Scale"},
+    "gated_short_conv": {"ConvW"},
+    "moe_ffn": {"RouterW", "RouterBias"},
 }
 
 

@@ -1,6 +1,8 @@
 """Layer functions of the decoder ops (``fluid/ops/decoder_ops.py``):
-latent attention, routed experts, the gated delta rule (KDA) and
-grouped-query window attention. All inference only."""
+latent attention, routed experts, the gated short convolution, the gated
+delta rule (KDA) and grouped-query window attention. ``rms_norm``,
+``rotary_embedding``, ``swiglu``, ``moe_ffn`` and ``gated_short_conv``
+have gradients; the others are inference only."""
 
 from ..layer_helper import LayerHelper
 
@@ -9,6 +11,7 @@ __all__ = [
     "rotary_embedding",
     "swiglu",
     "moe_ffn",
+    "gated_short_conv",
     "mla_window_attention",
     "mla_decode_paged_attention",
     "kda_window",
@@ -50,13 +53,15 @@ def swiglu(gate, up, name=None):
 
 def moe_ffn(x, router_w, router_bias, w1, w3, w2, num_experts,
             experts_per_token, expert_offset=0, scaling=1.0,
-            scoring="sigmoid", norm_topk=True, zero_experts=0, name=None):
+            scoring="sigmoid", norm_topk=True, zero_experts=0,
+            norm_eps=1e-20, name=None):
     """The routed part of a sparse expert layer over the experts held
     (``w1``/``w3`` [E_held, H, I], ``w2`` [E_held, I, H], global numbers
     from ``expert_offset``). -> (out like ``x``, counts int32 [E_held]),
     and with ``zero_experts`` identity experts after the ``num_experts``
     (``router_w`` that much wider) a third: the assignments that went to
-    them, int32 [1]. ``scoring``, ``norm_topk``: the op's."""
+    them, int32 [1]. ``scoring``, ``norm_topk``, ``norm_eps``: the
+    op's."""
     helper = LayerHelper("moe_ffn", name=name)
     out = helper.create_variable_for_type_inference(dtype=x.dtype)
     counts = helper.create_variable_for_type_inference(dtype="int32")
@@ -69,7 +74,8 @@ def moe_ffn(x, router_w, router_bias, w1, w3, w2, num_experts,
     # none of them is, op for op and byte for byte, what it was
     for key, value, default in (("scoring", str(scoring), "sigmoid"),
                                 ("norm_topk", bool(norm_topk), True),
-                                ("zero_experts", int(zero_experts), 0)):
+                                ("zero_experts", int(zero_experts), 0),
+                                ("norm_eps", float(norm_eps), 1e-20)):
         if value != default:
             attrs[key] = value
     if zero_experts:
@@ -84,6 +90,15 @@ def moe_ffn(x, router_w, router_bias, w1, w3, w2, num_experts,
     if zero_experts:
         return out, counts, outputs["ZeroCount"][0]
     return out, counts
+
+
+def gated_short_conv(x, conv_w, name=None):
+    """``C * conv(B * x)`` of ``x`` [N, T, 3*C] = ``B ‖ C ‖ x``: a
+    depthwise causal convolution over time with the taps ``conv_w``
+    [K, C] (tap K-1 on the current row), zeros before the sequence.
+    -> [N, T, C]."""
+    return _one_out("gated_short_conv", {"X": [x], "ConvW": [conv_w]}, {},
+                    x.dtype, name)
 
 
 def _mla_attrs(num_heads, nope_dim, rope_dim, v_dim):

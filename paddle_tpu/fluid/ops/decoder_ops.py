@@ -4,16 +4,23 @@ positions, the gated SiLU product, one routed-expert op, and latent
 attention in its two forms (up-projected for a window of queries,
 absorbed for one query a slot read through a block table).
 
-All are inference ops: none registers a gradient. Matmuls take their
-operands in the dtype they come in (bfloat16 in a served program) and
-accumulate in float32; norms, softmax and the router compute in float32.
+The ops a trained model of this kind needs register a gradient:
+``rms_norm``, ``rotary_embedding``, ``swiglu`` and ``gated_short_conv``
+through the generic maker (the vjp of the lowering), ``moe_ffn`` through
+a lowering of its own (``moe_ffn_grad``); the attention, delta-rule and
+paged ops are inference ops and register none. Matmuls take their
+operands in the dtype they come in (bfloat16 in a served program, and
+under AMP in a trained one) and accumulate in float32; norms, softmax
+and the router compute in float32.
 """
 
 from __future__ import annotations
 
+import functools
 import math
 
-from .registry import in_var, op, same_shape_infer, set_out
+from .registry import (GRAD_SUFFIX, generic_grad_maker, in_var, op,
+                       same_shape_infer, set_out)
 
 _NEG = -1e30
 _WINDOW_BLOCK = 512   # queries a block, keys a chunk of ``mla_window``
@@ -30,10 +37,10 @@ def rms_norm(x, w, eps):
     return (y * w.astype(jnp.float32)).astype(x.dtype)
 
 
-@op("rms_norm", infer_shape=same_shape_infer("X"))
+@op("rms_norm", infer_shape=same_shape_infer("X"), grad="generic")
 def _rms_norm(ctx, op_):
     """RMSNorm over the last axis: ``Scale * X / sqrt(mean(X^2) +
-    epsilon)``. Inference only (no grad op)."""
+    epsilon)``."""
     ctx.out(op_, "Out", rms_norm(
         ctx.in1(op_, "X"), ctx.in1(op_, "Scale"),
         float(op_.attr("epsilon", 1e-6))))
@@ -64,11 +71,12 @@ def rotary(x, pos, head_dim, rope_dim, theta, interleaved):
     return jnp.concatenate([keep, rot.astype(x.dtype)], -1).reshape(n, t, c)
 
 
-@op("rotary_embedding", infer_shape=same_shape_infer("X"))
+@op("rotary_embedding", infer_shape=same_shape_infer("X"), grad="generic")
 def _rotary_embedding(ctx, op_):
     """Rotary positions at FED positions (``Pos`` [N, T] or [N, T, 1]) on
     the last ``rope_dim`` values of each ``head_dim`` chunk of ``X``
-    [N, T, C]; see ``rotary``. Inference only (no grad op)."""
+    [N, T, C]; see ``rotary``. The gradient is the inverse rotation of
+    the cotangent (``Pos`` takes none)."""
     ctx.out(op_, "Out", rotary(
         ctx.in1(op_, "X"), ctx.in1(op_, "Pos"),
         int(op_.attr("head_dim")), int(op_.attr("rope_dim")),
@@ -76,10 +84,9 @@ def _rotary_embedding(ctx, op_):
         bool(op_.attr("interleaved", False))))
 
 
-@op("swiglu", infer_shape=same_shape_infer("Gate"))
+@op("swiglu", infer_shape=same_shape_infer("Gate"), grad="generic")
 def _swiglu(ctx, op_):
-    """silu(Gate) * Up, computed in float32, in Gate's dtype. Inference
-    only (no grad op)."""
+    """silu(Gate) * Up, computed in float32, in Gate's dtype."""
     import jax
     import jax.numpy as jnp
 
@@ -89,13 +96,16 @@ def _swiglu(ctx, op_):
                          * u.astype(jnp.float32)).astype(g.dtype))
 
 
-def route(x, wg, bias, k, scaling, scoring="sigmoid", norm_topk=True):
+def route(x, wg, bias, k, scaling, scoring="sigmoid", norm_topk=True,
+          norm_eps=1e-20):
     """-> (experts [T, k] int32, gates [T, k] float32). Scores are
     sigmoid(x Wg), or with ``scoring="softmax"`` softmax(x Wg) over all
     the router's outputs, in float32 from the float32-cast input; the k
     experts are the top k of score + bias; gates are the chosen SCORES
-    (the bias chooses and does not weigh), renormalised to sum 1 unless
-    ``norm_topk`` is false, and scaled."""
+    (the bias chooses and does not weigh), renormalised to sum 1 (over
+    their sum + ``norm_eps``) unless ``norm_topk`` is false, and scaled.
+    Under a gradient the gates differentiate in ``x`` and ``wg``; the
+    choice and the bias do not."""
     import jax
     import jax.numpy as jnp
 
@@ -106,7 +116,8 @@ def route(x, wg, bias, k, scaling, scoring="sigmoid", norm_topk=True):
     _, experts = jax.lax.top_k(s + bias.astype(jnp.float32)[None, :], k)
     chosen = jnp.take_along_axis(s, experts, axis=1)
     if norm_topk:
-        gates = scaling * chosen / (chosen.sum(-1, keepdims=True) + 1e-20)
+        gates = scaling * chosen / (chosen.sum(-1, keepdims=True)
+                                    + norm_eps)
     else:
         gates = scaling * chosen
     return experts.astype(jnp.int32), gates
@@ -128,13 +139,7 @@ def grouped_experts(x, experts, gates, w1, w3, w2, expert_offset,
     import jax.numpy as jnp
 
     t, k = experts.shape
-    held_n = w1.shape[0]
-    local = experts - expert_offset
-    held = (local >= 0) & (local < held_n)
-    # an assignment to an expert held elsewhere sorts past every group
-    flat = jnp.where(held, local, held_n).reshape(-1)
-    order = jnp.argsort(flat, stable=True)
-    sizes = jnp.bincount(flat, length=held_n + 1)[:held_n].astype(jnp.int32)
+    held, order, sizes = _sort_by_expert(experts, w1.shape[0], expert_offset)
     xs = x[order // k]
     f32 = jnp.float32
     a = jax.lax.ragged_dot(xs, w1, sizes, preferred_element_type=f32)
@@ -146,6 +151,81 @@ def grouped_experts(x, experts, gates, w1, w3, w2, expert_offset,
     # back to assignment order, then the k parts of a token add up
     y = y[jnp.argsort(order)].reshape(t, k, -1).sum(1)
     return y.astype(out_dtype or x.dtype), sizes
+
+
+def _sort_by_expert(experts, held_n, expert_offset):
+    """-> (held [T, k] bool: the assignment is to an expert held here,
+    order [T*k]: the assignments sorted by held expert, sizes int32
+    [E_held]: the rows of each group)."""
+    import jax.numpy as jnp
+
+    local = experts - expert_offset
+    held = (local >= 0) & (local < held_n)
+    # an assignment to an expert held elsewhere sorts past every group
+    flat = jnp.where(held, local, held_n).reshape(-1)
+    order = jnp.argsort(flat, stable=True)
+    sizes = jnp.bincount(flat, length=held_n + 1)[:held_n].astype(jnp.int32)
+    return held, order, sizes
+
+
+def grouped_experts_grad(x, experts, gates, w1, w3, w2, expert_offset, dout):
+    """The transposes of ``grouped_experts`` for the cotangent ``dout``
+    [T, H] of its sum: -> (dx [T, H] float32, dgates [T, k] float32, dW1,
+    dW3 [E_held, H, I], dW2 [E_held, I, H] in the weights' dtype). The
+    sort and the two up products are the forward's expressions again,
+    which the compiler shares where both passes are in one program (the
+    step compiled for a v5e runs 9 grouped products a layer, not 11, and
+    keeps the two float32 [T*k, I] products between the passes); their
+    SiLU, the gated product and the down product are not kept. The
+    products take ``x``'s dtype (bfloat16 under AMP) and accumulate in
+    float32. dX and
+    dW run over the held experts' groups alone
+    (``jax.lax.ragged_dot_general``): nothing of shape [T, E, I], no
+    assignment dropped. The gate's cotangent is <E_e(x), dout> computed
+    as <h, dout W2_e^T>, so the unweighted down product is never
+    formed."""
+    import jax
+    import jax.numpy as jnp
+
+    t, k = experts.shape
+    f32, dt = jnp.float32, x.dtype
+    held, order, sizes = _sort_by_expert(experts, w1.shape[0], expert_offset)
+    token = order // k
+    # rows past the last group belong to no held expert: what a grouped
+    # product leaves there is not defined, so they are zeroed
+    grouped = (jnp.arange(t * k) < sizes.sum())[:, None]
+    xs = x[token]
+    up = functools.partial(jax.lax.ragged_dot, group_sizes=sizes,
+                           preferred_element_type=f32)
+    a = jnp.where(grouped, up(xs, w1), 0.0)
+    b = jnp.where(grouped, up(xs, w3), 0.0)
+    sig = jax.nn.sigmoid(a)
+    silu = a * sig
+    h = silu * b
+    weight = jnp.where(held, gates, 0.0).reshape(-1)[order][:, None]
+    g = dout[token].astype(dt)
+    dh = jnp.where(grouped, up(g, jnp.swapaxes(w2, 1, 2)), 0.0)
+    dgates = (h * dh).sum(-1)
+    dh = dh * weight
+    da = (dh * b * sig * (1.0 + a * (1.0 - sig))).astype(dt)
+    db = (dh * silu).astype(dt)
+
+    def per_group(rows, cots, like):
+        """[E_held, rows' width, cots' width]: rows^T cots a group."""
+        dims = jax.lax.RaggedDotDimensionNumbers(
+            dot_dimension_numbers=(((0,), (0,)), ((), ())),
+            lhs_ragged_dimensions=(0,), rhs_group_dimensions=())
+        return jax.lax.ragged_dot_general(
+            rows, cots, sizes, dims,
+            preferred_element_type=f32).astype(like.dtype)
+
+    dw2 = per_group((h * weight).astype(dt), g, w2)
+    dw1, dw3 = per_group(xs, da, w1), per_group(xs, db, w3)
+    dxs = (up(da, jnp.swapaxes(w1, 1, 2)) + up(db, jnp.swapaxes(w3, 1, 2)))
+    back = jnp.argsort(order)
+    dx = jnp.where(grouped, dxs, 0.0)[back].reshape(t, k, -1).sum(1)
+    dgates = jnp.where(held, dgates[back].reshape(t, k), 0.0)
+    return dx, dgates, dw1, dw3, dw2
 
 
 def identity_experts(x, experts, gates, num_experts):
@@ -168,7 +248,28 @@ def _moe_ffn_infer(op_, block):
         set_out(op_, block, "ZeroCount", [1], "int32")
 
 
-@op("moe_ffn", infer_shape=_moe_ffn_infer)
+def _moe_route_attrs(op_):
+    return dict(k=int(op_.attr("experts_per_token")),
+                scaling=float(op_.attr("scaling", 1.0)),
+                scoring=op_.attr("scoring", "sigmoid"),
+                norm_topk=bool(op_.attr("norm_topk", True)),
+                norm_eps=float(op_.attr("norm_eps", 1e-20)))
+
+
+def _moe_ffn_grad_maker(op_):
+    """``moe_ffn_grad``: the forward's inputs and ``Out@GRAD`` in,
+    gradients of ``X``, ``RouterW`` and the three expert stacks out. The
+    choice is not differentiable: ``RouterBias`` gets no gradient, and
+    ``Counts`` / ``ZeroCount`` carry none back."""
+    spec = generic_grad_maker(op_)[0]
+    for slot in ("Out", "Counts", "ZeroCount", "Counts" + GRAD_SUFFIX,
+                 "ZeroCount" + GRAD_SUFFIX):
+        spec["inputs"].pop(slot, None)
+    spec["outputs"].pop("RouterBias" + GRAD_SUFFIX, None)
+    return [spec]
+
+
+@op("moe_ffn", infer_shape=_moe_ffn_infer, grad=_moe_ffn_grad_maker)
 def _moe_ffn(ctx, op_):
     """The routed part of a sparse expert layer. ``X`` [..., H] tokens,
     ``RouterW`` [H, num_experts], ``RouterBias`` [num_experts] (added to
@@ -190,9 +291,11 @@ def _moe_ffn(ctx, op_):
     grouped product; their part is computed for EVERY token given, as a
     shared expert's would be (under expert parallelism a token's home
     shard adds it, no exchange), and ``ZeroCount`` int32 [1] gives the
-    assignments that went to them. Inference only: no grad op is
-    registered (training needs one for the grouped product and the
-    router)."""
+    assignments that went to them; ``norm_eps`` (1e-20) is what the
+    renormalisation adds to the chosen scores' sum. The gradient is
+    ``moe_ffn_grad``: of ``X``, ``RouterW`` (through the scores and the
+    gates) and the held experts' stacks; the choice, ``RouterBias`` and
+    the counts take none."""
     import jax
     import jax.numpy as jnp
 
@@ -202,10 +305,7 @@ def _moe_ffn(ctx, op_):
         x2 = x.reshape(-1, x.shape[-1])
         experts, gates = route(
             x2, ctx.in1(op_, "RouterW"), ctx.in1(op_, "RouterBias"),
-            int(op_.attr("experts_per_token")),
-            float(op_.attr("scaling", 1.0)),
-            scoring=op_.attr("scoring", "sigmoid"),
-            norm_topk=bool(op_.attr("norm_topk", True)))
+            **_moe_route_attrs(op_))
         y, counts = grouped_experts(
             x2, experts, gates, ctx.in1(op_, "W1"), ctx.in1(op_, "W3"),
             ctx.in1(op_, "W2"), int(op_.attr("expert_offset", 0)),
@@ -216,6 +316,43 @@ def _moe_ffn(ctx, op_):
             ctx.out(op_, "ZeroCount", zero_count.reshape(1))
     ctx.out(op_, "Out", y.reshape(x.shape))
     ctx.out(op_, "Counts", counts)
+
+
+@op("moe_ffn_grad")
+def _moe_ffn_grad(ctx, op_):
+    """The gradient of ``moe_ffn`` from its inputs and ``Out@GRAD``:
+    ``route`` again (its vjp gives dX and dRouterW from the gates'
+    cotangent) and ``grouped_experts_grad``. A lowering of its own and
+    not the vjp of the forward's: that one keeps, beside the float32
+    [T*k, I] products of both up projections, their SiLU, the gated
+    product and the unweighted [T*k, H] down product from the forward
+    pass to the backward (1.5 GB more temporaries in the step of
+    ``lfm2-8b-a1b`` at 8,192 tokens: PERF.md, PR 44)."""
+    import jax
+    import jax.numpy as jnp
+
+    if int(op_.attr("zero_experts", 0)):
+        raise NotImplementedError(
+            "moe_ffn_grad: identity experts (zero_experts) are not "
+            "differentiated; no trained model has them")
+    x, dout = ctx.in1(op_, "X"), ctx.in1(op_, "Out" + GRAD_SUFFIX)
+    wg, bias = ctx.in1(op_, "RouterW"), ctx.in1(op_, "RouterBias")
+    w1, w3, w2 = (ctx.in1(op_, n) for n in ("W1", "W3", "W2"))
+    attrs = _moe_route_attrs(op_)
+    with jax.named_scope("moe_ffn_grad"):
+        x2 = x.reshape(-1, x.shape[-1])
+        gates, route_vjp, experts = jax.vjp(
+            lambda x_, wg_: route(x_, wg_, bias, **attrs)[::-1],
+            x2, wg, has_aux=True)
+        dx, dgates, dw1, dw3, dw2 = grouped_experts_grad(
+            x2, experts, gates, w1, w3, w2,
+            int(op_.attr("expert_offset", 0)),
+            dout.reshape(-1, dout.shape[-1]))
+        dx_route, dwg = route_vjp(dgates)
+        dx = (dx + dx_route.astype(jnp.float32)).astype(x.dtype)
+    for slot, grad in (("X", dx.reshape(x.shape)), ("RouterW", dwg),
+                       ("W1", dw1), ("W3", dw3), ("W2", dw2)):
+        ctx.out(op_, slot + GRAD_SUFFIX, grad)
 
 
 def mla_window(q, rows, wkvb, qpos, heads, nope, rope, vdim):
@@ -377,6 +514,37 @@ def short_conv(x, tail, w):
     t = x.shape[0]
     y = sum(w[j].astype(f32)[None, :] * padded[j:j + t] for j in range(taps))
     return y, padded
+
+
+def _gated_short_conv_infer(op_, block):
+    x = in_var(op_, block, "X")
+    set_out(op_, block, "Out", list(x.shape[:-1]) + [x.shape[-1] // 3],
+            x.dtype)
+
+
+@op("gated_short_conv", infer_shape=_gated_short_conv_infer, grad="generic")
+def _gated_short_conv(ctx, op_):
+    """The mixing of a gated short-convolution layer over whole
+    sequences: ``X`` [N, T, 3*C] is ``B ‖ C ‖ x`` (one input projection),
+    ``ConvW`` [K, C] the taps of a depthwise causal convolution over time
+    (``short_conv``: tap K-1 on the current row, zeros before the
+    sequence, no bias). ``Out = C * conv(B * x)`` [N, T, C], float32
+    inside, in ``X``'s dtype."""
+    import jax
+    import jax.numpy as jnp
+
+    x, w = ctx.in1(op_, "X"), ctx.in1(op_, "ConvW")
+    c = x.shape[-1] // 3
+    tail = jnp.zeros((w.shape[0] - 1, c), jnp.float32)
+
+    def one(row):
+        row = row.astype(jnp.float32)
+        y, _padded = short_conv(row[:, :c] * row[:, 2 * c:], tail, w)
+        return row[:, c:2 * c] * y
+
+    with jax.named_scope("short_conv"):
+        out = jax.vmap(one)(x).astype(x.dtype)
+    ctx.out(op_, "Out", out)
 
 
 def kda_inputs(y, f, bt, a_log, dt_bias, heads, head_dim):

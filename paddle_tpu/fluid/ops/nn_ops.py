@@ -923,11 +923,42 @@ def _shard_seed(seed, mesh, lead):
     return seed + idx
 
 
+def _repeat_key_heads(x, heads):
+    """Grouped queries: ``x`` [B, kv_heads, S, D] with fewer heads than
+    the queries' ``heads`` is repeated so that query head h reads key head
+    h // (heads / kv_heads); the kernels take as many key heads as query
+    heads. Under a gradient the repeat's transpose adds a key head's
+    dK/dV up over its group."""
+    import jax
+    import jax.numpy as jnp
+
+    if x.shape[1] == heads:
+        return x
+    with jax.named_scope("gqa_train"):
+        return jnp.repeat(x, heads // x.shape[1], axis=1)
+
+
+def _sum_key_heads(dx, kv_heads):
+    """The transpose of ``_repeat_key_heads``: [B, heads, S, D] ->
+    [B, kv_heads, S, D], float32 sums in ``dx``'s dtype."""
+    import jax
+    import jax.numpy as jnp
+
+    b, heads, s, d = dx.shape
+    if heads == kv_heads:
+        return dx
+    with jax.named_scope("gqa_train"):
+        return dx.reshape(b, kv_heads, heads // kv_heads, s, d).sum(
+            2, dtype=jnp.float32).astype(dx.dtype)
+
+
 @op("flash_attention", infer_shape=_flash_attention_infer, grad="generic")
 def _flash_attention(ctx, op_):
     """Online-softmax fused attention on [N, heads, S, d_head] inputs
     (paddle_tpu/kernels/flash_attention.py): the [S, S] score matrix never
-    touches HBM. Differentiable through the kernel's custom VJP, so the
+    touches HBM. ``K``/``V`` may hold fewer heads than ``Q`` (grouped
+    queries: a divisor of its count), see ``_repeat_key_heads``.
+    Differentiable through the kernel's custom VJP, so the
     generic grad maker Just Works."""
     from ...kernels.flash_attention import flash_attention_lse
 
@@ -956,6 +987,7 @@ def _flash_attention(ctx, op_):
             ctx.next_key(), (1, 1), 0, 1 << 23
         ).astype(jnp.float32)
     B, N = q.shape[:2]
+    k, v = _repeat_key_heads(k, N), _repeat_key_heads(v, N)
     mesh, lead = _shard_axes(B, N, interpret)
     if bias is not None and mesh is not None:
         raise NotImplementedError(
@@ -1170,6 +1202,8 @@ def _flash_attention_grad(ctx, op_):
     dout = ctx.in1(op_, "Out@GRAD")
     scale = op_.attr("scale", 0.0)
     B, N = q.shape[:2]
+    kv_heads = k.shape[1]
+    k, v = _repeat_key_heads(k, N), _repeat_key_heads(v, N)
     mesh, lead = _shard_axes(B, N, interpret)
     kb_in, kb_dims = _key_bias_dims(key_bias, B, N)
 
@@ -1193,8 +1227,8 @@ def _flash_attention_grad(ctx, op_):
     )
     dkb = dkb.reshape(B * N, -1)
     ctx.out(op_, "Q@GRAD", dq)
-    ctx.out(op_, "K@GRAD", dk)
-    ctx.out(op_, "V@GRAD", dv)
+    ctx.out(op_, "K@GRAD", _sum_key_heads(dk, kv_heads))
+    ctx.out(op_, "V@GRAD", _sum_key_heads(dv, kv_heads))
     kb_grad_names = [
         n for n in (op_.outputs.get("KeyBias@GRAD") or []) if n
     ]

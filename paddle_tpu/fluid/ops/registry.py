@@ -475,7 +475,11 @@ def _generic_grad_lower(ctx, op):
         og_names = op.inputs.get(slot + GRAD_SUFFIX, [])
         if og is None and idx < len(og_names):
             og = ctx.get_opt(og_names[idx])
-        if og is None:
+        if o is not None and not _is_float(o):
+            # an integer output (a count, an index) carries no gradient:
+            # its cotangent has the one type jax takes for it
+            og = np.zeros(o.shape, jax.dtypes.float0)
+        elif og is None:
             og = jnp.zeros_like(o) if o is not None else None
         cots.append(og)
 

@@ -359,6 +359,32 @@ case("flash_attention_kernel", op_type="flash_attention",
      attrs={"causal": True, "scale": 0.5, "interpret": True},
      tol=0.02)
 
+# -- the decoder ops a trained expert model needs (PR 44; tests/test_lfm2.py
+# holds each slot and the shares of the expert layer) ------------------------
+case("rms_norm", inputs={"X": U(190, (2, 3, 8)),
+                         "Scale": U(191, (8,), 0.5, 1.5)},
+     outputs={"Out": Z(2, 3, 8)}, attrs={"epsilon": 1e-5})
+case("rotary_embedding",
+     inputs={"X": U(192, (2, 3, 8)), "Pos": I(193, (2, 3), 0, 7)},
+     outputs={"Out": Z(2, 3, 8)},
+     attrs={"head_dim": 4, "rope_dim": 4, "theta": 100.0})
+case("swiglu", inputs={"Gate": U(194, (2, 3, 8)), "Up": U(195, (2, 3, 8))},
+     outputs={"Out": Z(2, 3, 8)})
+case("gated_short_conv",
+     inputs={"X": U(196, (2, 5, 12)), "ConvW": U(197, (3, 4))},
+     outputs={"Out": Z(2, 5, 4)})
+# a bias far apart fixes the top-3 choice (experts 1, 2 and 4 of 6; 1 and
+# 2 are held from offset 1): a finite difference crosses no boundary
+case("moe_ffn",
+     inputs={"X": U(198, (4, 8)), "RouterW": U(199, (8, 6)),
+             "RouterBias": np.array([0, 30, 20, 0.5, 10, 0.2], "float32"),
+             "W1": U(200, (3, 8, 5)), "W3": U(201, (3, 8, 5)),
+             "W2": U(202, (3, 5, 8))},
+     outputs={"Out": Z(4, 8), "Counts": np.zeros(3, "int32")},
+     outs=["Out"], check=["X", "RouterW", "W1", "W3", "W2"],
+     attrs={"num_experts": 6, "experts_per_token": 3, "expert_offset": 1,
+            "scaling": 1.5, "norm_eps": 1e-6})
+
 # -- ROI / deformable sampling (VERDICT r4 task 7: direct FD, kink-aware) ----
 # grads are checked wrt the FEATURE map (and learned offsets where smooth):
 # ROI-coordinate grads are excluded exactly as the reference's own tests do
